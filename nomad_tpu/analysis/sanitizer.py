@@ -118,6 +118,13 @@ class TraceCounter:
         with self._l:
             return {k: len(v) for k, v in sorted(self._seen.items())}
 
+    def signatures(self) -> Dict[str, set]:
+        """A copy of the signatures seen per kernel since the last
+        invalidation (chip_smoke.py diffs two of these to tell a new
+        count bucket from a new shape family)."""
+        with self._l:
+            return {k: set(v) for k, v in self._seen.items()}
+
     def invalidate(self) -> None:
         """The compiled caches were dropped: forget seen signatures so
         re-traces count as fresh compiles, keep the cumulative total."""

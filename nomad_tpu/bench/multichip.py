@@ -13,9 +13,10 @@ footprint the un-resident path would ship every eval.
 
 Run shape: the mesh needs 8 virtual CPU devices configured BEFORE jax
 initializes a backend, and bench.py has already initialized one — so
-`run_multichip_bench` drives this module's `main()` in a subprocess
-(the same isolation idiom as bench.py's accelerator probe) and parses
-its one JSON line.
+`run_multichip_bench` drives this module's `main()` in a child pinned
+to JAX_PLATFORMS=cpu and parses its one JSON line. The child never
+needs an accelerator; bench.py calls it only when its own platform is
+the CPU, so no key here is ever filed under a device's name.
 """
 
 from __future__ import annotations
@@ -150,8 +151,11 @@ def main() -> None:
     """Subprocess entry: force the 8-device virtual CPU platform
     BEFORE any backend initializes, run both arms, print ONE JSON
     line."""
-    from ..utils.platform import assert_cpu_devices, force_cpu_platform
+    from ..utils.platform import (assert_cpu_devices,
+                                  configure_compile_cache,
+                                  force_cpu_platform)
     force_cpu_platform(8)
+    configure_compile_cache()
     assert_cpu_devices(8)
     quick = os.environ.get("NOMAD_TPU_BENCH_QUICK", "") not in ("", "0")
     out = run_scenario(n_nodes=192 if quick else 1000,
@@ -162,24 +166,22 @@ def main() -> None:
 
 def run_multichip_bench(quick: bool = False,
                         timeout_s: float = 600.0) -> Dict:
-    """Drive main() in a subprocess (this process's jax backend is
-    already initialized single-device) and return its artifact keys;
-    failures land as multichip_error instead of a traceback."""
+    """Drive main() in a CPU-pinned child (this process's jax backend
+    is already initialized single-device) and return its artifact keys.
+    Raises when the child fails; bench.py records that as a failed
+    phase."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["NOMAD_TPU_BENCH_QUICK"] = "1" if quick else "0"
-    try:
-        res = subprocess.run(
-            [sys.executable, "-m", "nomad_tpu.bench.multichip"],
-            capture_output=True, text=True, timeout=timeout_s, env=env,
-            cwd=os.path.dirname(os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__)))))
-        if res.returncode != 0:
-            return {"multichip_error":
-                    f"rc={res.returncode}: {res.stderr[-500:]}"}
-        return json.loads(res.stdout.strip().splitlines()[-1])
-    except Exception as e:
-        return {"multichip_error": f"{type(e).__name__}: {e}"}
+    res = subprocess.run(
+        [sys.executable, "-m", "nomad_tpu.bench.multichip"],
+        capture_output=True, text=True, timeout=timeout_s, env=env,
+        cwd=os.path.dirname(os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__)))))
+    if res.returncode != 0:
+        raise RuntimeError(f"multichip child rc={res.returncode}: "
+                           f"{res.stderr[-500:]}")
+    return json.loads(res.stdout.strip().splitlines()[-1])
 
 
 if __name__ == "__main__":

@@ -1,11 +1,11 @@
 """Profile the C2M batch-eval path wall-to-wall (round-5 perf work).
 
-Usage: python profile_c2m.py [n_nodes] [seed_allocs]
-Env: NOMAD_TPU_PROFILE_CPU=1 to force CPU backend.
+Usage: python -m nomad_tpu.bench.profile_c2m [n_nodes] [seed_allocs]
+Runs on the ambient JAX backend (JAX_PLATFORMS=cpu for a CPU profile)
+and prints which one that was.
 """
 import cProfile
 import io
-import os
 import pstats
 import sys
 import time
@@ -17,14 +17,8 @@ def main():
     n_nodes = int(sys.argv[1]) if len(sys.argv) > 1 else 50000
     seed_allocs = int(sys.argv[2]) if len(sys.argv) > 2 else 0
 
-    if os.environ.get("NOMAD_TPU_PROFILE_CPU"):
-        from nomad_tpu.utils.platform import force_cpu_platform
-        force_cpu_platform(1)
-    else:
-        from nomad_tpu.utils.platform import force_cpu_platform, probe_accelerator
-        platform = probe_accelerator(timeout_s=120.0)
-        if platform is None or platform == "cpu":
-            force_cpu_platform(1)
+    from nomad_tpu.utils.platform import init_backend
+    print(f"backend: {init_backend()}", flush=True)
     from nomad_tpu.bench.ladder import (_eval_for, _seed_nodes,
                                         seed_c2m_allocs)
     from nomad_tpu.mock import fixtures as mock

@@ -274,7 +274,10 @@ def main() -> int:
     minutes = float(sys.argv[1]) if len(sys.argv) > 1 else 25.0
     n_nodes = int(sys.argv[2]) if len(sys.argv) > 2 else 50000
     seed = int(sys.argv[3]) if len(sys.argv) > 3 else 2_000_000
+    from ..utils.platform import init_backend
+    device = init_backend()
     out = run_soak(minutes, n_nodes, seed)
+    out.update(device)
     path = os.environ.get("NOMAD_TPU_SOAK_OUT") or os.path.join(
         os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__)))), "SOAK_r06.json")

@@ -95,28 +95,41 @@ class ClientConfig:
     stats_ring_slots: int = 128
 
 
+# sysfs PCI ids of Google TPU chips -> generation name
+_TPU_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICES = {"0x0027": "v3", "0x005e": "v4", "0x0062": "v5p",
+                    "0x0063": "v5e", "0x006f": "v6e"}
+
+
 def fingerprint_accelerator_devices():
-    """Detect locally attached JAX accelerators as a device group
-    (devices/gpu/nvidia/device.go Fingerprint, re-aimed at TPUs).
-    Returns [] when no accelerator backend is available."""
+    """Detect locally attached TPU chips as a device group
+    (devices/gpu/nvidia/device.go Fingerprint, re-aimed at TPUs)
+    WITHOUT opening them. A chip belongs to one process at a time: a
+    fingerprint that initialized a JAX backend — in the agent, or in
+    the device plugin's child while the agent's scheduler holds the
+    chip — would take it from the process that runs kernels on it, or
+    hang. The chips this host may use are its accel/vfio device nodes
+    (a host can show more PCI functions than it was handed); the PCI id
+    names the generation. Returns [] when there is none."""
+    import glob
     from ..models import NodeDevice, NodeDeviceResource
-    try:
-        import jax
-        if jax.default_backend() == "cpu":
-            return []
-        devs = jax.devices()
-    except Exception:
+    nodes = glob.glob("/dev/accel[0-9]*") + glob.glob("/dev/vfio/[0-9]*")
+    names = set()
+    for vendor_path in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        with open(vendor_path) as f:
+            if f.read().strip() != _TPU_PCI_VENDOR:
+                continue
+        with open(os.path.join(os.path.dirname(vendor_path),
+                               "device")) as f:
+            names.add(_TPU_PCI_DEVICES.get(f.read().strip()))
+    names.discard(None)
+    if not nodes or not names:
         return []
-    if not devs:
-        return []
-    kind = devs[0].platform            # "tpu" / "gpu"
-    name = getattr(devs[0], "device_kind", kind) or kind
     return [NodeDeviceResource(
-        vendor="google" if kind == "tpu" else "",
-        type=kind, name=str(name).replace(" ", "-").lower(),
-        attributes={"count": len(devs)},
-        instances=[NodeDevice(id=f"{kind}-{d.id}", healthy=True)
-                   for d in devs])]
+        vendor="google", type="tpu", name=sorted(names)[0],
+        attributes={"count": len(nodes)},
+        instances=[NodeDevice(id=f"tpu-{i}", healthy=True)
+                   for i in range(len(nodes))])]
 
 
 class TaskRunner:

@@ -1,11 +1,13 @@
 """Native runtime components.
 
 The reference keeps its wire codec compiled (go-msgpack + generated
-encoders); here codec.cpp is a CPython extension built on demand with
-g++ and loaded as `nomad_tpu_native_codec`. The build is cached beside
-the source keyed by source hash + python ABI; failures fall back to the
-pure-python msgpack package transparently (the wire format is
-identical, so mixed clusters interoperate).
+encoders); here codec.cpp and kway.cpp are CPython extensions built on
+demand with g++, from the tracked sources, on the machine that runs
+them. The build is cached beside the source (`_build/`, git-ignored;
+NOMAD_TPU_NATIVE_CACHE moves it) keyed by source hash + python ABI.
+A build or self-check failure logs a warning and the loader returns
+None: callers then run their pure-python path (same wire format, same
+merge order), and chip_smoke.py reports which modules loaded.
 
 NOMAD_TPU_NATIVE=0 disables the native path.
 """
@@ -32,9 +34,8 @@ def _cache_path(src: str, name: str) -> str:
     with open(src, "rb") as f:
         digest = hashlib.sha256(f.read()).hexdigest()[:16]
     abi = sysconfig.get_config_var("SOABI") or "abi3"
-    cache_dir = os.environ.get(
-        "NOMAD_TPU_NATIVE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "nomad-tpu"))
+    cache_dir = os.environ.get("NOMAD_TPU_NATIVE_CACHE",
+                               os.path.join(_HERE, "_build"))
     os.makedirs(cache_dir, exist_ok=True)
     return os.path.join(cache_dir, f"{name}-{digest}.{abi}.so")
 

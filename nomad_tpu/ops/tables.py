@@ -736,17 +736,18 @@ class NodeTableCache:
             t = self._table
         if t is None:
             return
+        # runs on the cold-start prefetch thread: a failed upload is
+        # counted and logged (the first eval then uploads in its own
+        # latency budget, or ships dense) instead of dying silently
+        from .device_table import note_device_op_failure
+        from .select import get_shared_sharded
         try:
             self.device.arrays_for(t)
-        except Exception:       # pragma: no cover — defensive: a dead
-            pass                # device falls back to dense shipping
-        try:
-            from .select import get_shared_sharded
             sh = get_shared_sharded()
             if sh is not None:
                 sh.resident.arrays_for(t)
-        except Exception:       # pragma: no cover — defensive: the
-            pass                # mesh path falls back to dense shipping
+        except Exception:
+            note_device_op_failure("table_cache.prefetch_device")
 
     def fold_mesh(self) -> dict:
         """Reclaim for the governor's mesh.reshard_debt watermark:

@@ -16,6 +16,7 @@ ask vectors and result placements (small), never the node table.
 
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 import jax
@@ -23,7 +24,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.select import (PACK_SHARD_KINDS, SelectRequest, _bucket_k,
-                          _select_scan, pack_request, unpack_result)
+                          _note_trace, _select_scan, cost_model,
+                          pack_request, unpack_result)
 from .sharded_table import (ShardedDeviceNodeTable, pad_for_mesh,
                             resident_enabled)
 
@@ -79,6 +81,7 @@ class ShardedSelect:
         mesh-resident table instead of crossing the bus."""
         n_pad = self.pad_to_shards(len(req.feasible))
         k = _bucket_k(max(req.count, 1))
+        t0 = time.perf_counter()
         args, statics = pack_request(req, n_pad)
         resident = self.resident_args(req, n_pad)
         placed_args = {}
@@ -93,9 +96,15 @@ class ShardedSelect:
             sharding = self._sharding_for(PACK_SHARD_KINDS[name])
             placed_args[name] = (value if sharding is None
                                  else jax.device_put(value, sharding))
+        fresh = _note_trace("scan@mesh", n_pad, k_steps=k, **statics)
         with self.mesh:
             _carry, outs = _select_scan(**placed_args, k_steps=k, **statics)
-        return unpack_result(req, outs)
+        out = unpack_result(req, outs)
+        # per-arm device stats only (window: pack + sharded placement +
+        # dispatch + unpack); no routing estimate reads an @mesh arm
+        cost_model.observe("scan@mesh", n_pad, time.perf_counter() - t0,
+                           compiled=fresh)
+        return out
 
     def resident_args(self, req: SelectRequest,
                       n_pad: int) -> Optional[dict]:

@@ -51,7 +51,7 @@ import numpy as np
 from ..utils.locks import make_lock
 from ..ops.device_table import (DeviceTableState, SPARSE_MAX_FRAC,
                                 _bucket_rows, _overlay_add, _scatter_set,
-                                enable_row_journal)
+                                enable_row_journal, note_device_op_failure)
 
 MESH_RESIDENT_ENV = "NOMAD_TPU_MESH_RESIDENT"
 
@@ -155,10 +155,11 @@ class ShardedDeviceNodeTable:
             if len(rows):
                 try:
                     st = self._scatter_locked(st, table, rows)
-                except Exception:   # pragma: no cover — defensive: a
-                    # failed device op must not poison scheduling
+                except Exception:
+                    # a failed device op must not poison scheduling —
+                    # counted as a failure, not as a stale miss
+                    note_device_op_failure("sharded_table.scatter")
                     self._state = None
-                    self.stats["stale_misses"] += 1
                     return None
                 self._state = st
             self._version = token

@@ -26,9 +26,12 @@ LOG = logging.getLogger("nomad_tpu.plugins.device")
 
 
 class AcceleratorDevicePlugin:
-    """In-proc implementation served by the plugin process: JAX
-    accelerator fingerprint + reservation env + runtime stats
-    (devices/gpu/nvidia/device.go re-aimed at TPUs)."""
+    """In-proc implementation served by the plugin process:
+    accelerator fingerprint + reservation env + health stats
+    (devices/gpu/nvidia/device.go re-aimed at TPUs). The plugin is a
+    child of the agent, whose scheduler may hold the chip, so nothing
+    here initializes a JAX backend: devices are enumerated from the OS
+    (client.agent.fingerprint_accelerator_devices)."""
 
     name = "accelerator"
     CONFIG_SPEC: Dict = {}
@@ -48,25 +51,13 @@ class AcceleratorDevicePlugin:
         }}
 
     def stats(self) -> List[Dict]:
-        try:
-            import jax
-            if jax.default_backend() == "cpu":
-                return []
-            out = []
-            for d in jax.devices():
-                entry = {"id": f"{d.platform}-{d.id}", "healthy": True}
-                try:
-                    ms = d.memory_stats()
-                    entry["memory_used_bytes"] = \
-                        int(ms.get("bytes_in_use", 0))
-                    entry["memory_limit_bytes"] = \
-                        int(ms.get("bytes_limit", 0))
-                except Exception:
-                    pass
-                out.append(entry)
-            return out
-        except Exception:
-            return []
+        """One health row per fingerprinted instance. Memory use is
+        only readable by the process that holds the chip, so it is not
+        reported from here."""
+        from ..client.agent import fingerprint_accelerator_devices
+        return [{"id": inst.id, "healthy": inst.healthy}
+                for g in fingerprint_accelerator_devices()
+                for inst in g.instances]
 
 
 DEVICE_PLUGIN_CATALOG = {

@@ -202,8 +202,8 @@ class ServerConfig:
     # for up to this window and ship as ONE vmapped padded device call.
     # The live window adapts off the per-lane arrival-rate EWMA
     # (idle lanes dispatch immediately) and queue depth (see
-    # governor_gateway_depth_high); over a tunneled accelerator the
-    # base widens to half the measured RTT. 0 disables the gateway
+    # governor_gateway_depth_high); on an accelerator backend the
+    # base widens to half the measured round trip. 0 disables the gateway
     # entirely (exactly the pre-gateway dispatch path);
     # NOMAD_TPU_MICROBATCH=0 is the runtime kill switch
     gateway_window_us: int = 2000
@@ -533,8 +533,9 @@ class Server:
                 # replaced on snapshot restore)
                 extra_fn=self._telemetry_extra)
         self.workers: List[Worker] = []
-        self._heartbeat_timers: Dict[str, threading.Timer] = {}
-        self._hb_lock = make_lock()
+        # node TTL timers: one heap on one thread (server/heartbeat.py)
+        from .heartbeat import HeartbeatTimers
+        self._heartbeats = HeartbeatTimers(self._invalidate_heartbeat)
         # per-node host-stats payloads carried by heartbeats (ISSUE
         # 13): node_id -> {payload..., received_at}; folded into the
         # cluster.* rollup by cluster_stats(), pruned when the node
@@ -694,14 +695,11 @@ class Server:
             # nodes yet == nothing to calibrate; benches with
             # programmatic node seeding call calibrate_cost_model
             # themselves after seeding)
-            try:
-                n = self.store.node_count()
-                if n >= 8:
-                    from ..ops.select import calibrate_cost_model
-                    calibrate_cost_model(
-                        n, lanes=self.config.gateway_min_batch)
-            except Exception:   # pragma: no cover — best effort
-                LOG.exception("dispatch calibration failed")
+            n = self.store.node_count()
+            if n >= 8:
+                from ..ops.select import calibrate_cost_model
+                calibrate_cost_model(
+                    n, lanes=self.config.gateway_min_batch)
 
     def _register_governor_gauges(self) -> None:
         """Wire every long-lived structure into the governor's
@@ -1175,10 +1173,7 @@ class Server:
         self.deployments_watcher.set_enabled(False)
         self.node_drainer.set_enabled(False)
         self.event_sinks.set_enabled(False)
-        with self._hb_lock:
-            for t in self._heartbeat_timers.values():
-                t.cancel()
-            self._heartbeat_timers.clear()
+        self._heartbeats.clear()
 
     def scheduler_plane_status(self) -> dict:
         """Per-member scheduler-plane status for `nomad server
@@ -1344,10 +1339,7 @@ class Server:
         self.eval_broker.set_enabled(False)
         self.blocked_evals.set_enabled(False)
         self.plan_queue.set_enabled(False)
-        with self._hb_lock:
-            for t in self._heartbeat_timers.values():
-                t.cancel()
-            self._heartbeat_timers.clear()
+        self._heartbeats.stop()
 
     def establish_leadership(self) -> None:
         """leader.go establishLeadership:222."""
@@ -3030,15 +3022,7 @@ class Server:
     def reset_heartbeat_timer(self, node_id: str) -> None:
         if self.raft is not None and not self._leader:
             return              # TTL timers are leader-only (heartbeat.go)
-        with self._hb_lock:
-            existing = self._heartbeat_timers.pop(node_id, None)
-            if existing is not None:
-                existing.cancel()
-            t = threading.Timer(self.config.heartbeat_ttl_s,
-                                self._invalidate_heartbeat, args=(node_id,))
-            t.daemon = True
-            self._heartbeat_timers[node_id] = t
-            t.start()
+        self._heartbeats.reset(node_id, self.config.heartbeat_ttl_s)
 
     def _invalidate_heartbeat(self, node_id: str) -> None:
         node = self.store.node_by_id(node_id)
