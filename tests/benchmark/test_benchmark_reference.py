@@ -1,0 +1,414 @@
+"""The plain reference: each guarantee's checker rejects what breaks it,
+and the controls come out as not correct."""
+import copy
+import json
+import os
+import random
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.lib import fleet as fleetlib  # noqa: E402
+from benchmark.lib import reference as ref  # noqa: E402
+from benchmark.lib import traffic  # noqa: E402
+
+PORTS = (20000, 32000)
+
+
+def load(*parts):
+    with open(os.path.join(ROOT, "benchmark", *parts)) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return load("configs", "prod-10k.json")
+
+
+@pytest.fixture(scope="module")
+def world(cfg):
+    fleet = fleetlib.build_fleet(cfg, 3, 320)
+    return fleet, fleetlib.backlog_usage(cfg, fleet)
+
+
+@pytest.fixture(scope="module")
+def mixed(cfg):
+    """A fleet that holds every machine class (the first 320 ordinals
+    are all of the smallest)."""
+    fleet = fleetlib.build_fleet(cfg, 3, 1280)
+    return fleet, fleetlib.backlog_usage(cfg, fleet)
+
+
+def jobs_of(mix_name, counts, seed=1):
+    mix = load("traffic", mix_name + ".json")
+    dcs = ["dc1", "dc2", "dc3", "dc4"]
+    return [traffic.plain_job(mix, f"{mix_name}-{seed}-{i}", c, dcs)
+            for i, c in enumerate(counts)]
+
+
+def answered(world, jobs, broken=None):
+    fleet, backlog = world
+    plain = ref.PlainScheduler(fleet, backlog, PORTS, broken=broken)
+    for j in jobs:
+        plain.submit(j)
+    return plain
+
+
+def judged(world, jobs, plain):
+    fleet, backlog = world
+    return ref.judge(fleet, backlog, jobs, plain.evals, plain.allocs,
+                     list(plain.full.values()), [], PORTS, 2)
+
+
+# -- the fleet -----------------------------------------------------------
+
+def test_fleet_shape_is_the_same_for_every_seed(cfg):
+    def shape(seed):
+        return sorted((n["name"], n["datacenter"], n["meta"]["rack"],
+                       n["class"]) for n in fleetlib.build_fleet(cfg, seed, 640))
+    assert shape(1) == shape(2)
+    ids1 = [n["id"] for n in fleetlib.build_fleet(cfg, 1, 640)]
+    assert ids1 == sorted(ids1)
+    assert ids1 != [n["id"] for n in fleetlib.build_fleet(cfg, 2, 640)]
+
+
+def test_fleet_classes_come_in_the_configured_tenths(cfg):
+    fleet = fleetlib.build_fleet(cfg, 1, 6400)
+    share = {c: sum(1 for n in fleet if n["class"] == c) / len(fleet)
+             for c in ("c1x", "c2x", "c4x")}
+    assert share == {"c1x": 0.6, "c2x": 0.3, "c4x": 0.1}
+    big = next(n for n in fleet if n["class"] == "c4x")
+    assert big["capacity"]["cpu"] == 4 * 4000 - 100
+    assert big["capacity"]["memory_mb"] == 4 * 8192 - 256
+
+
+def test_every_rack_of_every_datacenter_holds_every_class(cfg):
+    fleet = fleetlib.build_fleet(cfg, 1, 10000)
+    seen = {(n["datacenter"], n["meta"]["rack"], n["class"]) for n in fleet}
+    assert len(seen) == 4 * 16 * 3
+
+
+def test_backlog_leaves_the_room_the_mixes_count_on(cfg, world):
+    fleet, backlog = world
+    small = next(n for n in fleet if n["class"] == "c1x")
+    free_cpu = small["capacity"]["cpu"] - backlog[small["id"]]["cpu"]
+    assert free_cpu // 20 == 95 and free_cpu // 250 == 7
+
+
+# -- the checkers reject what breaks each guarantee ------------------------
+
+def test_whole_reference_is_correct(world):
+    jobs = jobs_of("service-stream", [1, 2, 3, 5, 10, 20, 50])
+    compared, found = judged(world, jobs, answered(world, jobs))
+    assert ref.is_correct(compared), found
+    assert set(compared) == set(ref.LIMITS)
+    assert all(c["limit"] == 0 for name, c in compared.items()
+               if name != "rank_gap")
+    assert ref.TIE_EPS < compared["rank_gap"]["limit"] < 0.4
+
+
+@pytest.mark.parametrize("broken,number,mix,counts", [
+    ("capacity", "over_capacity", "service-stream", [50, 50, 50, 50]),
+    ("capacity", "over_capacity", "batch-fill", [1000, 1000]),
+    ("constraints", "infeasible", "service-stream", [50] * 7),
+    ("lose", "lost_or_duplicated", "service-stream", [5, 5, 5, 5]),
+    ("lose", "lost_or_duplicated", "batch-fill", [1000]),
+    ("ports", "port_conflicts", "service-stream", [50, 50]),
+    ("norank", "rank_gap", "batch-fill", [100, 100]),
+])
+def test_control_is_not_correct(world, mixed, broken, number, mix, counts):
+    if broken == "norank":
+        world = mixed
+    jobs = jobs_of(mix, counts)
+    compared, found = judged(world, jobs, answered(world, jobs, broken))
+    assert not ref.is_correct(compared)
+    assert compared[number]["value"] > compared[number]["limit"], found
+    others = {k: v["value"] for k, v in compared.items() if k != number}
+    assert not any(others.values()), others
+
+
+def test_first_fit_stacks_a_job_and_ranks_nothing(mixed):
+    world = mixed
+    jobs = jobs_of("batch-fill", [100, 100])
+    compared, found = judged(world, jobs, answered(world, jobs, "firstfit"))
+    assert compared["stacked"]["value"] == 2, found
+    assert compared["rank_gap"]["value"] > compared["rank_gap"]["limit"]
+    guarantees = {k: v["value"] for k, v in compared.items()
+                  if k not in ("stacked", "rank_gap")}
+    assert not any(guarantees.values()), guarantees
+
+
+def test_unknown_control_is_refused(world):
+    with pytest.raises(ValueError):
+        ref.PlainScheduler(world[0], world[1], PORTS, broken="speed")
+
+
+def _one(world):
+    jobs = jobs_of("service-stream", [5])
+    return jobs, answered(world, jobs)
+
+
+def test_missing_placement_is_rejected(world):
+    jobs, plain = _one(world)
+    plain.allocs[jobs[0]["id"]].pop()
+    assert ref.check_committed(jobs, plain.allocs)
+
+
+def test_duplicate_placement_is_rejected(world):
+    jobs, plain = _one(world)
+    rows = plain.allocs[jobs[0]["id"]]
+    rows.append(dict(rows[0], id="other-id"))
+    assert "duplicated" in ref.check_committed(jobs, plain.allocs)[0]
+
+
+def test_alloc_id_twice_is_rejected(world):
+    jobs, plain = _one(world)
+    rows = plain.allocs[jobs[0]["id"]]
+    rows[1] = dict(rows[1], id=rows[0]["id"])
+    assert any("twice" in b for b in ref.check_committed(jobs, plain.allocs))
+
+
+def test_stopped_alloc_is_rejected(world):
+    jobs, plain = _one(world)
+    plain.allocs[jobs[0]["id"]][0]["desired_status"] = "stop"
+    assert ref.check_committed(jobs, plain.allocs)
+
+
+def test_over_commit_is_rejected(world):
+    fleet, backlog = world
+    jobs = jobs_of("batch-fill", [1000])
+    node = next(n for n in fleet if n["class"] == "c1x")
+    allocs = {jobs[0]["id"]: [{"id": f"a{i}", "node_id": node["id"],
+                               "name": f"x[{i}]"} for i in range(96)]}
+    bad = ref.check_capacity(fleet, ref.node_usage(backlog, jobs, allocs))
+    assert len(bad) == 1 and "cpu" in bad[0]
+    allocs[jobs[0]["id"]].pop()
+    assert not ref.check_capacity(fleet,
+                                  ref.node_usage(backlog, jobs, allocs))
+
+
+def test_infeasible_placement_is_rejected(world):
+    fleet, _ = world
+    jobs = jobs_of("service-stream", [1])
+    wrong = next(n for n in fleet if n["meta"]["rack"] == "r12")
+    right = next(n for n in fleet if n["meta"]["rack"] == "r3")
+    mk = lambda n: {jobs[0]["id"]: [{"id": "a", "node_id": n["id"],  # noqa
+                                     "name": "x[0]"}]}
+    assert "regexp" in ref.check_feasible(fleet, jobs, mk(wrong))[0]
+    assert not ref.check_feasible(fleet, jobs, mk(right))
+    assert "unknown node" in ref.check_feasible(
+        fleet, jobs, mk({"id": "no-such-node"}))[0]
+
+
+def test_wrong_datacenter_and_driver_are_rejected(world):
+    fleet, _ = world
+    job = jobs_of("service-stream", [1])[0]
+    node = next(n for n in fleet if n["meta"]["rack"] == "r3")
+    assert not ref.node_feasible(node, job)
+    assert ref.node_feasible(node, dict(job, datacenters=["dc9"]))
+    assert ref.node_feasible(node, dict(job, driver="docker"))
+
+
+@pytest.mark.parametrize("ports,bad", [
+    ([[20000, 20001], [20002, 20003]], 0),
+    ([[20000, 20001], [20001, 20003]], 1),
+    ([[19999, 20001]], 1),
+    ([[32001, 20001]], 1),
+])
+def test_port_clash_and_range_are_rejected(ports, bad):
+    full = [{"name": f"a{i}", "node_id": "n1", "allocated_resources": {
+        "tasks": {"web": {"networks": [{"dynamic_ports": [
+            {"label": "p", "value": v} for v in vals]}]}}}}
+        for i, vals in enumerate(ports)]
+    assert len(ref.check_ports(full, PORTS)) == bad
+
+
+def test_same_port_on_two_nodes_is_fine():
+    full = [{"name": f"a{i}", "node_id": f"n{i}", "allocated_resources": {
+        "tasks": {"web": {"networks": [{"dynamic_ports": [
+            {"label": "p", "value": 20000}]}]}}}} for i in range(2)]
+    assert not ref.check_ports(full, PORTS)
+
+
+@pytest.mark.parametrize("ev,never,unplaced", [
+    (None, 1, 0),
+    ({"status": "pending"}, 1, 0),
+    ({"status": "complete", "failed_tg_allocs": {}}, 0, 0),
+    ({"status": "complete", "failed_tg_allocs": {"web": {}}}, 0, 1),
+    ({"status": "complete", "blocked_eval": "abc"}, 0, 1),
+    ({"status": "failed"}, 0, 1),
+])
+def test_evals_are_judged_by_what_they_say(ev, never, unplaced):
+    jobs = jobs_of("service-stream", [1])
+    n, u = ref.check_evals(jobs, {jobs[0]["id"]: ev} if ev else {})
+    assert (len(n), len(u)) == (never, unplaced)
+
+
+def test_port_sample_takes_whole_nodes_within_budget(world):
+    jobs = jobs_of("service-stream", [50, 50, 20])
+    plain = answered(world, jobs)
+    ids = ref.port_sample(world[0], jobs, plain.allocs, 30,
+                          random.Random(1))
+    assert 0 < len(ids) <= 30
+    by_node = {}
+    for rows in plain.allocs.values():
+        for a in rows:
+            by_node.setdefault(a["node_id"], []).append(a["id"])
+    picked = set(ids)
+    for node_ids in by_node.values():
+        assert not picked & set(node_ids) or set(node_ids) <= picked
+
+
+# -- the ranking -----------------------------------------------------------
+
+def test_greedy_prefers_the_affinity_rack_and_spreads_datacenters(world):
+    fleet, backlog = world
+    job = jobs_of("service-stream", [10])[0]
+    nodes = ref.PlainScorer(fleet, job, backlog).greedy(10)
+    assert all(n["meta"]["rack"] == "r3" for n in nodes)
+    assert len({n["id"] for n in nodes}) == 10      # job anti-affinity
+    per_dc = {}
+    for n in nodes:
+        per_dc[n["datacenter"]] = per_dc.get(n["datacenter"], 0) + 1
+    assert len(per_dc) == 4 and max(per_dc.values()) <= 4
+
+
+def test_batch_greedy_packs_small_nodes_and_spreads_the_job(world):
+    fleet, backlog = world
+    job = jobs_of("batch-fill", [200])[0]
+    nodes = ref.PlainScorer(fleet, job, backlog).greedy(200)
+    # bin-packing prefers the fullest (smallest) class; the job's own
+    # anti-affinity sends each placement to a node it is not on yet
+    assert nodes[0]["class"] == "c1x"
+    assert len({n["id"] for n in nodes}) == 200
+
+
+def test_heap_greedy_equals_rescoring_every_node(world):
+    fleet, backlog = world
+    job = jobs_of("batch-fill", [120])[0]
+    fast = [n["name"] for n in
+            ref.PlainScorer(fleet, job, backlog).greedy(120)]
+    slow_scorer = ref.PlainScorer(fleet, job, backlog)
+    slow = []
+    for _ in range(120):
+        best = max((n for n in slow_scorer.nodes if slow_scorer.fits(n)),
+                   key=lambda n: (slow_scorer.score(n),
+                                  -slow_scorer.nodes.index(n)))
+        slow_scorer.place(best)
+        slow.append(best["name"])
+    assert fast == slow
+
+
+def test_plain_scheduler_carries_usage_from_job_to_job(world):
+    jobs = jobs_of("batch-fill", [1, 1])
+    plain = answered(world, jobs)
+    a = plain.allocs[jobs[0]["id"]][0]["node_id"]
+    b = plain.allocs[jobs[1]["id"]][0]["node_id"]
+    # the second job sees the first one's placement: that node is now
+    # the fullest, and a new job has no anti-affinity against it
+    assert a == b
+    assert plain.used[a]["cpu"] == 2000 + 2 * 20
+
+
+def _batch_answer(world, counts):
+    jobs = jobs_of("batch-fill", counts)
+    return jobs, answered(world, jobs)
+
+
+def test_rank_gap_is_nought_for_the_reference_s_own_greedy(world):
+    fleet, backlog = world
+    jobs, plain = _batch_answer(world, [100, 60, 100])
+    stacked, gap, widest = ref.check_rank(fleet, backlog, jobs,
+                                          plain.allocs, 1)
+    assert (stacked, gap, widest) == ([], 0.0, [])
+
+
+def test_rank_gap_reads_the_score_a_worse_node_gives_away(mixed):
+    fleet, backlog = mixed
+    jobs, plain = _batch_answer(mixed, [100])
+    taken = {a["node_id"] for a in plain.allocs[jobs[0]["id"]]}
+    big = next(n for n in fleet if n["class"] == "c4x"
+               and n["id"] not in taken)
+    plain.allocs[jobs[0]["id"]][7]["node_id"] = big["id"]
+    _stacked, gap, widest = ref.check_rank(fleet, backlog, jobs,
+                                           plain.allocs, 1)
+    scorer = ref.PlainScorer(fleet, jobs[0], backlog)
+    small = next(n for n in fleet if n["class"] == "c1x")
+    assert gap == pytest.approx(scorer.score(small) - scorer.score(big))
+    assert len(widest) == 1 and jobs[0]["id"] in widest[0]
+
+
+def test_rank_gap_allows_each_lane_its_own_best(world):
+    fleet, backlog = world
+    jobs, plain = _batch_answer(world, [50, 50])
+    # the second job as a second scheduler would place it: on untouched
+    # small nodes instead of the 50 the first job has just raised
+    first = {a["node_id"] for a in plain.allocs[jobs[0]["id"]]}
+    fresh = [n for n in fleet if n["class"] == "c1x"
+             and n["id"] not in first]
+    for a, n in zip(plain.allocs[jobs[1]["id"]], fresh):
+        a["node_id"] = n["id"]
+    one = ref.check_rank(fleet, backlog, jobs, plain.allocs, 1)[1]
+    two = ref.check_rank(fleet, backlog, jobs, plain.allocs, 2)[1]
+    assert one > ref.TIE_EPS and two == 0.0
+
+
+def test_rank_follows_the_store_s_commit_order(world):
+    fleet, backlog = world
+    jobs, plain = _batch_answer(world, [50, 50])
+    assert ref.check_rank(fleet, backlog, jobs, plain.allocs, 1)[1] == 0.0
+    # read back in the other order, the create_index still says which
+    # plan saw which
+    assert ref.check_rank(fleet, backlog, jobs[::-1], plain.allocs,
+                          1)[1] == 0.0
+
+
+def test_stacking_is_a_fault_only_where_the_reference_would_not(world):
+    fleet, backlog = world
+    jobs, plain = _batch_answer(world, [40])
+    got = plain.allocs[jobs[0]["id"]]
+    got[1]["node_id"] = got[0]["node_id"]
+    stacked, _gap, _w = ref.check_rank(fleet, backlog, jobs, plain.allocs, 1)
+    assert len(stacked) == 1 and "40 allocs on 39 nodes" in stacked[0]
+    # more instances than the fleet has nodes: stacking is the answer
+    jobs, plain = _batch_answer(world, [400])
+    assert len({a["node_id"] for a in plain.allocs[jobs[0]["id"]]}) < 400
+    assert ref.check_rank(fleet, backlog, jobs, plain.allocs, 1)[0] == []
+
+
+def test_spread_over_its_target_is_rejected(world):
+    fleet, backlog = world
+    jobs = jobs_of("service-stream", [10])
+    plain = answered(world, jobs)
+    assert ref.check_spread(fleet, jobs, plain.allocs) == []
+    dc1 = next(n for n in fleet if n["datacenter"] == "dc1")
+    for a in plain.allocs[jobs[0]["id"]][:5]:
+        a["node_id"] = dc1["id"]        # 40% of 10 allows 4 on dc1
+    bad = ref.check_spread(fleet, jobs, plain.allocs)
+    assert len(bad) == 1 and "on dc1" in bad[0]
+
+
+def test_binpack_scores_equal_the_plain_scorer_s(world):
+    import numpy as np
+    fleet, backlog = world
+    job = jobs_of("batch-fill", [1])[0]
+    scorer = ref.PlainScorer(fleet, job, backlog)
+    cap = np.array([[n["capacity"][d] for d in fleetlib.DIMS]
+                    for n in fleet], dtype=float)
+    used = np.array([[backlog[n["id"]][d] for d in fleetlib.DIMS]
+                     for n in fleet], dtype=float)
+    ask = np.array([job["ask"][d] for d in fleetlib.DIMS], dtype=float)
+    got = ref.binpack_scores(cap, used, ask)
+    assert got == pytest.approx([scorer.score(n) for n in fleet], abs=1e-12)
+
+
+def test_judge_takes_nothing_from_the_program():
+    src = open(os.path.join(ROOT, "benchmark", "lib", "reference.py")).read()
+    assert "nomad_tpu" not in src.split('"""', 2)[2]
+    src = open(os.path.join(ROOT, "benchmark", "lib", "fleet.py")).read()
+    assert "import jax" not in src and "from nomad_tpu" not in src
