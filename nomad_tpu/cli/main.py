@@ -1483,7 +1483,7 @@ def cmd_operator_top(args) -> int:
     # each stage's recent seconds (reservoirs hold the last 2048
     # reports); superset/idle stages stay out of the denominator like
     # stages.snapshot()
-    excluded = {"sched_host", "queue_wait"}
+    from ..utils.stages import SHARE_EXCLUDED as excluded
     stage_rows = []
     weights = {}
     for name in series:

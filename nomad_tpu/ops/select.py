@@ -27,12 +27,14 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import time
 from functools import lru_cache, partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from ..utils import stages
 from ..utils.locks import make_lock
 
 # JIT shape-cache bound (governor accounting): every distinct
@@ -402,13 +404,15 @@ def _scan_batched_jit(k_steps: int, spread_alg: bool, s_live: int,
     arm of multi-eval batching. Covers the FULL scoring surface
     (spreads, distinct-property, reserved ports) unlike the K-way arm,
     because it is literally the scan kernel with a lane axis."""
-    def fn(*args):
+    # the def's name is the program's on the device
+    # (jit__select_scan_many on the profiler's XLA Modules line)
+    def _select_scan_many(*args):
         return _select_scan_fn(*args, k_steps=k_steps,
                                spread_alg=spread_alg,
                                s_live=s_live, p_live=p_live)
     in_axes = tuple(None if name == "capacity" else 0
                     for name in _SCAN_ARGS)
-    return jax.jit(jax.vmap(fn, in_axes=in_axes))
+    return jax.jit(jax.vmap(_select_scan_many, in_axes=in_axes))
 
 
 def _local_final_score(after, cap_cpu, cap_mem, coll, penalty, affinity,
@@ -604,12 +608,12 @@ def _chunked_batched_jit(max_steps: int, spread_alg: bool):
     costs about as many node passes as its slowest lane — the chunk-ok
     arm of multi-eval batching (the scan arm covers spread/distinct
     lanes)."""
-    def fn(*args):
+    def _select_chunked_many(*args):    # the program's device name
         return _select_chunked_fn(*args, max_steps=max_steps,
                                   spread_alg=spread_alg)
     in_axes = tuple(None if name == "capacity" else 0
                     for name in _CHUNKED_ARGS)
-    return jax.jit(jax.vmap(fn, in_axes=in_axes))
+    return jax.jit(jax.vmap(_select_chunked_many, in_axes=in_axes))
 
 
 def _kway_core(capacity, used0, feasible, ask, k_valid,
@@ -645,18 +649,23 @@ def _kway_core(capacity, used0, feasible, ask, k_valid,
         (used, coll, free_p, dev_slots, remaining, step, _alive,
          out_widx, out_chunk, out_ti, out_ts, out_exh, out_feas) = state
 
-        feas = feasible & (free_p >= port_need) & port_ok & \
-            (dev_slots >= 1.0)
-        after = used + ask[None, :]
-        fit_dims = after <= capacity + 1e-6
-        fit = jnp.all(fit_dims, axis=1)
-        final, _b, _a, _p = _local_final_score(
-            after, cap_cpu, cap_mem, coll, penalty, affinity_norm,
-            desired_count, spread_alg, dev_score, dev_fires, pre_score)
-        ok = feas & fit
-        masked = jnp.where(ok, final, NEG_INF)
+        # named scopes: op metadata only, so a profile shows which
+        # phase of a step the device time went to
+        with jax.named_scope("mask_score"):
+            feas = feasible & (free_p >= port_need) & port_ok & \
+                (dev_slots >= 1.0)
+            after = used + ask[None, :]
+            fit_dims = after <= capacity + 1e-6
+            fit = jnp.all(fit_dims, axis=1)
+            final, _b, _a, _p = _local_final_score(
+                after, cap_cpu, cap_mem, coll, penalty, affinity_norm,
+                desired_count, spread_alg, dev_score, dev_fires,
+                pre_score)
+            ok = feas & fit
+            masked = jnp.where(ok, final, NEG_INF)
 
-        tv, ti = jax.lax.top_k(masked, w + 1)
+        with jax.named_scope("top_k"):
+            tv, ti = jax.lax.top_k(masked, w + 1)
         wl_val = tv[w]
         wl_idx = ti[w]
         widx = ti[:w]
@@ -733,19 +742,20 @@ def _kway_core(capacity, used0, feasible, ask, k_valid,
 
         # winner indices are distinct, so scatter-add is well-defined;
         # invalid lanes carry chunk 0 (no-op adds on a real node row)
-        safe_w = jnp.maximum(widx, 0)
-        used = used.at[safe_w].add(chunk[:, None] * ask[None, :])
-        coll = coll.at[safe_w].add(chunk_i)
-        free_p = free_p.at[safe_w].add(-chunk * port_need)
-        dev_slots = dev_slots.at[safe_w].add(-chunk)
+        with jax.named_scope("commit"):
+            safe_w = jnp.maximum(widx, 0)
+            used = used.at[safe_w].add(chunk[:, None] * ask[None, :])
+            coll = coll.at[safe_w].add(chunk_i)
+            free_p = free_p.at[safe_w].add(-chunk * port_need)
+            dev_slots = dev_slots.at[safe_w].add(-chunk)
 
-        out_widx = out_widx.at[step].set(
-            jnp.where(chunk_i > 0, widx, -1).astype(jnp.int32))
-        out_chunk = out_chunk.at[step].set(chunk_i)
-        out_ti = out_ti.at[step].set(top_idx)
-        out_ts = out_ts.at[step].set(top_scores)
-        out_exh = out_exh.at[step].set(exhausted)
-        out_feas = out_feas.at[step].set(feas_count)
+            out_widx = out_widx.at[step].set(
+                jnp.where(chunk_i > 0, widx, -1).astype(jnp.int32))
+            out_chunk = out_chunk.at[step].set(chunk_i)
+            out_ti = out_ti.at[step].set(top_idx)
+            out_ts = out_ts.at[step].set(top_scores)
+            out_exh = out_exh.at[step].set(exhausted)
+            out_feas = out_feas.at[step].set(feas_count)
 
         return (used, coll, free_p, dev_slots,
                 remaining - chunk_i.sum(), step + 1, valid,
@@ -1037,21 +1047,22 @@ def _stage_get(outs):
     `d2h` stage of the per-stage breakdown (the wall includes any
     remaining device compute — jax blocks the transfer on it — so d2h
     nests inside the kernel-stage window; see utils/stages)."""
-    from ..utils import stages
-    if not stages.enabled:
+    with stages.span("d2h"):
         return jax.device_get(outs)
-    import time as _time
-    t0 = _time.perf_counter()
-    vals = jax.device_get(outs)
-    stages.add("d2h", _time.perf_counter() - t0)
-    return vals
 
 
 def unpack_result(req: SelectRequest, outs) -> SelectResult:
     # ONE batched transfer: per-array np.asarray would serialize one
     # device round trip per output
+    vals = _stage_get(outs)
+    with stages.span("kernel_expand"):
+        return _unpack_fetched(req, vals)
+
+
+def _unpack_fetched(req: SelectRequest, vals) -> SelectResult:
+    """Host half of unpack_result, on arrays already fetched."""
     (choices, finals, s_bin, s_anti, s_pen, s_aff, s_spread, s_dev, s_pre,
-     top_idx, top_scores, exhausted, _ok_counts) = _stage_get(outs)
+     top_idx, top_scores, exhausted, _ok_counts) = vals
     # meta rows (top-k, exhaustion) are materialized only on the first
     # and failing steps; forward-fill the sentinels in between
     sentinel = exhausted[:, 0] < 0
@@ -1383,18 +1394,6 @@ class DispatchCostModel:
         span must not — no host dispatch is filed under a device arm's
         name."""
         shown = arm + "@cpu" if on_cpu else arm
-        from ..utils import stages
-        if stages.enabled:
-            # every arm reports its dispatch wall here — one choke
-            # point doubles as the bench's `kernel` stage accumulator
-            # AND the flight recorder's kernel-span emitter: solo arms
-            # attribute to the dispatching eval's thread context, a
-            # gateway fire fans the shared span out to every lane's
-            # trace, each carrying (arm, n_pad, lanes, fresh-compile)
-            stages.add("kernel", seconds)
-            from ..trace import emit_kernel
-            emit_kernel(shown, n_pad, seconds, lanes=lanes,
-                        fresh=compiled)
         # device economics (ISSUE 11): per-arm dispatch seconds and
         # fresh-compile counts, exported via nomad.device.* gauges and
         # the bench artifact — always on, like the recompile counter
@@ -1511,6 +1510,48 @@ BATCHED_ARMS = ("chunked_batched", "kway_batched", "scan_batched")
 # process-wide: every SelectKernel (workers, gateways, benches) feeds
 # and reads the same measured numbers
 cost_model = DispatchCostModel()
+
+
+class kernel_span:
+    """One dispatch's `kernel` window — dispatch through result
+    availability and host unpack/expand; packing and argument
+    placement stay outside, in `kernel_pack`. The one call an arm
+    makes: the stage report and its span land on every trace of the
+    thread context (the dispatching eval's, or each lane's of a
+    batched gateway fire) with (arm, n_pad, lanes, fresh-compile), and
+    the window's wall feeds the cost model and the device stats. That
+    wall is read inside the span, before its report is made, so the
+    router's input does not carry the recorder's cost. A dispatch that
+    raises is reported but never measured."""
+
+    __slots__ = ("arm", "n_pad", "lanes", "fresh", "on_cpu", "_span",
+                 "_t0")
+
+    def __init__(self, arm: str, n_pad: int, lanes: int = 1,
+                 fresh: bool = False, on_cpu: bool = False):
+        self.arm = arm
+        self.n_pad = n_pad
+        self.lanes = lanes
+        self.fresh = fresh
+        self.on_cpu = on_cpu
+
+    def __enter__(self) -> "kernel_span":
+        self._span = stages.span(
+            "kernel", arm=self.arm + "@cpu" if self.on_cpu else self.arm,
+            n_pad=int(self.n_pad), lanes=int(self.lanes),
+            fresh=bool(self.fresh)) if stages.enabled else stages.NULL_SPAN
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        seconds = time.perf_counter() - self._t0
+        self._span.__exit__(et, ev, tb)
+        if et is None:
+            cost_model.observe(self.arm, self.n_pad, seconds,
+                               lanes=self.lanes, compiled=self.fresh,
+                               on_cpu=self.on_cpu)
+        return False
 
 
 # -- device-economics accounting (ISSUE 11) ----------------------------
@@ -1906,7 +1947,6 @@ class SelectKernel:
 
     def _select(self, req: SelectRequest) -> SelectResult:
         _sanitize_request(req)
-        import time as _time
         sharded = self._mesh_sharded()
         if sharded is not None:
             chunk_ok = (not req.spreads and not req.distinct_props
@@ -1915,34 +1955,30 @@ class SelectKernel:
             n_pad_sh = sharded.pad_to_shards(len(req.feasible))
             if chunk_ok and req.count > 512 and n_pad_sh > KWAY_W:
                 # the @mesh windows include packing and sharded
-                # placement (the mesh path's h2d); they only feed the
-                # per-arm device stats, never the single-device
-                # routing estimates
-                t0 = _time.perf_counter()
-                # big batches keep the K-way kernel on the mesh: the
-                # same SPMD program, node axis sharded, top-k/gather
-                # collectives inserted by XLA; table-shaped columns
-                # come off the mesh-resident table when the request
-                # carries a live mirror token
-                args, _statics = pack_request(req, n_pad_sh)
-                cargs = sharded.place_chunked_args(
-                    {k: args[k] for k in _CHUNKED_ARGS},
-                    capacity_src=req.capacity, req=req)
+                # placement (the mesh path's h2d; no kernel_pack
+                # beside them); they only feed the per-arm device
+                # stats, never the single-device routing estimates
                 spread_alg = req.algorithm == "spread"
                 w = _kway_w(n_pad_sh)
                 fresh = _note_trace("kway@mesh", n_pad_sh,
                                     max_steps=_kway_steps(w),
                                     spread_alg=spread_alg, w=w)
-                with sharded.mesh:
-                    pending = _select_kway(**cargs,
-                                           max_steps=_kway_steps(w),
-                                           spread_alg=spread_alg, w=w)
-                out = self._finish_kway(req, cargs, spread_alg, pending,
-                                        w=w)
-                cost_model.observe("kway@mesh", n_pad_sh,
-                                   _time.perf_counter() - t0,
-                                   compiled=fresh)
-                return out
+                with kernel_span("kway@mesh", n_pad_sh, fresh=fresh):
+                    # big batches keep the K-way kernel on the mesh:
+                    # the same SPMD program, node axis sharded,
+                    # top-k/gather collectives inserted by XLA;
+                    # table-shaped columns come off the mesh-resident
+                    # table when the request carries a live mirror token
+                    args, _statics = pack_request(req, n_pad_sh)
+                    cargs = sharded.place_chunked_args(
+                        {k: args[k] for k in _CHUNKED_ARGS},
+                        capacity_src=req.capacity, req=req)
+                    with sharded.mesh:
+                        pending = _select_kway(**cargs,
+                                               max_steps=_kway_steps(w),
+                                               spread_alg=spread_alg, w=w)
+                    return self._finish_kway(req, cargs, spread_alg,
+                                             pending, w=w)
             return sharded.select(req)      # observes scan@mesh itself
         n = len(req.feasible)
         n_pad = _pad_n(n)
@@ -1961,58 +1997,54 @@ class SelectKernel:
             return self._run_chunked(req, n_pad, dev)
         dev = self._pick_device(n_pad, req.count, arm="scan")
         k = _bucket_k(max(req.count, 1))
-        args, statics = pack_request(req, n_pad)
-        args = self._place_args(args, dev)
-        resident = self._resident_args(req, n_pad, dev)
-        if resident:
-            args.update(resident)
+        with stages.span("kernel_pack"):
+            args, statics = pack_request(req, n_pad)
+            args = self._place_args(args, dev)
+            resident = self._resident_args(req, n_pad, dev)
+            if resident:
+                args.update(resident)
         fresh = _note_trace("scan", n_pad, k_steps=k,
                             cpu=dev is not None, **statics)
-        t0 = _time.perf_counter()
-        _carry, outs = _select_scan(**args, k_steps=k, **statics)
-        out = unpack_result(req, outs)
-        cost_model.observe("scan" + ("@cpu" if dev is not None else ""),
-                           n_pad, _time.perf_counter() - t0,
-                           compiled=fresh)
-        return out
+        with kernel_span("scan" + ("@cpu" if dev is not None else ""),
+                         n_pad, fresh=fresh):
+            _carry, outs = _select_scan(**args, k_steps=k, **statics)
+            return unpack_result(req, outs)
 
     # -- k-way chunked path --------------------------------------------
     def _pack_kway(self, req: SelectRequest, n_pad: int, dev):
         """Pack + place the K-way kernel args; returns
         (cargs, spread_alg, w). Split from the dispatch so the cost
         model's window starts at the dispatch, like the other arms."""
-        args, _statics = pack_request(req, n_pad)
-        cargs = {k: args[k] for k in _CHUNKED_ARGS}
-        cargs = self._place_args(cargs, dev)
-        resident = self._resident_args(req, n_pad, dev)
-        if resident:
-            cargs.update(resident)
+        with stages.span("kernel_pack"):
+            args, _statics = pack_request(req, n_pad)
+            cargs = {k: args[k] for k in _CHUNKED_ARGS}
+            cargs = self._place_args(cargs, dev)
+            resident = self._resident_args(req, n_pad, dev)
+            if resident:
+                cargs.update(resident)
         return cargs, req.algorithm == "spread", _kway_w(n_pad)
 
     def _finish_kway(self, req: SelectRequest, cargs, spread_alg,
                      pending, w: int) -> SelectResult:
-        return _expand_kway(req, self._finish_kway_rounds(
-            req, cargs, spread_alg, pending, w=w))
+        rounds = self._finish_kway_rounds(req, cargs, spread_alg,
+                                          pending, w=w)
+        with stages.span("kernel_expand"):
+            return _expand_kway(req, rounds)
 
     def _run_kway(self, req: SelectRequest, n_pad: int,
                   dev) -> SelectResult:
-        import time as _time
         cargs, spread_alg, w = self._pack_kway(req, n_pad, dev)
         fresh = _note_trace("kway", n_pad, max_steps=_kway_steps(w),
                             spread_alg=spread_alg, w=w,
                             cpu=dev is not None)
         # window matches every other arm: dispatch through
         # unpack/expand, packing/placement excluded
-        t0 = _time.perf_counter()
-        pending = _select_kway(**cargs, max_steps=_kway_steps(w),
-                               spread_alg=spread_alg, w=w)
-        rounds = self._finish_kway_rounds(req, cargs, spread_alg,
-                                          pending, w=w)
-        out = _expand_kway(req, rounds)
-        cost_model.observe("kway" + ("@cpu" if dev is not None else ""),
-                           n_pad, _time.perf_counter() - t0,
-                           compiled=fresh)
-        return out
+        with kernel_span("kway" + ("@cpu" if dev is not None else ""),
+                         n_pad, fresh=fresh):
+            pending = _select_kway(**cargs, max_steps=_kway_steps(w),
+                                   spread_alg=spread_alg, w=w)
+            return self._finish_kway(req, cargs, spread_alg, pending,
+                                     w=w)
 
     def select_many(self, reqs: List[SelectRequest]) -> List[SelectResult]:
         """Place B independent requests over the SAME node table in one
@@ -2073,20 +2105,30 @@ class SelectKernel:
             return [self.select(r) for r in reqs]
         metrics.incr_counter("nomad.select.batch_dispatch")
 
-        packs = [pack_request(r, n_pad)[0] for r in reqs]
-        cargs = self._pad_and_stack(packs, _CHUNKED_ARGS)
-        spread_alg = reqs[0].algorithm == "spread"
-        cargs, mesh_ctx, on_cpu = self._place_batched(
-            cargs, sharded, reqs[0].capacity, n_pad,
-            sum(min(r.count, 2 * n) for r in reqs),
-            table=reqs[0].table)
+        with stages.span("kernel_pack"):
+            packs = [pack_request(r, n_pad)[0] for r in reqs]
+            cargs = self._pad_and_stack(packs, _CHUNKED_ARGS)
+            spread_alg = reqs[0].algorithm == "spread"
+            cargs, mesh_ctx, on_cpu = self._place_batched(
+                cargs, sharded, reqs[0].capacity, n_pad,
+                sum(min(r.count, 2 * n) for r in reqs),
+                table=reqs[0].table)
         w = _kway_w(n_pad)
         fresh = _note_trace("kway_batched", n_pad,
                             max_steps=_kway_steps(w),
                             spread_alg=spread_alg, w=w,
                             lanes=len(cargs["k_valid"]), cpu=on_cpu)
-        import time as _time
-        t0 = _time.perf_counter()
+        # window includes per-lane unpack/expand so the number compares
+        # end-to-end against the solo arms (which include theirs)
+        with kernel_span("kway_batched", n_pad, lanes=len(reqs),
+                         fresh=fresh, on_cpu=on_cpu):
+            return self._kway_batched_results(
+                reqs, cargs, mesh_ctx, spread_alg, w)
+
+    def _kway_batched_results(self, reqs, cargs, mesh_ctx, spread_alg,
+                              w: int) -> List[SelectResult]:
+        """The kway_batched arm's `kernel` window: one vmapped dispatch,
+        per-lane overflow continued solo, then every lane expanded."""
         with mesh_ctx:
             carry, outs = _select_kway_batched(**cargs,
                                                max_steps=_kway_steps(w),
@@ -2094,7 +2136,7 @@ class SelectKernel:
                                                w=w)
         packed_i, ts = _stage_get(outs)
         d = reqs[0].capacity.shape[1]
-        results = []
+        lane_rounds = []
         for i, req in enumerate(reqs):
             pi = packed_i[i]
             widx = pi[:, :w]
@@ -2132,13 +2174,10 @@ class SelectKernel:
                 cont = self._finish_kway_rounds(req, lane, spread_alg,
                                                 pending, w=w)
                 rounds.extend(cont)
-            results.append(_expand_kway(req, rounds))
-        # window includes per-lane unpack/expand so the number compares
-        # end-to-end against the solo arms (which include theirs)
-        cost_model.observe("kway_batched", n_pad,
-                           _time.perf_counter() - t0, lanes=len(reqs),
-                           compiled=fresh, on_cpu=on_cpu)
-        return results
+            lane_rounds.append(rounds)
+        with stages.span("kernel_expand"):
+            return [_expand_kway(req, rounds)
+                    for req, rounds in zip(reqs, lane_rounds)]
 
     @staticmethod
     def _pad_and_stack(packs: List[Dict], arg_names) -> Dict:
@@ -2234,24 +2273,33 @@ class SelectKernel:
         """B chunk-eligible lanes through the vmapped chunked kernel in
         one dispatch; per-lane overflow continues on the solo kernel.
         Bit-identical to per-request select()."""
-        packs = [pack_request(r, n_pad)[0] for r in reqs]
         spread_alg = reqs[0].algorithm == "spread"
         maxc = max(r.count for r in reqs)
         max_steps = 64 if maxc <= 64 else 512
-        cargs = self._pad_and_stack(packs, _CHUNKED_ARGS)
+        with stages.span("kernel_pack"):
+            packs = [pack_request(r, n_pad)[0] for r in reqs]
+            cargs = self._pad_and_stack(packs, _CHUNKED_ARGS)
+            cargs, mesh_ctx, on_cpu = self._place_batched(
+                cargs, sharded, reqs[0].capacity, n_pad,
+                min(maxc, 2 * n_pad), table=reqs[0].table)
         fn = _chunked_batched_jit(max_steps, spread_alg)
-        cargs, mesh_ctx, on_cpu = self._place_batched(
-            cargs, sharded, reqs[0].capacity, n_pad, min(maxc, 2 * n_pad),
-            table=reqs[0].table)
         fresh = _note_trace("chunked_batched", n_pad,
                             max_steps=max_steps, spread_alg=spread_alg,
                             lanes=len(cargs["k_valid"]), cpu=on_cpu)
-        import time as _time
-        t0 = _time.perf_counter()
+        # window includes per-lane unpack/expand so the number compares
+        # end-to-end against the solo arms (which include theirs)
+        with kernel_span("chunked_batched", n_pad, lanes=len(reqs),
+                         fresh=fresh, on_cpu=on_cpu):
+            return self._chunked_batched_results(
+                reqs, fn, cargs, mesh_ctx, spread_alg)
+
+    def _chunked_batched_results(self, reqs, fn, cargs, mesh_ctx,
+                                 spread_alg) -> List[SelectResult]:
+        """The chunked_batched arm's `kernel` window."""
         with mesh_ctx:
             carry, outs = fn(*[cargs[nm] for nm in _CHUNKED_ARGS])
         outs_np = _stage_get(outs)
-        results = []
+        lane_rounds = []
         for i, req in enumerate(reqs):
             (choice, chunk, ti, ts, exh, feas, rem, steps) = \
                 (a[i] for a in outs_np)
@@ -2277,13 +2325,10 @@ class SelectKernel:
                     dev_slots0=np.asarray(ds0),
                     k_valid=np.int32(rem))
                 rounds.extend(self._chunked_rounds(lane, spread_alg))
-            results.append(_expand_chunks(req, rounds))
-        # window includes per-lane unpack/expand so the number compares
-        # end-to-end against the solo arms (which include theirs)
-        cost_model.observe("chunked_batched", n_pad,
-                           _time.perf_counter() - t0, lanes=len(reqs),
-                           compiled=fresh, on_cpu=on_cpu)
-        return results
+            lane_rounds.append(rounds)
+        with stages.span("kernel_expand"):
+            return [_expand_chunks(req, rounds)
+                    for req, rounds in zip(reqs, lane_rounds)]
 
     @staticmethod
     def _chunked_rounds(cargs: Dict, spread_alg: bool,
@@ -2312,36 +2357,34 @@ class SelectKernel:
         """B lanes through the vmapped scan kernel in one dispatch;
         results are bit-identical to per-request select() (the chunked
         and K-way solo paths are proven scan-equivalent)."""
-        packs = []
-        s_live = p_live = 0
-        for r in reqs:
-            args, st = pack_request(r, n_pad)
-            packs.append(args)
-            s_live = max(s_live, st["s_live"])
-            p_live = max(p_live, st["p_live"])
         spread_alg = reqs[0].algorithm == "spread"
         k = _bucket_k(max(max(r.count, 1) for r in reqs))
-        cargs = self._pad_and_stack(packs, _SCAN_ARGS)
+        with stages.span("kernel_pack"):
+            packs = []
+            s_live = p_live = 0
+            for r in reqs:
+                args, st = pack_request(r, n_pad)
+                packs.append(args)
+                s_live = max(s_live, st["s_live"])
+                p_live = max(p_live, st["p_live"])
+            cargs = self._pad_and_stack(packs, _SCAN_ARGS)
+            cargs, mesh_ctx, on_cpu = self._place_batched(
+                cargs, sharded, reqs[0].capacity, n_pad, k,
+                table=reqs[0].table)
         fn = _scan_batched_jit(k, spread_alg, s_live, p_live)
-        cargs, mesh_ctx, on_cpu = self._place_batched(
-            cargs, sharded, reqs[0].capacity, n_pad, k,
-            table=reqs[0].table)
         fresh = _note_trace("scan_batched", n_pad, k_steps=k,
                             s_live=s_live, p_live=p_live,
                             lanes=len(cargs["k_valid"]), cpu=on_cpu)
-        import time as _time
-        t0 = _time.perf_counter()
-        with mesh_ctx:
-            _carry, outs = fn(*[cargs[nm] for nm in _SCAN_ARGS])
-        outs_np = _stage_get(outs)
-        results = [unpack_result(r, tuple(a[i] for a in outs_np))
-                   for i, r in enumerate(reqs)]
         # window includes per-lane unpack so the number compares
         # end-to-end against the solo arms (which include theirs)
-        cost_model.observe("scan_batched", n_pad,
-                           _time.perf_counter() - t0, lanes=len(reqs),
-                           compiled=fresh, on_cpu=on_cpu)
-        return results
+        with kernel_span("scan_batched", n_pad, lanes=len(reqs),
+                         fresh=fresh, on_cpu=on_cpu):
+            with mesh_ctx:
+                _carry, outs = fn(*[cargs[nm] for nm in _SCAN_ARGS])
+            outs_np = _stage_get(outs)
+            with stages.span("kernel_expand"):
+                return [_unpack_fetched(r, tuple(a[i] for a in outs_np))
+                        for i, r in enumerate(reqs)]
 
     def _finish_kway_rounds(self, req, cargs, spread_alg, pending,
                             w: int):
@@ -2374,13 +2417,13 @@ class SelectKernel:
     # -- chunked path --------------------------------------------------
     def _run_chunked(self, req: SelectRequest, n_pad: int,
                      dev) -> SelectResult:
-        import time as _time
-        args, _statics = pack_request(req, n_pad)
-        cargs = {k: args[k] for k in _CHUNKED_ARGS}
-        cargs = self._place_args(cargs, dev)
-        resident = self._resident_args(req, n_pad, dev)
-        if resident:
-            cargs.update(resident)
+        with stages.span("kernel_pack"):
+            args, _statics = pack_request(req, n_pad)
+            cargs = {k: args[k] for k in _CHUNKED_ARGS}
+            cargs = self._place_args(cargs, dev)
+            resident = self._resident_args(req, n_pad, dev)
+            if resident:
+                cargs.update(resident)
         spread_alg = req.algorithm == "spread"
         # near-equal node scores make chunks short (each placement is
         # overtaken after 1-2 instances), so a big count can need
@@ -2398,29 +2441,13 @@ class SelectKernel:
                                     # (a step always places >=1 or stops)
         fresh = _note_trace("chunked", n_pad, max_steps=max_steps,
                             spread_alg=spread_alg, cpu=dev is not None)
-        rounds = []
-        t0 = _time.perf_counter()
-        while True:
-            (used, coll, freep, devs), outs = _select_chunked(
-                **cargs, max_steps=max_steps, spread_alg=spread_alg)
-            (choice, chunk, ti, ts, exh, feas,
-             rem, steps) = _stage_get(outs)
-            steps = int(steps)
-            rem = int(rem)
-            rounds.append((choice[:steps], chunk[:steps], ti[:steps],
-                           ts[:steps], exh[:steps], feas[:steps]))
-            if rem <= 0 or steps == 0:
-                break
-            if chunk[steps - 1] == 0:
-                break                        # infeasible: nothing placed
-            # ran out of steps: continue from the device-resident carry
-            cargs.update(used0=used, tg_coll0=coll, free_ports=freep,
-                         dev_slots0=devs, k_valid=np.int32(rem))
-        out = _expand_chunks(req, rounds)
-        cost_model.observe(
-            "chunked" + ("@cpu" if dev is not None else ""), n_pad,
-            _time.perf_counter() - t0, compiled=fresh)
-        return out
+        with kernel_span("chunked" + ("@cpu" if dev is not None else ""),
+                         n_pad, fresh=fresh):
+            # an infeasible ask stops after one round, an exhausted
+            # step budget continues from the device-resident carry
+            rounds = self._chunked_rounds(cargs, spread_alg, max_steps)
+            with stages.span("kernel_expand"):
+                return _expand_chunks(req, rounds)
 
 
 def _expand_chunks(req: SelectRequest, rounds) -> SelectResult:

@@ -714,15 +714,13 @@ class NodeTableCache:
         rebuild inside its latency budget. Pair with prefetch_device()
         to overlap the device H2D upload with WAL tail replay."""
         from ..utils import stages
-        t0 = time.perf_counter() if stages.enabled else 0.0
-        t = (NodeTable.build_from_columns(snapshot, cold)
-             if cold is not None else NodeTable.build_all(snapshot))
-        with self._lock:
-            self._table = self._stamp(t, self.device.note_rebuild())
-            self._index = snapshot.latest_index()
-            self.stats["primes"] = self.stats.get("primes", 0) + 1
-        if stages.enabled:
-            stages.add("table_build", time.perf_counter() - t0)
+        with stages.span("table_build"):
+            t = (NodeTable.build_from_columns(snapshot, cold)
+                 if cold is not None else NodeTable.build_all(snapshot))
+            with self._lock:
+                self._table = self._stamp(t, self.device.note_rebuild())
+                self._index = snapshot.latest_index()
+                self.stats["primes"] = self.stats.get("primes", 0) + 1
 
     def prefetch_device(self) -> None:
         """Materialize the device mirror for the current table (full
@@ -781,47 +779,45 @@ class NodeTableCache:
             if self._table is not None and target < self._index:
                 # older snapshot than the cache: serve it a private
                 # build — or nothing, for callers that would rather
-                # fall back than pay a full build
-                return NodeTable.build_all(snapshot) if build else None
-            t0 = time.perf_counter() if stages.enabled else 0.0
-            if self._table is None:
+                # fall back than pay a full build. A stage of its own:
+                # table_build stays the shared table's builds and
+                # refreshes
                 if not build:
                     return None
-                self.stats["full_builds"] += 1
-                self._table = self._stamp(NodeTable.build_all(snapshot),
-                                          self.device.note_rebuild())
-                self._index = target
-                if stages.enabled:
-                    stages.add("table_build", time.perf_counter() - t0)
-                return self._table
-            changes = store.changes_since(self._index, target)
-            if changes is None or any(k == "node" for k, _ in changes) \
-                    or (changes and not delta_enabled()):
-                if not build:
-                    return None
-                self.stats["full_builds"] += 1
-                self._table = self._stamp(NodeTable.build_all(snapshot),
-                                          self.device.note_rebuild())
-                self._index = target
-                if stages.enabled:
-                    stages.add("table_build", time.perf_counter() - t0)
-                return self._table
-            if changes:
-                # last-write-wins dedupe, then row deltas on a fresh
-                # clone; the touched rows ship to the device mirror as
-                # an async scatter (the double-buffered half of the
-                # pipelined worker loop — the device applies them while
-                # the host builds the next eval's masks)
-                seen = dict.fromkeys(aid for _k, aid in changes)
-                t = self._table.clone_for_deltas()
-                rows = t.apply_alloc_changes(snapshot, seen)
-                t.finalize()
-                BUILD_STATS["delta_refreshes"] += 1
-                self.stats["delta_refreshes"] += 1
-                self._table = self._stamp(
-                    t, self.device.note_delta(t, rows))
-                if stages.enabled:
-                    stages.add("table_build", time.perf_counter() - t0)
+                with stages.span("table_build_private"):
+                    return NodeTable.build_all(snapshot)
+            with stages.span("table_build") as sp:
+                if self._table is not None:
+                    changes = store.changes_since(self._index, target)
+                if self._table is None or changes is None \
+                        or any(k == "node" for k, _ in changes) \
+                        or (changes and not delta_enabled()):
+                    if not build:
+                        sp.cancel()
+                        return None
+                    self.stats["full_builds"] += 1
+                    self._table = self._stamp(
+                        NodeTable.build_all(snapshot),
+                        self.device.note_rebuild())
+                    self._index = target
+                    return self._table
+                if changes:
+                    # last-write-wins dedupe, then row deltas on a
+                    # fresh clone; the touched rows ship to the device
+                    # mirror as an async scatter (the double-buffered
+                    # half of the pipelined worker loop — the device
+                    # applies them while the host builds the next
+                    # eval's masks)
+                    seen = dict.fromkeys(aid for _k, aid in changes)
+                    t = self._table.clone_for_deltas()
+                    rows = t.apply_alloc_changes(snapshot, seen)
+                    t.finalize()
+                    BUILD_STATS["delta_refreshes"] += 1
+                    self.stats["delta_refreshes"] += 1
+                    self._table = self._stamp(
+                        t, self.device.note_delta(t, rows))
+                else:
+                    sp.cancel()     # nothing to apply: not a refresh
             self._index = target
             return self._table
 

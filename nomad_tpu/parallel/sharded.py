@@ -26,6 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..ops.select import (PACK_SHARD_KINDS, SelectRequest, _bucket_k,
                           _note_trace, _select_scan, cost_model,
                           pack_request, unpack_result)
+from ..utils import stages
 from .sharded_table import (ShardedDeviceNodeTable, pad_for_mesh,
                             resident_enabled)
 
@@ -82,28 +83,34 @@ class ShardedSelect:
         n_pad = self.pad_to_shards(len(req.feasible))
         k = _bucket_k(max(req.count, 1))
         t0 = time.perf_counter()
-        args, statics = pack_request(req, n_pad)
-        resident = self.resident_args(req, n_pad)
-        placed_args = {}
-        for name, value in args.items():
-            if resident is not None and name in resident:
-                placed_args[name] = resident[name]
-                continue
-            if name == "capacity":
-                placed_args[name] = self._resident_capacity(req.capacity,
-                                                            value)
-                continue
-            sharding = self._sharding_for(PACK_SHARD_KINDS[name])
-            placed_args[name] = (value if sharding is None
-                                 else jax.device_put(value, sharding))
-        fresh = _note_trace("scan@mesh", n_pad, k_steps=k, **statics)
-        with self.mesh:
-            _carry, outs = _select_scan(**placed_args, k_steps=k, **statics)
-        out = unpack_result(req, outs)
-        # per-arm device stats only (window: pack + sharded placement +
-        # dispatch + unpack); no routing estimate reads an @mesh arm
-        cost_model.observe("scan@mesh", n_pad, time.perf_counter() - t0,
-                           compiled=fresh)
+        # the @mesh window holds pack + sharded placement + dispatch +
+        # unpack, so `fresh` is known only inside it; it feeds the
+        # per-arm device stats only — no routing estimate reads an
+        # @mesh arm
+        with stages.span("kernel", arm="scan@mesh", n_pad=int(n_pad),
+                         lanes=1) as sp:
+            args, statics = pack_request(req, n_pad)
+            resident = self.resident_args(req, n_pad)
+            placed_args = {}
+            for name, value in args.items():
+                if resident is not None and name in resident:
+                    placed_args[name] = resident[name]
+                    continue
+                if name == "capacity":
+                    placed_args[name] = self._resident_capacity(
+                        req.capacity, value)
+                    continue
+                sharding = self._sharding_for(PACK_SHARD_KINDS[name])
+                placed_args[name] = (value if sharding is None
+                                     else jax.device_put(value, sharding))
+            fresh = _note_trace("scan@mesh", n_pad, k_steps=k, **statics)
+            sp.note(fresh=bool(fresh))
+            with self.mesh:
+                _carry, outs = _select_scan(**placed_args, k_steps=k,
+                                            **statics)
+            out = unpack_result(req, outs)
+            seconds = time.perf_counter() - t0  # before the span's report
+        cost_model.observe("scan@mesh", n_pad, seconds, compiled=fresh)
         return out
 
     def resident_args(self, req: SelectRequest,

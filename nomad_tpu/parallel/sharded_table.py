@@ -200,24 +200,21 @@ class ShardedDeviceNodeTable:
         shard-aware `build_from_columns` upload at cold start
         (NodeTableCache.prefetch_device)."""
         from ..utils import stages
-        import time as _time
-        t0 = _time.perf_counter() if stages.enabled else 0.0
-        n = table.n
-        n_pad = pad_for_mesh(self.mesh, n)
-        d = table.base_used.shape[1]
-        cap = np.zeros((n_pad, d), np.float32)
-        cap[:n] = table.capacity
-        used = np.zeros((n_pad, d), np.float32)
-        used[:n] = table.base_used
-        ports = np.zeros(n_pad, np.float32)
-        ports[:n] = table.free_ports
-        put = self._jax.device_put
-        st = DeviceTableState(token, mirror.epoch, n, n_pad,
-                              put(cap, self.node2_sharding),
-                              put(used, self.node2_sharding),
-                              put(ports, self.node_sharding))
-        if stages.enabled:
-            stages.add("h2d", _time.perf_counter() - t0)
+        with stages.span("h2d", upload=True):
+            n = table.n
+            n_pad = pad_for_mesh(self.mesh, n)
+            d = table.base_used.shape[1]
+            cap = np.zeros((n_pad, d), np.float32)
+            cap[:n] = table.capacity
+            used = np.zeros((n_pad, d), np.float32)
+            used[:n] = table.base_used
+            ports = np.zeros(n_pad, np.float32)
+            ports[:n] = table.free_ports
+            put = self._jax.device_put
+            st = DeviceTableState(token, mirror.epoch, n, n_pad,
+                                  put(cap, self.node2_sharding),
+                                  put(used, self.node2_sharding),
+                                  put(ports, self.node_sharding))
         self._state = st
         self._mirror = mirror
         self._version = token

@@ -192,8 +192,20 @@ class GenericScheduler:
 
     # -- reconcile + place --------------------------------------------
     def _compute_job_allocs(self) -> None:
+        with stages.span("reconcile") as sp:
+            results = self._reconcile()
+            # the attr rides onto the flight recorder's reconcile span
+            # (a slow reconcile means something different on the
+            # columnar engine vs the reference fallback)
+            sp.note(columnar=self._columnar_active)
+        # Compute placements (destructive first to discount resources)
+        self._compute_placements(results.destructive_update, results.place)
+
+    def _reconcile(self):
+        """The alloc-diff host phase (the `reconcile` stage): alloc
+        fetch + tainted split + reconciler + result staging into the
+        plan. Returns the reconciler's results."""
         ev = self.eval
-        t0 = time.perf_counter() if stages.enabled else 0.0
 
         # columnar reconcile engine: the state store's per-job alloc
         # index turns the O(allocs) host phase into mask ops
@@ -275,15 +287,7 @@ class GenericScheduler:
                 self.queued_allocs[tg_name] = \
                     self.queued_allocs.get(tg_name, 0) + n
 
-        if stages.enabled:
-            # attrs ride onto the flight recorder's reconcile span (a
-            # slow reconcile means something different on the columnar
-            # engine vs the reference fallback)
-            stages.add("reconcile", time.perf_counter() - t0,
-                       attrs={"columnar": self._columnar_active})
-
-        # Compute placements (destructive first to discount resources)
-        self._compute_placements(results.destructive_update, results.place)
+        return results
 
     def _spec_change_fn(self, old_job: Job, tg_name: str) -> bool:
         """Destructive-update verdict for the columnar reconciler: one
@@ -474,47 +478,23 @@ class GenericScheduler:
                     # general per-item body below — round-5 profile);
                     # leftovers (no fit, preemption winners, canaries)
                     # fall through to the general loop
-                    leftover = self._append_fresh_bulk(
-                        batch, options_list, tg, deployment_id)
+                    with stages.span("plan_build",
+                                     placements=len(batch)):
+                        leftover = self._append_fresh_bulk(
+                            batch, options_list, tg, deployment_id)
                     if not leftover:
                         continue
                     pairs = leftover
                 else:
                     pairs = list(zip(batch, options_list))
 
-                for (missing, _opts), (option, metrics) in pairs:
-                    # preferred-node miss falls back to the full node set
-                    if option is None and batch[0][1].preferred_nodes:
-                        fallback = self.engine.select_batch(
-                            tg, 1, ProposedIndex(
-                                self.engine.table, self.job,
-                                self.state.allocs_by_job(
-                                    self.job.namespace, self.job.id),
-                                self.plan),
-                            SelectOptions(
-                                penalty_node_ids=batch[0][1].penalty_node_ids))
-                        option, metrics = fallback[0] if fallback else (None, metrics)
-                    # no fit anywhere: try preemption before failing
-                    # (BinPackIterator evict path, rank.go:415-448)
-                    if option is None:
-                        option = self._try_preemption(tg, metrics)
-                    if option is not None:
-                        self._append_placement(missing, tg, option,
-                                               deployment_id, now)
-                        continue
-                    if tg.name in self.failed_tg_allocs:
-                        # coalesce later failures of the same group
-                        self.failed_tg_allocs[tg.name].coalesced_failures += 1
-                    else:
-                        # private copy: `metrics` may be the batch's
-                        # shared flyweight, and coalesced_failures
-                        # mutates on later failures
-                        self.failed_tg_allocs[tg.name] = metrics.copy()
-                    # back out the staged stop: a failed placement must not
-                    # leave its previous alloc stopping with no replacement
-                    stop_prev, _ = missing.stop_previous()
-                    if stop_prev and missing.previous_alloc is not None:
-                        self.plan.remove_update(missing.previous_alloc)
+                # the general per-item loop is plan_build too: one
+                # report a batch. The fallback select and the
+                # preemption search of an item that found no node
+                # (stages of their own) run inside its interval
+                with stages.span("plan_build", placements=len(pairs)):
+                    self._append_general(pairs, batch, tg, deployment_id,
+                                         now)
 
         # record class eligibility for the blocked eval — only over nodes
         # in the iteration set (ready & in-DC): a down node's class must
@@ -534,6 +514,44 @@ class GenericScheduler:
                             node.computed_class, False)
                         self.ctx.eligibility.set_class_eligibility(
                             node.computed_class, prev or bool(mask[i]))
+
+    def _append_general(self, pairs, batch, tg, deployment_id: str,
+                        now) -> None:
+        """Append (item, option) pairs to the plan one by one: what
+        _append_fresh_bulk left over, and every batch it cannot take."""
+        for (missing, _opts), (option, metrics) in pairs:
+            # preferred-node miss falls back to the full node set
+            if option is None and batch[0][1].preferred_nodes:
+                fallback = self.engine.select_batch(
+                    tg, 1, ProposedIndex(
+                        self.engine.table, self.job,
+                        self.state.allocs_by_job(
+                            self.job.namespace, self.job.id),
+                        self.plan),
+                    SelectOptions(
+                        penalty_node_ids=batch[0][1].penalty_node_ids))
+                option, metrics = fallback[0] if fallback else (None, metrics)
+            # no fit anywhere: try preemption before failing
+            # (BinPackIterator evict path, rank.go:415-448)
+            if option is None:
+                option = self._try_preemption(tg, metrics)
+            if option is not None:
+                self._append_placement(missing, tg, option,
+                                       deployment_id, now)
+                continue
+            if tg.name in self.failed_tg_allocs:
+                # coalesce later failures of the same group
+                self.failed_tg_allocs[tg.name].coalesced_failures += 1
+            else:
+                # private copy: `metrics` may be the batch's
+                # shared flyweight, and coalesced_failures
+                # mutates on later failures
+                self.failed_tg_allocs[tg.name] = metrics.copy()
+            # back out the staged stop: a failed placement must not
+            # leave its previous alloc stopping with no replacement
+            stop_prev, _ = missing.stop_previous()
+            if stop_prev and missing.previous_alloc is not None:
+                self.plan.remove_update(missing.previous_alloc)
 
     def _preemption_round_for(self, tg):
         """Per-(eval, task group) PreemptionRound when preemption is

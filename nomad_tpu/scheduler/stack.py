@@ -387,7 +387,7 @@ class PlacementEngine:
         self._feas_push_s = 0.0
         stages.add("feasibility", max(dt - push, 0.0))
         if push > 0.0:
-            stages.add("h2d", push)
+            stages.add("h2d", push, {"mask_park": True})
         return out
 
     def _feasibility(self, tg: TaskGroup) -> Tuple[np.ndarray,
@@ -563,9 +563,25 @@ class PlacementEngine:
         reduced by the victims' resources and they carry the logistic
         preemption scorer; victims are staged into the plan when such a
         node wins."""
+        from ..utils import stages
         assert self.table is not None and self.job is not None
-        t = self.table
         start = time.monotonic_ns()
+        with stages.span("select_prep"):
+            req, prep = self._select_request(tg, count, proposed, options,
+                                             preemption_round)
+        res = self.dispatch(req)
+        elapsed = time.monotonic_ns() - start
+        with stages.span("select_finish"):
+            return self._ranked_nodes(tg, res, proposed, preemption_round,
+                                      elapsed, *prep)
+
+    def _select_request(self, tg: TaskGroup, count: int,
+                        proposed: ProposedIndex,
+                        options: Optional[SelectOptions], preemption_round):
+        """The `select_prep` stage: masks, CSI claims, affinity / spread
+        / device / preemption columns and the SelectRequest itself.
+        Returns (request, what _ranked_nodes needs of the preparation)."""
+        t = self.table
         ent = self._engine_entry(tg)
         mask, filtered_counts = self.feasibility(tg)
         # the cached combined mask — the residue diff below compares
@@ -803,9 +819,19 @@ class PlacementEngine:
             feas_token=feas_token,
             feas_residue=feas_residue,
         )
-        res = self.dispatch(req)
-        elapsed = time.monotonic_ns() - start
+        return req, (count, count_requested, csi_cap_source,
+                     filtered_counts, dev_asks, dyn_ports, reserved_ports,
+                     pre_score)
 
+    def _ranked_nodes(self, tg: TaskGroup, res, proposed: ProposedIndex,
+                      preemption_round, elapsed: int, count: int,
+                      count_requested: int, csi_cap_source: str,
+                      filtered_counts: Dict[str, int], dev_asks,
+                      dyn_ports: int, reserved_ports, pre_score,
+                      ) -> List[Tuple[Optional[RankedNode], AllocMetric]]:
+        """The `select_finish` stage: one (RankedNode-or-None, metrics)
+        pair per requested instance from the kernel's result."""
+        t = self.table
         # host-side port assignment for winners, plan-consistent
         out: List[Tuple[Optional[RankedNode], AllocMetric]] = []
         self._shared_by_dc = dict(self.by_dc)

@@ -1980,26 +1980,32 @@ class Server:
         register is a compare-and-set against the job's current modify
         index (`job run -check-index`; job_endpoint.go:175
         RegisterEnforceIndexErrPrefix): 0 means "must not exist"."""
-        if enforce_index:
-            # check-and-apply must be atomic w.r.t. sibling enforced
-            # registrations (two HTTP threads both reading index 7 and
-            # both winning would be the lost update CAS exists to stop)
-            with self._register_l:
-                current = self.store.job_by_id(job.namespace, job.id)
-                cur_idx = current.job_modify_index \
-                    if current is not None else 0
-                if current is None and job_modify_index != 0:
-                    raise ValueError(
-                        "Enforcing job modify index "
-                        f"{job_modify_index}: job does not exist")
-                if current is not None and \
-                        job_modify_index != cur_idx:
-                    raise ValueError(
-                        "Enforcing job modify index "
-                        f"{job_modify_index}: job exists with "
-                        f"conflicting job modify index: {cur_idx}")
-                return self._register_job_validated(job, triggered_by)
-        return self._register_job_validated(job, triggered_by)
+        from ..utils import stages
+        # call -> job committed and eval enqueued, as the server sees
+        # it (the client's clock round the PUT adds HTTP and decode)
+        with stages.span("job_register", jobs=1):
+            if enforce_index:
+                # check-and-apply must be atomic w.r.t. sibling
+                # enforced registrations (two HTTP threads both reading
+                # index 7 and both winning would be the lost update CAS
+                # exists to stop)
+                with self._register_l:
+                    current = self.store.job_by_id(job.namespace, job.id)
+                    cur_idx = current.job_modify_index \
+                        if current is not None else 0
+                    if current is None and job_modify_index != 0:
+                        raise ValueError(
+                            "Enforcing job modify index "
+                            f"{job_modify_index}: job does not exist")
+                    if current is not None and \
+                            job_modify_index != cur_idx:
+                        raise ValueError(
+                            "Enforcing job modify index "
+                            f"{job_modify_index}: job exists with "
+                            f"conflicting job modify index: {cur_idx}")
+                    return self._register_job_validated(job,
+                                                        triggered_by)
+            return self._register_job_validated(job, triggered_by)
 
     def _register_job_validated(self, job: Job,
                                 triggered_by: str
@@ -2094,6 +2100,14 @@ class Server:
                 except Exception as e:
                     out.append(e)
             return out
+        from ..utils import stages
+        with stages.span("job_register", jobs=len(jobs)):
+            return self._register_jobs_coalesced(jobs, triggered_by)
+
+    def _register_jobs_coalesced(self, jobs: List[Job],
+                                 triggered_by: str) -> List:
+        """register_jobs_bulk through the ingest gateway: park every
+        admitted job, then gather."""
         slots = []              # (future | None, ev | result, err | None)
         for job in jobs:
             try:

@@ -401,7 +401,7 @@ class RemoteEvalLane(EvalLane):
         self.fs = fs
 
     def submit_plan(self, plan: Plan) -> Optional[PlanResult]:
-        from ..utils import metrics
+        from ..utils import metrics, stages
         t0 = time.monotonic()
         plan.eval_id = self.eval.id
         plan.eval_token = self.token
@@ -410,33 +410,39 @@ class RemoteEvalLane(EvalLane):
         addr = fs.leader_addr()
         if not addr:
             raise RpcError("no cluster leader for Plan.Submit")
-        res = fs.call(addr, "Plan.Submit",
-                      {"plan": to_wire(plan), "follower": fs.self_addr()},
-                      timeout_s=35.0)
-        if res.get("not_leader"):
-            fs.rehome(res.get("leader"))
-            raise RpcError("Plan.Submit: leadership moved")
-        if res.get("error"):
-            raise RpcError(f"Plan.Submit failed: {res['error']}")
-        result = decode_plan_result(res.get("result") or {})
-        fs.incr("remote_plans")
-        if chaos_faults.ACTIVE:
-            # same hook, same point in the protocol as the local lane:
-            # the plan IS committed (leader-side) and the eval is not
-            # yet acked — a worker-kill fault here exercises redelivery
-            # across the remote path too
-            chaos_faults.fire(
-                "worker.plan_committed", eval_id=self.eval.id,
-                placements=sum(len(a) for a in
-                               plan.node_allocation.values()))
-        metrics.measure_since("nomad.worker.submit_plan", t0)
-        if result.refresh_index:
-            # demoted (entirely or partially): the group's commit index
-            # is the refresh fence — wait for LOCAL replication to
-            # catch up so the retry sees why it lost
-            fs.incr("demoted_plans")
-            self.server.store.block_min_index(result.refresh_index - 1,
-                                              timeout_s=RAFT_SYNC_LIMIT)
+        # the same stage as the local lane's: RPC out -> result in
+        # hand, the refresh-index wait included (the leader's queue
+        # wait / verify / commit spans stay on the leader)
+        with stages.span("plan_submit", remote=True) as sp:
+            res = fs.call(addr, "Plan.Submit",
+                          {"plan": to_wire(plan),
+                           "follower": fs.self_addr()},
+                          timeout_s=35.0)
+            if res.get("not_leader"):
+                fs.rehome(res.get("leader"))
+                raise RpcError("Plan.Submit: leadership moved")
+            if res.get("error"):
+                raise RpcError(f"Plan.Submit failed: {res['error']}")
+            result = decode_plan_result(res.get("result") or {})
+            fs.incr("remote_plans")
+            if chaos_faults.ACTIVE:
+                # same hook, same point in the protocol as the local
+                # lane: the plan IS committed (leader-side) and the
+                # eval is not yet acked — a worker-kill fault here
+                # exercises redelivery across the remote path too
+                chaos_faults.fire(
+                    "worker.plan_committed", eval_id=self.eval.id,
+                    placements=sum(len(a) for a in
+                                   plan.node_allocation.values()))
+            metrics.measure_since("nomad.worker.submit_plan", t0)
+            sp.note(refreshed=bool(result.refresh_index))
+            if result.refresh_index:
+                # demoted (entirely or partially): the group's commit
+                # index is the refresh fence — wait for LOCAL
+                # replication to catch up so the retry sees why it lost
+                fs.incr("demoted_plans")
+                self.server.store.block_min_index(
+                    result.refresh_index - 1, timeout_s=RAFT_SYNC_LIMIT)
         return result
 
     def reblock_eval(self, ev: Evaluation) -> None:

@@ -466,19 +466,27 @@ class Persistence:
         drop the WAL prefix it covers (entries appended after the
         capture survive in the tail)."""
         import time as _time
+        from ..utils import stages
         t0 = _time.perf_counter()
         try:
-            with self._snap_l:
+            # the writer's own time, the wait for a sibling writer
+            # included: the interval last_snapshot_s reports. `entries`
+            # is the raft index the capture covers
+            with stages.span("snapshot_write",
+                             entries=snap.latest_index()) as sp, \
+                    self._snap_l:
                 if wal_mark < self._published_mark:
                     # a newer capture already published while this one
                     # waited: replacing it would pair an OLDER snapshot
                     # with a MORE-truncated WAL and lose the gap
+                    sp.cancel()
                     return
                 data = snap.dump_columnar() if self.columnar \
                     else snap.dump()
                 if extra is not None:
                     data["extra"] = extra
                 blob = msgpack.packb(data, use_bin_type=True)
+                sp.note(bytes=len(blob))
                 tmp = self.snapshot_path + ".tmp"
                 with open(tmp, "wb") as f:
                     f.write(blob)

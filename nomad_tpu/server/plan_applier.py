@@ -49,6 +49,7 @@ from ..models import (
 )
 from ..models.evaluation import TRIGGER_PREEMPTION
 from .plan_queue import PendingPlan, PlanQueue
+from ..utils import stages
 from ..utils.locks import make_lock
 
 PLAN_GROUP_ENV = "NOMAD_TPU_PLAN_GROUP"
@@ -252,6 +253,15 @@ class PlanApplier:
                 group = [pending] if pending is not None else []
             if not group:
                 continue
+            # the plan's wait behind the serialization point (mostly
+            # the other worker's commit), on the submitting eval's trace
+            if stages.enabled:
+                now = _time.monotonic()
+                for pending in group:
+                    trace.report(
+                        "plan_queue_wait", now - pending.enqueued_t,
+                        (getattr(pending.plan, "_trace", None),),
+                        end_mono=now, track="applier")
             if len(group) == 1:
                 # the escape hatch AND the idle-queue common case: one
                 # plan commits through the unchanged singleton path
@@ -292,7 +302,6 @@ class PlanApplier:
                 fail_futures(item[0], RuntimeError("plan applier stopped"))
 
     def _commit_loop(self) -> None:
-        from ..utils import stages
         while True:
             try:
                 item = self._commit_q.get(timeout=0.2)
@@ -305,19 +314,14 @@ class PlanApplier:
             pairs, waiter, group_index = item
             try:
                 if waiter is not None:
-                    c0 = _time.perf_counter() if stages.enabled else 0.0
-                    waiter()
-                    if stages.enabled:
-                        wdt = _time.perf_counter() - c0
-                        stages.add("plan_commit", wdt)
-                        # the quorum wait (pipelined behind the next
-                        # group's verification) on each member's trace
-                        for _future, result in pairs:
-                            trace.emit(
-                                getattr(result, "_trace", None),
-                                "plan_commit", wdt, track="committer",
-                                group=len(pairs), index=group_index,
-                                phase="quorum")
+                    # the quorum wait (pipelined behind the next
+                    # group's verification) on each member's trace
+                    with trace.span(
+                            "plan_commit",
+                            [getattr(r, "_trace", None) for _f, r in pairs],
+                            track="committer", group=len(pairs),
+                            index=group_index, phase="quorum"):
+                        waiter()
                 # demultiplex: every submitter gets ITS result off the
                 # one group commit, in submission order
                 for future, result in pairs:
@@ -353,21 +357,17 @@ class PlanApplier:
         return result
 
     def _apply(self, plan: Plan):
-        from ..utils import stages
         tr = getattr(plan, "_trace", None)
         self._check_token(plan)
         store = self.server.store
         snapshot = store.snapshot()
         self._retire_pending(snapshot)
-        _v0 = _time.perf_counter() if stages.enabled else 0.0
-        result, payload, evals, _conflicted = self._verify(snapshot,
-                                                           plan, ())
+        with trace.span("plan_verify", (tr,), track="applier",
+                        group=1) as sp:
+            result, payload, evals, _conflicted = self._verify(
+                snapshot, plan, ())
+            sp.note(demoted=bool(result.refresh_index))
         result._trace = tr      # committer attributes the quorum wait
-        if stages.enabled:
-            _vdt = _time.perf_counter() - _v0
-            stages.add("plan_verify", _vdt)
-            trace.emit(tr, "plan_verify", _vdt, track="applier",
-                       group=1, demoted=bool(result.refresh_index))
         if payload is None:
             return result, None
         from ..utils import metrics as _metrics
@@ -375,37 +375,35 @@ class PlanApplier:
                               _count_placements(result))
 
         # commit through the raft shim (FSM ApplyPlanResults)
-        _c0 = _time.perf_counter() if stages.enabled else 0.0
-        index, waiter = self.server.raft_apply_async(
-            "plan_results", payload)
-        if chaos_faults.ACTIVE:
-            # same dispatched-not-yet-quorum window as the group path
-            # below — the failover cell must trip even when the queue
-            # was idle and the plan committed as a singleton
-            chaos_faults.fire("plan.group_commit", index=index,
-                              plans=1)
-        result.alloc_index = index
-        if result.refresh_index:
-            # partial commit: the accepted slots land at THIS index,
-            # above the verify snapshot — the retry's refresh fence
-            # must cover them or a remote worker (whose local store
-            # lags the leader's) replans from a snapshot that predates
-            # the partial commit and re-places slots that already
-            # exist (plan_apply.go applyPlan RefreshIndex = max)
-            result.refresh_index = max(result.refresh_index, index)
-        if waiter is not None:
-            # apply-at-commit: the store won't show this plan until the
-            # committer's waiter resolves — overlay it for the next
-            # verification round
-            self._pending.append((index, result))
-        for ev in evals:
-            self.server.enqueue_eval(ev)
-        if stages.enabled:
-            _cdt = _time.perf_counter() - _c0
-            stages.add("plan_commit", _cdt)
-            trace.emit(tr, "plan_commit", _cdt, track="applier",
-                       group=1, index=index,
-                       pipelined=waiter is not None)
+        with trace.span("plan_commit", (tr,), track="applier",
+                        group=1) as sp:
+            index, waiter = self.server.raft_apply_async(
+                "plan_results", payload)
+            if chaos_faults.ACTIVE:
+                # same dispatched-not-yet-quorum window as the group
+                # path below — the failover cell must trip even when
+                # the queue was idle and the plan committed as a
+                # singleton
+                chaos_faults.fire("plan.group_commit", index=index,
+                                  plans=1)
+            result.alloc_index = index
+            if result.refresh_index:
+                # partial commit: the accepted slots land at THIS
+                # index, above the verify snapshot — the retry's
+                # refresh fence must cover them or a remote worker
+                # (whose local store lags the leader's) replans from a
+                # snapshot that predates the partial commit and
+                # re-places slots that already exist (plan_apply.go
+                # applyPlan RefreshIndex = max)
+                result.refresh_index = max(result.refresh_index, index)
+            if waiter is not None:
+                # apply-at-commit: the store won't show this plan until
+                # the committer's waiter resolves — overlay it for the
+                # next verification round
+                self._pending.append((index, result))
+            for ev in evals:
+                self.server.enqueue_eval(ev)
+            sp.note(index=index, pipelined=waiter is not None)
         return result, waiter
 
     def apply_group(self, group: List[PendingPlan]):
@@ -418,53 +416,52 @@ class PlanApplier:
         is [(future, result)] in submission order; futures are resolved
         by the committer, not here. A plan failing the token fence
         fails only its own future and drops out of the group."""
-        from ..utils import metrics, stages
+        from ..utils import metrics
         _t0 = _time.monotonic()
-        _v0 = _time.perf_counter() if stages.enabled else 0.0
-        store = self.server.store
-        snapshot = store.snapshot()
-        self._retire_pending(snapshot)
-
         entries: List[Tuple] = []       # (pending, result, payload, evals)
         accepted: List[PlanResult] = []
         conflicts = 0
-        for pending in group:
-            plan = pending.plan
-            tr = getattr(plan, "_trace", None)
-            _p0 = _time.perf_counter() if stages.enabled else 0.0
-            try:
-                self._check_token(plan)
-                result, payload, evals, conflicted = self._verify(
-                    snapshot, plan, accepted)
-            except Exception as e:
-                if not pending.future.done():
-                    pending.future.set_exception(e)
-                continue
-            result._trace = tr  # committer attributes the quorum wait
-            if stages.enabled:
-                # per-plan span with the group anatomy the aggregate
-                # window can't carry: width, intra-group conflict,
-                # demotion, and how long the plan sat queued behind
-                # the serialization point
-                trace.emit(
-                    tr, "plan_verify", _time.perf_counter() - _p0,
-                    track="applier", group=len(group),
-                    conflicted=conflicted,
-                    demoted=bool(result.refresh_index),
-                    queue_ms=round(max(
-                        _time.monotonic() - pending.enqueued_t, 0.0)
-                        * 1000.0, 3))
-            if conflicted:
-                conflicts += 1
-            entries.append((pending, result, payload, evals))
-            if payload is not None:
-                accepted.append(result)
-                metrics.incr_counter("nomad.plan.placements",
-                                     _count_placements(result))
-            metrics.incr_counter("nomad.plan.apply")
-        metrics.measure_since("nomad.plan.evaluate", _t0)
-        if stages.enabled:
-            stages.add("plan_verify", _time.perf_counter() - _v0)
+        # the stage is the group's whole verification window; each
+        # member's trace gets a span of its own plan's share of it
+        with stages.span("plan_verify"):
+            store = self.server.store
+            snapshot = store.snapshot()
+            self._retire_pending(snapshot)
+            for pending in group:
+                plan = pending.plan
+                tr = getattr(plan, "_trace", None)
+                _p0 = _time.perf_counter() if stages.enabled else 0.0
+                try:
+                    self._check_token(plan)
+                    result, payload, evals, conflicted = self._verify(
+                        snapshot, plan, accepted)
+                except Exception as e:
+                    if not pending.future.done():
+                        pending.future.set_exception(e)
+                    continue
+                result._trace = tr  # committer attributes the quorum wait
+                if stages.enabled:
+                    # per-plan span with the group anatomy the
+                    # aggregate window can't carry: width, intra-group
+                    # conflict, demotion, and how long the plan sat
+                    # queued behind the serialization point
+                    trace.emit(
+                        tr, "plan_verify", _time.perf_counter() - _p0,
+                        track="applier", group=len(group),
+                        conflicted=conflicted,
+                        demoted=bool(result.refresh_index),
+                        queue_ms=round(max(
+                            _time.monotonic() - pending.enqueued_t, 0.0)
+                            * 1000.0, 3))
+                if conflicted:
+                    conflicts += 1
+                entries.append((pending, result, payload, evals))
+                if payload is not None:
+                    accepted.append(result)
+                    metrics.incr_counter("nomad.plan.placements",
+                                         _count_placements(result))
+                metrics.incr_counter("nomad.plan.apply")
+            metrics.measure_since("nomad.plan.evaluate", _t0)
         self._note_group(len(group), conflicts)
 
         pairs = [(pending.future, result)
@@ -473,44 +470,44 @@ class PlanApplier:
         if not payloads:
             return pairs, None, 0
 
-        _c0 = _time.perf_counter() if stages.enabled else 0.0
-        index, waiter = self.server.raft_apply_async(
-            "plan_group_results", dict(groups=payloads))
-        if chaos_faults.ACTIVE:
-            # chaos hook (ISSUE 16 leader_failover_commit cell): the
-            # group's entry is in the leader's log and replicating, but
-            # no submitter future has resolved — the exact window where
-            # a dying leader must not double-commit (the entry either
-            # reaches quorum and survives into the new term, or it
-            # never happened; the workers' nack/redelivery covers both)
-            chaos_faults.fire("plan.group_commit", index=index,
-                              plans=len(payloads))
-        for _pending, result, payload, _evs in entries:
-            if payload is not None:
-                result.alloc_index = index
-                if waiter is not None:
-                    self._pending.append((index, result))
-            if result.refresh_index:
-                # a demoted plan's missing capacity becomes visible at
-                # the GROUP's commit index, not the snapshot's — point
-                # the worker's refresh fence there so the retry sees
-                # why it lost instead of replaying the same conflict
-                result.refresh_index = max(result.refresh_index, index)
-        for _pending, _result, _payload, evals in entries:
-            for ev in evals:
-                self.server.enqueue_eval(ev)
-        if stages.enabled:
-            _cdt = _time.perf_counter() - _c0
-            stages.add("plan_commit", _cdt)
-            # ONE raft entry / store transaction for the whole group:
-            # the shared commit span lands on every member's trace
-            # with the group size, so a p99 eval's anatomy shows
-            # whether it amortized its commit or paid one alone
+        # ONE raft entry / store transaction for the whole group: the
+        # shared commit span lands on every member's trace with the
+        # group size, so a p99 eval's anatomy shows whether it
+        # amortized its commit or paid one alone
+        with trace.span("plan_commit", track="applier",
+                        group=len(group)) as sp:
+            index, waiter = self.server.raft_apply_async(
+                "plan_group_results", dict(groups=payloads))
+            if chaos_faults.ACTIVE:
+                # chaos hook (ISSUE 16 leader_failover_commit cell):
+                # the group's entry is in the leader's log and
+                # replicating, but no submitter future has resolved —
+                # the exact window where a dying leader must not
+                # double-commit (the entry either reaches quorum and
+                # survives into the new term, or it never happened; the
+                # workers' nack/redelivery covers both)
+                chaos_faults.fire("plan.group_commit", index=index,
+                                  plans=len(payloads))
             for _pending, result, payload, _evs in entries:
-                trace.emit(getattr(result, "_trace", None),
-                           "plan_commit", _cdt, track="applier",
-                           group=len(group), index=index,
-                           committed=payload is not None)
+                if payload is not None:
+                    result.alloc_index = index
+                    if waiter is not None:
+                        self._pending.append((index, result))
+                if result.refresh_index:
+                    # a demoted plan's missing capacity becomes visible
+                    # at the GROUP's commit index, not the snapshot's —
+                    # point the worker's refresh fence there so the
+                    # retry sees why it lost instead of replaying the
+                    # same conflict
+                    result.refresh_index = max(result.refresh_index,
+                                               index)
+            for _pending, _result, _payload, evals in entries:
+                for ev in evals:
+                    self.server.enqueue_eval(ev)
+            sp.note(index=index)
+            for _pending, result, payload, _evs in entries:
+                sp.onto(getattr(result, "_trace", None),
+                        committed=payload is not None)
         return pairs, waiter, index
 
     # -- verification --------------------------------------------------

@@ -457,16 +457,12 @@ class MicroBatchGateway:
             waited = now - e[2]
             self.stats["wait_s_sum"] += waited
             if stages.enabled:
-                stages.add("gateway_wait", waited)
-            # flight recorder: the park span lands on the PARKED
-            # eval's trace (captured at dispatch()) with the batch
-            # anatomy — the firing thread belongs to some other eval
-            for tr_ in e[4]:
-                tr_.add_span("gateway_wait", waited, end_mono=now,
-                             track="gateway",
-                             attrs={"trigger": trigger,
-                                    "batch": batch_id,
-                                    "lanes": len(batch)})
+                # flight recorder: the park span lands on the PARKED
+                # eval's trace (captured at dispatch()) with the batch
+                # anatomy — the firing thread belongs to some other eval
+                trace.report("gateway_wait", waited, e[4], end_mono=now,
+                             track="gateway", trigger=trigger,
+                             batch=batch_id, lanes=len(batch))
         # every fire counts as in-flight (the drain trigger's
         # engine-busy signal); the MAX_INFLIGHT cap only limits how
         # WIDE a fire may be, so solo fallthroughs can exceed it
@@ -563,31 +559,37 @@ class EvalLane:
 
     # -- Planner interface --------------------------------------------
     def submit_plan(self, plan: Plan) -> Optional[PlanResult]:
-        from ..utils import metrics
+        from ..utils import metrics, stages
         t0 = time.monotonic()
         plan.eval_token = self.token
         plan.snapshot_index = self.snapshot_index
         # flight recorder: the applier/committer threads attribute
-        # their verify/commit spans through the plan, not thread-locals
+        # their queue-wait/verify/commit spans through the plan, not
+        # thread-locals
         plan._trace = trace.current()
-        future = self.server.plan_queue.enqueue(plan)
-        result: PlanResult = future.result(timeout=30)
-        if chaos_faults.ACTIVE:
-            # chaos hook (ISSUE 15): the plan IS committed at this
-            # point but the eval is not acked — an armed worker-kill
-            # fault raises here, modeling a scheduler worker dying
-            # mid-commit. The broker's nack path redelivers the eval
-            # and the retry's reconcile must see these placements
-            chaos_faults.fire(
-                "worker.plan_committed", eval_id=self.eval.id,
-                placements=sum(len(a) for a in
-                               plan.node_allocation.values()))
-        metrics.measure_since("nomad.worker.submit_plan", t0)
-        # if some placements were rejected, wait for the refresh index so
-        # the next attempt sees why (worker.go:318-340)
-        if result.refresh_index:
-            self.server.store.block_min_index(result.refresh_index - 1,
-                                              timeout_s=RAFT_SYNC_LIMIT)
+        # the worker is blocked from here to the result: the plan's
+        # wait in the queue, its verify and its commit nest inside
+        with stages.span("plan_submit") as sp:
+            future = self.server.plan_queue.enqueue(plan)
+            result: PlanResult = future.result(timeout=30)
+            if chaos_faults.ACTIVE:
+                # chaos hook (ISSUE 15): the plan IS committed at this
+                # point but the eval is not acked — an armed
+                # worker-kill fault raises here, modeling a scheduler
+                # worker dying mid-commit. The broker's nack path
+                # redelivers the eval and the retry's reconcile must
+                # see these placements
+                chaos_faults.fire(
+                    "worker.plan_committed", eval_id=self.eval.id,
+                    placements=sum(len(a) for a in
+                                   plan.node_allocation.values()))
+            metrics.measure_since("nomad.worker.submit_plan", t0)
+            # if some placements were rejected, wait for the refresh
+            # index so the next attempt sees why (worker.go:318-340)
+            sp.note(refreshed=bool(result.refresh_index))
+            if result.refresh_index:
+                self.server.store.block_min_index(
+                    result.refresh_index - 1, timeout_s=RAFT_SYNC_LIMIT)
         return result
 
     def refreshed_state(self, index: int):
@@ -810,7 +812,7 @@ class Worker:
                 stages.add("queue_wait",
                            getattr(ev, "queue_wait_s", 0.0) or 0.0)
         try:
-            with trace.use(tr):
+            with trace.use(tr), stages.annotate("eval", eval_id=ev.id):
                 # the snapshot fence (ISSUE 16 names it): wait for the
                 # LOCAL state store to catch up to the eval's modify
                 # index. Free on the leader; on a follower this is
@@ -860,9 +862,15 @@ class Worker:
                     if n_workers > 1:
                         sched.kernel_decorrelate = (self.id, n_workers)
                 t0 = time.monotonic()
-                sched.process(ev)
-                if stages.enabled and ev.type != JOB_TYPE_CORE:
-                    stages.add("sched_host", time.monotonic() - t0)
+                if ev.type == JOB_TYPE_CORE:
+                    sched.process(ev)
+                else:
+                    with stages.span("sched_host"):
+                        sched.process(ev)
+                    self_s = trace.uncovered_s(tr, "sched_host")
+                    if self_s is not None:
+                        # what no span of this eval's tree names
+                        stages.add("sched_host_self", self_s)
             metrics.measure_since(
                 f"nomad.worker.invoke_scheduler_{self._scheduler_for(ev)}"
                 if ev.type != JOB_TYPE_CORE
@@ -890,12 +898,8 @@ class Worker:
                     # healthy wide batches (then oscillate lane width)
                     gov.observe_eval_latency(elapsed / lat_scale,
                                              queue_wait_s=q_wait)
-                a0 = time.perf_counter() if stages.enabled else 0.0
-                with trace.use(tr):
+                with trace.use(tr), stages.span("broker_ack"):
                     self.broker.ack(ev.id, token)
-                    if stages.enabled:
-                        stages.add("broker_ack",
-                                   time.perf_counter() - a0)
                 # the ack closes the span tree: enqueue -> ... -> ack
                 trace.finish(tr, status="acked")
                 self.stats["processed"] += 1

@@ -27,20 +27,27 @@ enough for the C2M soak.
 Collection paths:
 
   ambient     utils/stages.py report sites forward every (stage,
-              seconds) through set_trace_hook — the aggregate sums
-              stay identical, and sites that run on the EVAL's own
-              thread (reconcile, table_build, h2d, d2h, sched_host,
-              broker_ack) land as spans on the thread-local current
-              trace(s). The hook also feeds the percentile
-              reservoirs for EVERY stage, traced context or not.
-  explicit    sites where thread-local attribution is wrong or
-              attribute-rich get their own emit calls: the gateway
-              records each parked request's wait onto the request's
-              CAPTURED trace (the firing thread is some other eval),
-              the dispatch cost model fans the kernel span out to
-              every lane of a batched fire, and the plan applier /
-              committer attach verify/commit spans to the trace the
-              submitting worker stamped onto the plan.
+              seconds, attrs) through set_trace_hook — the aggregate
+              sums stay identical, and sites that run on the EVAL's
+              own thread (AMBIENT_STAGES: sched_host and everything
+              the worker does inside it) land as spans on the
+              thread-local current trace(s). A batched gateway fire
+              installs the union of its lanes' traces, so the one
+              shared kernel / d2h span fans out to every lane. The
+              hook also feeds the percentile reservoirs for EVERY
+              stage, traced context or not.
+  explicit    stages measured across threads name their traces: the
+              gateway reports each parked request's wait onto the
+              request's CAPTURED trace (report(): the firing thread is
+              some other eval's), and the plan applier / committer
+              time queue wait, verify and commit onto the trace the
+              submitting worker stamped onto the plan (span()).
+
+While a profiler session is live every span is also a
+jax.profiler.TraceAnnotation "nomad/<stage>" for as long as it is open
+(utils/stages.py span), and the worker holds "nomad/eval" round each
+eval: the same tree sits in the xplane, on the profiler's clock, above
+the device ops.
 
 Exports three ways: `/v1/operator/trace` (JSON), `nomad operator
 trace [-exemplars] [-o chrome]` (Chrome trace-event JSON, loadable in
@@ -85,27 +92,52 @@ STAGE_RESERVOIR = 2048
 OWN_LATENCY_RESERVOIR = 512
 OWN_P99_EVERY = 32
 
-# static span-tree encoding: the per-dispatch stages nest inside the
-# scheduler's Process() window, everything else hangs off the eval
-# root — deterministic (testable) without runtime stack bookkeeping
+# static span-tree encoding, the same tree utils/stages.py's docstring
+# draws: deterministic (testable) without runtime stack bookkeeping.
+# The map has to tell the truth — a span lies inside its parent's
+# interval, and the direct children of sched_host do not overlap, so
+# that per eval they and sched_host_self sum to sched_host
+# (tests/test_trace.py checks both on a served eval). Where a stage has
+# more than one site the map gives the served path's steady state:
+# table_build under eval (the pipelined worker refreshes the table
+# BEFORE Process(); only worker_pipeline=False or a refused full
+# rebuild runs it inside sched_host), h2d under table_build (the
+# refresh's row scatter; a first upload, attr upload, sits in
+# kernel_pack and a mask park, attr mask_park, in select_prep, once per
+# table). Two things the sum assumes: solo dispatches (a batched
+# gateway fire reports kernel_pack / kernel once and lands them whole
+# on every lane's trace), and every placement finding a node (the
+# fallback select and the preemption search of one that did not run
+# inside plan_build's interval, and count twice)
 STAGE_PARENTS: Dict[str, Optional[str]] = {
-    "queue_wait": "eval", "gateway_wait": "sched_host",
+    "restore": None, "wal_replay": None, "job_register": None,
+    "snapshot_write": None,
+    "queue_wait": "eval", "fence_wait": "eval", "table_build": "eval",
+    "h2d": "table_build", "sched_host": "eval", "broker_ack": "eval",
     "reconcile": "sched_host", "preempt": "sched_host",
-    "table_build": "sched_host",
-    "h2d": "sched_host", "kernel": "sched_host", "d2h": "sched_host",
-    "sched_host": "eval", "plan_verify": "eval", "plan_commit": "eval",
-    "broker_ack": "eval", "restore": None, "wal_replay": None,
+    "table_build_private": "sched_host",
+    "select_prep": "sched_host", "feasibility": "select_prep",
+    "gateway_wait": "sched_host", "kernel_pack": "sched_host",
+    "kernel": "sched_host", "d2h": "kernel", "kernel_expand": "kernel",
+    "select_finish": "sched_host", "plan_build": "sched_host",
+    "plan_submit": "sched_host", "plan_queue_wait": "plan_submit",
+    "plan_verify": "plan_submit", "plan_commit": "plan_submit",
+    "sched_host_self": "sched_host",
 }
 
-# stages whose report site runs on the eval's OWN thread, so the
-# thread-local context attributes them correctly. The rest (kernel,
-# gateway_wait, plan_verify, plan_commit, queue_wait) report from
-# other threads or need per-request attrs and use the explicit
-# emitters below instead — the ambient hook emitting them too would
-# double-count or mis-attribute them.
+# stages whose report site runs on the eval's OWN thread — or, for the
+# device stages of a batched gateway fire, under the union context of
+# the fire's lanes — so the thread-local context attributes them. The
+# rest (queue_wait, gateway_wait, plan_queue_wait, plan_verify,
+# plan_commit) are measured across threads and name their traces
+# through span()/report() below; the ambient hook emitting them too
+# would double-count or mis-attribute them.
 AMBIENT_STAGES = frozenset({
-    "restore", "wal_replay", "table_build", "h2d", "d2h",
-    "reconcile", "preempt", "sched_host", "broker_ack",
+    "restore", "wal_replay", "fence_wait", "sched_host", "reconcile",
+    "preempt", "table_build", "h2d", "table_build_private",
+    "select_prep", "feasibility", "kernel_pack", "kernel", "d2h",
+    "kernel_expand", "select_finish", "plan_build", "plan_submit",
+    "sched_host_self", "broker_ack",
 })
 
 
@@ -119,19 +151,16 @@ def _env_enabled() -> bool:
 # batched fire's lane traces (with track "gateway") so shared device
 # spans fan out to every lane
 _tls = threading.local()
+_NO_CTX: Tuple[Tuple, Optional[str]] = ((), None)
 
 
 def current_all() -> Tuple:
-    return getattr(_tls, "ctx", ((), None))[0]
+    return getattr(_tls, "ctx", _NO_CTX)[0]
 
 
 def current():
     traces = current_all()
     return traces[0] if traces else None
-
-
-def _ctx() -> Tuple[Tuple, Optional[str]]:
-    return getattr(_tls, "ctx", ((), None))
 
 
 @contextmanager
@@ -144,7 +173,7 @@ def use(trace, track: Optional[str] = None):
 
 @contextmanager
 def use_many(traces, track: Optional[str] = None):
-    prev = getattr(_tls, "ctx", ((), None))
+    prev = getattr(_tls, "ctx", _NO_CTX)
     _tls.ctx = (tuple(traces), track)
     try:
         yield
@@ -153,12 +182,13 @@ def use_many(traces, track: Optional[str] = None):
 
 
 class EvalTrace:
-    """One eval's span tree. Span appends are lock-free (CPython list
-    append is atomic) because concurrent emitters (worker thread,
-    gateway firing thread, applier, committer) only ever append."""
+    """One eval's span tree. Recording a span is one tuple append
+    (lock-free: CPython list append is atomic, and the concurrent
+    emitters — worker thread, gateway firing thread, applier, committer
+    — only ever append); the span dicts are built on reading."""
 
     __slots__ = ("eval_id", "job_id", "namespace", "eval_type", "track",
-                 "wall0", "mono0", "spans", "total_ms", "status",
+                 "wall0", "mono0", "_raw", "total_ms", "status",
                  "gauges", "truncated")
 
     def __init__(self, eval_id: str, job_id: str, namespace: str,
@@ -170,7 +200,8 @@ class EvalTrace:
         self.track = track
         self.mono0 = mono0          # monotonic anchor (broker enqueue)
         self.wall0 = wall0          # wall anchor for export timestamps
-        self.spans: List[dict] = []
+        # (name, end_mono, dur_s, track, attrs)
+        self._raw: List[tuple] = []
         self.total_ms = 0.0
         self.status = "open"
         self.gauges: Optional[dict] = None   # set on exemplar promotion
@@ -180,26 +211,36 @@ class EvalTrace:
                  end_mono: Optional[float] = None,
                  track: Optional[str] = None,
                  attrs: Optional[dict] = None) -> None:
-        if len(self.spans) >= MAX_SPANS_PER_TRACE:
+        raw = self._raw
+        if len(raw) >= MAX_SPANS_PER_TRACE:
             self.truncated += 1
             return
-        end = time.monotonic() if end_mono is None else end_mono
-        t0 = max(0.0, (end - max(dur_s, 0.0)) - self.mono0)
-        span = {"name": name, "t0_ms": round(t0 * 1000.0, 3),
-                "dur_ms": round(max(dur_s, 0.0) * 1000.0, 3),
-                "track": track or self.track,
-                "parent": STAGE_PARENTS.get(name, "eval")}
-        if attrs:
-            span["attrs"] = attrs
-        self.spans.append(span)
+        raw.append((name, time.monotonic() if end_mono is None
+                    else end_mono, dur_s, track, attrs))
         tracer.stats["spans"] += 1      # racy inc; stats, not billing
+
+    @property
+    def spans(self) -> List[dict]:
+        out = []
+        for name, end, dur_s, track, attrs in list(self._raw):
+            dur_s = max(dur_s, 0.0)
+            span = {"name": name,
+                    "t0_ms": round(max(0.0, end - dur_s - self.mono0)
+                                   * 1000.0, 3),
+                    "dur_ms": round(dur_s * 1000.0, 3),
+                    "track": track or self.track,
+                    "parent": STAGE_PARENTS.get(name, "eval")}
+            if attrs:
+                span["attrs"] = attrs
+            out.append(span)
+        return out
 
     def to_dict(self) -> dict:
         out = {"eval_id": self.eval_id, "job_id": self.job_id,
                "namespace": self.namespace, "type": self.eval_type,
                "track": self.track, "start": round(self.wall0, 6),
                "total_ms": round(self.total_ms, 3),
-               "status": self.status, "spans": list(self.spans)}
+               "status": self.status, "spans": self.spans}
         if self.gauges is not None:
             out["gauges"] = self.gauges
         if self.truncated:
@@ -207,7 +248,7 @@ class EvalTrace:
         return out
 
     def est_bytes(self) -> int:
-        return TRACE_EST_BYTES + SPAN_EST_BYTES * len(self.spans)
+        return TRACE_EST_BYTES + SPAN_EST_BYTES * len(self._raw)
 
 
 class Tracer:
@@ -459,7 +500,17 @@ class Tracer:
         return len(self._ring)
 
     # -- stage percentiles ---------------------------------------------
-    def observe_stage(self, stage: str, seconds: float) -> None:
+    def _on_stage(self, stage: str, seconds: float,
+                  attrs: Optional[dict] = None) -> None:
+        """The stages.add hook: every report feeds its stage's
+        reservoir, an ambient one also lands as a span on the thread
+        context's trace(s)."""
+        if stage in AMBIENT_STAGES:
+            traces, track = getattr(_tls, "ctx", _NO_CTX)
+            if traces:
+                end = time.monotonic()  # stamped before any other work
+                for tr in traces:
+                    tr.add_span(stage, seconds, end, track, attrs)
         # append under the lock: stage_percentiles() copies these
         # deques for sorting, and CPython raises on a deque mutated
         # mid-iteration — one short lock per report, the same cost
@@ -494,15 +545,6 @@ class Tracer:
                           "p99_ms": round(pct(99), 4),
                           "count": len(vals)}
         return out
-
-    # -- the stages.add hook -------------------------------------------
-    def _on_stage(self, stage: str, seconds: float,
-                  attrs: Optional[dict] = None) -> None:
-        self.observe_stage(stage, seconds)
-        if stage in AMBIENT_STAGES:
-            traces, track = _ctx()
-            for tr in traces:
-                tr.add_span(stage, seconds, track=track, attrs=attrs)
 
     # -- status / export -----------------------------------------------
     def status(self, limit: int = 32,
@@ -610,17 +652,75 @@ def emit(tr: Optional[EvalTrace], name: str, dur_s: float,
                 attrs=attrs or None)
 
 
-def emit_kernel(arm: str, n_pad: int, seconds: float, lanes: int = 1,
-                fresh: bool = False) -> None:
-    """Kernel-dispatch span onto every trace in the thread context —
-    the dispatch cost model's choke point calls this, so solo arms
-    attribute to the dispatching eval and a batched gateway fire fans
-    the one shared device span out to all of its lanes. `fresh` is the
-    _note_trace verdict: this dispatch paid an XLA trace+compile."""
-    traces, track = _ctx()
-    if not traces:
-        return
-    attrs = {"arm": arm, "n_pad": int(n_pad), "lanes": int(lanes),
-             "fresh": bool(fresh)}
+class _TraceSpan(stages.Span):
+    """stages.Span whose report also lands, as one span each, on
+    explicit traces: the sites that run on another thread than the
+    eval's (applier, committer)."""
+
+    __slots__ = ("_targets", "_track")
+
+    def onto(self, tr: Optional[EvalTrace], **extra) -> None:
+        """One more trace to land on, with attributes of its own."""
+        if tr is not None:
+            self._targets.append((tr, extra))
+
+    def __exit__(self, et, ev, tb) -> bool:
+        stages.Span.__exit__(self, et, ev, tb)
+        if self._live:
+            for tr, extra in self._targets:
+                attrs = dict(self.attrs, **extra) if extra \
+                    else self.attrs
+                tr.add_span(self.stage, self.seconds, track=self._track,
+                            attrs=attrs or None)
+        return False
+
+
+def span(stage: str, traces=(), track: Optional[str] = None, **attrs):
+    """stages.span for a stage that is not ambient: one call times the
+    block, reports the stage and attaches the span to each of `traces`
+    (more through .onto())."""
+    if not stages.enabled:
+        return stages.NULL_SPAN
+    sp = _TraceSpan(stage, attrs)
+    sp._targets = [(tr, None) for tr in traces if tr is not None]
+    sp._track = track
+    return sp
+
+
+def report(stage: str, seconds: float, traces=(),
+           end_mono: Optional[float] = None,
+           track: Optional[str] = None, **attrs) -> None:
+    """One after-the-fact report of a wait measured across threads
+    (gateway_wait, plan_queue_wait): the stage report and the span on
+    each of the traces that waited. Callers guard with `if
+    stages.enabled:`, as for stages.add."""
+    attrs = attrs or None
+    stages.add(stage, seconds, attrs)
     for tr in traces:
-        tr.add_span("kernel", seconds, track=track, attrs=attrs)
+        if tr is not None:
+            tr.add_span(stage, seconds, end_mono=end_mono, track=track,
+                        attrs=attrs)
+
+
+def uncovered_s(tr: Optional[EvalTrace], name: str) -> Optional[float]:
+    """Seconds of the trace's newest `name` span that no other span of
+    the trace covers: the union of the others, clipped to the
+    interval, taken from its length (never below 0). None without a
+    trace or without such a span."""
+    if tr is None:
+        return None
+    raw = list(tr._raw)         # (name, end_mono, dur_s, track, attrs)
+    own = next((r for r in reversed(raw) if r[0] == name), None)
+    if own is None:
+        return None
+    b = own[1]
+    a = b - max(own[2], 0.0)
+    cuts = sorted((max(r[1] - r[2], a), min(r[1], b))
+                  for r in raw if r is not own
+                  and r[1] - r[2] < b and r[1] > a)
+    covered, at = 0.0, a
+    for lo, hi in cuts:
+        if hi > at:
+            covered += hi - max(lo, at)
+            at = hi
+    return max(b - a - covered, 0.0)

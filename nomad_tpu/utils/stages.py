@@ -3,46 +3,72 @@
 BENCH_r05 showed a 13x gap between in-kernel placement rate (163.8k/s)
 and end-to-end (12.3k/s) with no way to say WHERE the host time went —
 the gap had to be inferred from side channels. This module gives every
-stage of the pipeline a named accumulator:
+stage of the pipeline a named accumulator. The stages, as the tree the
+flight recorder draws them in (trace/tracer.py STAGE_PARENTS is the
+same tree; a stage's time lies inside its parent's):
 
+  (no eval)
     restore       cold start: snapshot load + store rebuild
-                  (server/persistence.py restore_into — ISSUE 8)
+                  (server/persistence.py restore_into)
     wal_replay    cold start: batched WAL tail replay into the FSM
-    table_build   host-side NodeTable full builds + delta refreshes
-    h2d           host->device transfers (uploads, scatters, arg ships)
-    kernel        device dispatch through result availability
-    d2h           device->host result transfers (device_get)
-    reconcile     alloc-diff host phase: alloc fetch + tainted split +
-                  AllocReconciler.compute + result staging (ISSUE 6:
-                  this cost was previously invisible — it had to be
-                  inferred as "the rest of the host share")
-    preempt       victim selection across candidate nodes: the memo
-                  sweep + batched columnar matrix pass (or per-node
-                  reference Preemptor runs) behind the kernel's
-                  pre_score/freed columns and the no-fit fallback
-                  (ISSUE 10: BENCH_r05's worst number — 354
-                  placements/s — was this phase, previously lumped
-                  into sched_host; reported from
-                  scheduler/preemption.py _evaluate_pending with
-                  nodes-scanned / victim-count attrs for the flight
-                  recorder)
-    queue_wait    time the eval sat in the broker's READY queue before
-                  a worker dequeued it (ISSUE 9: the enqueue->dequeue
-                  leg of the flight recorder's span tree; idle time,
-                  not attributable work — see SHARE_EXCLUDED)
-    gateway_wait  time an eval's kernel request spent parked in the
-                  micro-batch gateway's dispatch window before its
-                  batch fired (ISSUE 7: queue/coalescing wait was
-                  invisible in the latency attribution; nests inside
-                  sched_host like the device stages do)
-    sched_host    one whole scheduler Process() call as seen by the
-                  worker (reconcile + placement + plan build; overlaps
-                  kernel/h2d/d2h by design — see the note below)
-    plan_verify   plan verification against the freshest snapshot +
-                  group overlay (the serialization point's read half)
-    plan_commit   raft append/apply + quorum wait + store transaction
-                  (the serialization point's write half)
+    job_register  Server.register_job / register_jobs_bulk: call ->
+                  job committed and eval enqueued (attr jobs)
+    snapshot_write  the background snapshot writer: serialize, fsync,
+                  publish, truncate the WAL (attrs entries, bytes)
+  eval            enqueue -> ack (the trace's root, not a stage)
+    queue_wait    time the eval sat in the broker before a worker
+                  dequeued it (dead time — see SHARE_EXCLUDED)
+    fence_wait    wait for the local store to reach the eval's modify
+                  index (~0 on a leader; replication lag on a follower)
+    table_build   NodeTableCache full builds + delta refreshes: the
+                  pipelined worker refreshes BEFORE Process() (inside
+                  sched_host only with worker_pipeline off, or when
+                  the refresh needs a full rebuild)
+      h2d           host->device transfers: the refresh's row scatter
+                    (also, once per table: the first upload, inside
+                    kernel_pack, and a mask park, inside select_prep)
     broker_ack    eval broker ack bookkeeping
+    sched_host    one whole scheduler Process() call as seen by the
+                  worker. Its direct children do not overlap, so with
+                  sched_host_self they sum to it:
+      reconcile     alloc-diff host phase: alloc fetch + tainted split
+                    + AllocReconciler.compute + result staging (attr
+                    columnar)
+      preempt       victim selection across candidate nodes
+                    (scheduler/preemption.py; attrs nodes_scanned,
+                    victims)
+      table_build_private  the private full build a snapshot OLDER
+                    than the cache pays under the cache lock
+      select_prep   select_batch entry -> just before the dispatch:
+                    masks, CSI, affinities, spread inputs, the request
+        feasibility   constraint-mask production (cached per table)
+      gateway_wait  the request parked in the micro-batch gateway
+                    (attrs trigger, batch, lanes)
+      kernel_pack   pack_request / stacking / argument placement: what
+                    the kernel window leaves out
+      kernel        device dispatch through result availability AND
+                    host unpack (attrs arm, n_pad, lanes, fresh)
+        d2h           device->host result transfers (device_get; the
+                      wall includes remaining device compute)
+        kernel_expand host unpack/expand of the fetched result
+      select_finish dispatch returned -> RankedNodes returned: winner
+                    materialization, resources, ports, metrics
+      plan_build    appending the placements to the plan (attr
+                    placements; a placement that found no node runs
+                    its fallback select / preemption search in here)
+      plan_submit   plan enqueued -> result in hand, the refresh-index
+                    wait included (attr refreshed)
+        plan_queue_wait  enqueue -> the applier takes the plan
+        plan_verify   verification against the freshest snapshot +
+                      group overlay (the serialization point's read
+                      half; attrs group, demoted, queue_ms)
+        plan_commit   raft append/apply + quorum wait + store
+                      transaction (the write half; attrs group, index)
+      sched_host_self  the part of sched_host no other span of the
+                    eval's trace covers (union, not sum): scheduler
+                    set-up, the eval-status write, thread hand-offs —
+                    and collector pauses, which nothing inside the
+                    program times
 
 r8 lumped verify, raft apply, and ack bookkeeping into one
 `plan_apply` bucket; the group-commit applier splits it so the bench
@@ -63,6 +89,14 @@ the thread-local current trace. The aggregate sums are untouched.
 with both off the hot path pays one module-global bool check per
 report site, exactly as before.
 
+A site that wraps code makes one call, `with stages.span(stage,
+**attrs):` — the clock, the report as the interval ends, and, while a
+profiler session is live, a jax.profiler.TraceAnnotation
+"nomad/<stage>" held open meanwhile, so the session shows the stage on
+its own clock above the device ops. A wait measured across threads
+(queue_wait, gateway_wait, plan_queue_wait) stays an after-the-fact
+add().
+
 The same stage can be reported from overlapping layers (a kernel
 dispatch inside a plan-apply verify); accumulators are independent
 sums, not a strict partition of wall clock — shares are computed over
@@ -72,22 +106,28 @@ between rounds, not the absolute seconds.
 
 from __future__ import annotations
 
+import sys
+import time
 from typing import Callable, Dict, Optional
 from .locks import make_lock
 
-STAGES = ("restore", "wal_replay", "table_build", "feasibility", "h2d",
-          "kernel", "d2h", "reconcile", "preempt", "queue_wait",
-          "fence_wait", "gateway_wait", "sched_host", "plan_verify",
-          "plan_commit", "broker_ack")
+STAGES = ("restore", "wal_replay", "job_register", "snapshot_write",
+          "queue_wait", "fence_wait", "sched_host", "reconcile",
+          "preempt", "table_build", "h2d", "table_build_private",
+          "select_prep", "feasibility", "gateway_wait", "kernel_pack",
+          "kernel", "d2h", "kernel_expand", "select_finish",
+          "plan_build", "plan_submit", "plan_queue_wait", "plan_verify",
+          "plan_commit", "sched_host_self", "broker_ack")
 
 # superset accumulators: wholly contain other stages' time (sched_host
-# wraps reconcile + table_build + h2d + kernel + d2h per dispatch), so
+# wraps reconcile + kernel + d2h + plan_submit per eval, plan_submit
+# wraps the plan's queue wait + plan_verify + plan_commit), so
 # they are EXCLUDED from the share denominator — otherwise adding one
 # would halve every other stage's share and break the cross-round
 # share comparisons the bench artifacts exist for. Their own `share`
 # is still reported relative to that same denominator (it can
 # legitimately exceed other stages' combined share).
-SHARE_SUPERSETS = frozenset({"sched_host"})
+SHARE_SUPERSETS = frozenset({"sched_host", "plan_submit"})
 
 # queue_wait is dead time on the broker heap, not attributable work: a
 # paused-worker burst would let it dwarf every real stage and wreck
@@ -95,9 +135,11 @@ SHARE_SUPERSETS = frozenset({"sched_host"})
 # (its own share is still reported against it, like the supersets).
 # fence_wait (ISSUE 16) is the same kind of dead time — replication
 # lag observed at the snapshot fence, ~0 on a leader and bounded by
-# follower_fence_timeout_s on a lagging follower
+# follower_fence_timeout_s on a lagging follower; plan_queue_wait is
+# the plan's wait behind the serialization point
 SHARE_EXCLUDED = SHARE_SUPERSETS | frozenset({"queue_wait",
-                                              "fence_wait"})
+                                              "fence_wait",
+                                              "plan_queue_wait"})
 
 # cold-start stages dilute steady-state shares when a run cold-boots
 # mid-round (ISSUE 9 satellite): snapshot() reports `steady_share`
@@ -165,6 +207,110 @@ def add(stage: str, seconds: float,
             hook(stage, seconds, attrs)
         except Exception:       # pragma: no cover — defensive
             pass
+
+
+class Span:
+    """One stage's interval as a context manager: the clock is read on
+    entry and exit and the report (add) is made as the interval ends —
+    on an exception too. While a profiler session is live a
+    jax.profiler.TraceAnnotation "nomad/<stage>" is held open for the
+    interval, so the stage sits in the same xplane, on the profiler's
+    clock, above the device ops it waited for."""
+
+    __slots__ = ("stage", "attrs", "seconds", "_t0", "_ann", "_live")
+
+    def __init__(self, stage: str, attrs: dict):
+        self.stage = stage
+        self.attrs = attrs
+        self._live = True
+
+    def note(self, **attrs) -> None:
+        """Attributes known only once the work has run."""
+        self.attrs.update(attrs)
+
+    def cancel(self) -> None:
+        """This interval turned out not to be an occurrence of the
+        stage (a table refresh with nothing to apply): no report."""
+        self._live = False
+
+    def __enter__(self) -> "Span":
+        live = _profiling
+        if live is not None and live():
+            self._ann = _trace_annotation("nomad/" + self.stage)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        self.seconds = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+        if self._live:
+            add(self.stage, self.seconds, self.attrs or None)
+        return False
+
+
+class _NullSpan:
+    """What span()/annotate() hand out when nothing listens."""
+
+    __slots__ = ()
+    seconds = 0.0
+
+    def note(self, **attrs) -> None:
+        pass
+
+    def cancel(self) -> None:
+        pass
+
+    def onto(self, tr, **extra) -> None:     # trace.span's
+        pass
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        return False
+
+
+NULL_SPAN = _NullSpan()
+# jax.profiler.TraceAnnotation and its is_enabled (a profiler session
+# is live), bound once jax is loaded. jax is never imported from here:
+# a process that has not loaded it has no session to be seen in
+_trace_annotation = None
+_profiling: Optional[Callable] = None
+
+
+def _bind_profiler() -> None:
+    global _trace_annotation, _profiling
+    if "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _trace_annotation = TraceAnnotation
+        _profiling = TraceAnnotation.is_enabled
+
+
+def annotate(name: str, **attrs):
+    """A jax.profiler.TraceAnnotation "nomad/<name>" while a report
+    site is live and a profiler session runs, else a no-op."""
+    if not enabled:
+        return NULL_SPAN
+    if _profiling is None:
+        _bind_profiler()
+    if _profiling is None or not _profiling():
+        return NULL_SPAN
+    return _trace_annotation("nomad/" + name, **attrs)
+
+
+def span(stage: str, **attrs):
+    """`with stages.span("kernel", arm=...):` — the one call a report
+    site that wraps code makes. With nothing listening it costs the
+    one bool read the `if stages.enabled:` guards cost."""
+    if not enabled:
+        return NULL_SPAN
+    if _profiling is None:
+        _bind_profiler()
+    return Span(stage, attrs)
 
 
 def snapshot() -> Dict[str, dict]:

@@ -15,7 +15,8 @@ import pytest
 
 from nomad_tpu import mock, trace
 from nomad_tpu.server import Server, ServerConfig
-from nomad_tpu.trace import EvalTrace, Tracer, to_chrome, tracer
+from nomad_tpu.trace import (STAGE_PARENTS, EvalTrace, Tracer, to_chrome,
+                             tracer)
 from nomad_tpu.utils import stages
 
 
@@ -112,7 +113,8 @@ def test_solo_path_span_tree_complete():
         assert t["status"] == "acked"
         assert t["total_ms"] > 0
         for sp in t["spans"]:
-            assert sp["parent"] in (None, "eval", "sched_host")
+            assert sp["parent"] in (None, "eval") \
+                or sp["parent"] in STAGE_PARENTS
             assert sp["t0_ms"] >= 0.0 and sp["dur_ms"] >= 0.0
             # spans sit inside the eval window (small slack for the
             # finish-side bookkeeping racing the deferred ack)
@@ -247,12 +249,13 @@ def test_group_commit_and_demotion_span_attrs():
 def test_kernel_span_fans_out_to_every_lane():
     """A batched fire's ONE device dispatch must land on each lane's
     trace (the gateway installs the union context around _run)."""
-    from nomad_tpu.ops.select import cost_model
+    from nomad_tpu.ops.select import kernel_span
 
     t1 = _mk_eval_trace("lane-1")
     t2 = _mk_eval_trace("lane-2")
     with trace.use_many([t1, t2], track="gateway"):
-        cost_model.observe("kway_batched", 128, 0.005, lanes=2)
+        with kernel_span("kway_batched", 128, lanes=2):
+            pass
     for tr in (t1, t2):
         ks = [s for s in tr.spans if s["name"] == "kernel"]
         assert len(ks) == 1
@@ -261,10 +264,313 @@ def test_kernel_span_fans_out_to_every_lane():
         assert ks[0]["track"] == "gateway"
     # compile walls are flagged, not hidden
     with trace.use(t1):
-        cost_model.observe("chunked", 64, 1.5, compiled=True)
+        with kernel_span("chunked", 64, fresh=True):
+            pass
     fresh = [s for s in t1.spans
              if s["name"] == "kernel" and s["attrs"]["fresh"]]
     assert len(fresh) == 1
+
+
+# -- the closed span tree (ISSUE 25) -----------------------------------
+
+class _Tap:
+    """Every stage report, kept, and passed on to the recorder."""
+
+    def __init__(self):
+        self.reports = []               # (stage, seconds, attrs)
+        self._prev, self._prev_on = stages._trace_hook, stages._trace_on
+        stages.set_trace_hook(self._on, on=True)
+
+    def _on(self, stage, seconds, attrs=None):
+        self.reports.append((stage, seconds, attrs))
+        if self._prev is not None and self._prev_on:
+            self._prev(stage, seconds, attrs)
+
+    def close(self):
+        stages.set_trace_hook(self._prev, on=self._prev_on)
+
+    def of(self, stage):
+        return [r for r in self.reports if r[0] == stage]
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """Three jobs by register_job and two by one bulk register through
+    a real Server with a data_dir; then one forced snapshot and one
+    forced private table build. What each stage test reads: the stage
+    reports and the placing evals' traces."""
+    tracer.reset()
+    srv = Server(ServerConfig(
+        num_schedulers=2, heartbeat_ttl_s=3600.0,
+        data_dir=str(tmp_path_factory.mktemp("served"))))
+    srv.start()
+    tap = _Tap()        # a Server's construction re-arms the recorder
+    try:
+        for i in range(12):
+            node = mock.node()
+            node.name = f"served-n{i}"
+            node.compute_class()
+            srv.register_node(node)
+
+        def mk(i):
+            job = mock.job()
+            job.id = f"served-{i}"
+            tg = job.task_groups[0]
+            tg.count = 2
+            for t in tg.tasks:
+                t.resources.networks = []
+            tg.networks = []
+            return job
+
+        jobs = [mk(i) for i in range(5)]
+        for job in jobs[:3]:
+            srv.register_job(job)
+        bulk = srv.register_jobs_bulk(jobs[3:])
+        assert not any(isinstance(r, Exception) for r in bulk)
+        deadline = time.time() + 30
+        while time.time() < deadline and not all(
+                len(srv.store.allocs_by_job("default", j.id)) == 2
+                for j in jobs):
+            time.sleep(0.005)
+        coalesced = srv.ingest is not None
+
+        srv.persistence.snapshot(srv.store)
+        snapshot_s = srv.persistence.stats["last_snapshot_s"]
+
+        # a snapshot the cache has moved past pays a private build
+        old = srv.store.snapshot()
+        extra = mock.node()
+        extra.compute_class()
+        srv.register_node(extra)
+        srv.store.snapshot().node_table()
+        private = _mk_eval_trace("ev-private")
+        before = len(tap.of("table_build_private"))
+        with trace.use(private):
+            assert old.node_table() is not None
+        private_reports = len(tap.of("table_build_private")) - before
+    finally:
+        srv.shutdown()
+        tap.close()
+    traces = [t for t in _traces_for("served")
+              if any(s["name"] == "plan_commit" for s in t["spans"])]
+    assert len(traces) == 5
+    return {"tap": tap, "traces": traces, "coalesced": coalesced,
+            "snapshot_s": snapshot_s, "private": private.to_dict(),
+            "private_reports": private_reports}
+
+
+def _count(t, name):
+    return sum(1 for s in t["spans"] if s["name"] == name)
+
+
+# per placing eval: the stage occurs as often as the span it pairs with
+ONCE_PER = {"select_prep": "select_finish", "feasibility": "select_prep",
+            "select_finish": "plan_build", "plan_build": "select_prep",
+            "kernel_pack": "kernel", "kernel_expand": "kernel",
+            "plan_submit": "plan_queue_wait",
+            "plan_queue_wait": "plan_verify"}
+
+
+@pytest.mark.parametrize("stage", [
+    "job_register", "select_prep", "feasibility", "kernel_pack",
+    "kernel_expand", "select_finish", "plan_build", "plan_submit",
+    "plan_queue_wait", "table_build_private", "snapshot_write",
+    "sched_host_self"])
+def test_stage_is_reported_once_per_occurrence_under_its_parent(
+        served, stage):
+    assert stage in stages.STAGES and stage in STAGE_PARENTS
+    tap, traces = served["tap"], served["traces"]
+    reports = tap.of(stage)
+    assert reports and all(s >= 0.0 for _n, s, _a in reports)
+    if stage == "job_register":
+        sizes = sorted(a["jobs"] for _n, _s, a in reports)
+        assert sizes == ([1, 1, 1, 2] if served["coalesced"]
+                         else [1] * 5)
+        assert STAGE_PARENTS[stage] is None
+        assert not any(_count(t, stage) for t in traces)
+        return
+    if stage == "snapshot_write":
+        (_n, seconds, attrs), = reports
+        assert attrs["bytes"] > 0 and attrs["entries"] > 0
+        assert seconds == pytest.approx(served["snapshot_s"], abs=0.005)
+        return
+    if stage == "table_build_private":
+        assert served["private_reports"] == 1
+        assert _count(served["private"], stage) == 1
+        return
+    for t in traces:
+        spans = [s for s in t["spans"] if s["name"] == stage]
+        assert spans, (stage, [s["name"] for s in t["spans"]])
+        if stage == "sched_host_self":
+            assert len(spans) == 1
+        else:
+            assert len(spans) == _count(t, ONCE_PER[stage])
+        parents = [s for s in t["spans"]
+                   if s["name"] == STAGE_PARENTS[stage]]
+        for sp in spans:
+            if stage == "sched_host_self":
+                continue        # scattered time, drawn at the end
+            # inside one span of the parent's name (0.2 ms of slack for
+            # the report's own latency and the rounding to a us)
+            assert any(p["t0_ms"] - 0.2 <= sp["t0_ms"] and
+                       sp["t0_ms"] + sp["dur_ms"]
+                       <= p["t0_ms"] + p["dur_ms"] + 0.2
+                       for p in parents), (sp, parents)
+        if stage == "plan_submit":
+            assert all(sp["attrs"]["refreshed"] in (True, False)
+                       for sp in spans)
+
+
+def test_children_of_sched_host_and_self_sum_to_it(served):
+    """The check the parent map is held to: per eval, the spans whose
+    parent is sched_host (sched_host_self among them) add up to the
+    sched_host span — a stage that really nests in a sibling would
+    count twice and break it. The one stage the map files elsewhere
+    that can run in there: table_build, when the worker's refresh
+    before Process() was refused a full build (the first eval on a
+    cold cache) and the scheduler builds the table itself."""
+    for t in served["traces"]:
+        host = next(s for s in t["spans"] if s["name"] == "sched_host")
+        end = host["t0_ms"] + host["dur_ms"]
+        kids = sum(s["dur_ms"] for s in t["spans"]
+                   if s["parent"] == "sched_host"
+                   or (s["name"] == "table_build"
+                       and host["t0_ms"] <= s["t0_ms"]
+                       and s["t0_ms"] + s["dur_ms"] <= end))
+        assert kids == pytest.approx(host["dur_ms"], rel=0.02, abs=0.05)
+        me = next(s for s in t["spans"]
+                  if s["name"] == "sched_host_self")
+        assert 0.0 <= me["dur_ms"] <= host["dur_ms"]
+
+
+def test_uncovered_is_a_union_clipped_and_never_negative():
+    now = time.monotonic()
+    tr = EvalTrace("ev", "job", "default", "batch", "w",
+                   mono0=now - 1.0, wall0=time.time() - 1.0)
+
+    def span(name, a_ms, b_ms):
+        tr.add_span(name, (b_ms - a_ms) / 1000.0,
+                    end_mono=tr.mono0 + b_ms / 1000.0)
+
+    assert trace.uncovered_s(tr, "sched_host") is None
+    span("queue_wait", 0.0, 10.0)       # ends where the interval starts
+    span("table_build", 5.0, 20.0)      # straddles the start: 10 inside
+    span("sched_host", 10.0, 110.0)
+    span("kernel", 30.0, 60.0)
+    span("d2h", 40.0, 50.0)             # nested: adds nothing
+    span("plan_build", 55.0, 70.0)      # overlaps kernel: 10 more
+    span("plan_submit", 100.0, 130.0)   # straddles the end: 10 inside
+    # covered: 10 + (30..70 = 40) + 10 = 60 of 100
+    assert trace.uncovered_s(tr, "sched_host") == pytest.approx(0.040)
+    span("reconcile", 0.0, 200.0)       # covers everything
+    assert trace.uncovered_s(tr, "sched_host") == 0.0
+
+
+def test_span_reports_on_an_exception_and_is_free_when_off(monkeypatch):
+    tap = _Tap()
+    try:
+        with pytest.raises(ValueError):
+            with stages.span("select_prep", why="test") as sp:
+                sp.note(more=1)
+                raise ValueError("boom")
+        (_n, seconds, attrs), = tap.of("select_prep")
+        assert seconds >= 0.0 and attrs == {"why": "test", "more": 1}
+        with stages.span("table_build") as sp:
+            sp.cancel()
+        assert not tap.of("table_build")
+    finally:
+        tap.close()
+    # nothing listens: one bool read, then the shared no-op object —
+    # no Span, no clock, no TraceAnnotation
+    stages.disable()
+    monkeypatch.setenv("NOMAD_TPU_TRACE", "0")
+    tracer.refresh()
+    assert not stages.enabled
+    assert stages.span("kernel", arm="x") is stages.NULL_SPAN
+    assert stages.annotate("eval", eval_id="x") is stages.NULL_SPAN
+    assert trace.span("plan_commit", (None,)) is stages.NULL_SPAN
+    with stages.span("kernel") as sp:
+        sp.note(a=1)
+        sp.cancel()
+        sp.onto(None)
+    assert sp.seconds == 0.0
+    monkeypatch.delenv("NOMAD_TPU_TRACE")
+    tracer.refresh()
+
+
+def test_stages_sit_on_the_profilers_clock(tmp_path):
+    """Under a live jax.profiler session the eval and its stages are
+    TraceAnnotations in the host planes: nested in time, and within
+    1 ms of the spans of the eval's own trace."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    srv = Server(ServerConfig(num_schedulers=1, heartbeat_ttl_s=3600.0))
+    srv.start()
+    try:
+        for i in range(8):
+            node = mock.node()
+            node.name = f"prof-n{i}"
+            node.compute_class()
+            srv.register_node(node)
+
+        def run(job_id):
+            job = mock.job()
+            job.id = job_id
+            tg = job.task_groups[0]
+            tg.count = 2
+            for t in tg.tasks:
+                t.resources.networks = []
+            tg.networks = []
+            srv.register_job(job)
+            deadline = time.time() + 30
+            while time.time() < deadline and len(
+                    srv.store.allocs_by_job("default", job_id)) < 2:
+                time.sleep(0.005)
+            time.sleep(0.2)             # the deferred ack
+
+        run("prof-warm")                # compiles stay out of the trace
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            wall = time.time()
+            with TraceAnnotation("test_clock_mark"):
+                time.sleep(0.002)
+            run("prof-traced")
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        srv.shutdown()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("nomad/") \
+                        or ev.name == "test_clock_mark":
+                    events.setdefault(ev.name, []).append(
+                        (ev.start_ns / 1e9, ev.duration_ns / 1e9))
+    # seconds to add to a profiler time to get this process's wall clock
+    offset = wall - events["test_clock_mark"][0][0]
+    tr, = [t for t in tracer.recent(10) if t["job_id"] == "prof-traced"]
+    (e0, e_dur), = events["nomad/eval"]
+    for stage in ("sched_host", "kernel", "plan_submit"):
+        (p0, p_dur), = events["nomad/" + stage]
+        sp, = [s for s in tr["spans"] if s["name"] == stage]
+        at = tr["start"] + sp["t0_ms"] / 1000.0
+        assert p0 + offset == pytest.approx(at, abs=0.001)
+        assert p_dur == pytest.approx(sp["dur_ms"] / 1000.0, abs=0.001)
+        assert e0 <= p0 and p0 + p_dur <= e0 + e_dur
+    (h0, h_dur), = events["nomad/sched_host"]
+    for stage in ("kernel", "plan_submit"):
+        (p0, p_dur), = events["nomad/" + stage]
+        assert h0 <= p0 and p0 + p_dur <= h0 + h_dur
 
 
 # -- ring bounding / exemplars under churn -----------------------------
