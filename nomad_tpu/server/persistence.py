@@ -24,7 +24,8 @@ from ..models import (Allocation, Deployment, Evaluation, Job, Node,
 from ..models.alloc import DesiredTransition
 from ..models.deployment import DeploymentStatusUpdate
 from ..models.node import DrainStrategy
-from ..utils.codec import from_wire, to_wire
+from ..utils import stages
+from ..utils.codec import ShareMemo, from_wire, to_wire
 from ..utils.locks import make_lock
 
 # payload field -> model type (list-wrapped == repeated)
@@ -68,6 +69,11 @@ SCHEMAS: Dict[str, Dict[str, Any]] = {
 }
 
 
+# the entries the plan applier commits (stage wal_encode times their
+# framing, inside plan_commit)
+PLAN_ENTRIES = frozenset({"plan_results", "plan_group_results"})
+
+
 def _register_acl_schemas() -> None:
     # deferred: nomad_tpu.acl imports jobspec which imports models —
     # registering lazily avoids a cycle at module import time
@@ -104,19 +110,26 @@ def _register_acl_schemas() -> None:
 _register_acl_schemas()
 
 
-def encode_payload(msg_type: str, payload: dict) -> dict:
+def encode_payload(msg_type: str, payload: dict,
+                   memo: Optional[ShareMemo] = None) -> dict:
+    """The wire form of one raft entry's payload, for msgpack: the
+    tree is packed and dropped, never edited, so within one call an
+    object reached twice is walked once and its subtree reused by
+    reference (utils/codec.py ShareMemo) — a plan's 1,000 allocations
+    hang off one AllocatedResources and a few AllocMetrics, and the
+    bytes are what a walk of every occurrence gives. The memo never
+    outlives the call; a caller passes its own to read the counts."""
+    if memo is None:
+        memo = ShareMemo()
     if msg_type == "plan_group_results":
-        return {"groups": [encode_payload("plan_results", g)
+        return {"groups": [encode_payload("plan_results", g, memo)
                            for g in payload.get("groups", [])]}
     if msg_type == "ingest_batch":
         # each sub-entry encodes under its own kind's schema; the
         # "kind" tag itself is a plain string and rides through
-        return {"entries": [encode_payload(e.get("kind", ""), e)
+        return {"entries": [encode_payload(e.get("kind", ""), e, memo)
                             for e in payload.get("entries", [])]}
-    out = {}
-    for k, v in payload.items():
-        out[k] = to_wire(v)
-    return out
+    return {k: to_wire(v, memo) for k, v in payload.items()}
 
 
 def decode_payload(msg_type: str, data: dict) -> dict:
@@ -166,12 +179,21 @@ class RaftLog:
             self._f = None
 
     def append(self, index: int, msg_type: str, payload: dict,
-               sync: bool = False) -> None:
+               sync: bool = False) -> Tuple[int, int]:
+        """Frame and write one entry; returns the encoder's counts
+        (ShareMemo's objects, shared). A plan entry reports the
+        framing (wire form + packb, not the write) as stage
+        wal_encode."""
         import time as _time
-        frame = msgpack.packb(
-            {"i": index, "t": msg_type, "ts": _time.time(),
-             "p": encode_payload(msg_type, payload)},
-            use_bin_type=True)
+        memo = ShareMemo()
+        with (stages.span("wal_encode") if msg_type in PLAN_ENTRIES
+              else stages.NULL_SPAN) as sp:
+            frame = msgpack.packb(
+                {"i": index, "t": msg_type, "ts": _time.time(),
+                 "p": encode_payload(msg_type, payload, memo)},
+                use_bin_type=True)
+            sp.note(objects=memo.objects, shared=memo.shared,
+                    bytes=len(frame))
         with self._l:
             self._f.write(struct.pack("<I", len(frame)))
             self._f.write(frame)
@@ -181,6 +203,7 @@ class RaftLog:
                 self._dirty = False
             else:
                 self._dirty = True
+        return memo.objects, memo.shared
 
     def sync(self) -> None:
         """Group-fsync point: ONE fsync covers every append since the
@@ -311,6 +334,10 @@ class Persistence:
             "snapshot_skipped_inflight": 0, "last_snapshot_s": 0.0,
             "last_snapshot_format": 0, "snapshot_errors": 0,
             "restore_s": 0.0, "restore_format": 0,
+            # the WAL encoder (encode_payload): dataclass instances
+            # walked, and subtrees reused because the payload reached
+            # the same object again
+            "wal_objects": 0, "wal_shared": 0,
         }
         # server-level state (e.g. the GC TimeTable) rides along in the
         # snapshot under "extra"; the provider is set by the Server
@@ -361,7 +388,6 @@ class Persistence:
         mid-snapshot is ignored (os.replace is atomic, so the prior
         snapshot + un-truncated WAL are intact) and cleaned up."""
         import time as _time
-        from ..utils import stages
         t0 = _time.perf_counter()
         highest = 0
         tmp = self.snapshot_path + ".tmp"
@@ -389,8 +415,12 @@ class Persistence:
         return highest, entries
 
     def record(self, index: int, msg_type: str, payload: dict) -> None:
-        self.log.append(index, msg_type, payload,
-                        sync=self.wal_fsync and not self.wal_group_fsync)
+        objects, shared = self.log.append(
+            index, msg_type, payload,
+            sync=self.wal_fsync and not self.wal_group_fsync)
+        with self._stats_l:
+            self.stats["wal_objects"] += objects
+            self.stats["wal_shared"] += shared
 
     def commit_barrier(self) -> None:
         """Group-fsync boundary: called once per committed apply batch
@@ -466,7 +496,6 @@ class Persistence:
         drop the WAL prefix it covers (entries appended after the
         capture survive in the tail)."""
         import time as _time
-        from ..utils import stages
         t0 = _time.perf_counter()
         try:
             # the writer's own time, the wait for a sibling writer

@@ -377,8 +377,9 @@ class PlanApplier:
         # commit through the raft shim (FSM ApplyPlanResults)
         with trace.span("plan_commit", (tr,), track="applier",
                         group=1) as sp:
-            index, waiter = self.server.raft_apply_async(
-                "plan_results", payload)
+            with trace.use(tr, "applier"):      # wal_encode lands on it
+                index, waiter = self.server.raft_apply_async(
+                    "plan_results", payload)
             if chaos_faults.ACTIVE:
                 # same dispatched-not-yet-quorum window as the group
                 # path below — the failover cell must trip even when
@@ -476,8 +477,10 @@ class PlanApplier:
         # amortized its commit or paid one alone
         with trace.span("plan_commit", track="applier",
                         group=len(group)) as sp:
-            index, waiter = self.server.raft_apply_async(
-                "plan_group_results", dict(groups=payloads))
+            with trace.use_many(    # wal_encode lands on each member's
+                    [r._trace for _pe, r, _p, _e in entries], "applier"):
+                index, waiter = self.server.raft_apply_async(
+                    "plan_group_results", dict(groups=payloads))
             if chaos_faults.ACTIVE:
                 # chaos hook (ISSUE 16 leader_failover_commit cell):
                 # the group's entry is in the leader's log and

@@ -122,7 +122,7 @@ STAGE_PARENTS: Dict[str, Optional[str]] = {
     "select_finish": "sched_host", "plan_build": "sched_host",
     "plan_submit": "sched_host", "plan_queue_wait": "plan_submit",
     "plan_verify": "plan_submit", "plan_commit": "plan_submit",
-    "sched_host_self": "sched_host",
+    "wal_encode": "plan_commit", "sched_host_self": "sched_host",
 }
 
 # stages whose report site runs on the eval's OWN thread — or, for the
@@ -131,13 +131,16 @@ STAGE_PARENTS: Dict[str, Optional[str]] = {
 # rest (queue_wait, gateway_wait, plan_queue_wait, plan_verify,
 # plan_commit) are measured across threads and name their traces
 # through span()/report() below; the ambient hook emitting them too
-# would double-count or mis-attribute them.
+# would double-count or mis-attribute them. wal_encode is reported
+# deep under the applier's raft append (server/persistence.py), which
+# knows no plan: the applier installs the committing plans' traces as
+# its own thread's context round that call.
 AMBIENT_STAGES = frozenset({
     "restore", "wal_replay", "fence_wait", "sched_host", "reconcile",
     "preempt", "table_build", "h2d", "table_build_private",
     "select_prep", "feasibility", "kernel_pack", "kernel", "d2h",
     "kernel_expand", "select_finish", "plan_build", "plan_submit",
-    "sched_host_self", "broker_ack",
+    "wal_encode", "sched_host_self", "broker_ack",
 })
 
 
@@ -167,14 +170,16 @@ def current():
 def use(trace, track: Optional[str] = None):
     """Install one trace (or None for a no-op) as this thread's span
     context for the duration of the block."""
-    with use_many((trace,) if trace is not None else (), track):
+    with use_many((trace,), track):
         yield
 
 
 @contextmanager
 def use_many(traces, track: Optional[str] = None):
+    """The same for several traces (None among them counts for
+    nothing): one report lands on each."""
     prev = getattr(_tls, "ctx", _NO_CTX)
-    _tls.ctx = (tuple(traces), track)
+    _tls.ctx = (tuple(t for t in traces if t is not None), track)
     try:
         yield
     finally:

@@ -8,33 +8,98 @@ import dataclasses
 import enum
 import types
 import typing
-from typing import Any, get_args, get_origin, get_type_hints
+from typing import Any, Optional, get_args, get_origin, get_type_hints
 
 _HINTS_CACHE: dict = {}
 
+# values that are their own wire form, by exact type: the branch nearly
+# every field of a model takes
+_ATOMS = frozenset((str, int, float, bool, type(None)))
+_SELF, _ENUM, _BYTES, _DATACLASS, _DICT, _SEQ = range(6)
+# type -> (kind, field names): what to_wire does with an instance,
+# worked out once per type instead of once per object
+_PLANS: dict = {}
 
-def to_wire(obj: Any) -> Any:
+
+def _plan_for(t: type) -> tuple:
+    """Classify a type in the order the wire form has always tested an
+    instance in (bytes, then the scalars and their subclasses, enums,
+    dataclasses, dicts, sequences; anything else rides through), and
+    for a dataclass read its field names once."""
+    names = None
+    if issubclass(t, bytes):
+        kind = _BYTES
+    elif issubclass(t, (str, int, float, bool)):
+        kind = _SELF
+    elif issubclass(t, enum.Enum):
+        kind = _ENUM
+    elif dataclasses.is_dataclass(t):
+        kind = _DATACLASS
+        names = tuple(f.name for f in dataclasses.fields(t))
+    elif issubclass(t, dict):
+        kind = _DICT
+    elif issubclass(t, (list, tuple, set, frozenset)):
+        kind = _SEQ
+    else:
+        kind = _SELF
+    plan = _PLANS[t] = (kind, names)
+    return plan
+
+
+class ShareMemo(dict):
+    """One encoding's identity memo: id(obj) -> (obj, wire form), the
+    object held so its id cannot be reused while the memo lives. An
+    object reached twice is walked once and its wire form is reused BY
+    REFERENCE, so the tree aliases wherever the objects did: only for
+    a tree that is serialized and dropped, never edited (the WAL
+    record, server/persistence.py). `objects` counts the dataclass
+    instances walked, `shared` the subtrees reused."""
+
+    __slots__ = ("objects", "shared")
+
+    def __init__(self):
+        super().__init__()
+        self.objects = 0
+        self.shared = 0
+
+
+def to_wire(obj: Any, memo: Optional[ShareMemo] = None) -> Any:
     """Recursively convert dataclasses/enums/containers to plain data.
     bytes become tagged base64 dicts so the output is JSON-safe AND
-    round-trips losslessly even inside Any-typed containers."""
-    if isinstance(obj, bytes):
+    round-trips losslessly even inside Any-typed containers. Without a
+    memo every dict and list of the result is new and the caller's to
+    edit; with one see ShareMemo."""
+    t = type(obj)
+    if t in _ATOMS:
+        return obj
+    kind, names = _PLANS.get(t) or _plan_for(t)
+    if kind == _SELF:
+        return obj
+    if kind == _ENUM:
+        return obj.value
+    if kind == _BYTES:
         import base64
         return {"__b64__": base64.b64encode(obj).decode("ascii")}
-    if obj is None or isinstance(obj, (str, int, float, bool)):
-        return obj
-    if isinstance(obj, enum.Enum):
-        return obj.value
-    if dataclasses.is_dataclass(obj) and not isinstance(obj, type):
+    if memo is not None:
+        hit = memo.get(id(obj))
+        if hit is not None:
+            memo.shared += 1
+            return hit[1]
+    if kind == _DATACLASS:
         out = {}
-        for f in dataclasses.fields(obj):
-            v = getattr(obj, f.name)
-            out[f.name] = to_wire(v)
-        return out
-    if isinstance(obj, dict):
-        return {k: to_wire(v) for k, v in obj.items()}
-    if isinstance(obj, (list, tuple, set, frozenset)):
-        return [to_wire(v) for v in obj]
-    return obj
+        for name in names:
+            v = getattr(obj, name)
+            out[name] = v if type(v) in _ATOMS else to_wire(v, memo)
+        if memo is not None:
+            memo.objects += 1
+    elif kind == _DICT:
+        out = {k: v if type(v) in _ATOMS else to_wire(v, memo)
+               for k, v in obj.items()}
+    else:
+        out = [v if type(v) in _ATOMS else to_wire(v, memo) for v in obj]
+    if memo is not None:
+        memo[id(obj)] = (obj, out)
+    return out
 
 
 def from_wire(cls: Any, data: Any) -> Any:

@@ -64,6 +64,9 @@ same tree; a stage's time lies inside its parent's):
                       half; attrs group, demoted, queue_ms)
         plan_commit   raft append/apply + quorum wait + store
                       transaction (the write half; attrs group, index)
+          wal_encode    framing the plan's WAL record: wire form of
+                        the payload + msgpack (server/persistence.py;
+                        attrs objects, shared, bytes)
       sched_host_self  the part of sched_host no other span of the
                     eval's trace covers (union, not sum): scheduler
                     set-up, the eval-status write, thread hand-offs —
@@ -117,7 +120,7 @@ STAGES = ("restore", "wal_replay", "job_register", "snapshot_write",
           "select_prep", "feasibility", "gateway_wait", "kernel_pack",
           "kernel", "d2h", "kernel_expand", "select_finish",
           "plan_build", "plan_submit", "plan_queue_wait", "plan_verify",
-          "plan_commit", "sched_host_self", "broker_ack")
+          "plan_commit", "wal_encode", "sched_host_self", "broker_ack")
 
 # superset accumulators: wholly contain other stages' time (sched_host
 # wraps reconcile + kernel + d2h + plan_submit per eval, plan_submit

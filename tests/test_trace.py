@@ -368,14 +368,15 @@ ONCE_PER = {"select_prep": "select_finish", "feasibility": "select_prep",
             "select_finish": "plan_build", "plan_build": "select_prep",
             "kernel_pack": "kernel", "kernel_expand": "kernel",
             "plan_submit": "plan_queue_wait",
-            "plan_queue_wait": "plan_verify"}
+            "plan_queue_wait": "plan_verify",
+            "wal_encode": "plan_commit"}
 
 
 @pytest.mark.parametrize("stage", [
     "job_register", "select_prep", "feasibility", "kernel_pack",
     "kernel_expand", "select_finish", "plan_build", "plan_submit",
-    "plan_queue_wait", "table_build_private", "snapshot_write",
-    "sched_host_self"])
+    "plan_queue_wait", "wal_encode", "table_build_private",
+    "snapshot_write", "sched_host_self"])
 def test_stage_is_reported_once_per_occurrence_under_its_parent(
         served, stage):
     assert stage in stages.STAGES and stage in STAGE_PARENTS
@@ -419,6 +420,14 @@ def test_stage_is_reported_once_per_occurrence_under_its_parent(
         if stage == "plan_submit":
             assert all(sp["attrs"]["refreshed"] in (True, False)
                        for sp in spans)
+        if stage == "wal_encode":
+            # two placements off one flyweight: two Allocations and
+            # what they share, walked once
+            for sp in spans:
+                assert sp["track"] == "applier"
+                assert sp["attrs"]["objects"] >= 2
+                assert sp["attrs"]["shared"] >= 1
+                assert sp["attrs"]["bytes"] > 0
 
 
 def test_children_of_sched_host_and_self_sum_to_it(served):
