@@ -17,6 +17,7 @@ per-domain handlers in command/agent/*_endpoint.go. Routes:
 from __future__ import annotations
 
 import http.client
+import io
 import json
 import os
 import re
@@ -94,8 +95,21 @@ class HTTPApiServer:
                     self.send_header("X-Nomad-Index", str(index))
                 for k, v in (headers or {}).items():
                     self.send_header(k, v)
-                self.end_headers()
-                self.wfile.write(body)
+                self._send(body)
+
+            def _send(self, body: bytes) -> None:
+                """End the headers and send them with the body in ONE
+                write. The socket's wfile is unbuffered: two writes
+                are two segments, and on a kept-alive connection the
+                second waits (Nagle) for the client's delayed ACK of
+                the first — 40 ms on every GET."""
+                sock_file, self.wfile = self.wfile, io.BytesIO()
+                try:
+                    self.end_headers()
+                    head = self.wfile.getvalue()
+                finally:
+                    self.wfile = sock_file
+                self.wfile.write(head + body)
 
             def _error(self, code: int, msg: str,
                        headers: Optional[dict] = None):
@@ -133,8 +147,7 @@ class HTTPApiServer:
                                          "text/html; charset=utf-8")
                         self.send_header("Content-Length",
                                          str(len(body)))
-                        self.end_headers()
-                        self.wfile.write(body)
+                        self._send(body)
                         return
                     q = {k: v[0] for k, v in parse_qs(url.query).items()}
                     token = self.headers.get("X-Nomad-Token", "")
@@ -455,8 +468,7 @@ class HTTPApiServer:
         clen = resp.headers.get("Content-Length")
         if clen is not None:
             handler.send_header("Content-Length", clen)
-            handler.end_headers()
-            handler.wfile.write(resp.read(int(clen)))
+            handler._send(resp.read(int(clen)))
             return
         # chunked stream: relay each piece as it arrives (read1 returns
         # what's buffered instead of blocking for a full read)

@@ -1341,14 +1341,15 @@ def _expand_kway(req: SelectRequest, rounds) -> SelectResult:
     ))
 
 class DispatchCostModel:
-    """Measured per-shape dispatch costs, replacing the static step
-    constants once warm.
+    """Measured per-shape dispatch costs: what the gateways' solo-or-
+    batched question rests on (host-or-accelerator is no longer asked:
+    SelectKernel._pick_device).
 
     Every device phase (dispatch through result transfer) of the solo
     and batched kernel arms reports its wall clock here, keyed by
     (arm, n_pad) — batched arms report seconds PER LANE so solo and
-    batched numbers compare directly. The batching and host/accel
-    routing decisions then rest on what THIS host+device pair actually
+    batched numbers compare directly. The batching decisions then
+    rest on what THIS host+device pair actually
     measured at this table shape rather than on constants calibrated
     on different hardware (BENCH_r05: the static model demoted every
     broker lane on real TPU — service_broker_batches=0 — while the
@@ -1578,25 +1579,6 @@ DEVICE_STATS: Dict[str, float] = {
 DEVICE_ARM_STATS: Dict[str, List[float]] = {}
 
 
-# host/accelerator routing decisions (_pick_device): how many the
-# measured cost model made and how many the static step-cost prior
-# made, by destination, plus the inputs of the latest decision per
-# (arm, n_pad) — a run's record of WHY its dispatches landed where
-# they did
-ROUTE_STATS: Dict[str, int] = {}
-ROUTE_LAST: Dict[str, dict] = {}
-
-
-def _note_route(arm: str, n_pad: int, basis: str, to_cpu: bool,
-                est_cpu: float, est_accel: float) -> None:
-    key = f"{basis}->{'cpu' if to_cpu else 'accel'}"
-    with _DEVICE_L:
-        ROUTE_STATS[key] = ROUTE_STATS.get(key, 0) + 1
-        ROUTE_LAST[f"{arm}@{n_pad}"] = {
-            "basis": basis, "chose": "cpu" if to_cpu else "accel",
-            "est_cpu_s": est_cpu, "est_accel_s": est_accel}
-
-
 def _note_pack(n: int, n_pad: int) -> None:
     with _DEVICE_L:
         DEVICE_STATS["pad_n_sum"] += n
@@ -1619,7 +1601,8 @@ def device_stats_snapshot() -> Dict[str, object]:
     """One read for the bench artifact and the telemetry collector:
     pad-waste ratio, per-arm dispatch seconds / dispatch counts /
     fresh-compile counts (arms suffixed @cpu ran on the host backend,
-    @mesh on the sharded mesh), the router's decisions, and the device
+    @mesh on the sharded mesh), the measured round trip to the
+    accelerator, and the device
     ops that failed into a host path (device_table.DEVICE_OP_FAILURES;
     a healthy run holds none)."""
     from .device_table import DEVICE_OP_FAILURES
@@ -1628,9 +1611,7 @@ def device_stats_snapshot() -> Dict[str, object]:
         np_sum = DEVICE_STATS["pad_npad_sum"]
         packs = DEVICE_STATS["packs"]
         arms = {a: list(v) for a, v in DEVICE_ARM_STATS.items()}
-        routing = {"decisions": dict(ROUTE_STATS),
-                   "last": {k: dict(v) for k, v in ROUTE_LAST.items()},
-                   "accel_rtt_s": (_accel_rtt_cache[0]
+        routing = {"accel_rtt_s": (_accel_rtt_cache[0]
                                    if _accel_rtt_cache else None)}
     return {
         "routing": routing,
@@ -1763,6 +1744,15 @@ def decorrelation_slice(req, lane: int, total: int, cache):
     return slice_mask, cache
 
 
+def couples_nodes(req) -> bool:
+    """The ask's score (or its feasibility) at one node depends on
+    what the same dispatch placed on others — spread, distinct_*,
+    reserved-port exclusivity: the scan arm's asks. The rest are
+    node-local and take the chunked or the K-way arm."""
+    return bool(req.spreads or req.distinct_props or req.distinct_hosts
+                or req.scan_exclusive)
+
+
 def partition_lanes(reqs, lane_base: int, total: int, cache):
     """Decorrelate the lanes of ONE batched dispatch: identical argmax
     sequences would make every lane place on the same winners and
@@ -1799,12 +1789,10 @@ class SelectKernel:
     """Host wrapper: pads request arrays, routes the dispatch to the
     best backend, and unpacks results.
 
-    Routing (backend="auto"): when the default backend is an
-    accelerator, small placements still run on the host CPU backend —
-    a per-eval device dispatch costs two host<->device round trips
-    (inputs + results), which only amortizes over large batches. The
-    cost model compares measured round-trip latency against estimated
-    step counts; NOMAD_TPU_SELECT_BACKEND=cpu|accel|auto overrides.
+    Routing: every dispatch runs on the default backend — the
+    accelerator where there is one. NOMAD_TPU_SELECT_BACKEND=cpu forces
+    the host CPU backend (arms then read `<arm>@cpu`); `accel` and
+    `auto` both mean the default (_pick_device has the history).
 
     Two device kernels:
       - _select_chunked: node-local scoring (no spread/distinct/
@@ -1812,13 +1800,6 @@ class SelectKernel:
         O(nodes-touched) instead of O(count) sequential steps.
       - _select_scan: the general one-instance-per-step scan.
     """
-
-    # static prior for one accelerator scan step at 1k-16k nodes: an
-    # assumed rate, not re-measured on a local chip — _note_route
-    # records whether it or the measured cost model made each decision
-    _ACCEL_STEP_S = 150e-6
-    _CPU_STEP_BASE_S = 25e-6
-    _CPU_STEP_PER_NODE_S = 40e-9
 
     def __init__(self, backend: Optional[str] = None):
         import os
@@ -1851,36 +1832,21 @@ class SelectKernel:
         return self._sharded
 
     # -- routing -------------------------------------------------------
-    def _pick_device(self, n: int, est_steps: int, arm: str = "chunked"):
-        """Returns the CPU device to force host execution, or None to
-        use the default (accelerator) placement. Prefers MEASURED
-        per-shape dispatch costs (cost_model) over the static step
-        constants once either side is warm at this table shape."""
-        if jax.default_backend() == "cpu":
-            return None                      # already on host
-        if self.backend == "accel":
+    def _pick_device(self):
+        """The CPU device when NOMAD_TPU_SELECT_BACKEND=cpu forces the
+        host backend, else None: the default placement, which on a
+        machine with an accelerator is the accelerator, for every arm
+        and count. There is no cost model between the two any more
+        (PR 27): it compared a static prior fitted to a transport with
+        ~700x the local chip's latency and, once warm, per-arm EWMAs
+        that folded every count into one number — so the first three
+        one-instance scans went to the host by the prior, the EWMA of
+        those read faster than the chip's 50-instance scans, and whole
+        windows of the service cell ran `scan@cpu` with the chip 99.97%
+        idle, run by run differently."""
+        if self.backend != "cpu" or jax.default_backend() == "cpu":
             return None
-        cpu = _cpu_device()
-        if cpu is None:
-            return None
-        if self.backend == "cpu":
-            return cpu
-        meas_accel = cost_model.estimate(arm, n)
-        meas_cpu = cost_model.estimate(arm + "@cpu", n)
-        if meas_accel is not None and meas_cpu is not None:
-            # measured walls include d2h/unpack/continuation rounds the
-            # step formulas omit — only compare like against like; a
-            # lone measurement never overrides the formula pair
-            basis, est_cpu, est_accel = "measured", meas_cpu, meas_accel
-        else:
-            basis = "static"
-            est_cpu = est_steps * (self._CPU_STEP_BASE_S
-                                   + n * self._CPU_STEP_PER_NODE_S)
-            est_accel = 2 * _accel_roundtrip_s() \
-                + est_steps * self._ACCEL_STEP_S
-        to_cpu = est_cpu <= est_accel
-        _note_route(arm, n, basis, to_cpu, est_cpu, est_accel)
-        return cpu if to_cpu else None
+        return _cpu_device()
 
     @staticmethod
     def _place_args(args: Dict, dev) -> Dict:
@@ -1949,9 +1915,7 @@ class SelectKernel:
         _sanitize_request(req)
         sharded = self._mesh_sharded()
         if sharded is not None:
-            chunk_ok = (not req.spreads and not req.distinct_props
-                        and not req.distinct_hosts
-                        and not req.scan_exclusive)
+            chunk_ok = not couples_nodes(req)
             n_pad_sh = sharded.pad_to_shards(len(req.feasible))
             if chunk_ok and req.count > 512 and n_pad_sh > KWAY_W:
                 # the @mesh windows include packing and sharded
@@ -1982,20 +1946,14 @@ class SelectKernel:
             return sharded.select(req)      # observes scan@mesh itself
         n = len(req.feasible)
         n_pad = _pad_n(n)
-        chunk_ok = (not req.spreads and not req.distinct_props
-                    and not req.distinct_hosts and not req.scan_exclusive)
-        if chunk_ok:
-            # chunked steps ~ nodes touched + overtakes, bounded by count
-            est_steps = min(req.count, 2 * n)
-            arm = "kway" if req.count > 512 and n_pad > KWAY_W \
-                else "chunked"
-            dev = self._pick_device(n_pad, est_steps, arm=arm)
-            if arm == "kway":
+        if not couples_nodes(req):
+            dev = self._pick_device()
+            if req.count > 512 and n_pad > KWAY_W:
                 # big batches: K-way phases place on the top-32 nodes at
                 # once — an order of magnitude fewer sequential steps
                 return self._run_kway(req, n_pad, dev)
             return self._run_chunked(req, n_pad, dev)
-        dev = self._pick_device(n_pad, req.count, arm="scan")
+        dev = self._pick_device()
         k = _bucket_k(max(req.count, 1))
         with stages.span("kernel_pack"):
             args, statics = pack_request(req, n_pad)
@@ -2072,8 +2030,7 @@ class SelectKernel:
                            and r.algorithm == reqs[0].algorithm
                            for r in reqs)
         def _chunk_ok(r):
-            return (not r.spreads and not r.distinct_props
-                    and not r.distinct_hosts and not r.scan_exclusive)
+            return not couples_nodes(r)
 
         # small/medium chunk-eligible batches take the vmapped CHUNKED
         # kernel: steps ~ slowest lane's nodes-touched, the same
@@ -2110,9 +2067,7 @@ class SelectKernel:
             cargs = self._pad_and_stack(packs, _CHUNKED_ARGS)
             spread_alg = reqs[0].algorithm == "spread"
             cargs, mesh_ctx, on_cpu = self._place_batched(
-                cargs, sharded, reqs[0].capacity, n_pad,
-                sum(min(r.count, 2 * n) for r in reqs),
-                table=reqs[0].table)
+                cargs, sharded, reqs[0].capacity, table=reqs[0].table)
         w = _kway_w(n_pad)
         fresh = _note_trace("kway_batched", n_pad,
                             max_steps=_kway_steps(w),
@@ -2203,18 +2158,18 @@ class SelectKernel:
         return cargs
 
     def _place_batched(self, cargs: Dict, sharded, capacity_src,
-                       n_pad: int, est_steps: int, table=None):
+                       table=None):
         """Device placement for a stacked batch: mesh shardings when
         sharded (node axis split, lane axis replicated, capacity on the
-        mesh-resident table / identity cache), else the host/accel
-        cost-model pick. Returns (placed_cargs, mesh_context,
-        on_cpu)."""
+        mesh-resident table / identity cache), else the default
+        backend unless the host is forced. Returns (placed_cargs,
+        mesh_context, on_cpu)."""
         import contextlib
         if sharded is not None:
             placed = sharded.place_batched_chunked_args(
                 cargs, capacity_src=capacity_src, table=table)
             return placed, sharded.mesh, False
-        dev = self._pick_device(n_pad, est_steps)
+        dev = self._pick_device()
         return (self._place_args(cargs, dev), contextlib.nullcontext(),
                 dev is not None)
 
@@ -2229,11 +2184,11 @@ class SelectKernel:
         decision is measured-batched < measured-solo * tolerance.
         Until the batched side is warm, a periodic probe lets lanes
         fire so the measurement exists at all. The static fallback
-        remains: batch only when the dispatch would route to the
-        accelerator (on host-routed shapes B solo chunked dispatches
-        beat one vmapped dispatch and the GIL serializes lane host
-        work). Overridable with NOMAD_TPU_EVAL_BATCH=force|off (tests
-        force lanes on CPU hosts).
+        remains: batch only when the dispatch runs on an accelerator
+        (on the host backend B solo chunked dispatches beat one
+        vmapped dispatch and the GIL serializes lane host work).
+        Overridable with NOMAD_TPU_EVAL_BATCH=force|off (tests force
+        lanes on CPU hosts).
 
         `tolerance` > 1 is the continuous-batching caller's setting
         (server/worker.py MicroBatchGateway): the per-lane EWMA folds
@@ -2265,8 +2220,7 @@ class SelectKernel:
         if solo is not None and batched is None and \
                 cost_model.probe_due():
             return True                 # exploration: measure a batch
-        return self._pick_device(
-            n_pad, _bucket_k(max(count_hint, 1))) is None
+        return self._pick_device() is None
 
     def _run_chunked_batched(self, reqs: List[SelectRequest], n_pad: int,
                              sharded) -> List[SelectResult]:
@@ -2280,8 +2234,7 @@ class SelectKernel:
             packs = [pack_request(r, n_pad)[0] for r in reqs]
             cargs = self._pad_and_stack(packs, _CHUNKED_ARGS)
             cargs, mesh_ctx, on_cpu = self._place_batched(
-                cargs, sharded, reqs[0].capacity, n_pad,
-                min(maxc, 2 * n_pad), table=reqs[0].table)
+                cargs, sharded, reqs[0].capacity, table=reqs[0].table)
         fn = _chunked_batched_jit(max_steps, spread_alg)
         fresh = _note_trace("chunked_batched", n_pad,
                             max_steps=max_steps, spread_alg=spread_alg,
@@ -2369,8 +2322,7 @@ class SelectKernel:
                 p_live = max(p_live, st["p_live"])
             cargs = self._pad_and_stack(packs, _SCAN_ARGS)
             cargs, mesh_ctx, on_cpu = self._place_batched(
-                cargs, sharded, reqs[0].capacity, n_pad, k,
-                table=reqs[0].table)
+                cargs, sharded, reqs[0].capacity, table=reqs[0].table)
         fn = _scan_batched_jit(k, spread_alg, s_live, p_live)
         fresh = _note_trace("scan_batched", n_pad, k_steps=k,
                             s_live=s_live, p_live=p_live,

@@ -37,6 +37,8 @@ from .util import (adjust_queued_allocations, tainted_nodes,
 
 MAX_SERVICE_ATTEMPTS = 5
 MAX_BATCH_ATTEMPTS = 2
+# races an eval may lose in all before it fails (process())
+MAX_RACES_LOST = 32
 
 BLOCKED_EVAL_MAX_PLAN_DESC = "created due to placement conflicts"
 BLOCKED_EVAL_FAILED_PLACEMENTS = "created to place remaining allocations"
@@ -88,11 +90,21 @@ class GenericScheduler:
         # that committed ANYTHING resets the attempt budget — under
         # optimistic concurrency a storm of plan conflicts burns rounds
         # while still converging, and only zero-progress rounds may
-        # exhaust the limit
-        progress = [False]
+        # exhaust the limit. A plan that commits whole or not at all
+        # (a one-instance eval; every node-coupling ask: _process_once)
+        # can show no partial progress, so a round it LOST — refused
+        # against a state newer than the one it ranked: another plan
+        # took its node first — resets the budget too: two workers
+        # that rank the whole fleet choose the same fullest node, and
+        # five races lost in a row (a one-instance eval of the 10k-node
+        # service cell, PR 27) are no reason to fail an eval the fleet
+        # has room for. A refusal against the very state the plan
+        # ranked still counts, and MAX_RACES_LOST bounds the races.
+        progress = [False, False]       # committed something, lost a race
         attempts = 0
+        races = 0
         while True:
-            progress[0] = False
+            progress[:] = (False, False)
             try:
                 done = self._process_once(progress)
             except SetStatusError as e:
@@ -102,6 +114,10 @@ class GenericScheduler:
                 self._set_status(EVAL_STATUS_COMPLETE, "")
                 return
             if progress[0]:
+                attempts = 0
+                continue
+            if progress[1] and races < MAX_RACES_LOST:
+                races += 1
                 attempts = 0
                 continue
             attempts += 1
@@ -133,6 +149,7 @@ class GenericScheduler:
         self.followup_evals = []
 
         self.plan = ev.make_plan(self.job)
+        ranked_index = snapshot.latest_index()
         self.blocked = None
         self.ctx = EvalContext(snapshot, ev, self.plan)
         self.engine = PlacementEngine(snapshot)
@@ -172,6 +189,16 @@ class GenericScheduler:
         if self.plan.is_no_op():
             return True
 
+        # a node-coupling ask (spread, distinct_*) is one greedy
+        # sequence: each step was scored on the steps before it having
+        # landed. A plan that keeps what the applier accepted of it and
+        # re-places the rest holds nodes no ranking of the fleet would
+        # choose (a chosen node's coupled score 0.2 below the best
+        # node's: a machine class) — so it commits whole or is ranked
+        # again, whole, against the state that refused it
+        if self.engine.coupled:
+            self.plan.all_at_once = True
+
         # submit the plan
         result = self.planner.submit_plan(self.plan)
         self.plan_result = result
@@ -187,6 +214,7 @@ class GenericScheduler:
                     result.refresh_index) if hasattr(
                         self.planner, "refreshed_state") else self.state
             progress[0] = actual > 0
+            progress[1] = result.refresh_index > ranked_index
             return False
         return True
 

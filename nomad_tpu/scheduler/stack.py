@@ -31,9 +31,10 @@ from ..models.resources import (AllocatedCpuResources,
                                 AllocatedMemoryResources)
 from ..ops import NodeTable, ProposedIndex, SelectKernel, SelectRequest
 from ..ops import spread as spread_ops
-from ..ops.select import TOP_K
+from ..ops.select import TOP_K, couples_nodes
 from ..ops.tables import DIM_NAMES
 from ..ops.targets import affinity_columns, constraint_mask
+from ..utils import stages
 from ..utils.locks import make_lock
 
 
@@ -204,6 +205,10 @@ class PlacementEngine:
         # set when push_combined parks a combined mask on the mirror
         self._feas_tokens: Dict[Tuple, Tuple] = {}
         self._feas_push_s = 0.0
+        self._mask_build_s: Optional[float] = None
+        # a select of this eval coupled the nodes (ops/select.py
+        # couples_nodes): its plan commits whole or not at all
+        self.coupled = False
         # per-eval NetworkIndex cache: shared across select_batch calls so
         # port offers stay consistent between task groups of one plan
         self._net_cache: Dict[str, NetworkIndex] = {}
@@ -373,9 +378,9 @@ class PlacementEngine:
         (static key, datacenters) — many evals for the same job skip
         the whole masking pass, not just the column builds. Callers
         must copy before mutating (select_batch does)."""
-        from ..utils import stages
         if not stages.enabled:
             return self._feasibility(tg)
+        self._mask_build_s = None
         t0 = time.perf_counter()
         out = self._feasibility(tg)
         dt = time.perf_counter() - t0
@@ -385,6 +390,12 @@ class PlacementEngine:
         # mask-build attribution the bench compares across arms
         push = self._feas_push_s
         self._feas_push_s = 0.0
+        # the combined mask was BUILT (no cache answered): reported
+        # here, beside its parent, so that both are drawn as ending
+        # together whatever the park after the build took
+        built, self._mask_build_s = self._mask_build_s, None
+        if built is not None:
+            stages.add("mask_build", built)
         stages.add("feasibility", max(dt - push, 0.0))
         if push > 0.0:
             stages.add("h2d", push, {"mask_park": True})
@@ -420,6 +431,7 @@ class PlacementEngine:
             ENGINE_CACHE_STATS["mask_misses"] += 1
         else:
             ENGINE_CACHE_STATS["mask_uncached"] += 1
+        t_build = time.perf_counter()
         mask = self._base_mask.copy()
         counts: Dict[str, int] = {}
         for reason, m in self._static_checks(tg, ent.static_key):
@@ -428,6 +440,7 @@ class PlacementEngine:
             if n:
                 counts[reason] = counts.get(reason, 0) + n
             mask &= m
+        self._mask_build_s = time.perf_counter() - t_build
         out = (mask, counts)
         if feas_key is not None:
             t.mask_cache[feas_key] = out
@@ -563,7 +576,6 @@ class PlacementEngine:
         reduced by the victims' resources and they carry the logistic
         preemption scorer; victims are staged into the plan when such a
         node wins."""
-        from ..utils import stages
         assert self.table is not None and self.job is not None
         start = time.monotonic_ns()
         with stages.span("select_prep"):
@@ -674,14 +686,6 @@ class PlacementEngine:
                 if row is not None:
                     penalty[row] = True
 
-        # affinities: job + group + tasks (rank.go NodeAffinityIterator)
-        affinities = list(self.job.affinities) + list(tg.affinities)
-        for task in tg.tasks:
-            affinities.extend(task.affinities)
-        aff_col, aff_sum = (None, 0.0)
-        if affinities:
-            aff_col, aff_sum = affinity_columns(t.cols, affinities)
-
         dyn_ports, reserved_ports = ent.port_asks
         port_ok = t.reserved_ports_ok(reserved_ports) if reserved_ports else None
 
@@ -696,8 +700,18 @@ class PlacementEngine:
                 t.nodes, dev_asks,
                 lambda nid: self._proposed_allocs_on(nid, proposed.plan))
 
-        t_build = time.perf_counter()
-        spreads, sum_spread_w = self._spread_inputs(tg, proposed)
+        # affinities: job + group + tasks (rank.go NodeAffinityIterator)
+        affinities = list(self.job.affinities) + list(tg.affinities)
+        for task in tg.tasks:
+            affinities.extend(task.affinities)
+        aff_col, aff_sum = (None, 0.0)
+        with stages.span("spread_inputs") as sp:
+            if affinities:
+                aff_col, aff_sum = affinity_columns(t.cols, affinities)
+            t_build = time.perf_counter()
+            spreads, sum_spread_w = self._spread_inputs(tg, proposed)
+            if not affinities and not spreads:
+                sp.cancel()
         distinct_props = self._distinct_prop_inputs(tg, proposed)
         distinct_hosts = self._has_distinct_hosts(tg)
         if spreads or distinct_props:
@@ -819,6 +833,8 @@ class PlacementEngine:
             feas_token=feas_token,
             feas_residue=feas_residue,
         )
+        if couples_nodes(req):
+            self.coupled = True
         return req, (count, count_requested, csi_cap_source,
                      filtered_counts, dev_asks, dyn_ports, reserved_ports,
                      pre_score)
@@ -887,6 +903,10 @@ class PlacementEngine:
         simple_networks = (not simple_resources and not dev_asks
                            and dyn_ports == 0 and not reserved_ports)
         node_fly: Dict[int, Tuple] = {}
+        # the port_assign stage: the winners' NetworkIndex builds and
+        # offers, summed here and reported once as the loop ends
+        timed = stages.enabled and not simple_resources
+        assign_s, assigned = 0.0, 0
         for step in range(count):
             idx = node_idx_l[step]
             if same_prev[step] and shared_metric is not None:
@@ -913,6 +933,8 @@ class PlacementEngine:
                         proposed.plan.append_preempted_alloc(v, "")
                     saved_net = self._net_cache.pop(node.id, None)
                     saved_dev = self._dev_cache.pop(node.id, None)
+            if timed:
+                t_assign = time.perf_counter()
             if simple_resources:
                 task_resources, shared, ok = fly_tr, fly_shared, True
             elif simple_networks and idx in node_fly:
@@ -934,6 +956,9 @@ class PlacementEngine:
                     node, tg, proposed.plan)
                 if simple_networks and ok:
                     node_fly[idx] = (task_resources, shared, ok)
+            if timed:
+                assign_s += time.perf_counter() - t_assign
+                assigned += 1
             if not ok:
                 # roll the staged victims back: an eviction without a
                 # replacement placement must not reach the plan
@@ -971,6 +996,9 @@ class PlacementEngine:
                 metrics=metrics,
                 preempted_allocs=victims,
             ), metrics))
+        if assigned:
+            stages.add("port_assign", assign_s,
+                       {"ports": assigned * dyn_ports, "winners": assigned})
         # instances beyond the CSI write-claim budget fail placement
         # with the volume named, instead of being staged unclaimable
         for _ in range(count_requested - count):

@@ -101,7 +101,9 @@ class ServerConfig:
     failed_eval_unblock_delay_s: float = 60.0
     dev_mode: bool = True
     data_dir: str = ""              # empty == in-memory only
-    snapshot_every: int = 1024      # WAL entries between snapshots
+    # WAL entries between snapshots (1 GiB of WAL triggers one too:
+    # server/persistence.py SNAPSHOT_WAL_BYTES)
+    snapshot_every: int = 8192
     # columnar snapshot & cold-start recovery pipeline (ISSUE 8,
     # server/persistence.py + state/columnar.py):
     # write format-2 columnar snapshots (struct-of-arrays framed in

@@ -295,7 +295,19 @@ class Persistence:
     # local by construction — never replicated, safe to delete
     COST_MODEL = "cost_model.json"
 
-    def __init__(self, data_dir: str, snapshot_every: int = 1024, *,
+    # a snapshot is due after `snapshot_every` WAL entries OR this many
+    # WAL bytes since the last one, whichever comes first. Entries:
+    # 8,192 is hashicorp/raft's SnapshotThreshold, which upstream
+    # Nomad runs with; the 1,024 of before put a whole-store dump of
+    # 400k allocations — 5-7 s on a thread that shares the GIL, during
+    # which scheduling all but stops — inside every 20 s of a stream
+    # of small service evals (PR 27). Bytes: a stream of 1,000-
+    # placement plans writes 2.6 MB an entry, and 8,192 of those would
+    # be a 20 GB log to replay; 1 GiB keeps that regime's cadence where
+    # 1,024 entries had it
+    SNAPSHOT_WAL_BYTES = 1 << 30
+
+    def __init__(self, data_dir: str, snapshot_every: int = 8192, *,
                  columnar: bool = True, background: bool = True,
                  wal_fsync: bool = False, wal_group_fsync: bool = True):
         self.data_dir = data_dir
@@ -315,6 +327,7 @@ class Persistence:
         os.makedirs(data_dir, exist_ok=True)
         self.log = RaftLog(os.path.join(data_dir, self.WAL))
         self._since_snapshot = 0
+        self._bytes_at_snapshot = 0     # log.size() at the last trigger
         self._l = make_lock()
         self._snap_l = make_lock()      # one snapshot writer
         self._trigger_l = make_lock()
@@ -439,9 +452,12 @@ class Persistence:
         of a large store."""
         with self._l:
             self._since_snapshot += 1
-            if self._since_snapshot < self.snapshot_every:
+            size = self.log.size()
+            if self._since_snapshot < self.snapshot_every and \
+                    size - self._bytes_at_snapshot < self.SNAPSHOT_WAL_BYTES:
                 return
             self._since_snapshot = 0
+            self._bytes_at_snapshot = size
         self.trigger_snapshot(store)
 
     def trigger_snapshot(self, store) -> Optional[threading.Thread]:

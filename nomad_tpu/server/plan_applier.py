@@ -598,6 +598,14 @@ class PlanApplier:
         result.deployment_updates = list(plan.deployment_updates)
         if rejected:
             result.refresh_index = snapshot.latest_index()
+            if plan.all_at_once:
+                # gang commit (plan_apply.go evaluatePlan): one refused
+                # node refuses the plan, stops and deployment included
+                result.node_update = {}
+                result.node_allocation = {}
+                result.node_preemptions = {}
+                result.deployment = None
+                result.deployment_updates = []
         if result.is_no_op():
             return result, None, [], conflicted
 

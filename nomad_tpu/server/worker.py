@@ -538,6 +538,10 @@ class MicroBatchGateway:
             if slice_mask is not None:
                 original = req.feasible
                 req.feasible = slice_mask
+                # the sliced mask no longer matches the device-resident
+                # copy, which would be ranked in its place
+                req.feas_token = None
+                req.feas_residue = None
                 res = self._kernel.select(req)
                 if res.placed < req.count:
                     req.feasible = original
@@ -877,6 +881,10 @@ class Worker:
                 else "nomad.worker.invoke_scheduler_core", t0)
             gov = getattr(self.server, "governor", None)
             elapsed = time.monotonic() - t0
+            # what the pressure gauge may read of it: not the
+            # collections that ran meanwhile (utils/gcsafe.py PAUSES)
+            host_s = max(elapsed - gcsafe.pause_overlap_s(
+                t0, t0 + elapsed), 0.0)
 
             # service-latency attribution fix (ISSUE 7 satellite): the
             # broker stamps how long the eval sat in the READY queue;
@@ -896,7 +904,7 @@ class Worker:
                     # work in wall clock, and feeding that raw into
                     # the p99 gauge would engage backpressure on
                     # healthy wide batches (then oscillate lane width)
-                    gov.observe_eval_latency(elapsed / lat_scale,
+                    gov.observe_eval_latency(host_s / lat_scale,
                                              queue_wait_s=q_wait)
                 with trace.use(tr), stages.span("broker_ack"):
                     self.broker.ack(ev.id, token)

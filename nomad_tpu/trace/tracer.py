@@ -117,9 +117,11 @@ STAGE_PARENTS: Dict[str, Optional[str]] = {
     "reconcile": "sched_host", "preempt": "sched_host",
     "table_build_private": "sched_host",
     "select_prep": "sched_host", "feasibility": "select_prep",
+    "mask_build": "feasibility", "spread_inputs": "select_prep",
     "gateway_wait": "sched_host", "kernel_pack": "sched_host",
     "kernel": "sched_host", "d2h": "kernel", "kernel_expand": "kernel",
-    "select_finish": "sched_host", "plan_build": "sched_host",
+    "select_finish": "sched_host", "port_assign": "select_finish",
+    "plan_build": "sched_host",
     "plan_submit": "sched_host", "plan_queue_wait": "plan_submit",
     "plan_verify": "plan_submit", "plan_commit": "plan_submit",
     "wal_encode": "plan_commit", "sched_host_self": "sched_host",
@@ -138,8 +140,9 @@ STAGE_PARENTS: Dict[str, Optional[str]] = {
 AMBIENT_STAGES = frozenset({
     "restore", "wal_replay", "fence_wait", "sched_host", "reconcile",
     "preempt", "table_build", "h2d", "table_build_private",
-    "select_prep", "feasibility", "kernel_pack", "kernel", "d2h",
-    "kernel_expand", "select_finish", "plan_build", "plan_submit",
+    "select_prep", "feasibility", "mask_build", "spread_inputs",
+    "kernel_pack", "kernel", "d2h", "kernel_expand", "select_finish",
+    "port_assign", "plan_build", "plan_submit",
     "wal_encode", "sched_host_self", "broker_ack",
 })
 

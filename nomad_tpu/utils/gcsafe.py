@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import gc
 import time
+from collections import deque
 from .locks import make_lock
 
 _lock = make_lock()
@@ -40,6 +41,29 @@ MIN_COLLECT_INTERVAL_S = 0.05
 # while workers were busy). After freeze_steady_state() the full pass
 # skips the frozen substrate, so it stays cheap even at C2M scale.
 FULL_COLLECT_INTERVAL_S = 10.0
+
+
+# the collections run here, as (start, end) on time.monotonic: every
+# thread stands still for one (it holds the GIL), so an eval that
+# spans one reads that much longer without the host being any busier.
+# The governor's latency gauge takes them out (server/worker.py): a
+# 1.6 s full collection every 10 s inside 1-2% of the evals is a p99
+# over its one-second watermark, and the shed valve it opens parks
+# every new eval for seconds (PR 27)
+PAUSES: deque = deque(maxlen=1024)
+MIN_PAUSE_S = 0.001
+
+
+def pause_overlap_s(t0: float, t1: float) -> float:
+    """Seconds of [t0, t1] (time.monotonic) spent inside collections
+    that safepoint() ran."""
+    total = 0.0
+    for start, end in reversed(list(PAUSES)):   # newest first, in order
+        if end <= t0:
+            break
+        if start < t1:
+            total += min(end, t1) - max(start, t0)
+    return total
 
 
 def enter() -> None:
@@ -84,6 +108,9 @@ def safepoint() -> None:
             gc.collect()
         else:
             gc.collect(1)
+        end = time.monotonic()
+        if end - now >= MIN_PAUSE_S:
+            PAUSES.append((now, end))
     finally:
         _lock.release()
 

@@ -42,6 +42,14 @@ same tree; a stage's time lies inside its parent's):
       select_prep   select_batch entry -> just before the dispatch:
                     masks, CSI, affinities, spread inputs, the request
         feasibility   constraint-mask production (cached per table)
+          mask_build    the combined mask built: the static columns
+                        (compiled, or the scalar reference) ANDed over
+                        the ready-in-datacenter base, with the filtered
+                        counts — only when no cache answered (reported
+                        beside feasibility, as the build's seconds)
+        spread_inputs the affinity column and the spreads' kernel state
+                      (value codes, proposed counts, desired counts);
+                      only for a group that has an affinity or a spread
       gateway_wait  the request parked in the micro-batch gateway
                     (attrs trigger, batch, lanes)
       kernel_pack   pack_request / stacking / argument placement: what
@@ -53,6 +61,11 @@ same tree; a stage's time lies inside its parent's):
         kernel_expand host unpack/expand of the fetched result
       select_finish dispatch returned -> RankedNodes returned: winner
                     materialization, resources, ports, metrics
+        port_assign   the winners' NetworkIndex builds and their port
+                      and bandwidth offers (_net_index_for +
+                      _assign_resources), summed over the batch's
+                      winners and reported once, as select_finish
+                      ends (attrs ports, winners)
       plan_build    appending the placements to the plan (attr
                     placements; a placement that found no node runs
                     its fallback select / preemption search in here)
@@ -117,8 +130,9 @@ from .locks import make_lock
 STAGES = ("restore", "wal_replay", "job_register", "snapshot_write",
           "queue_wait", "fence_wait", "sched_host", "reconcile",
           "preempt", "table_build", "h2d", "table_build_private",
-          "select_prep", "feasibility", "gateway_wait", "kernel_pack",
-          "kernel", "d2h", "kernel_expand", "select_finish",
+          "select_prep", "feasibility", "mask_build", "spread_inputs",
+          "gateway_wait", "kernel_pack", "kernel", "d2h",
+          "kernel_expand", "select_finish", "port_assign",
           "plan_build", "plan_submit", "plan_queue_wait", "plan_verify",
           "plan_commit", "wal_encode", "sched_host_self", "broker_ack")
 
