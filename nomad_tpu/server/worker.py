@@ -700,10 +700,11 @@ class Worker:
             self._paused.clear()
 
     def run(self) -> None:
-        # GC safepoints (utils/gcsafe.py): automatic collections on a
-        # C2M-sized heap land mid-eval and cost 30-60 ms of scheduling
-        # latency; when enabled, collection happens between evals
-        # instead — coordinated across workers, restored on exit
+        # GC safepoints (utils/gcsafe.py): automatic collections land
+        # mid-eval, and a full one walks the whole resident heap (1.8 s
+        # at 1.9M objects); when enabled, collection happens between
+        # evals instead, a full pass walks only what was allocated since
+        # the last — coordinated across workers, restored on exit
         use_safepoints = getattr(self.server.config,
                                  "gc_safepoints", False)
         if use_safepoints:

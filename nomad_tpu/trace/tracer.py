@@ -111,7 +111,7 @@ OWN_P99_EVERY = 32
 # inside plan_build's interval, and count twice)
 STAGE_PARENTS: Dict[str, Optional[str]] = {
     "restore": None, "wal_replay": None, "job_register": None,
-    "snapshot_write": None,
+    "snapshot_write": None, "gc_full": None, "gc_whole_walk": None,
     "queue_wait": "eval", "fence_wait": "eval", "table_build": "eval",
     "h2d": "table_build", "sched_host": "eval", "broker_ack": "eval",
     "reconcile": "sched_host", "preempt": "sched_host",

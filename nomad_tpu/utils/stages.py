@@ -15,6 +15,13 @@ same tree; a stage's time lies inside its parent's):
                   job committed and eval enqueued (attr jobs)
     snapshot_write  the background snapshot writer: serialize, fsync,
                   publish, truncate the WAL (attrs entries, bytes)
+    gc_full       a full collection at a safepoint (utils/gcsafe.py),
+                  its survivors frozen: every thread stands still for
+                  it (attrs walked, the tracked objects it traversed;
+                  frozen, the permanent generation after it)
+    gc_whole_walk   the full pass that unfroze first and so walked
+                  every object: the same interval as its gc_full
+                  (attrs walked, reclaimed)
   eval            enqueue -> ack (the trace's root, not a stage)
     queue_wait    time the eval sat in the broker before a worker
                   dequeued it (dead time — see SHARE_EXCLUDED)
@@ -83,8 +90,9 @@ same tree; a stage's time lies inside its parent's):
       sched_host_self  the part of sched_host no other span of the
                     eval's trace covers (union, not sum): scheduler
                     set-up, the eval-status write, thread hand-offs —
-                    and collector pauses, which nothing inside the
-                    program times
+                    and a collector pause that falls between spans (a
+                    worker's safepoint stops its sibling mid-eval: the
+                    pause lands in whatever span is open there)
 
 r8 lumped verify, raft apply, and ack bookkeeping into one
 `plan_apply` bucket; the group-commit applier splits it so the bench
@@ -128,6 +136,7 @@ from typing import Callable, Dict, Optional
 from .locks import make_lock
 
 STAGES = ("restore", "wal_replay", "job_register", "snapshot_write",
+          "gc_full", "gc_whole_walk",
           "queue_wait", "fence_wait", "sched_host", "reconcile",
           "preempt", "table_build", "h2d", "table_build_private",
           "select_prep", "feasibility", "mask_build", "spread_inputs",
@@ -153,10 +162,12 @@ SHARE_SUPERSETS = frozenset({"sched_host", "plan_submit"})
 # fence_wait (ISSUE 16) is the same kind of dead time — replication
 # lag observed at the snapshot fence, ~0 on a leader and bounded by
 # follower_fence_timeout_s on a lagging follower; plan_queue_wait is
-# the plan's wait behind the serialization point
+# the plan's wait behind the serialization point; gc_whole_walk is the
+# very interval its gc_full reports
 SHARE_EXCLUDED = SHARE_SUPERSETS | frozenset({"queue_wait",
                                               "fence_wait",
-                                              "plan_queue_wait"})
+                                              "plan_queue_wait",
+                                              "gc_whole_walk"})
 
 # cold-start stages dilute steady-state shares when a run cold-boots
 # mid-round (ISSUE 9 satellite): snapshot() reports `steady_share`

@@ -138,6 +138,11 @@ def test_the_plan_that_lost_the_race_is_ranked_again_whole(served,
     rows = _put_and_read(served, [first, second])
     jobs = {j["id"]: j for j in (first, second)}
     won, lost = order
+    # the allocations are readable as the applier commits; the worker
+    # that submitted notes its result a thread switch later
+    deadline = time.time() + WAIT_S
+    while None in submits[lost] and time.time() < deadline:
+        time.sleep(0.02)
 
     assert submits[won] == [(50, 50, True)]
     # refused whole, though most of its nodes still had room for it ...

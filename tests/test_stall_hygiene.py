@@ -14,18 +14,40 @@ from nomad_tpu.server.persistence import Persistence
 from nomad_tpu.utils import gcsafe
 
 
-def test_safepoint_collections_are_on_the_ledger(monkeypatch):
-    monkeypatch.setattr(gcsafe, "_last_collect", 0.0)
-    monkeypatch.setattr(gcsafe, "_last_full_collect", 0.0)
+@pytest.mark.parametrize("whole", [False, True],
+                         ids=["full_pass", "whole_walk"])
+def test_safepoint_collections_are_on_the_ledger(monkeypatch, whole):
+    """A full pass that walks what is new, and one that unfreezes and
+    walks everything (the regime's first; later ones by growth): either
+    way the whole interval, freeze and count included, is a pause the
+    governor's gauge can take out."""
+    from nomad_tpu.utils import stages
+    monkeypatch.setattr(gcsafe, "MIN_COLLECT_INTERVAL_S", 0.0)
     monkeypatch.setattr(gcsafe, "MIN_PAUSE_S", 0.0)
-    gcsafe.PAUSES.clear()
-    t0 = time.monotonic()
-    with gcsafe.safepoints():
-        gcsafe.safepoint()              # a full collection: the budget is due
-    t1 = time.monotonic()
+    walks = []
+    prev, prev_on = stages._trace_hook, stages._trace_on
+    stages.set_trace_hook(
+        lambda stage, seconds, attrs=None:
+        stage == "gc_whole_walk" and walks.append(seconds))
+    try:
+        with gcsafe.safepoints():
+            if not whole:
+                monkeypatch.setattr(gcsafe, "_last_full_collect", 0.0)
+                gcsafe.safepoint()      # the regime's first, out of the way
+                del walks[:]
+            monkeypatch.setattr(gcsafe, "_last_collect", 0.0)
+            monkeypatch.setattr(gcsafe, "_last_full_collect", 0.0)
+            gcsafe.PAUSES.clear()
+            t0 = time.monotonic()
+            gcsafe.safepoint()          # a full collection: the budget is due
+            t1 = time.monotonic()
+    finally:
+        stages.set_trace_hook(prev, on=prev_on)
     (start, end), = gcsafe.PAUSES
     assert t0 <= start <= end <= t1
     assert gcsafe.pause_overlap_s(t0, t1) == end - start
+    assert len(walks) == (1 if whole else 0)
+    assert all(0.0 < s <= end - start for s in walks)
     gcsafe.PAUSES.clear()
 
 
