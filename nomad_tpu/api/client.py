@@ -393,7 +393,7 @@ class ApiClient:
             params={"n": str(last)} if last else None)
 
     def flatness(self) -> dict:
-        """Live steady-state verdict: bench/soak.flatness_verdict run
+        """Live steady-state verdict: telemetry flatness_verdict run
         over the in-process telemetry ring."""
         return self._request("GET", "/v1/operator/flatness")
 

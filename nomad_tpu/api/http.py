@@ -1056,8 +1056,8 @@ class HTTPApiServer:
             out.update(tel.history(last=last or None))
             return out, idx
 
-        # live flatness verdict (ISSUE 11): bench/soak.flatness_verdict
-        # — the soak artifact's pass/fail math — run over the live
+        # live flatness verdict (ISSUE 11): telemetry's
+        # flatness_verdict run over the live
         # telemetry ring, so an operator (or the validation campaign)
         # reads steady-state health without a post-hoc harness
         if path == "/v1/operator/flatness" and method == "GET":

@@ -7,13 +7,12 @@ A cell's artifact section carries, per the FoundationDB/Jepsen shape
 the ROADMAP names: the seeded workload's throughput (placements/s,
 p50/p99 of the workload's settle latencies), EVERY invariant verdict
 with its evidence, a flatness verdict over the cell's windows (the
-SAME `bench/soak.flatness_verdict` math the soak and the live
-/v1/operator/flatness route use), the exact fault schedule the
+SAME `telemetry.collector.flatness_verdict` math the live
+/v1/operator/flatness route uses), the exact fault schedule the
 injector delivered, and the r18 race-sanitizer finding count when the
 cell ran under NOMAD_TPU_RACE=1.
 
-Entry points: `run_matrix` (the `nomad dev chaos` CLI and
-`bench_scenario_matrix` in bench/ladder.py), `run_cell` (tests drive
+Entry points: `run_matrix` (the `nomad dev chaos` CLI), `run_cell` (tests drive
 single cells), `write_artifact`/`latest_artifact` (CHAOS_rNN.json;
 `nomad operator debug` bundles the latest one as chaos.json).
 """
@@ -156,19 +155,19 @@ class Cell:
 
     # -- verdict assembly ----------------------------------------------
     def flatness(self) -> dict:
-        """The soak's verdict math over this cell's windows. Quick
+        """The flatness verdict's math over this cell's windows. Quick
         cells run seconds-long windows, where an RSS least-squares
         slope extrapolated to MB/HOUR is dominated by allocator noise
         (the r15 live-verdict note measured -10161 MB/h on a healthy
         agent) — so quick mode widens the bounds and records that it
-        did; the full matrix uses the soak's production bounds."""
-        from ..bench.soak import flatness_verdict
+        did; the full matrix uses the verdict's production bounds."""
+        from ..telemetry.collector import flatness_verdict
         if self.quick:
             # bound TOTAL growth, not the hourly extrapolation: allow
             # <=192 MB across the whole quick cell (JIT compiles +
             # bounded caches filling to plateau), expressed as the
             # equivalent slope over the cell's actual span so the
-            # verdict's units match the soak's
+            # verdict's units match the production bounds'
             span_h = max((self._windows[-1]["t_min"]
                           - self._windows[0]["t_min"]) / 60.0, 1e-4)
             verdict = flatness_verdict(self._windows,

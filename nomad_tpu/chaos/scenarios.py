@@ -1,5 +1,5 @@
 """The scenario matrix's cells (ISSUE 15): everything the reference's
-scheduler surface covers that the bench ladder didn't, each run under
+scheduler surface covers that the old bench ladder didn't, each run under
 an injected fault with invariant checks.
 
 Cells (chaos/matrix.py runs them; `nomad dev chaos -cell NAME` runs

@@ -1479,14 +1479,9 @@ def cmd_operator_top(args) -> int:
         print(f"  queue              = {ilast('queue_depth'):.0f} deep, "
               f"window {ilast('window_us'):.0f} us")
 
-    # recent per-stage share: p50 x reservoir occupancy approximates
-    # each stage's recent seconds (reservoirs hold the last 2048
-    # reports); superset/idle stages stay out of the denominator like
-    # stages.snapshot()
-    from ..utils.stages import SHARE_EXCLUDED as excluded
+    # per-stage percentiles over the reservoirs' last 2048 reports
     stage_rows = []
-    weights = {}
-    for name in series:
+    for name in sorted(series):
         if name.startswith("stage.") and name.endswith(".p50_ms"):
             stage = name[len("stage."):-len(".p50_ms")]
             p50 = (tail_vals(series, name) or [0.0])[-1]
@@ -1494,18 +1489,12 @@ def cmd_operator_top(args) -> int:
                    or [0.0])[-1]
             cnt = (tail_vals(series, f"stage_count.{stage}")
                    or [0.0])[-1]
-            weights[stage] = (p50 * cnt, p50, p99, cnt)
-    denom = sum(w for s, (w, _p, _q, _c) in weights.items()
-                if s not in excluded) or 1.0
-    for stage in sorted(weights):
-        w, p50, p99, cnt = weights[stage]
-        share = 0.0 if stage in excluded else w / denom
-        stage_rows.append([stage, f"{p50:.2f}", f"{p99:.2f}",
-                           int(cnt), f"{share:.1%}"])
+            stage_rows.append([stage, f"{p50:.2f}", f"{p99:.2f}",
+                               int(cnt)])
     if stage_rows:
         print()
         _print_rows(stage_rows, ["Stage", "p50 ms", "p99 ms",
-                                 "Samples", "Recent share"])
+                                 "Samples"])
 
     # device economics (the validation campaign's instruments)
     print()

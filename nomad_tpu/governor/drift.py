@@ -26,8 +26,9 @@ DEGRADES_DOWN = "down"
 
 def least_squares_slope(points: List[Tuple[float, float]]) -> float:
     """Slope of a least-squares fit over (t, value) points, in
-    value-units per t-unit. Shared by the drift detector and the soak
-    verdict (bench/soak.py) so the regression math exists once."""
+    value-units per t-unit. Shared by the drift detector and the flatness
+    verdict (telemetry/collector.py) so the regression math exists
+    once."""
     n = len(points)
     if n < 2:
         return 0.0

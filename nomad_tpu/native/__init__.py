@@ -41,9 +41,15 @@ def _cache_path(src: str, name: str) -> str:
 
 
 def _build(src: str, so_path: str) -> bool:
+    """Compile into a temp name of this process's own and publish it
+    with os.replace: processes that start together on an empty cache
+    (two agents on a fresh install, six test workers) each build a
+    whole file, and none takes another's from under it. One that has
+    already loaded the file it replaces keeps that file's mapping."""
     include = sysconfig.get_path("include")
+    tmp = f"{so_path}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17",
-           f"-I{include}", src, "-o", so_path + ".tmp"]
+           f"-I{include}", src, "-o", tmp]
     try:
         out = subprocess.run(cmd, capture_output=True, text=True,
                              timeout=120)
@@ -53,7 +59,7 @@ def _build(src: str, so_path: str) -> bool:
     if out.returncode != 0:
         LOG.warning("native build failed:\n%s", out.stderr[-2000:])
         return False
-    os.replace(so_path + ".tmp", so_path)
+    os.replace(tmp, so_path)    # over another process's: the same build
     return True
 
 

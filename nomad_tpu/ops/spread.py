@@ -46,8 +46,8 @@ STATS: Dict[str, int] = {
     "distinct_folds": 0,       # distinct state folded to plan-time verdict
 }
 
-# accumulated input-build seconds per arm; the bench_feas_residue cell
-# delta-reads these to compute spread_score_speedup (scalar_s/vector_s)
+# accumulated input-build seconds per arm; nothing in the tree reads
+# them (ROADMAP D0): the spread_inputs span times the same build
 TIMINGS: Dict[str, float] = {"vector_s": 0.0, "scalar_s": 0.0}
 
 

@@ -38,7 +38,7 @@ class RejectPlan:
 
 class Harness:
     # recorded plans/evals are assertion material for tests, but a
-    # long bench loop (bench/soak.py) drives hundreds of thousands of
+    # long soak loop drives hundreds of thousands of
     # evals through one harness — unbounded recording was one of the
     # round-5 soak's RSS leaks (each plan pins its placed allocs and
     # job). Tests never come close to this bound.

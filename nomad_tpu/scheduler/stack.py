@@ -715,8 +715,7 @@ class PlacementEngine:
         distinct_props = self._distinct_prop_inputs(tg, proposed)
         distinct_hosts = self._has_distinct_hosts(tg)
         if spreads or distinct_props:
-            # per-arm build-time attribution: bench_feas_residue's
-            # spread_score_speedup is the scalar/vector ratio of these
+            # per-arm build-time attribution (ops/spread.py TIMINGS)
             spread_ops.note_build(time.perf_counter() - t_build)
         if count == 1 and (distinct_hosts or distinct_props) \
                 and spread_ops.enabled() \

@@ -829,9 +829,9 @@ class Server:
         # mesh-sharded resident node table (parallel/sharded_table.py):
         # device count, sharded residency footprint, and the reshard /
         # delta-scatter traffic split — `mesh.reshard_uploads` flat
-        # across a warm eval run IS the zero-reupload steady state the
-        # multichip bench asserts. All read through the process-wide
-        # snapshot (empty dict -> 0 while no mesh dispatcher exists).
+        # across a warm eval run IS the zero-reupload steady state. All
+        # read through the process-wide snapshot (empty dict -> 0 while
+        # no mesh dispatcher exists).
         # The scattered-row debt carries the watermark, with a
         # contiguous sharded re-upload as the reclaim (the mesh analog
         # of node_table.delta_debt's fold-to-rebuild)

@@ -66,9 +66,9 @@ INGEST_KINDS = ("job_register", "alloc_client_update",
 # immediate and shrinking further just burns reclaim rounds
 SCALE_MIN = 0.125
 
-# process-wide accounting (the GROUP_STATS idiom): bench.py reads this
-# after a run so batching is attributable across every server the
-# bench spun up. Written only by gateway threads; racy reads are fine.
+# process-wide accounting (the GROUP_STATS idiom) across every server
+# of the process. Written only by gateway threads; nothing in the tree
+# reads it (ROADMAP D0): the ingest.* gauges carry the same counts.
 INGEST_STATS: Dict[str, int] = {
     "batches": 0, "writes": 0, "coalesced": 0, "shed": 0, "max_size": 0,
 }

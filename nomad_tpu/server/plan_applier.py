@@ -61,9 +61,9 @@ CONFLICT_WINDOW_S = 10.0
 # consecutive conflict-free groups before a shrunk bound re-widens
 GROUP_RECOVER_CLEAN = 32
 
-# process-wide accounting (the BUILD_STATS idiom): bench.py reads this
-# after a run so group sizing is attributable across every server the
-# bench spun up. Written only by applier threads; racy reads are fine.
+# process-wide accounting (the BUILD_STATS idiom) across every server
+# of the process. Written only by applier threads; nothing in the tree
+# reads it (ROADMAP D0): the plan_group.* gauges carry the same counts.
 GROUP_STATS: Dict[str, int] = {
     "groups": 0, "plans": 0, "conflict_retries": 0,
     "singleton_fallbacks": 0, "max_size": 0,
@@ -125,7 +125,7 @@ class PlanApplier:
         self._failed_pending: set = set()
         self._failed_l = make_lock()
         # per-applier group accounting (the governor gauges read these;
-        # GROUP_STATS above is the cross-server bench aggregate)
+        # GROUP_STATS above is the cross-server aggregate)
         self.stats: Dict[str, int] = {
             "groups": 0, "plans": 0, "conflict_retries": 0,
             "singleton_fallbacks": 0,

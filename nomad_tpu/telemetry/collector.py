@@ -1,15 +1,16 @@
 """Retained telemetry: the server-side sampling collector (ISSUE 11).
 
-`/v1/metrics` is a point-in-time InmemSink snapshot; the soak harness
-computes flatness verdicts AFTER a run from windows it assembled
-itself; and the device economics the north star turns on (pad waste,
-compile counts, dispatch seconds) lived only in process-local structs.
+`/v1/metrics` is a point-in-time InmemSink snapshot; a flatness
+verdict had to be computed AFTER a run from windows a harness
+assembled itself; and the device economics the north star turns on
+(pad waste, compile counts, dispatch seconds) lived only in
+process-local structs.
 This collector closes all three gaps in-process: a background sampler
 snapshots governor gauges, counter totals (rates derived from slot
 deltas at read time), stage percentile reservoirs, device-economics
 stats, and RSS into bounded struct-of-arrays ring buffers — numpy
 float64 columns, one write cursor, wrap-around overwrite — so
-`/v1/operator/flatness` can run `bench/soak.flatness_verdict` over the
+`/v1/operator/flatness` can run `flatness_verdict` (below) over the
 LIVE ring and `nomad operator top` can render rates and trends from
 history instead of a single scrape.
 
@@ -32,10 +33,12 @@ import math
 import os
 import threading
 import time
+from statistics import median
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from ..governor.drift import least_squares_slope
 from ..governor.governor import rss_mb
 from ..utils import metrics
 from ..utils.locks import make_lock
@@ -92,6 +95,72 @@ def default_device_fn() -> Dict[str, float]:
             out["device.mesh_stale_misses"] = ms["stale_misses"]
     except Exception:       # pragma: no cover — defensive
         pass
+    return out
+
+
+# acceptance thresholds (ISSUE r6): a run passes when p99 in the
+# last window-half stays within this ratio of the first half and RSS
+# grows no faster than this slope
+MAX_P99_DRIFT_RATIO = 1.5
+MAX_RSS_SLOPE_MB_PER_HOUR = 100.0
+
+
+def _slope_per_hour(ts_min: List[float], values: List[float]) -> float:
+    """Least-squares slope in units/hour over (minutes, value) points —
+    robust to one noisy endpoint, unlike last-minus-first."""
+    return least_squares_slope(list(zip(ts_min, values))) * 60.0
+
+
+def flatness_verdict(windows: List[Dict],
+                     max_p99_ratio: float = MAX_P99_DRIFT_RATIO,
+                     max_rss_slope: float = MAX_RSS_SLOPE_MB_PER_HOUR,
+                     warmup_windows: int = 1) -> Dict:
+    """The machine-checkable steady-state verdict over per-window
+    samples. p99 drift is median-of-last-half over median-of-first-half
+    (single-window spikes don't flip the verdict); RSS slope is the
+    least-squares fit across the measured windows.
+
+    The first `warmup_windows` are excluded when enough windows remain
+    (>=3 measured): the run's BOUNDED structures (identity memos,
+    changelog ring, harness history, JIT caches) legitimately fill to
+    their plateau during the first window, and a steady-state verdict
+    judges the plateau, not the fill — the r6 6-min run measured
+    +29 MB in window 1-2 and then three windows of RSS flat to 0.1 MB.
+    The exclusion is recorded in the verdict."""
+    out: Dict = {"max_p99_drift_ratio": max_p99_ratio,
+                 "max_rss_slope_mb_per_hour": max_rss_slope}
+    if len(windows) - warmup_windows >= 3:
+        windows = windows[warmup_windows:]
+        out["warmup_windows_excluded"] = warmup_windows
+    else:
+        out["warmup_windows_excluded"] = 0
+    if len(windows) < 2:
+        out.update({"pass": False, "reason": "fewer than 2 windows"})
+        return out
+    p99 = [w["p99_ms"] for w in windows]
+    half = max(1, len(p99) // 2)
+    # median of each half: real drift raises every late window (and
+    # the median with it); one noisy-neighbor window must not flip a
+    # steady-state verdict the other five windows contradict
+    first = median(p99[:half])
+    last = median(p99[len(p99) - half:])
+    ratio = (last / first) if first > 0 else 1.0
+    rss_slope = _slope_per_hour([w["t_min"] for w in windows],
+                                [w["rss_mb"] for w in windows])
+    out["p99_drift_ratio"] = round(ratio, 3)
+    out["p99_first_half_ms"] = round(first, 1)
+    out["p99_last_half_ms"] = round(last, 1)
+    out["rss_slope_mb_per_hour"] = round(rss_slope, 1)
+    out["pass"] = bool(ratio <= max_p99_ratio
+                       and rss_slope <= max_rss_slope)
+    if not out["pass"]:
+        reasons = []
+        if ratio > max_p99_ratio:
+            reasons.append(f"p99 drift {ratio:.2f}x > {max_p99_ratio}x")
+        if rss_slope > max_rss_slope:
+            reasons.append(f"rss slope {rss_slope:.0f} MB/h > "
+                           f"{max_rss_slope:.0f} MB/h")
+        out["reason"] = "; ".join(reasons)
     return out
 
 
@@ -279,8 +348,8 @@ class TelemetryCollector:
         return [float(v) for v in out]
 
     def windows(self) -> List[Dict]:
-        """The soak-window shape over the ring — the rows
-        `bench/soak.flatness_verdict` consumes: per-slot t_min (from
+        """The window shape over the ring — the rows
+        `flatness_verdict` consumes: per-slot t_min (from
         the first retained sample), p99_ms (full-latency reservoir),
         rss_mb, and the evals counted between slots."""
         with self._l:
@@ -316,11 +385,10 @@ class TelemetryCollector:
     MIN_VERDICT_SPAN_S = 120.0
 
     def flatness(self, **kw) -> dict:
-        """Live verdict: `bench/soak.flatness_verdict` over the
-        in-process ring — the same math the soak artifact records,
-        pointed at retained history instead of harness windows.
+        """Live verdict: `flatness_verdict` over the in-process ring
+        (the chaos matrix runs the same math over its cells' windows).
 
-        The soak calibrates its thresholds for 60-second windows
+        Its thresholds are calibrated for 60-second windows
         (warmup_windows=1 excludes a full minute of legitimate
         bounded-structure fill). The ring samples much faster, so the
         warmup exclusion is rescaled to cover the same ~60 seconds of
@@ -328,7 +396,6 @@ class TelemetryCollector:
         history exists the verdict reports pass=None ("insufficient
         history") instead of failing a healthy server on a
         noise-dominated slope fit."""
-        from ..bench.soak import flatness_verdict
         windows = self.windows()
         kw.setdefault("warmup_windows",
                       max(1, math.ceil(60.0 / self.interval_s)))

@@ -219,8 +219,8 @@ def freeze_steady_state() -> None:
     """A whole walk now, without waiting for a safepoint's budget:
     reclaim what is already dead and move the live heap to the
     permanent generation. What safepoint() does by itself one interval
-    after a load; the old bench stack (bench/soak.py, bench/ladder.py)
-    calls it so that its first timed eval does not hold that walk."""
+    after a load; for a harness whose first timed eval must not hold
+    that walk (tests/test_gcsafe.py is the one caller: ROADMAP D0)."""
     with _lock:
         _full_pass(whole=True)
 

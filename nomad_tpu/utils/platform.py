@@ -1,7 +1,7 @@
 """JAX backend selection and compile-cache placement.
 
-One rule for every entry point that starts JAX (the CLI agent, bench.py,
-chip_smoke.py, the soak and profile drivers):
+One rule for every entry point that starts JAX (the CLI agent,
+chip_smoke.py, benchmark/run.py):
 
   - ``JAX_PLATFORMS=cpu`` set by the caller means CPU (tests, laptops).
   - otherwise the process initializes the ambient backend itself, as

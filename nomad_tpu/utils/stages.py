@@ -24,7 +24,7 @@ same tree; a stage's time lies inside its parent's):
                   (attrs walked, reclaimed)
   eval            enqueue -> ack (the trace's root, not a stage)
     queue_wait    time the eval sat in the broker before a worker
-                  dequeued it (dead time — see SHARE_EXCLUDED)
+                  dequeued it (dead time, not work)
     fence_wait    wait for the local store to reach the eval's modify
                   index (~0 on a leader; replication lag on a follower)
     table_build   NodeTableCache full builds + delta refreshes: the
@@ -94,14 +94,10 @@ same tree; a stage's time lies inside its parent's):
                     worker's safepoint stops its sibling mid-eval: the
                     pause lands in whatever span is open there)
 
-r8 lumped verify, raft apply, and ack bookkeeping into one
-`plan_apply` bucket; the group-commit applier splits it so the bench
-artifact can show whether batched commit actually shrank the commit
-half (one raft entry / store transaction / event flush per GROUP).
-
-`bench.py` enables collection around a run and emits the snapshot in
-the JSON artifact (`stage_breakdown`), so the kernel-vs-e2e gap is
-attributable per round instead of inferred.
+enable() / snapshot() / disable() give a test the summed seconds and
+the call count of every stage reported in between. Shares of a whole
+are not computed here: the tree above is the flight recorder's
+(STAGE_PARENTS), and the benchmark reads its spans.
 
 The eval flight recorder (nomad_tpu/trace/, ISSUE 9) taps the same
 report sites: every add() forwards (stage, seconds, attrs) through the
@@ -111,7 +107,7 @@ the thread-local current trace. The aggregate sums are untouched.
 `enabled` is therefore True whenever EITHER consumer wants reports
 (accumulation via enable()/disable(), tracing via set_trace_hook);
 with both off the hot path pays one module-global bool check per
-report site, exactly as before.
+report site.
 
 A site that wraps code makes one call, `with stages.span(stage,
 **attrs):` — the clock, the report as the interval ends, and, while a
@@ -123,9 +119,7 @@ add().
 
 The same stage can be reported from overlapping layers (a kernel
 dispatch inside a plan-apply verify); accumulators are independent
-sums, not a strict partition of wall clock — shares are computed over
-the sum of stages, and the interesting signal is the RATIO moving
-between rounds, not the absolute seconds.
+sums, not a partition of wall clock.
 """
 
 from __future__ import annotations
@@ -145,37 +139,6 @@ STAGES = ("restore", "wal_replay", "job_register", "snapshot_write",
           "plan_build", "plan_submit", "plan_queue_wait", "plan_verify",
           "plan_commit", "wal_encode", "sched_host_self", "broker_ack")
 
-# superset accumulators: wholly contain other stages' time (sched_host
-# wraps reconcile + kernel + d2h + plan_submit per eval, plan_submit
-# wraps the plan's queue wait + plan_verify + plan_commit), so
-# they are EXCLUDED from the share denominator — otherwise adding one
-# would halve every other stage's share and break the cross-round
-# share comparisons the bench artifacts exist for. Their own `share`
-# is still reported relative to that same denominator (it can
-# legitimately exceed other stages' combined share).
-SHARE_SUPERSETS = frozenset({"sched_host", "plan_submit"})
-
-# queue_wait is dead time on the broker heap, not attributable work: a
-# paused-worker burst would let it dwarf every real stage and wreck
-# the cross-round share ratios, so it too stays out of the denominator
-# (its own share is still reported against it, like the supersets).
-# fence_wait (ISSUE 16) is the same kind of dead time — replication
-# lag observed at the snapshot fence, ~0 on a leader and bounded by
-# follower_fence_timeout_s on a lagging follower; plan_queue_wait is
-# the plan's wait behind the serialization point; gc_whole_walk is the
-# very interval its gc_full reports
-SHARE_EXCLUDED = SHARE_SUPERSETS | frozenset({"queue_wait",
-                                              "fence_wait",
-                                              "plan_queue_wait",
-                                              "gc_whole_walk"})
-
-# cold-start stages dilute steady-state shares when a run cold-boots
-# mid-round (ISSUE 9 satellite): snapshot() reports `steady_share`
-# over a denominator that excludes them, so cross-round ratio
-# comparisons survive a cold boot in the same run. The cold stages'
-# own steady_share is 0.0 by definition.
-COLD_STAGES = frozenset({"restore", "wal_replay"})
-
 enabled = False
 
 _l = make_lock()
@@ -191,8 +154,8 @@ _trace_on = False
 def set_trace_hook(hook: Optional[Callable], on: bool = True) -> None:
     """Register (or disarm) the flight recorder's report tap. Arms the
     module-global `enabled` flag so the `if stages.enabled:` guards at
-    every report site fire for the tracer even while bench
-    accumulation is off."""
+    every report site fire for the tracer even while accumulation is
+    off."""
     global _trace_hook, _trace_on, enabled
     _trace_hook = hook
     _trace_on = bool(on and hook is not None)
@@ -342,22 +305,8 @@ def span(stage: str, **attrs):
 
 
 def snapshot() -> Dict[str, dict]:
-    """{stage: {seconds, calls, share, steady_share}} over all stages
-    reported since enable(). `share` is each stage's fraction of the
-    summed stage time — the attribution number the bench artifact
-    records; `steady_share` excludes the cold-start stages from the
-    denominator (and reports 0.0 for them) so steady-state ratios
-    compare across rounds regardless of whether a round cold-booted."""
+    """{stage: {seconds, calls}} over every stage of STAGES, and any
+    other name reported, since enable()."""
     with _l:
-        total = sum(v[0] for s, v in _acc.items()
-                    if s not in SHARE_EXCLUDED)
-        steady = sum(v[0] for s, v in _acc.items()
-                     if s not in SHARE_EXCLUDED and s not in COLD_STAGES)
-        return {
-            s: {"seconds": round(v[0], 4), "calls": v[1],
-                "share": round(v[0] / total, 4) if total > 0 else 0.0,
-                "steady_share": (
-                    0.0 if s in COLD_STAGES or steady <= 0
-                    else round(v[0] / steady, 4))}
-            for s, v in _acc.items() if v[1] > 0 or s in STAGES
-        }
+        return {s: {"seconds": round(v[0], 4), "calls": v[1]}
+                for s, v in _acc.items() if v[1] > 0 or s in STAGES}

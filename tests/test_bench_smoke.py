@@ -1,304 +1,31 @@
-"""Benchmark-harness smoke: bench.py and the ladder must keep working
-against the live scheduler API. Round-1 shipped a bench that crashed at
-round end (BENCH_r01.json rc=1) because nothing exercised it in CI —
-this runs the same entry points at toy scale on CPU so backend drift
-fails fast (VERDICT r2 item 10).
-"""
-
-import json
-import os
-import subprocess
-import sys
-
-
-def test_bench_py_emits_json_line_on_cpu():
-    """Run the real bench.py with tiny knobs; it must exit 0 and print
-    one parseable JSON line with the headline keys."""
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["NOMAD_TPU_C2M_ALLOCS"] = "0"       # skip the 2M seed in CI
-    env["NOMAD_TPU_BENCH_QUICK"] = "1"
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py")],
-        capture_output=True, text=True, timeout=900, env=env, cwd=repo)
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = out.stdout.strip().splitlines()[-1]
-    data = json.loads(line)
-    assert data["metric"] == "placements_per_sec_batch10k_1k_nodes"
-    assert "error" not in data, data
-    assert "ladder_error" not in data, data
-    assert "c2m_error" not in data, data
-    assert data["value"] > 0
-    assert data["e2e_placements_per_sec"] > 0
-    assert data["service_p99_ms"] > 0
-    assert data["preemption_placements_per_sec"] > 0
-    # batched columnar preemption (ISSUE 10): the ladder runs the
-    # scenario columnar AND with NOMAD_TPU_COLUMNAR_PREEMPT=0
-    # in-process; the victim-selection speedup must clear 2x at quick
-    # CI scale (measured ~2.6x) and the preempt stage must be
-    # attributed in the breakdown
-    assert data["preemption_placements_per_sec_off"] > 0
-    assert data["preemption_speedup"] >= 2.0, data
-    assert data["preemption_p50_ms"] > 0
-    assert data["preemption_nodes_scanned"] > 0
-    assert 0.0 <= data["preemption_victim_cache_hit_rate"] <= 1.0
-    # per-stage attribution (ISSUE 2 satellite): the artifact carries
-    # the breakdown that makes the kernel-vs-e2e gap attributable
-    assert "stage_error" not in data, data
-    bd = data["stage_breakdown"]
-    # plan_apply split into plan_verify/plan_commit (ISSUE 4 satellite:
-    # the artifact must attribute verify separately from commit so the
-    # group-commit win is measurable per round)
-    # reconcile + sched_host joined the breakdown (ISSUE 6 satellite:
-    # the alloc-diff host phase is now attributable, not inferred);
-    # gateway_wait joined in ISSUE 7 (micro-batch coalescing wait)
-    # restore + wal_replay joined in ISSUE 8 (cold-start recovery
-    # attribution: snapshot load and batched WAL replay are stages);
-    # queue_wait joined in ISSUE 9 (the flight recorder's broker
-    # enqueue->dequeue leg), which also added steady_share (shares
-    # with the cold-start stages excluded from the denominator)
-    # preempt joined in ISSUE 10 (batched columnar victim selection:
-    # the phase behind BENCH_r05's worst number is now attributable)
-    # feasibility joined in ISSUE 17 (compiled columnar feasibility:
-    # mask production attributed separately from the h2d push)
-    for stage in ("restore", "wal_replay", "table_build", "feasibility",
-                  "h2d", "kernel", "d2h", "reconcile", "preempt",
-                  "queue_wait",
-                  "gateway_wait", "sched_host", "plan_verify",
-                  "plan_commit", "broker_ack"):
-        assert stage in bd, f"missing stage {stage}: {bd}"
-        assert set(bd[stage]) == {"seconds", "calls", "share",
-                                  "steady_share"}
-    assert bd["kernel"]["seconds"] > 0          # e2e phases dispatched
-    assert bd["plan_verify"]["calls"] > 0
-    assert bd["plan_commit"]["calls"] > 0
-    assert bd["broker_ack"]["calls"] > 0
-    assert bd["reconcile"]["calls"] > 0
-    assert bd["reconcile"]["seconds"] > 0
-    assert bd["preempt"]["calls"] > 0
-    assert bd["preempt"]["seconds"] > 0
-    assert bd["sched_host"]["calls"] > 0
-    # sched_host (superset) and queue_wait (broker idle time) are
-    # excluded from the share denominator (utils/stages.py
-    # SHARE_EXCLUDED) so r9-era share comparisons stay meaningful
-    excluded = {"sched_host", "queue_wait"}
-    shares = sum(v["share"] for k, v in bd.items() if k not in excluded)
-    assert 0.99 <= shares <= 1.01 or shares == 0.0
-    # steady_share: same identity with restore/wal_replay excluded
-    # too, and the cold stages report 0.0 by definition (ISSUE 9
-    # satellite: cold-start stages must not dilute steady-state
-    # ratios across rounds)
-    steady = sum(v["steady_share"] for k, v in bd.items()
-                 if k not in excluded | {"restore", "wal_replay"})
-    assert 0.99 <= steady <= 1.01 or steady == 0.0
-    assert bd["restore"]["steady_share"] == 0.0
-    assert bd["wal_replay"]["steady_share"] == 0.0
-    assert bd["queue_wait"]["calls"] > 0
-    # resident-table counters + measured dispatch costs ride along
-    assert data["table_build_stats"]["delta_refreshes"] >= 0
-    assert data["dispatch_cost_model"], "cost model never observed"
-    # device economics (ISSUE 11): pad waste and per-arm dispatch
-    # seconds / fresh-compile counts are first-class artifact keys —
-    # the validation campaign's instruments
-    assert data["telemetry"] == "on"
-    # runtime race sanitizer attribution (ISSUE 14): governed runs
-    # must record whether the lock shims were instrumenting
-    assert data["race"] in ("on", "off")
-    assert 0.0 <= data["pad_waste_ratio"] < 1.0
-    assert data["device_dispatch_s"], "no arm reported dispatch time"
-    assert all(v >= 0 for v in data["device_dispatch_s"].values())
-    assert any(v > 0 for v in data["device_dispatch_s"].values())
-    assert data["device_compiles"], "no arm reported compile counts"
-    assert sum(data["device_compiles"].values()) >= 1
-    assert set(data["device_compiles"]) == set(data["device_dispatch_s"])
-    assert all(data["device_dispatches"][a] >= data["device_compiles"][a]
-               for a in data["device_compiles"])
-    # group-commit + engine-reuse attribution (ISSUE 4 satellite)
-    assert data["plan_group_stats"]["groups"] > 0
-    assert data["plan_group_mean_size"] >= 1.0
-    assert data["plan_group_conflict_retries"] >= 0
-    assert 0.0 <= data["engine_reuse_hit_rate"] <= 1.0
-    # the broker burst scenario reports its own group sizing
-    assert data["service_broker_plan_group_mean_size"] >= 1.0
-    # micro-batch gateway engagement (ISSUE 7): with the cost model
-    # calibration-seeded, the broker burst MUST coalesce evals into
-    # shared device dispatches — the r5 regression this PR kills —
-    # and the gateway's parked time is attributable in the breakdown
-    assert data["microbatch"] == "on"
-    assert data["service_broker_batches"] > 0, data
-    assert data["service_microbatch_occupancy_mean"] > 1.0, data
-    assert data["service_microbatch_window_us"] > 0
-    assert data["service_microbatch_placements_per_sec"] > 0
-    assert data["service_microbatch_placements_per_sec_off"] > 0
-    assert data["service_microbatch_speedup"] > 0
-    assert data["service_microbatch_p99_ms"] > 0
-    assert bd["gateway_wait"]["calls"] > 0
-    # columnar reconcile engine (ISSUE 6): the deployment-wave scenario
-    # must show the memo paying one deep diff per version pair (hit
-    # rate ~1.0) and a >= 2x evals/s win over the engine-off path
-    assert data["deploy_wave_evals_per_sec"] > 0
-    assert data["deploy_wave_tasks_updated_hit_rate"] > 0.9
-    assert data["deploy_wave_speedup"] >= 2.0, data
-    assert data["deploy_wave_reconcile_stage_s"] >= 0.0
-    assert 0.0 <= data["tasks_updated_hit_rate"] <= 1.0
-    # mesh-sharded residency (ISSUE 12): the multichip ladder ran both
-    # arms on the forced 8-device CPU mesh, the resident table engaged
-    # (hits counted), and the steady-state timed window performed ZERO
-    # full column re-uploads — per-dispatch H2D on the mesh is deltas +
-    # request arrays, not the dense columns the off arm ships
-    assert "multichip_error" not in data, data
-    assert data["mesh_devices"] == 8
-    assert data["mesh_placements_per_sec"] > 0
-    assert data["mesh_placements_per_sec_off"] > 0
-    assert data["mesh_speedup"] > 0
-    assert data["mesh_resident_hits"] > 0
-    assert data["mesh_reupload_bytes"] == 0, data
-    assert data["mesh_reupload_bytes_total"] > 0
-    assert data["mesh_delta_scatters"] >= 0
-    assert data["mesh_reupload_bytes"] < \
-        data["mesh_dense_bytes_per_dispatch_off"]
-    # cluster workload observability (ISSUE 13): real client agents
-    # with the stats sampler on ran a job inside the ladder; the
-    # artifact carries the fleet economics — nodes reporting host
-    # stats via heartbeat, memory genuinely used on the hosts, and
-    # the scheduler's allocated share from the resident node table
-    # (cpu used can honestly be ~0 on an idle CI host, so only its
-    # range is asserted)
-    assert data["cluster_nodes"] > 0
-    assert data["cluster_nodes_reporting"] == data["cluster_nodes"]
-    assert data["cluster_stale_heartbeats"] == 0
-    assert 0.0 <= data["fleet_cpu_used_ratio"] <= 1.0
-    assert 0.0 < data["fleet_mem_used_ratio"] < 1.0
-    assert data["fleet_cpu_allocated_ratio"] > 0.0
-    assert data["fleet_mem_allocated_ratio"] > 0.0
-    # cold-start recovery (ISSUE 8): the columnar snapshot + primed
-    # table + batched replay must beat the legacy object-snapshot
-    # restore by >= 3x at the same scale (measured ~8x at quick scale;
-    # the bench itself asserts reconcile.index_rebuilds == 0 and zero
-    # full NodeTable builds after recovery), and the recovery stages
-    # must be attributed in the breakdown
-    assert data["cold_allocs"] > 0
-    assert data["cold_restore_s"] > 0
-    assert data["cold_table_build_s"] >= 0
-    assert data["cold_wal_replay_s"] >= 0
-    assert data["cold_start_speedup"] >= 3.0, data
-    assert bd["restore"]["calls"] > 0
-    assert bd["wal_replay"]["calls"] > 0
-    # eval flight recorder (ISSUE 9): tracing was armed, the per-stage
-    # PERCENTILE breakdown rides the artifact next to the sums, and at
-    # least one tail exemplar carries a COMPLETE span tree —
-    # enqueue->ack with the gateway batch id and commit group attrs
-    # populated (bench.py computes the completeness bit)
-    assert data["trace"] == "on"
-    sp = data["stage_percentiles"]
-    for stage in ("kernel", "plan_verify", "plan_commit", "sched_host",
-                  "queue_wait", "gateway_wait", "preempt"):
-        assert stage in sp, f"missing percentile stage {stage}: {sp}"
-        assert sp[stage]["count"] > 0
-        assert sp[stage]["p50_ms"] <= sp[stage]["p99_ms"]
-    assert data["trace_exemplars"] >= 1, data
-    # the CI-stable claim: a complete capture exists in the recorder
-    # (exemplar set OR ring — which traces win the worst-K exemplar
-    # slots is load-dependent; trace_exemplar_complete is recorded in
-    # the artifact for the TPU run to judge at scale)
-    assert data["trace_capture_complete"] is True, data
-    assert data["service_trace_exemplars"] >= 1
-    # scenario matrix under chaos (ISSUE 15): the quick ladder runs
-    # the three fastest cells — including the worker-kill-mid-commit
-    # and WAL-tail-corruption acceptance cells — and EVERY invariant
-    # (no lost/duplicated alloc, no double commit, recovery to
-    # intent) must hold inside the bench run
-    assert data["chaos_cells"] >= 3
-    assert data["chaos_cells_passed"] == data["chaos_cells"], data
-    assert data["chaos_invariants_checked"] > 0
-    assert data["chaos_invariants_failed"] == 0, data
-    assert data["chaos_worker_kill_pass"] is True, data
-    assert data["chaos_wal_corruption_pass"] is True, data
-    assert data["chaos_race"] in ("on", "off")
-    assert data["chaos_race_findings"] == 0
-    # distributed scheduler plane (ISSUE 16): the 3-server ladder
-    # scenario ran both arms on a geo-stretched ring (wire_latency
-    # armed identically in both) and the follower plane must clear
-    # 2x the leader-only control arm; structural engagement —
-    # followers actually dequeued and planned remotely, and the
-    # applier amortized remote plans into groups — rides the artifact
-    assert data["multiserver_placements_per_sec"] > 0
-    assert data["multiserver_placements_per_sec_off"] > 0
-    assert data["multiserver_speedup"] >= 2.0, data
-    assert data["multiserver_fence_wait_p99_ms"] >= 0.0
-    assert data["multiserver_remote_demotions"] >= 0
-    assert data["multiserver_remote_dequeues"] > 0
-    assert data["multiserver_plans"] > 0
-    assert 0 < data["multiserver_plan_groups"] <= data["multiserver_plans"]
-    assert data["multiserver_rtt_ms"] > 0
-    # compiled feasibility engine (ISSUE 17): the ladder ran the
-    # constraint-heavy cell with NOMAD_TPU_COLUMNAR_FEAS on and off
-    # in-process; the compiled path must clear 3x the scalar attribute
-    # walk at quick scale, the warm window must pay ZERO column
-    # rebuilds (incremental intern maintenance only), and the mask
-    # cache must serve >90% of evals from cache/journal patches
-    assert data["feas_mask_build_ms"] > 0
-    assert data["feas_mask_build_ms_off"] > 0
-    assert data["feas_speedup"] >= 3.0, data
-    assert data["feas_intern_values"] > 0
-    assert data["feas_mask_cache_hit_rate"] > 0.9, data
-    assert data["feas_column_rebuilds"] == 0, data
-    assert data["feas_rows_patched"] > 0
-    assert bd["feasibility"]["calls"] > 0
-    # residue-compiled feasibility (ISSUE 20): the ladder ran the
-    # CSI/spread/distinct cell with NOMAD_TPU_FEAS_RESIDUE on and off
-    # in-process; the device mask token must survive every per-eval
-    # CSI mask mutation as a sparse residue scatter (zero warm full
-    # re-uploads), and the vectorized spread/distinct input builds
-    # must clear 2x the scalar walk + O(N) re-encode at quick scale
-    assert data["feas_resident_token_survival_rate"] >= 0.9, data
-    assert data["feas_residue_scatters"] > 0
-    assert data["feas_residue_rows"] > 0
-    assert data["feas_warm_mask_uploads"] == 0, data
-    assert data["spread_build_ms"] > 0
-    assert data["spread_build_ms_off"] > 0
-    assert data["spread_score_speedup"] >= 2.0, data
-    assert data["spread_score_evals"] > 0
-    # columnar admission path (ISSUE 19): the ladder ran the write
-    # storm with the ingest gateway on and off in-process against a
-    # durable WAL; the group-applied arm must clear 2x the
-    # entry-per-write control arm, genuinely coalesce (mean group
-    # size > 1), and the service-read side must not regress to zero
-    assert data["ingest"] == "on"
-    assert data["ingest_writes_per_sec"] > 0
-    assert data["ingest_writes_per_sec_off"] > 0
-    assert data["ingest_speedup"] >= 2.0, data
-    assert data["ingest_write_p99_ms"] > 0
-    assert data["ingest_group_mean_size"] > 1.0, data
-    assert data["ingest_coalesced_writes"] > 0
-    assert data["ingest_shed"] >= 0
-    assert data["ingest_read_placements_per_sec"] > 0
-    assert data["ingest_read_placements_per_sec_off"] > 0
-
-
-def test_chaos_list_shows_scheduler_plane_cells():
-    """`nomad dev chaos -list` must advertise the two ISSUE 16 cells
-    alongside the rest of the matrix."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, "-m", "nomad_tpu.cli.main", "dev", "chaos",
-         "-list"],
-        capture_output=True, text=True, timeout=120, env=env, cwd=repo)
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "leader_failover_commit" in out.stdout, out.stdout
-    assert "follower_fence" in out.stdout, out.stdout
+"""What nomad_tpu/bench/ladder.py still holds: the fleet and backlog
+seeders the benchmark, chip_smoke.py and other tests build clusters
+with, at a scale tier-1 can afford."""
 
 
 def test_c2m_seed_path_at_toy_scale():
-    """The 2M-alloc seed machinery (scheduler path + replay loader)
-    at a scale CI can afford; asserts the alloc table really holds the
-    rows and the benched evals still place."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    from nomad_tpu.bench.ladder import bench_c2m_scale
-    out = bench_c2m_scale(n_nodes=200, seed_allocs=5000,
-                          batch_count=50, n_service=2)
-    assert out["c2m_allocs"] == 5000
-    assert out["c2m_batch_placed"] == 50
-    assert out["c2m_service_p99_ms"] > 0
+    """seed_c2m_allocs' two ways in (the scheduler path and the replay
+    loader): the store really holds the rows, and a batch eval over
+    the seeded fleet still places."""
+    from nomad_tpu import mock
+    from nomad_tpu.bench.ladder import (_eval_for, _seed_nodes,
+                                        seed_c2m_allocs)
+    from nomad_tpu.scheduler.harness import Harness
+
+    h = Harness()
+    nodes = _seed_nodes(h, 200)
+    out = seed_c2m_allocs(h, nodes, 5000, sched_allocs=2000)
+    assert set(out) == {"seed_s", "sched_s"}
+    assert sum(1 for _ in h.store.allocs()) == 5000
+    assert len(h.store.allocs_by_job("default", "c2m-seed")) == 3000
+
+    job = mock.batch_job()
+    job.id = "c2m-batch"
+    job.datacenters = [f"dc{d}" for d in (1, 2, 3, 4)]
+    tg = job.task_groups[0]
+    tg.count = 50
+    tg.tasks[0].resources.networks = []
+    tg.networks = []
+    h.store.upsert_job(h.next_index(), job)
+    h.process("batch", _eval_for(job))
+    assert sum(len(a) for a in h.plans[-1].node_allocation.values()) == 50

@@ -270,3 +270,16 @@ def test_swim_partition_cell():
     assert by_name["partitioned_member_removed"]["pass"]
     assert by_name["quorum_writes_survive"]["pass"]
     assert by_name["victim_process_survived_partition"]["pass"]
+
+
+def test_chaos_list_shows_scheduler_plane_cells():
+    """`nomad dev chaos -list` must advertise the two ISSUE 16 cells
+    alongside the rest of the matrix."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "-m", "nomad_tpu.cli.main", "dev", "chaos",
+         "-list"],
+        capture_output=True, text=True, timeout=120, env=env, cwd=REPO)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "leader_failover_commit" in out.stdout, out.stdout
+    assert "follower_fence" in out.stdout, out.stdout

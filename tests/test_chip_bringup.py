@@ -1,10 +1,10 @@
 """Bring-up guards (ISSUE 21): the compile cache can be placed from
 outside and is otherwise at one fixed in-checkout path; no entry point
 continues on the CPU after failing to get an accelerator it was asked
-for; bench.py exits non-zero when a phase raises; chip_smoke.py's
-scenario builder imports without JAX and its plain-reference checkers
-reject bad placements; node TTL timers cost one thread, not one per
-node; the accelerator fingerprint never opens the device.
+for; chip_smoke.py's scenario builder imports without JAX and its
+plain-reference checkers reject bad placements; node TTL timers cost
+one thread, not one per node; the accelerator fingerprint never opens
+the device.
 """
 
 import json
@@ -62,40 +62,6 @@ def test_agent_exits_nonzero_without_the_accelerator_it_was_asked_for():
     assert out.returncode != 0
     assert "failed to initialize" in out.stderr
     assert "agent started" not in out.stdout
-
-
-def test_bench_exits_nonzero_without_the_accelerator_it_was_asked_for():
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")], cwd=REPO,
-        env={**os.environ, "JAX_PLATFORMS": "tpu"},
-        capture_output=True, text=True, timeout=120)
-    assert out.returncode != 0
-    data = json.loads(out.stdout.strip().splitlines()[-1])
-    assert "error" in data and "platform" not in data
-
-
-def test_bench_exits_nonzero_when_a_later_phase_raises():
-    """The JSON line still prints (diagnosis), stamped with the device,
-    but a run whose ladder raised is not a result."""
-    code = (
-        "import importlib.util, sys\n"
-        "spec = importlib.util.spec_from_file_location('bench', 'bench.py')\n"
-        "bench = importlib.util.module_from_spec(spec)\n"
-        "spec.loader.exec_module(bench)\n"
-        "bench.run_kernel_bench = lambda: 1.0\n"
-        "import nomad_tpu.bench.ladder as ladder\n"
-        "import nomad_tpu.bench.multichip as multichip\n"
-        "def boom(**kw): raise RuntimeError('ladder broke')\n"
-        "ladder.run_ladder = boom\n"
-        "multichip.run_multichip_bench = lambda **kw: {}\n"
-        "sys.exit(bench.main())\n")
-    out = _run(code, {"JAX_PLATFORMS": "cpu", "NOMAD_TPU_C2M_ALLOCS": "0",
-                      "NOMAD_TPU_BENCH_QUICK": "1"}, timeout=300)
-    assert out.returncode == 1, out.stderr[-2000:]
-    data = json.loads(out.stdout.strip().splitlines()[-1])
-    assert data["ladder_error"] == "RuntimeError: ladder broke"
-    assert data["platform"] == "cpu" and data["device_count"] >= 1
-    assert data["device_kind"]
 
 
 def test_chip_smoke_refuses_to_run_without_an_accelerator():
@@ -248,17 +214,28 @@ def test_smoke_tells_a_retry_bucket_from_a_new_shape_family(smoke):
 
 # -- program repairs the chip run forced -------------------------------
 
-def test_heartbeat_timers_use_one_thread_for_the_fleet():
+def test_heartbeat_timers_use_one_thread_for_the_fleet(monkeypatch):
     from nomad_tpu.server.heartbeat import HeartbeatTimers
     expired = []
     hb = HeartbeatTimers(expired.append)
-    before = threading.active_count()
+    # the threads THIS thread starts (arming a timer runs here), not the
+    # process's count: in a worker that ran other files first, their
+    # daemon threads still start and end meanwhile (active_count read
+    # 20 for 21 and 17 for 16 in two runs)
+    me, started, start = threading.current_thread(), [], threading.Thread.start
+
+    def counting_start(t):
+        if threading.current_thread() is me:
+            started.append(t)
+        return start(t)
+
+    monkeypatch.setattr(threading.Thread, "start", counting_start)
     try:
         for i in range(10_000):
             hb.reset(f"far-{i}", 3600.0)
         hb.reset("kept", 0.15)
         hb.reset("gone", 0.15)
-        assert threading.active_count() == before + 1
+        assert started == [hb._thread] and hb._thread.is_alive()
         assert hb.armed() == 10_002
         time.sleep(0.05)
         hb.reset("kept", 3600.0)        # a heartbeat postpones expiry

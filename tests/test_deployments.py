@@ -84,9 +84,12 @@ def test_rolling_deployment_succeeds_and_marks_stable(cluster):
     state = d.task_groups["web"]
     assert state.placed_allocs == 2
     assert state.healthy_allocs == 2
-    # the completed version is flagged stable (the rollback target)
-    stored = server.store.job_by_id(job.namespace, job.id)
-    assert stored.stable is True
+    # the completed version is flagged stable (the rollback target).
+    # Success and the flag ride in one raft entry but are two
+    # publishes of the store, the status first: a reader that has just
+    # seen SUCCESSFUL waits for the second, it does not race it
+    assert _wait_for(lambda: server.store.job_by_id(
+        job.namespace, job.id).stable is True)
 
 
 def test_failed_allocs_fail_deployment_and_auto_revert(cluster):
@@ -95,9 +98,13 @@ def test_failed_allocs_fail_deployment_and_auto_revert(cluster):
     server.register_job(job)
     _wait_successful(server, job)          # v0 becomes the stable target
 
-    # v1: tasks exit non-zero immediately -> unhealthy -> fail + revert
+    # v1: tasks fail at start -> unhealthy -> fail + revert. A task
+    # that is never RUNNING cannot be called healthy first: 30 ms of
+    # run against min_healthy_time 50 ms was a margin of 20 ms, and on a
+    # loaded CPU the runner saw the exit late, both allocs were healthy
+    # by then and v1 SUCCEEDED (tests/test_e2e.py keeps the non-zero exit)
     bad = server.store.job_by_id(job.namespace, job.id).copy()
-    bad.task_groups[0].tasks[0].config = {"run_for": "30ms", "exit_code": "1"}
+    bad.task_groups[0].tasks[0].config = {"start_error": "boom"}
     bad.task_groups[0].update = job.task_groups[0].update
     server.register_job(bad)
 
@@ -111,7 +118,7 @@ def test_failed_allocs_fail_deployment_and_auto_revert(cluster):
     assert _wait_for(lambda: server.store.job_by_id(
         job.namespace, job.id).version == 2)
     reverted = server.store.job_by_id(job.namespace, job.id)
-    assert reverted.task_groups[0].tasks[0].config.get("exit_code") is None
+    assert reverted.task_groups[0].tasks[0].config.get("start_error") is None
 
 
 def test_canary_manual_promotion_flow(cluster):

@@ -123,8 +123,13 @@ def test_sink_delete_stops_delivery():
         s.upsert_event_sink(EventSink(id="snk3", address=rx.url))
         s.register_node(mock.node())
         assert _wait(lambda: rx.events)
+        worker = s.event_sinks._workers["snk3"]
         s.delete_event_sink("snk3")
-        time.sleep(1.5)               # manager reconciles at 1s cadence
+        # the manager reconciles at a 1 s cadence and a stopped worker
+        # still sends what arrives inside its 0.5 s poll: wait until its
+        # thread HAS ended (1.5 s of sleep was not always enough on a
+        # loaded CPU), then look for deliveries that must not come
+        assert _wait(lambda: not worker._thread.is_alive())
         seen = len(rx.events)
         s.register_node(mock.node())
         time.sleep(1.5)

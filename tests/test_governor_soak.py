@@ -85,8 +85,10 @@ def test_mini_soak_gauges_hold_and_rss_bounded(cluster):
         and server.eval_broker.stats.total_unacked == 0,
         90.0), "ready queue failed to drain"
 
-    # the governor sampled throughout (0.1 s cadence)
-    assert gov._samples > 10
+    # the governor samples throughout (0.1 s cadence): how many samples
+    # fit in the churn depends on how fast the churn ran (read 10 once),
+    # so wait for the count instead of racing it
+    assert _wait_for(lambda: gov._samples > 10, timeout=30.0)
     assert gov.latency_samples() > 0
 
     # every watermarked gauge is back inside its bound at steady state

@@ -7,6 +7,7 @@ syncs in the steady-state loop", "no silent recompiles", "no lock held
 across dispatch", "no undocumented governor knobs"): a PR that
 reintroduces one fails tier-1 here."""
 
+import ast
 import os
 import subprocess
 import sys
@@ -24,6 +25,42 @@ def test_tree_is_lint_clean():
     # the justified escape hatches exist and stay few: if this number
     # climbs, the fences are being papered over instead of used
     assert len(findings) <= 12
+
+
+def test_nothing_under_nomad_tpu_imports_the_bench_package():
+    """The arrows point down: nomad_tpu/bench/ holds what benchmarks
+    and tests build clusters with, and no module of the program
+    imports it (until PR 29 the collector and the chaos matrix took
+    flatness_verdict from bench/soak.py)."""
+    pkg = os.path.join(REPO, "nomad_tpu")
+    uphill = []
+    for dirpath, _dirs, files in os.walk(pkg):
+        rel = os.path.relpath(dirpath, pkg)
+        if rel.split(os.sep)[0] in ("bench", "__pycache__"):
+            continue
+        here = ["nomad_tpu"] + ([] if rel == "." else rel.split(os.sep))
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, name)
+            with open(path) as f:
+                tree = ast.parse(f.read(), path)
+            for node in ast.walk(tree):
+                if isinstance(node, ast.Import):
+                    mods = [a.name for a in node.names]
+                elif isinstance(node, ast.ImportFrom):
+                    base = here[:len(here) - node.level + 1] \
+                        if node.level else []
+                    mod = ".".join(
+                        base + ([node.module] if node.module else []))
+                    mods = [mod] + [f"{mod}.{a.name}" for a in node.names]
+                else:
+                    continue
+                if any(m == "nomad_tpu.bench"
+                       or m.startswith("nomad_tpu.bench.") for m in mods):
+                    uphill.append(f"{os.path.relpath(path, REPO)}:"
+                                  f"{node.lineno}")
+    assert not uphill, uphill
 
 
 def test_module_entrypoint_exit_codes():

@@ -3,6 +3,9 @@ python-msgpack in both directions, fuzzed roundtrips, RPC integration.
 """
 
 import os
+import shutil
+import subprocess
+import sys
 
 import msgpack
 import pytest
@@ -11,8 +14,36 @@ from nomad_tpu.native import load_codec
 
 native = load_codec()
 
+# a machine without a compiler runs the msgpack path and skips this
+# file; with one, a loader that gives None is a fault of the program
 pytestmark = pytest.mark.skipif(
-    native is None, reason="native codec unavailable (no g++?)")
+    shutil.which("g++") is None, reason="no g++: the msgpack path")
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_a_compiler_means_the_codec_loads():
+    assert native is not None, (
+        "g++ is here and load_codec() gave None: the build or its "
+        "self-check failed (the nomad_tpu.native warning says which)")
+
+
+def test_six_processes_on_an_empty_cache_all_get_both_modules(tmp_path):
+    """Two agents, or six test workers, first started on a fresh
+    install: each builds for itself and every one loads both."""
+    code = ("from nomad_tpu.native import load_codec, load_kway\n"
+            "print(load_codec() is not None, load_kway() is not None)\n")
+    env = {**os.environ, "NOMAD_TPU_NATIVE_CACHE": str(tmp_path)}
+    env.pop("NOMAD_TPU_NATIVE", None)
+    procs = [subprocess.Popen([sys.executable, "-c", code], cwd=REPO,
+                              env=env, stdout=subprocess.PIPE,
+                              stderr=subprocess.PIPE, text=True)
+             for _ in range(6)]
+    outs = [p.communicate(timeout=300) for p in procs]
+    assert [o.strip() for o, _e in outs] == ["True True"] * 6, outs
+    # what is left is the two modules, no process's temp file
+    assert sorted(n.split("-")[0] for n in os.listdir(tmp_path)) == [
+        "nomad_tpu_native_codec", "nomad_tpu_native_kway"]
 
 
 CASES = [

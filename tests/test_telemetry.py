@@ -4,10 +4,11 @@ setupTelemetry, worker.go:162-282 measure points, agent_endpoint.go
 monitor/pprof) — plus the ISSUE 11 retained-telemetry core: histogram
 buckets + Prometheus exposition round-trip, InmemSink parity
 (interval-anchored Timestamp, explicit empty-sample Min), the
-struct-of-arrays history ring's bounding, live flatness verdict
-parity with bench/soak.py, /v1/operator/telemetry + /v1/operator/
+struct-of-arrays history ring's bounding, the flatness verdict and
+the live route's parity with it, /v1/operator/telemetry + /v1/operator/
 flatness + ?format=prometheus surface, `operator top`, the
-NOMAD_TPU_TELEMETRY kill switch, and the paired collector-overhead
+NOMAD_TPU_TELEMETRY kill switch, what one sample costs as counts, and
+(slow, by hand on a quiet machine) the paired collector-overhead
 smoke.
 """
 
@@ -27,6 +28,7 @@ from nomad_tpu.api.client import ApiClient
 from nomad_tpu.server import Server, ServerConfig
 from nomad_tpu.telemetry import MAX_SERIES, TelemetryCollector
 from nomad_tpu.telemetry import collector as telemetry_collector
+from nomad_tpu.telemetry.collector import flatness_verdict
 from nomad_tpu.utils.metrics import (HIST_BUCKETS_MS, INTERVAL_S,
                                      Histogram, MetricsRegistry,
                                      prom_name)
@@ -259,6 +261,33 @@ def test_monitor_buffer_levels_and_blocking():
 
 # -- ISSUE 11: live flatness verdict parity -----------------------------
 
+class TestFlatnessVerdict:
+    def test_flat_windows_pass(self):
+        windows = [{"t_min": i, "p99_ms": 50.0 + (i % 2),
+                    "rss_mb": 1000.0 + i} for i in range(10)]
+        v = flatness_verdict(windows)
+        assert v["pass"] is True
+        assert v["p99_drift_ratio"] < 1.1
+        assert v["rss_slope_mb_per_hour"] == 60.0  # 1 MB/min fit
+
+    def test_p99_drift_fails(self):
+        windows = [{"t_min": i, "p99_ms": 50.0 * (1 + i),
+                    "rss_mb": 1000.0} for i in range(10)]
+        v = flatness_verdict(windows)
+        assert v["pass"] is False
+        assert "p99 drift" in v["reason"]
+
+    def test_rss_slope_fails(self):
+        windows = [{"t_min": i, "p99_ms": 50.0,
+                    "rss_mb": 1000.0 + 10.0 * i} for i in range(10)]
+        v = flatness_verdict(windows)
+        assert v["pass"] is False
+        assert "rss slope" in v["reason"]
+
+    def test_too_few_windows(self):
+        assert flatness_verdict([])["pass"] is False
+
+
 def _scripted_collector(monkeypatch, p99s, rsss):
     """A collector whose windows are fully scripted: latency_fn and
     rss_mb return the given series step by step, one sample per
@@ -279,12 +308,10 @@ def _scripted_collector(monkeypatch, p99s, rsss):
 
 
 def test_flatness_verdict_parity_with_soak(monkeypatch):
-    """/v1/operator/flatness reuses bench/soak.flatness_verdict: over
-    identical synthetic windows the live verdict and the soak
-    harness's verdict are the SAME dict (same drift ratios, slopes,
+    """/v1/operator/flatness reuses flatness_verdict: over
+    identical synthetic windows the live verdict and the function's
+    own are the SAME dict (same drift ratios, slopes,
     pass bit, reasons) — for a flat window set and a drifting one."""
-    from nomad_tpu.bench.soak import flatness_verdict
-
     flat_p99 = [50.0, 52.0, 49.0, 51.0, 50.0, 52.0, 50.0, 51.0]
     flat_rss = [500.0, 501.0, 500.5, 501.0, 500.8, 501.2, 500.9, 501.0]
     drift_p99 = [50.0, 52.0, 60.0, 75.0, 90.0, 120.0, 150.0, 180.0]
@@ -306,10 +333,9 @@ def test_flatness_verdict_parity_with_soak(monkeypatch):
 
 
 def test_flatness_route_matches_soak_verdict(monkeypatch):
-    """The HTTP route serves the same verdict the soak harness would
-    compute over the server collector's windows (background sampling
+    """The HTTP route serves the same verdict flatness_verdict
+    computes over the server collector's windows (background sampling
     disabled: interval pinned high, samples driven by hand)."""
-    from nomad_tpu.bench.soak import flatness_verdict
     server = Server(ServerConfig(num_schedulers=0,
                                  telemetry_sample_interval_s=3600.0))
     api = HTTPApiServer(server, port=0)
@@ -478,8 +504,74 @@ def test_prometheus_route_reflects_registry():
         server.shutdown()
 
 
+# -- what one sample does, as counts -----------------------------------
+
+def test_sample_once_reads_each_source_once_and_allocates_nothing_new():
+    """The collector's cost per sample, in what repeats exactly: every
+    source is read once (the latency reservoir twice, p50 and p99),
+    every key of the row is written to one slot of its own series, and
+    from the second sample on nothing is allocated that the ring's
+    length could scale: the same arrays, the same bytes, across a
+    wrap. (The wall-clock twin below is marked slow.)"""
+    calls = {"gauges": 0, "latency": [], "stage": 0, "device": 0,
+             "extra": 0}
+
+    def gauges():
+        calls["gauges"] += 1
+        return {"g.a": 1.0, "g.b": 2.0}
+
+    def latency(p):
+        calls["latency"].append(p)
+        return float(p)
+
+    def stage():
+        calls["stage"] += 1
+        return {"kernel": {"p50_ms": 1.0, "p99_ms": 2.0, "count": 3}}
+
+    def device():
+        calls["device"] += 1
+        return {"device.packs": 4.0}
+
+    def extra():
+        calls["extra"] += 1
+        return {"cluster.nodes_total": 5.0}
+
+    slots = 8
+    tc = TelemetryCollector(interval_s=60.0, slots=slots,
+                            gauges_fn=gauges, latency_fn=latency,
+                            stage_fn=stage, device_fn=device,
+                            extra_fn=extra)
+    assert tc.sample_once(now=1000.0) == 1
+    counters = {n for n in tc._series if n.startswith("counter.")}
+    want = {"process.rss_mb", "g.a", "g.b", "latency.p50_ms",
+            "latency.p99_ms", "stage.kernel.p50_ms",
+            "stage.kernel.p99_ms", "stage_count.kernel", "device.packs",
+            "cluster.nodes_total"} | counters
+    assert set(tc._series) == want
+    arrays = {n: id(a) for n, a in tc._series.items()}
+    t_id, ring_bytes = id(tc._t), tc.status()["ring_bytes"]
+    assert ring_bytes == (1 + len(want)) * slots * 8
+
+    n = 3 * slots + 1                   # wraps the ring three times
+    for i in range(1, n):
+        assert tc.sample_once(now=1000.0 + 60.0 * i) == i + 1
+    assert calls["gauges"] == calls["stage"] == calls["device"] \
+        == calls["extra"] == n
+    assert calls["latency"] == [50, 99] * n
+    # the same arrays hold the ring: nothing was re-made or grown
+    assert {m: id(a) for m, a in tc._series.items()} == arrays
+    assert id(tc._t) == t_id
+    assert tc.status()["ring_bytes"] == ring_bytes
+    assert tc.status()["series_dropped"] == 0
+    # one value a series a slot: every slot of every series is written
+    for name in want - counters:
+        assert not np.isnan(tc._series[name]).any(), name
+    assert len(tc.history()["t"]) == slots
+
+
 # -- ISSUE 11 acceptance: paired collector-overhead smoke ---------------
 
+@pytest.mark.slow
 def test_collector_overhead_within_5pct(monkeypatch):
     """Two overhead bounds (r13 paired methodology, split): (a)
     collector-on MODE keeps e2e eval latency within 5% of

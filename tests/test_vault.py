@@ -69,8 +69,9 @@ def test_derive_tracks_accessor_and_injects_token(cluster, tmp_path):
     runner = client.runners[alloc.id]
     secrets = runner.alloc_dir.task_paths(acc.task)[2]
     tok_file = os.path.join(secrets, "vault_token")
-    assert _wait_for(lambda: os.path.exists(tok_file))
-    assert open(tok_file).read() == acc.token
+    # the hook creates the file, then writes it: wait for the content
+    assert _wait_for(lambda: os.path.exists(tok_file)
+                     and open(tok_file).read() == acc.token)
 
 
 def test_short_ttl_token_survives_task_via_renewal(cluster):
