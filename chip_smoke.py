@@ -543,7 +543,7 @@ def _shape_family(signature: tuple) -> tuple:
     fresh compile the first attempt's shape never causes."""
     return tuple(x for x in signature
                  if not (isinstance(x, tuple) and len(x) == 2
-                         and x[0] in ("k_steps", "max_steps")))
+                         and x[0] in ("k_steps", "max_steps", "k_out")))
 
 
 def new_shape_families(before: Dict[str, set],
@@ -940,8 +940,7 @@ class Smoke:
         rep["ingest"] = (dict(srv.ingest.stats)
                          if getattr(srv, "ingest", None) else None)
         rep["mesh"] = mesh_stats_snapshot()
-        rep["native"] = {"kway": native.load_kway() is not None,
-                         "codec": native.load_codec() is not None}
+        rep["native"] = {"codec": native.load_codec() is not None}
         rep["xla_backend_compiles"] = {"count": self.xla.count,
                                        "seconds_setup_info":
                                        round(self.xla.seconds, 1)}

@@ -1,13 +1,13 @@
 """Native runtime components.
 
 The reference keeps its wire codec compiled (go-msgpack + generated
-encoders); here codec.cpp and kway.cpp are CPython extensions built on
-demand with g++, from the tracked sources, on the machine that runs
-them. The build is cached beside the source (`_build/`, git-ignored;
+encoders); here codec.cpp is a CPython extension built on demand with
+g++, from the tracked source, on the machine that runs it. The build
+is cached beside the source (`_build/`, git-ignored;
 NOMAD_TPU_NATIVE_CACHE moves it) keyed by source hash + python ABI.
 A build or self-check failure logs a warning and the loader returns
-None: callers then run their pure-python path (same wire format, same
-merge order), and chip_smoke.py reports which modules loaded.
+None: callers then run their pure-python path (same wire format), and
+chip_smoke.py reports whether the module loaded.
 
 NOMAD_TPU_NATIVE=0 disables the native path.
 """
@@ -102,40 +102,4 @@ def load_codec():
         return mod
     except Exception as e:       # pragma: no cover — env-dependent
         LOG.warning("native codec unavailable: %s", e)
-        return None
-
-
-_kway_loaded = None
-_kway_attempted = False
-
-
-def load_kway():
-    """The native k-way stream merge (kway.cpp) used by the placement
-    kernel's host expansion, or None (python-heap fallback)."""
-    global _kway_loaded, _kway_attempted
-    if _kway_loaded is not None or _kway_attempted:
-        return _kway_loaded
-    _kway_attempted = True
-    try:
-        mod = _load_module(os.path.join(_HERE, "kway.cpp"),
-                           "nomad_tpu_native_kway")
-        if mod is None:
-            return None
-        # self-check: two streams, scores [3,1] on node 5 and [2,4] on
-        # node 9 -> pop order (row,j): (0,0) s=3, (1,0) s=2 ... heads
-        # compared, stream 1 advances to 4 -> (1,1), then (0,1)
-        import struct
-        scores = struct.pack("4f", 3.0, 1.0, 2.0, 4.0)
-        nodes = struct.pack("2i", 5, 9)
-        lens = struct.pack("2i", 2, 2)
-        out = mod.merge(scores, nodes, lens, 2, 100)
-        got = struct.unpack("8i", out)
-        if got != (0, 1, 1, 0, 0, 0, 1, 1):
-            LOG.warning("native kway self-check failed; falling back "
-                        "(%r)", got)
-            return None
-        _kway_loaded = mod
-        return mod
-    except Exception as e:       # pragma: no cover — env-dependent
-        LOG.warning("native kway unavailable: %s", e)
         return None

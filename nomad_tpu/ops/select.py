@@ -616,22 +616,144 @@ def _chunked_batched_jit(max_steps: int, spread_alg: bool):
     return jax.jit(jax.vmap(_select_chunked_many, in_axes=in_axes))
 
 
+def _segment_cummin(starts, x):
+    """Running minimum of x along its one axis, begun anew wherever
+    `starts` is set."""
+    def combine(a, b):
+        (a_start, a_min), (b_start, b_min) = a, b
+        return (a_start | b_start,
+                jnp.where(b_start, b_min, jnp.minimum(a_min, b_min)))
+    return jax.lax.associative_scan(combine, (starts, x))[1]
+
+
+# the K-way payload's header row (the int payload's last): what the
+# host asks of a dispatch before it reads the sequence
+KWAY_PLACED, KWAY_REMAINING, KWAY_PHASES, KWAY_TAIL_PHASES, \
+    KWAY_LAST_PLACED = range(5)
+# the float payload's columns before its TOP_K meta scores
+_KWAY_SCORE_COLS = ("final", "binpack", "job-anti-affinity",
+                    "node-reschedule-penalty", "node-affinity",
+                    "devices", "preemption")
+
+
+def _kway_sequence(out_widx, out_chunk, out_start, out_ti, out_ts, out_exh,
+                   remaining, steps, tails,
+                   capacity, used0, ask, tg_coll0, penalty, affinity_norm,
+                   desired_count, dev_score, dev_fires, pre_score,
+                   *, spread_alg: bool, k_out: int):
+    """The phases' (winners, chunks) as the per-instance greedy
+    sequence, in one pass over the `k_out` placed instances — no loop
+    over phases or winners.
+
+    Within a phase every winner's next score beats the waterline, so
+    the scan's order there is the merge of the winners' score streams:
+    pop the stream whose CURRENT head is largest, ties to the lowest
+    node index. The streams are not monotonic (a bin-pack score rises
+    as its node fills), so sorting the scores is not that merge. But:
+
+      Lemma. The merge pops element j of stream k in the order of the
+      key (-m_k(j), node_k, j), where m_k(j) = min over i <= j of
+      s_k(i) is the stream's running minimum.
+
+      Proof. m_k does not rise along a stream, so for any t the
+      elements with m >= t are a prefix of every stream, each scoring
+      >= t. Take the first pop of an element x with m < t while such a
+      prefix has elements left: all before x in its stream were of the
+      prefix, so x itself scores < t, and the stream with prefix left
+      has a head scoring >= t — the merge pops the larger head, not x.
+      So everything with m >= t goes before anything with m < t. Those
+      of one m are, in each stream, a run whose first scores exactly m
+      (the minimum is reached there) and whose rest score >= m: once
+      all above m are gone the heads are such firsts and heads below
+      m, the pop takes the lowest node among the firsts, and that
+      stream's next heads (>= m, on the lowest node) keep winning
+      until its run ends. Hence node, then j.
+
+    So: each element's (phase, winner, offset) by a search on the
+    running sum of the chunks; its score from _local_final_score — the
+    function the phase body and the scan rank by — at the winner's
+    `start + offset` earlier placements of this dispatch; the running
+    minimum along each stream (a stream's elements are contiguous); one
+    sort by (phase, -m, node, offset). The meta rows (top-k, exhausted
+    dimensions) are those of the last phase that made them, the first
+    and the failing ones; what was not placed carries the last phase's.
+
+    Returns (packed_i [k_out + 1, 1 + TOP_K + D], packed_f [k_out,
+    len(_KWAY_SCORE_COLS) + TOP_K]): node, top_idx, exhausted_dim per
+    instance with the header row (KWAY_*) last; the scores and
+    top_scores. ONE int payload + one float payload: every
+    device->host copy is its own transfer op with a fixed cost on top
+    of the bytes."""
+    max_steps, w = out_chunk.shape
+    chunks = out_chunk.reshape(-1)
+    ends = jnp.cumsum(chunks)
+    placed = ends[-1]
+    e = jnp.arange(k_out, dtype=jnp.int32)
+    live = e < placed
+    flat = jnp.minimum(jnp.searchsorted(ends, e, side="right"),
+                       chunks.shape[0] - 1).astype(jnp.int32)
+    offset = e - (ends[flat] - chunks[flat])
+    phase = jnp.where(live, flat // w, max_steps)       # the dead sort last
+    node = jnp.maximum(out_widx.reshape(-1)[flat], 0)
+    prior = out_start.reshape(-1)[flat] + offset
+
+    after = used0[node] \
+        + (prior + 1).astype(jnp.float32)[:, None] * ask[None, :]
+    affinity = affinity_norm[node]
+    pre = pre_score[node]
+    final, binpack, anti, pen = _local_final_score(
+        after, jnp.maximum(capacity[node, 0], 1e-9),
+        jnp.maximum(capacity[node, 1], 1e-9),
+        tg_coll0[node].astype(jnp.float32) + prior.astype(jnp.float32),
+        penalty[node], affinity, desired_count, spread_alg,
+        dev_score[node], dev_fires, pre)
+    dev = jnp.where(dev_fires > 0, dev_score[node], 0.0)
+
+    run_min = _segment_cummin((offset == 0) | ~live, final)
+    order = jnp.lexsort((offset, node, -run_min, phase))
+
+    # a phase's meta row: the last at or before it that was materialized
+    # (rows past `steps` were never written and read as the defaults)
+    made = jax.lax.cummax(jnp.where(out_exh[:, 0] >= 0,
+                                    jnp.arange(max_steps), 0))
+    meta = made[jnp.where(live, phase[order], jnp.maximum(steps - 1, 0))]
+
+    scores = (final, binpack, anti, pen, affinity, dev, pre)
+    packed_f = jnp.concatenate(
+        [jnp.where(live, col[order], 0.0)[:, None] for col in scores]
+        + [out_ts[meta]], axis=1)
+    packed_i = jnp.concatenate(
+        [jnp.where(live, node[order], -1)[:, None], out_ti[meta],
+         out_exh[meta]], axis=1)
+    last_placed = jnp.where(
+        steps > 0, out_chunk[jnp.maximum(steps - 1, 0)].sum(), 0)
+    head = jnp.stack([placed, remaining, steps, tails, last_placed])
+    header = jnp.zeros(packed_i.shape[1], jnp.int32).at[:len(head)].set(
+        head)                                       # in KWAY_* order
+    return jnp.concatenate([packed_i, header[None, :]]), packed_f
+
+
 def _kway_core(capacity, used0, feasible, ask, k_valid,
                tg_coll0, penalty, affinity_norm, desired_count,
                port_need, free_ports, port_ok,
                dev_slots0, dev_score, dev_fires, pre_score,
-               *, max_steps: int, spread_alg: bool, w: int):
+               *, max_steps: int, spread_alg: bool, w: int, k_out: int):
     """K-way chunked greedy placement for node-local scoring: each phase
     takes the top-W nodes and gives EACH the number of sub-placements
     that keep its own score above the (W+1)-th node's score (the
     waterline), under the scan's argmax tie rule. Greedy only ever picks
     the current argmax, and scores are node-local, so until every winner
     falls below the waterline the argmax stays inside the winner set —
-    the multiset of placements per phase is exactly the greedy one (the
-    host reconstructs the exact order with a heap merge,
-    _expand_kway). A phase whose winner chunks would overshoot the
+    the multiset of placements per phase is exactly the greedy one, and
+    _kway_sequence puts it in the greedy order before anything leaves
+    the device. A phase whose winner chunks would overshoot the
     remaining count degenerates to placing only on the single best node,
     preserving exactness for the tail.
+
+    Returns the carry (for a continuation when max_steps runs out) and
+    the per-instance sequence of the `k_out` (a _bucket_k of the ask's
+    count) placements this dispatch can hold, as one int and one float
+    payload (_kway_sequence).
 
     Phases ~ count/(W * avg-chunk) instead of the 2-way kernel's
     count/avg-chunk steps — an order of magnitude fewer sequential
@@ -646,8 +768,8 @@ def _kway_core(capacity, used0, feasible, ask, k_valid,
         return (remaining > 0) & alive & (step < max_steps)
 
     def body(state):
-        (used, coll, free_p, dev_slots, remaining, step, _alive,
-         out_widx, out_chunk, out_ti, out_ts, out_exh, out_feas) = state
+        (used, coll, free_p, dev_slots, remaining, step, _alive, tails,
+         out_widx, out_chunk, out_start, out_ti, out_ts, out_exh) = state
 
         # named scopes: op metadata only, so a profile shows which
         # phase of a step the device time went to
@@ -690,7 +812,7 @@ def _kway_core(capacity, used0, feasible, ask, k_valid,
                     jnp.full((capacity.shape[1],), -1, jnp.int32),
                     jnp.int32(-1))
 
-        top_idx, top_scores, exhausted, feas_count = jax.lax.cond(
+        top_idx, top_scores, exhausted, _feas_count = jax.lax.cond(
             (step == 0) | ~valid, _meta, _no_meta, operand=None)
 
         # physical capacity per winner
@@ -735,8 +857,8 @@ def _kway_core(capacity, used0, feasible, ask, k_valid,
                         .astype(jnp.float32), 1.0), a_max[0])
         first_only = jnp.zeros_like(chunk).at[0].set(
             jnp.minimum(chunk0, remaining.astype(jnp.float32)))
-        chunk = jnp.where(total > remaining.astype(jnp.float32),
-                          first_only, chunk)
+        overshoot = total > remaining.astype(jnp.float32)
+        chunk = jnp.where(overshoot, first_only, chunk)
         chunk = jnp.where(valid, chunk, jnp.zeros_like(chunk))
         chunk_i = chunk.astype(jnp.int32)
 
@@ -744,6 +866,10 @@ def _kway_core(capacity, used0, feasible, ask, k_valid,
         # invalid lanes carry chunk 0 (no-op adds on a real node row)
         with jax.named_scope("commit"):
             safe_w = jnp.maximum(widx, 0)
+            # what each winner already took in THIS dispatch: its
+            # stream's scores start that far past used0 / tg_coll0
+            out_start = out_start.at[step].set(
+                coll[safe_w] - tg_coll0[safe_w])
             used = used.at[safe_w].add(chunk[:, None] * ask[None, :])
             coll = coll.at[safe_w].add(chunk_i)
             free_p = free_p.at[safe_w].add(-chunk * port_need)
@@ -755,36 +881,37 @@ def _kway_core(capacity, used0, feasible, ask, k_valid,
             out_ti = out_ti.at[step].set(top_idx)
             out_ts = out_ts.at[step].set(top_scores)
             out_exh = out_exh.at[step].set(exhausted)
-            out_feas = out_feas.at[step].set(feas_count)
 
         return (used, coll, free_p, dev_slots,
                 remaining - chunk_i.sum(), step + 1, valid,
-                out_widx, out_chunk, out_ti, out_ts, out_exh, out_feas)
+                tails + (valid & overshoot).astype(jnp.int32),
+                out_widx, out_chunk, out_start, out_ti, out_ts, out_exh)
 
     d = capacity.shape[1]
     state0 = (used0, tg_coll0, free_ports, dev_slots0, k_valid,
-              jnp.int32(0), jnp.bool_(True),
+              jnp.int32(0), jnp.bool_(True), jnp.int32(0),
               jnp.full((max_steps, w), -1, jnp.int32),
+              jnp.zeros((max_steps, w), jnp.int32),
               jnp.zeros((max_steps, w), jnp.int32),
               jnp.full((max_steps, TOP_K), -1, jnp.int32),
               jnp.full((max_steps, TOP_K), NEG_INF, jnp.float32),
-              jnp.zeros((max_steps, d), jnp.int32),
-              jnp.zeros(max_steps, jnp.int32))
+              jnp.zeros((max_steps, d), jnp.int32))
     out = jax.lax.while_loop(cond, body, state0)
-    (used, coll, free_p, dev_slots, remaining, steps, _alive,
-     out_widx, out_chunk, out_ti, out_ts, out_exh, out_feas) = out
-    # ONE int payload + one float payload: every device->host copy is
-    # its own transfer op with a fixed cost on top of the bytes
-    packed_i = jnp.concatenate(
-        [out_widx, out_chunk, out_ti, out_exh, out_feas[:, None],
-         jnp.broadcast_to(remaining[None, None], (max_steps, 1)),
-         jnp.broadcast_to(steps[None, None], (max_steps, 1))], axis=1)
-    return ((used, coll, free_p, dev_slots), (packed_i, out_ts))
+    (used, coll, free_p, dev_slots, remaining, steps, _alive, tails,
+     out_widx, out_chunk, out_start, out_ti, out_ts, out_exh) = out
+    with jax.named_scope("sequence"):
+        payload = _kway_sequence(
+            out_widx, out_chunk, out_start, out_ti, out_ts, out_exh,
+            remaining, steps, tails,
+            capacity, used0, ask, tg_coll0, penalty, affinity_norm,
+            desired_count, dev_score, dev_fires, pre_score,
+            spread_alg=spread_alg, k_out=k_out)
+    return ((used, coll, free_p, dev_slots), payload)
 
 
 _select_kway = partial(jax.jit, static_argnames=("max_steps",
-                                                 "spread_alg",
-                                                 "w"))(_kway_core)
+                                                 "spread_alg", "w",
+                                                 "k_out"))(_kway_core)
 
 # Multi-eval batching (SURVEY §2.6 row 1: "batch multiple evals per
 # device dispatch"): B independent placement problems over ONE shared
@@ -793,14 +920,16 @@ _select_kway = partial(jax.jit, static_argnames=("max_steps",
 _KWAY_BATCH_AXES = (None,) + (0,) * 15
 
 
-@partial(jax.jit, static_argnames=("max_steps", "spread_alg", "w"))
+@partial(jax.jit, static_argnames=("max_steps", "spread_alg", "w",
+                                   "k_out"))
 def _select_kway_batched(capacity, used0, feasible, ask, k_valid,
                          tg_coll0, penalty, affinity_norm, desired_count,
                          port_need, free_ports, port_ok,
                          dev_slots0, dev_score, dev_fires, pre_score,
-                         *, max_steps: int, spread_alg: bool, w: int):
+                         *, max_steps: int, spread_alg: bool, w: int,
+                         k_out: int):
     fn = partial(_kway_core, max_steps=max_steps, spread_alg=spread_alg,
-                 w=w)
+                 w=w, k_out=k_out)
     return jax.vmap(fn, in_axes=_KWAY_BATCH_AXES)(
         capacity, used0, feasible, ask, k_valid,
         tg_coll0, penalty, affinity_norm, desired_count,
@@ -1117,228 +1246,45 @@ _CHUNKED_ARGS = ("capacity", "used0", "feasible", "ask", "k_valid",
                  "dev_slots0", "dev_score", "dev_fires", "pre_score")
 
 
-def _node_local_scores_np(req: SelectRequest, c: int, start: int,
-                          m: int):
-    """Scores of sub-placements start..start+m-1 on node c, float32,
-    identical math to the kernels (_local_final_score)."""
-    ask = np.asarray(req.ask, np.float32)
-    a = np.arange(m, dtype=np.float32)
-    after = (req.used[c].astype(np.float32)[None, :]
-             + (start + a[:, None] + 1.0) * ask)
-    cap_cpu = np.float32(max(req.capacity[c, 0], 1e-9))
-    cap_mem = np.float32(max(req.capacity[c, 1], 1e-9))
-    free_cpu = np.float32(1.0) - after[:, 0] / cap_cpu
-    free_mem = np.float32(1.0) - after[:, 1] / cap_mem
-    total = (np.power(np.float32(10.0), free_cpu)
-             + np.power(np.float32(10.0), free_mem))
-    if req.algorithm == "spread":
-        fit_score = np.clip(total - 2.0, 0.0, 18.0)
-    else:
-        fit_score = np.clip(20.0 - total, 0.0, 18.0)
-    binp = (fit_score / np.float32(18.0)).astype(np.float32)
-    desired = np.float32(max(req.desired_count, 1.0))
-    coll = np.float32(req.tg_collisions[c]) + np.float32(start) + a
-    anti_fires = coll > 0
-    anti = np.where(anti_fires, -(coll + 1.0) / desired,
-                    0.0).astype(np.float32)
-    pen_f = bool(req.penalty[c]) if req.penalty is not None else False
-    pen = np.float32(-1.0 if pen_f else 0.0)
-    if req.affinity is not None and req.affinity_sum_weights > 0:
-        aff = np.float32(req.affinity[c] / req.affinity_sum_weights)
-    else:
-        aff = np.float32(0.0)
-    dev = np.float32(req.dev_score[c]) if req.dev_fires \
-        and req.dev_score is not None else np.float32(0.0)
-    pre = np.float32(req.pre_score[c]) if req.pre_score is not None \
-        else np.float32(0.0)
-    fired = (1.0 + anti_fires.astype(np.float32)
-             + np.float32(1.0 if pen_f else 0.0)
-             + np.float32(1.0 if aff != 0.0 else 0.0)
-             + np.float32(1.0 if req.dev_fires else 0.0)
-             + np.float32(1.0 if pre != 0.0 else 0.0))
-    fin = ((binp + anti + pen + aff + dev + pre) / fired).astype(np.float32)
-    return fin, binp, anti, pen, aff, dev, pre
-
-
-def _node_local_scores_batch(req: SelectRequest, cs, starts, ms):
-    """All winners of a phase at once: float32 score streams shaped
-    [W, max_m] with the SAME op order and dtypes as
-    _node_local_scores_np, so results stay bit-identical — the
-    per-winner call overhead (30 tiny numpy ops each) dominated
-    multi-batch expansion."""
-    cs = np.asarray(cs, np.int32)
-    starts = np.asarray(starts, np.float32)
-    ms = np.asarray(ms, np.int32)
-    max_m = int(ms.max()) if len(ms) else 0
-    ask = np.asarray(req.ask, np.float32)
-    a = np.arange(max_m, dtype=np.float32)
-    # [W, max_m, D]
-    after = (req.used[cs].astype(np.float32)[:, None, :]
-             + (starts[:, None] + a[None, :] + 1.0)[:, :, None] * ask)
-    cap = np.maximum(req.capacity[cs].astype(np.float32), 1e-9)
-    free_cpu = np.float32(1.0) - after[:, :, 0] / cap[:, None, 0]
-    free_mem = np.float32(1.0) - after[:, :, 1] / cap[:, None, 1]
-    total = (np.power(np.float32(10.0), free_cpu)
-             + np.power(np.float32(10.0), free_mem))
-    if req.algorithm == "spread":
-        fit_score = np.clip(total - 2.0, 0.0, 18.0)
-    else:
-        fit_score = np.clip(20.0 - total, 0.0, 18.0)
-    binp = (fit_score / np.float32(18.0)).astype(np.float32)
-    desired = np.float32(max(req.desired_count, 1.0))
-    coll = (req.tg_collisions[cs].astype(np.float32)[:, None]
-            + starts[:, None] + a[None, :])
-    anti_fires = coll > 0
-    anti = np.where(anti_fires, -(coll + 1.0) / desired,
-                    0.0).astype(np.float32)
-    pen_f = req.penalty[cs].astype(bool) if req.penalty is not None \
-        else np.zeros(len(cs), bool)
-    pen_v = np.where(pen_f, np.float32(-1.0), np.float32(0.0))
-    if req.affinity is not None and req.affinity_sum_weights > 0:
-        aff_v = (req.affinity[cs] / req.affinity_sum_weights
-                 ).astype(np.float32)
-    else:
-        aff_v = np.zeros(len(cs), np.float32)
-    if req.dev_fires and req.dev_score is not None:
-        dev_v = req.dev_score[cs].astype(np.float32)
-    else:
-        dev_v = np.zeros(len(cs), np.float32)
-    pre_v = req.pre_score[cs].astype(np.float32) \
-        if req.pre_score is not None else np.zeros(len(cs), np.float32)
-    fired = (1.0 + anti_fires.astype(np.float32)
-             + pen_f.astype(np.float32)[:, None]
-             + (aff_v != 0.0).astype(np.float32)[:, None]
-             + np.float32(1.0 if req.dev_fires else 0.0)
-             + (pre_v != 0.0).astype(np.float32)[:, None])
-    fin = ((binp + anti + pen_v[:, None] + aff_v[:, None]
-            + dev_v[:, None] + pre_v[:, None]) / fired).astype(np.float32)
-    return fin, binp, anti, pen_v, aff_v, dev_v, pre_v
-
-
-def _kway_merge_py(fin_m, nodes_v, len_v, limit):
-    """Streaming k-way merge, python fallback: pop the stream whose
-    CURRENT head score is max (ties -> lowest node id), advance that
-    stream. Streams are NOT monotonic (binpack scores rise as a node
-    fills), so this is a true merge, not a sort."""
-    import heapq
-    heap = []
-    for k in range(len(nodes_v)):
-        if len_v[k] > 0:
-            heapq.heappush(heap, (-float(fin_m[k, 0]),
-                                  int(nodes_v[k]), k, 0))
-    ok: List[int] = []
-    oj: List[int] = []
-    while heap and len(ok) < limit:
-        _negs, node, k, j = heapq.heappop(heap)
-        ok.append(k)
-        oj.append(j)
-        if j + 1 < len_v[k]:
-            heapq.heappush(heap, (-float(fin_m[k, j + 1]), node,
-                                  k, j + 1))
-    return np.asarray(ok, np.int32), np.asarray(oj, np.int32)
-
-
-def _kway_merge(fin_m, nodes_v, len_v, limit):
-    """The per-phase greedy merge; native (native/kway.cpp) when
-    available — the python heap costs ~3-5us/instance and dominated
-    multi-batch expansion."""
-    from ..native import load_kway
-    mod = load_kway()
-    if mod is None:
-        return _kway_merge_py(fin_m, nodes_v, len_v, limit)
-    out = mod.merge(np.ascontiguousarray(fin_m, np.float32).tobytes(),
-                    nodes_v.astype(np.int32).tobytes(),
-                    len_v.astype(np.int32).tobytes(),
-                    fin_m.shape[1], int(limit))
-    pairs = np.frombuffer(out, np.int32)
-    p = len(pairs) // 2
-    return pairs[:p].copy(), pairs[p:].copy()
-
-
 def _expand_kway(req: SelectRequest, rounds) -> SelectResult:
-    """Expand per-phase (winners, chunks) into the exact per-instance
-    greedy sequence: within a phase every winner's next-score beats the
-    waterline, so true greedy order is the streaming merge of the
-    winners' score streams (max CURRENT head first, ties to the lowest
-    node index) — identical to the scan's argmax sequence."""
-    n = len(req.feasible)
-    k_total = req.count
-    d = req.capacity.shape[1]
+    """Host half of the K-way arm: the dispatch's sequence payloads
+    (_kway_sequence; more than one only when a dispatch ran out of its
+    phase budget and continued) joined in order and handed to the scan's
+    unpack — a slice, a clamp and the result's guard."""
+    pi, pf = rounds[-1]
+    pi = pi[:-1]
+    if len(rounds) > 1:
+        # a round that continued placed `KWAY_PLACED` and no less
+        cuts = [int(r[0][-1, KWAY_PLACED]) for r in rounds[:-1]]
+        pi = np.concatenate(
+            [r[0][:c] for r, c in zip(rounds, cuts)] + [pi])
+        pf = np.concatenate(
+            [r[1][:c] for r, c in zip(rounds, cuts)] + [pf])
+    s = len(_KWAY_SCORE_COLS)
+    fin, s_bin, s_anti, s_pen, s_aff, s_dev, s_pre = pf[:, :s].T
+    return _unpack_fetched(req, (
+        pi[:, 0], fin, s_bin, s_anti, s_pen, s_aff,
+        np.zeros(len(pi), np.float32), s_dev, s_pre,
+        pi[:, 1:1 + TOP_K], pf[:, s:], pi[:, 1 + TOP_K:], None))
 
-    node_idx = np.full(k_total, -1, np.int32)
-    final = np.zeros(k_total, np.float32)
-    comp = {name: np.zeros(k_total, np.float32)
-            for name in ("binpack", "job-anti-affinity",
-                         "node-reschedule-penalty", "node-affinity",
-                         "devices", "preemption")}
-    top_i = np.full((k_total, TOP_K), -1, np.int32)
-    top_s = np.full((k_total, TOP_K), NEG_INF, np.float32)
-    exh_out = np.zeros((k_total, d), np.int32)
 
-    pos = 0
-    extra: Dict[int, int] = {}          # node -> placed so far overall
-    last_meta = None
-    fail = None
-    for (widx, chunk, ti, ts, exh, _feas) in rounds:
-        for s in range(len(widx)):
-            if exh[s][0] >= 0:
-                last_meta = (ti[s], ts[s], exh[s])
-            winners = [(int(widx[s][w]), int(chunk[s][w]))
-                       for w in range(widx.shape[1])
-                       if chunk[s][w] > 0 and widx[s][w] >= 0]
-            if not winners:
-                fail = last_meta
-                continue
-            # score streams for ALL winners of this phase in one
-            # vectorized shot ([W, max_m]; rows past each winner's m
-            # are garbage the merge never reads)
-            nodes_v = np.asarray([c for c, _m in winners], np.int32)
-            len_v = np.asarray([mm for _c, mm in winners], np.int32)
-            starts_v = np.asarray([extra.get(c, 0)
-                                   for c, _m in winners], np.float32)
-            for c, mm in winners:
-                extra[c] = extra.get(c, 0) + mm
-            fin_m, bin_m, anti_m, pen_v, aff_v, dev_v, pre_v = \
-                _node_local_scores_batch(req, nodes_v, starts_v, len_v)
-            ok, oj = _kway_merge(fin_m, nodes_v, len_v, k_total - pos)
-            m = len(ok)
-            if m == 0:
-                continue
-            sl = slice(pos, pos + m)
-            node_idx[sl] = nodes_v[ok]
-            final[sl] = fin_m[ok, oj]
-            comp["binpack"][sl] = bin_m[ok, oj]
-            comp["job-anti-affinity"][sl] = anti_m[ok, oj]
-            comp["node-reschedule-penalty"][sl] = pen_v[ok]
-            comp["node-affinity"][sl] = aff_v[ok]
-            comp["devices"][sl] = dev_v[ok]
-            comp["preemption"][sl] = pre_v[ok]
-            m_ti, m_ts, m_exh = last_meta if last_meta is not None else \
-                (np.full(TOP_K, -1, np.int32), np.full(TOP_K, NEG_INF),
-                 np.zeros(d, np.int32))
-            top_i[sl] = np.where(np.asarray(m_ti) >= n, -1,
-                                 np.asarray(m_ti))[None, :]
-            top_s[sl] = np.asarray(m_ts)[None, :]
-            exh_out[sl] = np.maximum(np.asarray(m_exh), 0)[None, :]
-            pos += m
-    if fail is not None and pos < k_total:
-        ti_f, ts_f, exh_f = fail
-        top_i[pos:] = np.where(np.asarray(ti_f) >= n, -1, np.asarray(ti_f))
-        top_s[pos:] = ts_f
-        exh_out[pos:] = exh_f
+def _kway_continues(packed_i) -> bool:
+    """A dispatch that left instances unplaced and whose last phase
+    still placed some ran out of phases, not of nodes."""
+    head = packed_i[-1]
+    return bool(head[KWAY_REMAINING] > 0 and head[KWAY_PHASES] > 0
+                and head[KWAY_LAST_PLACED] > 0)
 
-    considered = req.n_considered if req.n_considered is not None else n
-    comp["allocation-spread"] = np.zeros(k_total, np.float32)
-    return _sanitize_result(req, SelectResult(
-        node_idx=node_idx,
-        final_score=final,
-        scores=comp,
-        top_idx=top_i, top_scores=top_s,
-        nodes_evaluated=considered,
-        nodes_filtered=int(considered - np.count_nonzero(req.feasible)),
-        exhausted_dim=exh_out,
-        placed=pos,
-    ))
+
+def _kway_counts(lanes) -> Dict[str, int]:
+    """The kernel_expand span's attrs on the K-way arm, summed over
+    the rounds of every lane: phases run, those of them the overshoot
+    rule held to the best node alone, instances placed."""
+    heads = np.stack([pi[-1] for rounds in lanes for pi, _pf in rounds])
+    return {"phases": int(heads[:, KWAY_PHASES].sum()),
+            "tail_phases": int(heads[:, KWAY_TAIL_PHASES].sum()),
+            "placed": int(heads[:, KWAY_PLACED].sum())}
+
 
 class DispatchCostModel:
     """Measured per-shape dispatch costs: what the gateways' solo-or-
@@ -1924,9 +1870,11 @@ class SelectKernel:
                 # stats, never the single-device routing estimates
                 spread_alg = req.algorithm == "spread"
                 w = _kway_w(n_pad_sh)
+                k_out = _bucket_k(req.count)
                 fresh = _note_trace("kway@mesh", n_pad_sh,
                                     max_steps=_kway_steps(w),
-                                    spread_alg=spread_alg, w=w)
+                                    spread_alg=spread_alg, w=w,
+                                    k_out=k_out)
                 with kernel_span("kway@mesh", n_pad_sh, fresh=fresh):
                     # big batches keep the K-way kernel on the mesh:
                     # the same SPMD program, node axis sharded,
@@ -1940,7 +1888,8 @@ class SelectKernel:
                     with sharded.mesh:
                         pending = _select_kway(**cargs,
                                                max_steps=_kway_steps(w),
-                                               spread_alg=spread_alg, w=w)
+                                               spread_alg=spread_alg, w=w,
+                                               k_out=k_out)
                     return self._finish_kway(req, cargs, spread_alg,
                                              pending, w=w)
             return sharded.select(req)      # observes scan@mesh itself
@@ -1986,21 +1935,23 @@ class SelectKernel:
                      pending, w: int) -> SelectResult:
         rounds = self._finish_kway_rounds(req, cargs, spread_alg,
                                           pending, w=w)
-        with stages.span("kernel_expand"):
+        with stages.span("kernel_expand", **_kway_counts([rounds])):
             return _expand_kway(req, rounds)
 
     def _run_kway(self, req: SelectRequest, n_pad: int,
                   dev) -> SelectResult:
         cargs, spread_alg, w = self._pack_kway(req, n_pad, dev)
+        k_out = _bucket_k(req.count)
         fresh = _note_trace("kway", n_pad, max_steps=_kway_steps(w),
-                            spread_alg=spread_alg, w=w,
+                            spread_alg=spread_alg, w=w, k_out=k_out,
                             cpu=dev is not None)
         # window matches every other arm: dispatch through
         # unpack/expand, packing/placement excluded
         with kernel_span("kway" + ("@cpu" if dev is not None else ""),
                          n_pad, fresh=fresh):
             pending = _select_kway(**cargs, max_steps=_kway_steps(w),
-                                   spread_alg=spread_alg, w=w)
+                                   spread_alg=spread_alg, w=w,
+                                   k_out=k_out)
             return self._finish_kway(req, cargs, spread_alg, pending,
                                      w=w)
 
@@ -2069,41 +2020,32 @@ class SelectKernel:
             cargs, mesh_ctx, on_cpu = self._place_batched(
                 cargs, sharded, reqs[0].capacity, table=reqs[0].table)
         w = _kway_w(n_pad)
+        k_out = _bucket_k(max(r.count for r in reqs))
         fresh = _note_trace("kway_batched", n_pad,
                             max_steps=_kway_steps(w),
-                            spread_alg=spread_alg, w=w,
+                            spread_alg=spread_alg, w=w, k_out=k_out,
                             lanes=len(cargs["k_valid"]), cpu=on_cpu)
         # window includes per-lane unpack/expand so the number compares
         # end-to-end against the solo arms (which include theirs)
         with kernel_span("kway_batched", n_pad, lanes=len(reqs),
                          fresh=fresh, on_cpu=on_cpu):
             return self._kway_batched_results(
-                reqs, cargs, mesh_ctx, spread_alg, w)
+                reqs, cargs, mesh_ctx, spread_alg, w, k_out)
 
     def _kway_batched_results(self, reqs, cargs, mesh_ctx, spread_alg,
-                              w: int) -> List[SelectResult]:
+                              w: int, k_out: int) -> List[SelectResult]:
         """The kway_batched arm's `kernel` window: one vmapped dispatch,
         per-lane overflow continued solo, then every lane expanded."""
         with mesh_ctx:
             carry, outs = _select_kway_batched(**cargs,
                                                max_steps=_kway_steps(w),
                                                spread_alg=spread_alg,
-                                               w=w)
-        packed_i, ts = _stage_get(outs)
-        d = reqs[0].capacity.shape[1]
+                                               w=w, k_out=k_out)
+        packed_i, packed_f = _stage_get(outs)
         lane_rounds = []
         for i, req in enumerate(reqs):
-            pi = packed_i[i]
-            widx = pi[:, :w]
-            chunk = pi[:, w:2 * w]
-            ti = pi[:, 2 * w:2 * w + TOP_K]
-            exh = pi[:, 2 * w + TOP_K:2 * w + TOP_K + d]
-            feas = pi[:, -3]
-            rem = int(pi[0, -2])
-            steps = int(pi[0, -1])
-            rounds = [(widx[:steps], chunk[:steps], ti[:steps],
-                       ts[i][:steps], exh[:steps], feas[:steps])]
-            if rem > 0 and steps > 0 and chunk[steps - 1].sum() > 0:
+            rounds = [(packed_i[i], packed_f[i])]
+            if _kway_continues(packed_i[i]):
                 # rare overflow of the phase budget: continue this lane
                 # on the single-request kernel from its carry state
                 # host copies: the continuation runs on the default
@@ -2122,15 +2064,16 @@ class SelectKernel:
                     tg_coll0=np.asarray(tg0),
                     free_ports=np.asarray(fp0),
                     dev_slots0=np.asarray(ds0),
-                    k_valid=np.int32(rem))
+                    k_valid=packed_i[i][-1, KWAY_REMAINING])
                 pending = _select_kway(**lane,
                                        max_steps=_kway_steps(w),
-                                       spread_alg=spread_alg, w=w)
+                                       spread_alg=spread_alg, w=w,
+                                       k_out=_bucket_k(req.count))
                 cont = self._finish_kway_rounds(req, lane, spread_alg,
                                                 pending, w=w)
                 rounds.extend(cont)
             lane_rounds.append(rounds)
-        with stages.span("kernel_expand"):
+        with stages.span("kernel_expand", **_kway_counts(lane_rounds)):
             return [_expand_kway(req, rounds)
                     for req, rounds in zip(reqs, lane_rounds)]
 
@@ -2340,31 +2283,23 @@ class SelectKernel:
 
     def _finish_kway_rounds(self, req, cargs, spread_alg, pending,
                             w: int):
-        """Continuation rounds only (no expansion) — shared by the
-        batched path's per-lane overflow handling."""
-        d = req.capacity.shape[1]
+        """The dispatch's payloads fetched, and those of its
+        continuations while one ran out of its phase budget (no
+        expansion) — shared by the batched path's per-lane overflow
+        handling."""
         rounds = []
         while True:
             (used, coll, freep, devs), outs = pending
-            packed_i, ts = _stage_get(outs)
-            widx = packed_i[:, :w]
-            chunk = packed_i[:, w:2 * w]
-            ti = packed_i[:, 2 * w:2 * w + TOP_K]
-            exh = packed_i[:, 2 * w + TOP_K:2 * w + TOP_K + d]
-            feas = packed_i[:, -3]
-            rem = int(packed_i[0, -2])
-            steps = int(packed_i[0, -1])
-            rounds.append((widx[:steps], chunk[:steps], ti[:steps],
-                           ts[:steps], exh[:steps], feas[:steps]))
-            if rem <= 0 or steps == 0:
-                break
-            if chunk[steps - 1].sum() == 0:
-                break
+            packed_i, packed_f = _stage_get(outs)
+            rounds.append((packed_i, packed_f))
+            if not _kway_continues(packed_i):
+                return rounds
             cargs.update(used0=used, tg_coll0=coll, free_ports=freep,
-                         dev_slots0=devs, k_valid=np.int32(rem))
+                         dev_slots0=devs,
+                         k_valid=packed_i[-1, KWAY_REMAINING])
             pending = _select_kway(**cargs, max_steps=_kway_steps(w),
-                                   spread_alg=spread_alg, w=w)
-        return rounds
+                                   spread_alg=spread_alg, w=w,
+                                   k_out=_bucket_k(req.count))
 
     # -- chunked path --------------------------------------------------
     def _run_chunked(self, req: SelectRequest, n_pad: int,

@@ -65,7 +65,11 @@ same tree; a stage's time lies inside its parent's):
                     host unpack (attrs arm, n_pad, lanes, fresh)
         d2h           device->host result transfers (device_get; the
                       wall includes remaining device compute)
-        kernel_expand host unpack/expand of the fetched result
+        kernel_expand host unpack/expand of the fetched result (on
+                      the K-way arm, whose program hands back the
+                      per-instance sequence: attrs phases, tail_phases
+                      — those the overshoot rule held to one node —
+                      and placed)
       select_finish dispatch returned -> RankedNodes returned: winner
                     materialization, resources, ports, metrics
         port_assign   the winners' NetworkIndex builds and their port

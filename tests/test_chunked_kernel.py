@@ -261,72 +261,231 @@ def test_n_considered_metrics():
     assert res.nodes_filtered == 2
 
 
-def test_native_kway_merge_matches_python():
-    """native/kway.cpp merge == the python heap merge on random
-    non-monotonic streams (incl. score ties across streams)."""
-    from nomad_tpu.native import load_kway
-    from nomad_tpu.ops.select import _kway_merge_py
-
-    import shutil
-    if shutil.which("g++") is None:
-        import pytest
-        pytest.skip("no g++: the python heap merge")
-    mod = load_kway()
-    assert mod is not None, "g++ is here and load_kway() gave None"
-    rng = np.random.RandomState(7)
-    for trial in range(20):
-        w = rng.randint(1, 33)
-        max_m = rng.randint(1, 65)
-        fin = rng.uniform(0, 1, size=(w, max_m)).astype(np.float32)
-        # force ties sometimes
-        if trial % 3 == 0:
-            fin = np.round(fin * 4) / 4
-        nodes = rng.permutation(1000)[:w].astype(np.int32)
-        lens = rng.randint(0, max_m + 1, size=w).astype(np.int64)
-        limit = int(rng.randint(1, int(lens.sum()) + 2))
-        ok_py, oj_py = _kway_merge_py(fin, nodes, lens, limit)
-        out = mod.merge(np.ascontiguousarray(fin).tobytes(),
-                        nodes.tobytes(),
-                        lens.astype(np.int32).tobytes(), max_m, limit)
-        pairs = np.frombuffer(out, np.int32)
-        p = len(pairs) // 2
-        ok_c, oj_c = pairs[:p], pairs[p:]
-        assert np.array_equal(ok_py, ok_c), (trial, ok_py, ok_c)
-        assert np.array_equal(oj_py, oj_c), trial
+def _heap_merge(fin_m, nodes_v, len_v, limit):
+    """The K-way arm's order, plainly: pop the stream whose CURRENT
+    head score is largest (ties to the lowest node index), advance that
+    stream. The host ran this phase by phase until the program took the
+    order on itself (_kway_sequence); kept as its oracle."""
+    import heapq
+    heap = []
+    for k in range(len(nodes_v)):
+        if len_v[k] > 0:
+            heapq.heappush(heap, (-float(fin_m[k, 0]),
+                                  int(nodes_v[k]), k, 0))
+    ok, oj = [], []
+    while heap and len(ok) < limit:
+        _negs, node, k, j = heapq.heappop(heap)
+        ok.append(k)
+        oj.append(j)
+        if j + 1 < len_v[k]:
+            heapq.heappush(heap, (-float(fin_m[k, j + 1]), node,
+                                  k, j + 1))
+    return np.asarray(ok, np.int32), np.asarray(oj, np.int32)
 
 
-def test_batch_scores_match_scalar():
-    """_node_local_scores_batch is bit-identical to the per-winner
-    _node_local_scores_np (the scan kernels' host-side score math)."""
-    from nomad_tpu.ops.select import (_node_local_scores_batch,
-                                      _node_local_scores_np)
-    rng = np.random.RandomState(11)
-    n = 64
-    for trial in range(10):
-        cap = np.tile(np.array([[4000.0, 8192.0, 102400.0, 1000.0]],
-                               np.float32), (n, 1))
-        req = sel.SelectRequest(
-            ask=np.array([100.0, 150.0, 10.0, 0.0], np.float32),
-            count=100,
-            feasible=np.ones(n, bool), capacity=cap,
-            used=(cap * rng.uniform(0, 0.5, (n, 4))).astype(np.float32),
-            desired_count=float(rng.randint(1, 200)),
-            tg_collisions=rng.randint(0, 3, n).astype(np.int32),
-            job_count=np.zeros(n, np.int32),
-            penalty=(rng.rand(n) < 0.3),
-            algorithm="spread" if trial % 2 else "binpack")
+@pytest.mark.parametrize("seed", range(8))
+def test_running_minimum_order_is_the_heap_merge(seed):
+    """_kway_sequence's lemma: the merge pops element j of stream k in
+    the order of (-running minimum, node, j) — on non-monotonic streams
+    of unequal lengths, with exact ties in and across streams, under a
+    limit. The running minimum is the program's own (_segment_cummin
+    over the streams laid end to end, as the program lays them)."""
+    import jax
+    cummin = jax.jit(sel._segment_cummin)
+    rng = np.random.RandomState(300 + seed)
+    for trial in range(40):
         w = rng.randint(1, 9)
-        cs = rng.permutation(n)[:w]
-        starts = rng.randint(0, 5, w)
-        ms = rng.randint(1, 12, w)
-        fin_m, bin_m, anti_m, pen_v, aff_v, dev_v, pre_v = \
-            _node_local_scores_batch(req, cs, starts, ms)
-        for k in range(w):
-            fin, binp, anti, pen, aff, dev, pre = _node_local_scores_np(
-                req, int(cs[k]), int(starts[k]), int(ms[k]))
-            m = ms[k]
-            assert np.array_equal(fin_m[k, :m], fin), trial
-            assert np.array_equal(bin_m[k, :m], binp)
-            assert np.array_equal(anti_m[k, :m], anti)
-            assert pen_v[k] == pen and aff_v[k] == aff
-            assert dev_v[k] == dev and pre_v[k] == pre
+        max_m = rng.randint(1, 13)
+        # a handful of score values: ties abound
+        fin = rng.randint(0, 4, size=(w, max_m)).astype(np.float32)
+        if trial % 4 == 0:
+            fin = rng.uniform(0, 1, size=(w, max_m)).astype(np.float32)
+        nodes = rng.permutation(1000)[:w].astype(np.int32)
+        lens = rng.randint(0, max_m + 1, size=w)
+        limit = int(rng.randint(1, int(lens.sum()) + 2))
+        want_k, want_j = _heap_merge(fin, nodes, lens, limit)
+
+        k_flat = np.repeat(np.arange(w), lens)
+        j_flat = np.concatenate([np.arange(m) for m in lens]
+                                + [np.zeros(0, int)])
+        if not len(k_flat):
+            assert len(want_k) == 0
+            continue
+        # one shape, so one compile: what lies past the streams begins
+        # a stream of its own each
+        pad = 128 - len(k_flat)
+        run_min = np.asarray(cummin(
+            np.concatenate([j_flat == 0, np.ones(pad, bool)]),
+            np.concatenate([fin[k_flat, j_flat],
+                            np.zeros(pad, np.float32)])))[:len(k_flat)]
+        order = np.lexsort((j_flat, nodes[k_flat], -run_min))[:limit]
+        assert np.array_equal(k_flat[order], want_k), (seed, trial)
+        assert np.array_equal(j_flat[order], want_j), (seed, trial)
+
+
+class _StageTap:
+    """Every stage report, kept, and passed on to the recorder."""
+
+    def __init__(self):
+        from nomad_tpu.utils import stages
+        self.stages = stages
+        self.reports = []               # (stage, seconds, attrs)
+        self._prev, self._prev_on = stages._trace_hook, stages._trace_on
+        stages.set_trace_hook(self._on, on=True)
+
+    def _on(self, stage, seconds, attrs=None):
+        self.reports.append((stage, seconds, attrs))
+        if self._prev is not None and self._prev_on:
+            self._prev(stage, seconds, attrs)
+
+    def close(self):
+        self.stages.set_trace_hook(self._prev, on=self._prev_on)
+
+    def attrs_of(self, stage):
+        return [r[2] for r in self.reports if r[0] == stage]
+
+
+@pytest.fixture
+def stage_tap():
+    tap = _StageTap()
+    try:
+        yield tap
+    finally:
+        tap.close()
+
+
+def _batch_cell_request(rng, n=5000, count=1000):
+    """One worker's share of `prod-10k_batch-fill`: the mock node in
+    three classes 1x/2x/4x at 60/30/10%, 40 resident allocs of cpu 50 /
+    64 MB on each, an ask of cpu 20 / 32 MB with job anti-affinity."""
+    scale = rng.choice([1.0, 2.0, 4.0], size=n, p=[0.6, 0.3, 0.1])
+    capacity = (scale[:, None] * np.array(
+        [[3900.0, 7936.0, 98304.0, 1000.0]])).astype(np.float32)
+    used = np.tile(np.array([[2000.0, 2560.0, 0.0, 0.0]], np.float32),
+                   (n, 1))
+    return sel.SelectRequest(
+        ask=np.array([20.0, 32.0, 0.0, 0.0], np.float32), count=count,
+        feasible=np.ones(n, bool), capacity=capacity, used=used,
+        desired_count=float(count),
+        tg_collisions=np.zeros(n, np.int32),
+        job_count=np.zeros(n, np.int32))
+
+
+def _copy(req):
+    return sel.SelectRequest(**{f.name: getattr(req, f.name)
+                                for f in req.__dataclass_fields__.values()})
+
+
+def test_kway_batch_cell_shape_matches_scan(stage_tap):
+    """The batch cell's eval: anti-affinity holds every winner's chunk
+    to 1, so seven phases of 128 place 896 and the overshoot rule walks
+    the last 104 one phase each — the sequence is the scan's all the
+    same, and the kernel_expand span says how it came about."""
+    req = _batch_cell_request(np.random.RandomState(5))
+    kway = sel.SelectKernel().select(_copy(req))
+    scan = _scan_reference(_copy(req))
+    _assert_equivalent(kway, scan)
+    assert np.array_equal(kway.top_idx, scan.top_idx)
+    assert np.array_equal(kway.exhausted_dim, scan.exhausted_dim)
+    assert stage_tap.attrs_of("kernel_expand")[0] == {
+        "phases": 111, "tail_phases": 104, "placed": 1000}
+
+
+@pytest.mark.parametrize("algorithm", ["binpack", "spread"])
+def test_kway_continuation_over_max_steps(monkeypatch, stage_tap,
+                                          algorithm):
+    """A dispatch that runs out of its phase budget continues from the
+    device-resident carry; the rounds' sequences join in order and a
+    node's later streams start where its earlier ones ended."""
+    monkeypatch.setattr(sel, "_kway_steps", lambda w: 4)
+    rng = np.random.RandomState(17)
+    req1 = _random_request(rng, 150, 700, algorithm)
+    req1.port_need = 0.0
+    kway = sel.SelectKernel().select(_copy(req1))
+    scan = _scan_reference(_copy(req1))
+    _assert_equivalent(kway, scan)
+    for name in kway.scores:
+        assert np.allclose(kway.scores[name], scan.scores[name],
+                           rtol=1e-4, atol=1e-5), name
+    got = stage_tap.attrs_of("kernel_expand")[0]
+    assert got["phases"] > 4 and got["placed"] == kway.placed
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_kway_batched_lanes_match_solo(seed):
+    """Lanes of one vmapped K-way dispatch, of unequal counts under one
+    sequence bucket, each equal to the lane dispatched alone."""
+    rng = np.random.RandomState(400 + seed)
+    n = rng.randint(60, 250)
+    base = _random_request(rng, n, 1, "spread" if seed == 1 else "binpack")
+    reqs = []
+    for count in (rng.randint(513, 1300), rng.randint(300, 513),
+                  rng.randint(1, 40)):
+        r = _copy(base)
+        r.count = int(count)
+        r.desired_count = float(count)
+        r.used = base.used + rng.uniform(0, 50, base.used.shape
+                                         ).astype(np.float32)
+        reqs.append(r)
+    before = sel.device_stats_snapshot()["dispatches"].get(
+        "kway_batched", 0)
+    kernel = sel.SelectKernel()
+    batched = kernel.select_many([_copy(r) for r in reqs])
+    assert sel.device_stats_snapshot()["dispatches"]["kway_batched"] \
+        == before + 1
+    for r, got in zip(reqs, batched):
+        solo = kernel.select(_copy(r))
+        _assert_equivalent(got, solo)
+        assert np.array_equal(got.top_idx, solo.top_idx)
+        assert np.array_equal(got.exhausted_dim, solo.exhausted_dim)
+
+
+def test_kway_host_half_walks_no_phases(stage_tap):
+    """What is left of kernel_expand on the K-way arm is a slice, a
+    clamp and the result's guard: the calls it makes (Python and C
+    alike, counted by the profile hook) are as many for 111 phases as
+    for 6, and few."""
+    import sys
+    kernel = sel.SelectKernel()
+
+    def fetched(req):
+        n_pad = sel._pad_n(len(req.feasible))
+        cargs, spread_alg, w = kernel._pack_kway(req, n_pad, None)
+        pending = sel._select_kway(
+            **cargs, max_steps=sel._kway_steps(w), spread_alg=spread_alg,
+            w=w, k_out=sel._bucket_k(req.count))
+        return kernel._finish_kway_rounds(req, cargs, spread_alg,
+                                          pending, w=w)
+
+    def calls_of(req, rounds):
+        calls = [0]
+
+        def hook(_frame, event, _arg):
+            if event in ("call", "c_call"):
+                calls[0] += 1
+        sys.setprofile(hook)
+        try:
+            res = sel._expand_kway(req, rounds)
+        finally:
+            sys.setprofile(None)
+        return res, calls[0]
+
+    rng = np.random.RandomState(5)
+    long_req = _batch_cell_request(rng)
+    # the job on every node already and its anti-affinity next to
+    # nothing: a node's score rises as it fills, so chunks are long
+    # and the phases a handful
+    short_req = _batch_cell_request(rng)
+    short_req.desired_count = 1e9
+    short_req.tg_collisions = np.ones(len(short_req.feasible), np.int32)
+    long_rounds, short_rounds = fetched(long_req), fetched(short_req)
+    assert len(long_rounds) == len(short_rounds) == 1
+    phases = [int(r[0][0][-1, sel.KWAY_PHASES])
+              for r in (long_rounds, short_rounds)]
+    assert phases[0] >= 100 and phases[1] <= 20, phases
+    sel._expand_kway(short_req, short_rounds)   # first-use imports
+    (long_res, long_calls), (short_res, short_calls) = \
+        calls_of(long_req, long_rounds), calls_of(short_req, short_rounds)
+    assert long_res.placed == short_res.placed == 1000
+    assert long_calls == short_calls, (long_calls, short_calls)
+    assert long_calls <= 60, long_calls

@@ -28,11 +28,11 @@ def test_a_compiler_means_the_codec_loads():
         "self-check failed (the nomad_tpu.native warning says which)")
 
 
-def test_six_processes_on_an_empty_cache_all_get_both_modules(tmp_path):
+def test_six_processes_on_an_empty_cache_all_get_the_module(tmp_path):
     """Two agents, or six test workers, first started on a fresh
-    install: each builds for itself and every one loads both."""
-    code = ("from nomad_tpu.native import load_codec, load_kway\n"
-            "print(load_codec() is not None, load_kway() is not None)\n")
+    install: each builds for itself and every one loads it."""
+    code = ("from nomad_tpu.native import load_codec\n"
+            "print(load_codec() is not None)\n")
     env = {**os.environ, "NOMAD_TPU_NATIVE_CACHE": str(tmp_path)}
     env.pop("NOMAD_TPU_NATIVE", None)
     procs = [subprocess.Popen([sys.executable, "-c", code], cwd=REPO,
@@ -40,10 +40,10 @@ def test_six_processes_on_an_empty_cache_all_get_both_modules(tmp_path):
                               stderr=subprocess.PIPE, text=True)
              for _ in range(6)]
     outs = [p.communicate(timeout=300) for p in procs]
-    assert [o.strip() for o, _e in outs] == ["True True"] * 6, outs
-    # what is left is the two modules, no process's temp file
-    assert sorted(n.split("-")[0] for n in os.listdir(tmp_path)) == [
-        "nomad_tpu_native_codec", "nomad_tpu_native_kway"]
+    assert [o.strip() for o, _e in outs] == ["True"] * 6, outs
+    # what is left is the module, no process's temp file
+    assert [n.split("-")[0] for n in os.listdir(tmp_path)] == [
+        "nomad_tpu_native_codec"]
 
 
 CASES = [
