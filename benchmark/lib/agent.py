@@ -183,8 +183,11 @@ class Agent:
         from nomad_tpu.rpc import RpcServer
         from nomad_tpu.server import Server, ServerConfig
         t0 = time.perf_counter()
-        self.srv = Server(ServerConfig(data_dir=self.data_dir,
-                                       **self.cfg["server"]))
+        # `decorrelation` states the rule the program ships with, for
+        # the reference to recompute: a property, not a ServerConfig field
+        fields = {k: v for k, v in self.cfg["server"].items()
+                  if k != "decorrelation"}
+        self.srv = Server(ServerConfig(data_dir=self.data_dir, **fields))
         self.rpc = RpcServer(self.srv, port=0)
         self.srv.rpc_server = self.rpc
         self.srv.start()
@@ -224,11 +227,33 @@ class Agent:
                 "device_op_failures": dict(snap["device_op_failures"])}
 
     def counters(self) -> Dict[str, float]:
-        """Program counters the readers take deltas of over the window."""
-        stats = self.srv.persistence.stats if self.srv.persistence else {}
+        """Program counters the readers take at the window's open and
+        close: the snapshot counts, the WAL's absolute stream position
+        (bytes ever appended), what it has taken since the last
+        snapshot was triggered, and the two triggers. The since-trigger
+        pair and the triggers are Persistence's own attributes: its
+        `stats` does not carry them yet (PERF.md, Open questions)."""
+        p = self.srv.persistence
+        if p is None:
+            return {}
+        stats, size = p.stats, p.log.size()
         return {"persistence.background_snapshots":
                 float(stats.get("background_snapshots", 0)),
-                "persistence.snapshots": float(stats.get("snapshots", 0))}
+                "persistence.snapshots": float(stats.get("snapshots", 0)),
+                "persistence.wal_bytes": float(size),
+                "persistence.wal_bytes_since_snapshot":
+                float(size - p._bytes_at_snapshot),
+                "persistence.wal_entries_since_snapshot":
+                float(p._since_snapshot),
+                "persistence.snapshot_wal_bytes":
+                float(p.SNAPSHOT_WAL_BYTES),
+                "persistence.snapshot_every": float(p.snapshot_every)}
+
+    def settle(self, timeout_s: float = 30.0) -> None:
+        """Wait out a snapshot writer that is still running, so that
+        its `snapshot_write` span reaches the tap before it closes."""
+        if self.srv.persistence is not None:
+            self.srv.persistence.wait_idle(timeout_s)
 
     @staticmethod
     def signatures() -> Dict[str, set]:

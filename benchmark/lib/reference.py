@@ -240,9 +240,21 @@ def binpack_scores(capacity: np.ndarray, used: np.ndarray,
     return np.clip(20.0 - total, 0.0, 18.0) / 18.0
 
 
+def lane_ids(n_rows: int, lanes: int, rule: dict) -> np.ndarray:
+    """The lane of every node table row under the deployment's stated
+    decorrelation rule (the configuration file's server.decorrelation):
+    rows in ascending node id, lane of row i =
+    ((i x multiplier mod 2^modulus_bits) >> shift) mod lanes."""
+    mix = (np.arange(n_rows, dtype=np.uint64) * np.uint64(rule["multiplier"])
+           ) & np.uint64((1 << rule["modulus_bits"]) - 1)
+    return ((mix >> np.uint64(rule["shift"])) % np.uint64(lanes)
+            ).astype(np.int64)
+
+
 def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
                jobs: List[dict], allocs: Dict[str, List[dict]],
-               lanes: int) -> Tuple[List[str], float, List[str]]:
+               lanes: int, decorrelation: Optional[dict] = None
+               ) -> Tuple[List[str], float, List[str]]:
     """The ranking, judged plan by plan in the order the store
     committed them (the allocs' create_index), each against the fleet
     as it stood before that plan: the resident backlog plus every plan
@@ -255,14 +267,19 @@ def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
               holds the job scores at most (1 - 2/count)/2, and at
               least `count` other nodes with room score above that.
     rank_gap  the widest gap by which a plan's worst chosen node scores
-              below the reference's (lanes x k)-th best node with room,
-              k being the plan's placements. `lanes` concurrent
-              schedulers rank over disjoint shares of the fleet (that
-              is how they avoid each other's winners), so the k best of
-              one share are not the k best of the fleet; but each share
-              holds at most k nodes a job of that size has raised above
-              the untouched ones, so the worst of its k best is no
-              worse than the (lanes x k)-th best overall.
+              below the bound of the share that ranked it, k being the
+              plan's distinct nodes. `lanes` concurrent schedulers rank
+              large asks over disjoint shares of the fleet
+              (`decorrelation`, the configuration's stated rule: that
+              is how they avoid each other's winners). A plan of an ask
+              of `min_count` instances or more whose rows all lie in
+              one lane was ranked over that lane: its bound is the k-th
+              best node with room OF THAT LANE, whatever the other
+              lane still holds (a share can run out of a machine class
+              before the fleet does). Any other plan ranked the whole
+              fleet, beside up to `lanes` - 1 others that did: its
+              bound is the (lanes x k)-th best node with room of the
+              fleet. Without a rule every plan is of the second kind.
 
     Returns (stacked, rank_gap, the widest gaps described)."""
     row = {n["id"]: i for i, n in enumerate(fleet)}
@@ -270,6 +287,8 @@ def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
                         dtype=np.float64)
     used = np.array([[backlog[n["id"]][d] for d in DIMS] for n in fleet],
                     dtype=np.float64)
+    lane_of = lane_ids(len(fleet), lanes, decorrelation) \
+        if decorrelation and lanes > 1 else None
     plans = []          # (create_index, job, rows of its placements)
     for job in jobs:
         by_index: Dict[int, List[int]] = collections.defaultdict(list)
@@ -296,9 +315,11 @@ def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
             room = feasible[shape] & np.all(used + ask <= capacity, axis=1)
             score = binpack_scores(capacity, used, ask)
             before = held.get(job["id"])
+            asked = job["count"]        # what this plan's select asked for
             if before is not None:      # a retry: the job's own allocs
                 score = np.where(before > 0, (score - (before + 1.0)
                                               / job["count"]) / 2.0, score)
+                asked -= int(before.sum())
             if before is None and job["count"] > 1:
                 ceiling = (1.0 - 2.0 / job["count"]) / 2.0
                 mine = [row[a["node_id"]] for a in allocs[job["id"]]
@@ -308,16 +329,21 @@ def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
                     stacked.append(f"{job['id']}: {len(mine)} allocs on "
                                    f"{len(set(mine))} nodes")
             k = int((chosen > 0).sum())
-            best = np.sort(score[room])[::-1]
+            pool, nth, share = room, lanes * k, f"the {lanes}x{k}-th best"
+            if lane_of is not None and k \
+                    and asked >= decorrelation["min_count"]:
+                lane = lane_of[rows[0]]
+                if bool((lane_of[rows] == lane).all()):
+                    pool, nth = room & (lane_of == lane), k
+                    share = f"lane {int(lane)}'s {k}-th best"
+            best = np.sort(score[pool])[::-1]
             if k and len(best):
-                ref = float(best[min(lanes * k, len(best)) - 1])
+                ref = float(best[min(nth, len(best)) - 1])
                 worst = float(score[chosen > 0].min())
                 gap = max(0.0, ref - worst)
                 widest.append((gap, (
                     f"{job['id']} plan {index}: worst of {k} chosen scores "
-                    f"{worst:.6f}, the {lanes}x{k}-th best with room "
-                    f"{ref:.6f}, the {k}-th best "
-                    f"{float(best[min(k, len(best)) - 1]):.6f}, the best "
+                    f"{worst:.6f}, {share} with room {ref:.6f}, the best "
                     f"{float(best[0]):.6f}")))
                 rank_gap = max(rank_gap, gap)
         held[job["id"]] = held.get(job["id"], 0) + chosen
@@ -354,14 +380,16 @@ def port_sample(fleet: List[dict], jobs: List[dict],
 def judge(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
           jobs: List[dict], evals: Dict[str, dict],
           allocs: Dict[str, List[dict]], full_allocs: List[dict],
-          unread: List[str], port_range, lanes: int
+          unread: List[str], port_range, lanes: int,
+          decorrelation: Optional[dict] = None
           ) -> Tuple[dict, Dict[str, List[str]]]:
     """Every number compared, beside its limit, and what broke each.
-    `lanes` is the configuration's number of concurrent schedulers."""
+    `lanes` is the configuration's number of concurrent schedulers,
+    `decorrelation` its stated rule for their shares of the fleet."""
     never, unplaced = check_evals(jobs, evals)
     placed_jobs = [j for j in jobs if j["id"] in allocs]
     stacked, rank_gap, widest = check_rank(fleet, backlog, placed_jobs,
-                                           allocs, lanes)
+                                           allocs, lanes, decorrelation)
     found = {
         "never_completed": never,
         "unplaced_evals": unplaced,

@@ -11,7 +11,8 @@ from the .xplane.pb the JAX profiler writes.
   kernels   the device time of the placement programs: the events of
             the "XLA Modules" line whose name matches lib/kernels.json
   gaps      the idle intervals, longest first, each named by the host
-            stage that covers most of it
+            stage that covers most of it (a wait only where nothing
+            else covers any)
 """
 
 from __future__ import annotations
@@ -190,10 +191,16 @@ def idle_gaps(events: Sequence[dict], t0: float, t1: float) -> List[Interval]:
     return gaps
 
 
+# an eval (or a plan) waiting is not the host working: a wait names a
+# gap only where no working stage covers any of it
+WAITS = ("queue_wait", "gateway_wait", "fence_wait", "plan_queue_wait")
+
+
 def name_gap(gap: Interval, spans: Sequence[Tuple[str, float, float]]) -> str:
     """The host stage that covers most of `gap` ("idle" if none does);
     `spans` are (stage, start, end) on the gap's clock. A stage that
-    wraps others (sched_host) only names what its children leave."""
+    wraps others (sched_host) only names what its children leave, and a
+    wait (WAITS) only a gap that nothing else covers."""
     cover: Dict[str, float] = {}
     for stage, a, b in spans:
         lo, hi = max(a, gap[0]), min(b, gap[1])
@@ -201,6 +208,9 @@ def name_gap(gap: Interval, spans: Sequence[Tuple[str, float, float]]) -> str:
             cover[stage] = cover.get(stage, 0.0) + hi - lo
     if not cover:
         return "idle"
+    working = {s: v for s, v in cover.items() if s not in WAITS}
+    if working:
+        cover = working
     inner = {s: v for s, v in cover.items() if s != "sched_host"}
     if inner and max(inner.values()) >= 0.5 * cover.get("sched_host", 0.0):
         cover = inner

@@ -251,6 +251,8 @@ class Run:
         missing = watch.wait_for(ids, give_up)
         if missing:
             log(f"{len(missing)} evals never completed: {missing[:3]}")
+        if taps:
+            agent.settle()
         for tap in taps.values():
             tap.close()
 
@@ -553,16 +555,12 @@ def main(argv=None) -> int:
     compared, found = reference.judge(
         run.fleet, run.backlog, got["jobs"], got["evals"], got["allocs"],
         got["full"], got["unread"], run.port_range,
-        run.cfg["server"]["num_schedulers"])
+        run.cfg["server"]["num_schedulers"],
+        run.cfg["server"].get("decorrelation"))
     compared["harness_problems"] = {"value": len(run.problems), "limit": 0}
     correct = reference.is_correct(compared)
-    one_lane = reference.check_rank(
-        run.fleet, run.backlog, [j for j in got["jobs"]
-                                 if j["id"] in got["allocs"]],
-        got["allocs"], 1)[1]
     log(f"reference judged {len(got['jobs'])} jobs in "
-        f"{time.perf_counter() - t_j:.2f}s; rank_gap against the whole "
-        f"fleet's k best (one lane, not compared): {one_lane:.6f}")
+        f"{time.perf_counter() - t_j:.2f}s")
 
     metrics = read_metrics(plan["per_layer"] if args.trace
                            else plan["end_to_end"], run.obs)

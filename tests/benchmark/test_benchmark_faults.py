@@ -67,6 +67,9 @@ def test_broken_timed_path_is_not_correct(cell, patch, number, nodes):
     assert c["value"] > c["limit"], line["compared"]
     if nodes == "6000":
         assert set(line["arms"]) == {"kway"}
+        # a shifted row is in its share or not, never a near miss: the
+        # share's bound reads it as far off as the whole fleet's did
+        assert c["value"] >= 0.4
 
 
 def test_sound_kway_run_ranks_as_the_reference():

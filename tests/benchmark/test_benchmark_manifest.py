@@ -21,9 +21,25 @@ def manifest():
         return json.load(f)
 
 
+def over(m):
+    """What each parametrised test of this file runs over, as a
+    function of the manifest: test_benchmark_manifest_room.py runs the
+    same tests over a manifest that has grown."""
+    return {
+        "test_config_entry_and_file": m["configs"],
+        "test_cell_entry_and_its_files": m["workloads"],
+        "test_end_to_end_entry": m["end_to_end"],
+        "test_per_layer_entry": m["per_layer"],
+        "test_metric_file_names_a_reader_and_agrees":
+            m["end_to_end"] + m["per_layer"],
+        "test_every_cell_reports_setup_another_metric_and_a_layer":
+            [w["name"] for w in m["workloads"]]}
+
+
 M = manifest()
 CELLS = [w["name"] for w in M["workloads"]]
 METRICS = M["end_to_end"] + M["per_layer"]
+OVER = over(M)
 
 
 def spec_of(metric):
@@ -45,7 +61,8 @@ def test_command_stays_inside_paths():
     assert "tests/benchmark" in M["paths"]
 
 
-@pytest.mark.parametrize("config", M["configs"], ids=lambda c: c["name"])
+@pytest.mark.parametrize("config", OVER["test_config_entry_and_file"],
+                         ids=lambda c: c["name"])
 def test_config_entry_and_file(config):
     assert set(config) == {"name", "source", "file", "reduced", "why"}
     assert NAME.match(config["name"])
@@ -60,11 +77,12 @@ def test_config_entry_and_file(config):
     assert any(w["config"] == config["name"] for w in M["workloads"])
 
 
-@pytest.mark.parametrize("cell", M["workloads"], ids=lambda w: w["name"])
+@pytest.mark.parametrize("cell", OVER["test_cell_entry_and_its_files"],
+                         ids=lambda w: w["name"])
 def test_cell_entry_and_its_files(cell):
     assert set(cell) == {"name", "config", "traffic", "chips", "why"}
     assert NAME.match(cell["name"]) and NAME.match(cell["traffic"])
-    assert cell["chips"] == 1
+    assert cell["chips"] in (1, 4)
     assert 1 <= len(cell["why"]) <= 200
     assert cell["config"] in {c["name"] for c in M["configs"]}
     assert os.path.exists(os.path.join(ROOT, "benchmark", "traffic",
@@ -75,9 +93,16 @@ def test_cells_pair_config_and_traffic_once():
     pairs = [(w["config"], w["traffic"]) for w in M["workloads"]]
     assert len(pairs) == len(set(pairs))
     assert len(CELLS) == len(set(CELLS))
+    # one file and one source a configuration, and four chips for at
+    # most half of the cells (one always may)
+    for key in ("name", "file", "source"):
+        assert len({c[key] for c in M["configs"]}) == len(M["configs"])
+    assert sum(w["chips"] == 4 for w in M["workloads"]) \
+        <= max(1, len(CELLS) // 2)
 
 
-@pytest.mark.parametrize("metric", M["end_to_end"], ids=lambda m: m["name"])
+@pytest.mark.parametrize("metric", OVER["test_end_to_end_entry"],
+                         ids=lambda m: m["name"])
 def test_end_to_end_entry(metric):
     assert set(metric) - {"workloads"} == {"name", "unit", "better",
                                            "bound", "source"}
@@ -88,7 +113,8 @@ def test_end_to_end_entry(metric):
     assert set(metric.get("workloads", CELLS)) <= set(CELLS)
 
 
-@pytest.mark.parametrize("metric", M["per_layer"], ids=lambda m: m["name"])
+@pytest.mark.parametrize("metric", OVER["test_per_layer_entry"],
+                         ids=lambda m: m["name"])
 def test_per_layer_entry(metric):
     assert set(metric) - {"workloads"} == {"name", "unit", "better",
                                            "source", "layer", "moves"}
@@ -102,7 +128,9 @@ def test_per_layer_entry(metric):
     assert (suffix == "batch") == (metric["moves"] == "placements_per_s")
 
 
-@pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])
+@pytest.mark.parametrize(
+    "metric", OVER["test_metric_file_names_a_reader_and_agrees"],
+    ids=lambda m: m["name"])
 def test_metric_file_names_a_reader_and_agrees(metric):
     spec = spec_of(metric)
     assert spec["name"] == metric["name"]
@@ -121,7 +149,8 @@ def test_no_metric_file_without_an_entry():
     assert on_disk == names and len(names) == len(METRICS)
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize(
+    "cell", OVER["test_every_cell_reports_setup_another_metric_and_a_layer"])
 def test_every_cell_reports_setup_another_metric_and_a_layer(cell):
     from benchmark.run import plan_cell
     plan = plan_cell(M, cell)

@@ -412,3 +412,176 @@ def test_judge_takes_nothing_from_the_program():
     assert "nomad_tpu" not in src.split('"""', 2)[2]
     src = open(os.path.join(ROOT, "benchmark", "lib", "fleet.py")).read()
     assert "import jax" not in src and "from nomad_tpu" not in src
+
+
+# -- a plan is judged against the share that ranked it ---------------------
+
+LANES = 2
+
+
+@pytest.fixture(scope="module")
+def rule(cfg):
+    return cfg["server"]["decorrelation"]
+
+
+def test_the_stated_rule_gives_the_program_s_lanes(cfg, rule):
+    """The configuration's rule against ops/select.decorrelation_slice
+    on the cell's padded table: the two cannot drift apart unseen."""
+    import types
+    import numpy as np
+    from nomad_tpu.ops.select import decorrelation_slice
+    n = 16384
+    req = types.SimpleNamespace(
+        feasible=np.ones(n, bool), count=1000,
+        capacity=np.full((n, 4), 4000.0), used=np.zeros((n, 4)),
+        ask=np.array([20.0, 32.0, 0.0, 0.0]))
+    lanes = cfg["server"]["num_schedulers"]
+    mine = ref.lane_ids(n, lanes, rule)
+    for lane in range(lanes):
+        mask, (key, theirs) = decorrelation_slice(req, lane, lanes,
+                                                  (None, None))
+        assert key == (n, lanes) and np.array_equal(theirs, mine)
+        assert np.array_equal(mask, mine == lane)
+    assert sorted(np.bincount(mine)) == [8192, 8192]
+    assert rule["min_count"] == 256 and rule["headroom_factor"] == 2
+    # below the rule's size the program slices nothing (worker._solo,
+    # SelectKernel._decorrelate_mask: `req.count < 256`)
+    import inspect
+    import nomad_tpu.ops.select as sel
+    assert f"req.count < {rule['min_count']}" in inspect.getsource(
+        sel.SelectKernel._decorrelate_mask)
+
+
+@pytest.fixture(scope="module")
+def exhausted(cfg, rule):
+    """5,120 nodes of every class; lane 0 has no 1x node with room left
+    (its share's generations are spent), lane 1's are untouched. One
+    job of 600 ranked by the program's K-way arm as scheduler 0."""
+    import numpy as np
+    import nomad_tpu.ops.select as sel
+    fleet = fleetlib.build_fleet(cfg, 11, 5120)
+    backlog = fleetlib.backlog_usage(cfg, fleet)
+    lane_of = ref.lane_ids(len(fleet), LANES, rule)
+    for i, n in enumerate(fleet):
+        if n["class"] == "c1x" and lane_of[i] == 0:
+            backlog[n["id"]] = dict(backlog[n["id"]],
+                                    cpu=n["capacity"]["cpu"])
+    job, = jobs_of("batch-fill", [600])
+    dims = fleetlib.DIMS
+    req = sel.SelectRequest(
+        ask=np.array([job["ask"][d] for d in dims], np.float32),
+        count=job["count"], feasible=np.ones(len(fleet), bool),
+        capacity=np.array([[n["capacity"][d] for d in dims]
+                           for n in fleet], np.float32),
+        used=np.array([[backlog[n["id"]][d] for d in dims]
+                       for n in fleet], np.float32),
+        desired_count=float(job["count"]),
+        tg_collisions=np.zeros(len(fleet), np.int32),
+        job_count=np.zeros(len(fleet), np.int32))
+    before = sel.device_stats_snapshot()["dispatches"].get("kway", 0)
+    kernel = sel.SelectKernel()
+    kernel.decorrelate = (0, LANES)
+    res = kernel.select(req)
+    assert res.placed == job["count"]
+    assert sel.device_stats_snapshot()["dispatches"]["kway"] == before + 1
+    rows = [int(r) for r in res.node_idx[:res.placed]]
+    allocs = {job["id"]: [
+        {"id": f"a{i}", "name": f"{job['id']}.{job['group']}[{i}]",
+         "node_id": fleet[r]["id"], "job_id": job["id"],
+         "desired_status": "run", "create_index": 7}
+        for i, r in enumerate(rows)]}
+    return fleet, backlog, job, allocs, lane_of
+
+
+def test_a_share_that_ran_out_of_a_class_is_judged_within_the_share(
+        exhausted, rule):
+    fleet, backlog, job, allocs, lane_of = exhausted
+    row = {n["id"]: i for i, n in enumerate(fleet)}
+    rows = [row[a["node_id"]] for a in allocs[job["id"]]]
+    assert {int(lane_of[r]) for r in rows} == {0}
+    assert {fleet[r]["class"] for r in rows} == {"c2x"}
+    # the bound of before this rule (every plan against the whole
+    # fleet's (lanes x k)-th best: check_rank without a rule) fails the
+    # program for what decorrelated shares do: lane 1 still holds
+    # untouched 1x nodes
+    old = ref.check_rank(fleet, backlog, [job], allocs, LANES)[1]
+    stacked, new, widest = ref.check_rank(fleet, backlog, [job], allocs,
+                                          LANES, rule)
+    assert old > 0.2 and old > ref.RANK_GAP_LIMIT
+    assert new <= ref.TIE_EPS and stacked == [] and widest == []
+
+
+def test_a_plan_that_skips_its_lane_s_best_by_a_class_fails(exhausted, rule):
+    import copy
+    fleet, backlog, job, allocs, lane_of = exhausted
+    taken = {a["node_id"] for a in allocs[job["id"]]}
+    big = next(n for i, n in enumerate(fleet) if n["class"] == "c4x"
+               and lane_of[i] == 0 and n["id"] not in taken)
+    moved = copy.deepcopy(allocs)
+    moved[job["id"]][5]["node_id"] = big["id"]
+    gap, widest = ref.check_rank(fleet, backlog, [job], moved, LANES,
+                                 rule)[1:]
+    scorer = ref.PlainScorer(fleet, job, backlog)
+    mid = next(n for i, n in enumerate(fleet) if n["class"] == "c2x"
+               and lane_of[i] == 0)
+    assert gap == pytest.approx(scorer.score(mid) - scorer.score(big))
+    assert gap > 3 * ref.RANK_GAP_LIMIT and "lane 0's 600-th" in widest[0]
+    # the other lane's better nodes buy it nothing: a row moved ACROSS
+    # the lanes makes it a whole-fleet plan, held to the fleet's best
+    small = next(n for i, n in enumerate(fleet) if n["class"] == "c1x"
+                 and lane_of[i] == 1)
+    across = copy.deepcopy(allocs)
+    across[job["id"]][5]["node_id"] = small["id"]
+    gap = ref.check_rank(fleet, backlog, [job], across, LANES, rule)[1]
+    assert gap == pytest.approx(scorer.score(small) - scorer.score(mid))
+
+
+def test_small_asks_and_retries_below_the_rule_s_size_rank_the_fleet(
+        mixed, rule):
+    """An ask under min_count is never sliced: its plan is held to the
+    whole fleet's (lanes x k)-th best even where its rows happen to lie
+    in one lane; so is the tail of a retry."""
+    fleet, backlog = mixed
+    lane_of = ref.lane_ids(len(fleet), LANES, rule)
+    jobs = jobs_of("batch-fill", [3, 300])
+    big = [n for i, n in enumerate(fleet) if n["class"] == "c4x"
+           and lane_of[i] == 0]
+    small = [n for i, n in enumerate(fleet) if n["class"] == "c1x"
+             and lane_of[i] == 0]
+
+    def on(job, nodes, index):
+        return [{"id": f"{job['id']}-{index}-{i}", "node_id": n["id"],
+                 "name": f"{job['id']}.{job['group']}[{i}]",
+                 "create_index": index} for i, n in enumerate(nodes)]
+    # three allocs of a 3-ask on lane 0's 4x nodes: one lane, but a
+    # small ask, so the fleet's 1x nodes are the bound
+    allocs = {jobs[0]["id"]: on(jobs[0], big[:3], 1)}
+    assert ref.check_rank(fleet, backlog, jobs[:1], allocs, LANES,
+                          rule)[1] > 0.4
+    # a 300-ask: 290 committed on lane 0's best, the retry of 10 (under
+    # min_count) on lane 0's 4x nodes is a whole-fleet plan and fails;
+    # the same ten as part of the sliced first plan would fail too
+    allocs = {jobs[1]["id"]: on(jobs[1], small[:290], 2)
+              + on(jobs[1], big[:10], 3)}
+    assert ref.check_rank(fleet, backlog, jobs[1:], allocs, LANES,
+                          rule)[1] > 0.4
+    allocs = {jobs[1]["id"]: on(jobs[1], small[:290] + big[:10], 2)}
+    assert ref.check_rank(fleet, backlog, jobs[1:], allocs, LANES,
+                          rule)[1] > 0.4
+
+
+@pytest.mark.parametrize("broken", ["firstfit", "norank"])
+def test_controls_read_as_far_off_under_the_lane_bound(cfg, rule, broken):
+    """A control ranks nothing, lanes least of all: its plans straddle
+    them and are held to the fleet's (lanes x k)-th best, as before."""
+    fleet = fleetlib.build_fleet(cfg, 3, 2560)
+    backlog = fleetlib.backlog_usage(cfg, fleet)
+    jobs = jobs_of("batch-fill", [600, 600])
+    plain = answered((fleet, backlog), jobs, broken)
+    gap = ref.check_rank(fleet, backlog, jobs, plain.allocs, LANES, rule)[1]
+    assert gap >= 0.4
+    assert gap == ref.check_rank(fleet, backlog, jobs, plain.allocs,
+                                 LANES)[1]
+    whole = answered((fleet, backlog), jobs)
+    assert ref.check_rank(fleet, backlog, jobs, whole.allocs, LANES,
+                          rule)[1] == 0.0

@@ -56,9 +56,23 @@ def test_children_of_sched_host_and_self_sum_to_it(traced):
     assert total == pytest.approx(traced["sched_host_ms_per_eval.batch"],
                                   rel=0.05)
     # one kernel dispatch and one plan an eval, but for the retries of
-    # partly committed plans
-    assert 1.0 <= traced["plan_submits_per_eval.batch"] < 2.0
+    # partly committed plans. Not `1.0 <=`: a plan_submit that ends just
+    # before the window opens belongs to an eval that completes inside
+    # it, so a 3 s toy window reads a little under one (0.976 once in
+    # sixteen runs; the chip's 51 s window reads 0.99664: ledger, PR 30)
+    assert 0.9 <= traced["plan_submits_per_eval.batch"] < 2.0
     assert traced["kernel_dispatches_per_eval.batch"] >= 0.9
+
+
+def test_the_wal_and_the_snapshot_are_in_the_traced_line(traced):
+    """ISSUE 31's four: Agent.counters() found the WAL's position, what
+    it took since the last snapshot and the two triggers in the program,
+    and the tap heard no snapshot and no whole walk in a 3 s toy."""
+    assert traced["wal_kb_per_placement.batch"] > 1.0
+    assert 0.0 < traced["snapshot_due_share.batch"] < 100.0
+    assert traced["snapshot_write_share.batch"] == 0.0
+    assert traced["gc_whole_walks_per_eval.batch"] == 0.0
+    assert traced["snapshots_in_window.batch"] == 0.0
 
 
 def test_stage_count_per_eval_on_a_hand_made_window():

@@ -105,6 +105,28 @@ def test_gap_is_named_by_the_stage_the_host_was_in(gap, want):
     assert tr.name_gap(gap, spans) == want
 
 
+@pytest.mark.parametrize("gap,want", [
+    # the evals that wait cover all of it, a collection a tenth: the
+    # host was collecting, the evals were only waiting for it
+    ((4.0, 5.0), "gc_full"),
+    # the worker works inside its own eval's gateway park
+    ((5.0, 6.0), "kernel_pack"),
+    # only the wrapper and a wait: the wrapper names it
+    ((6.0, 7.0), "sched_host"),
+    # waits alone: the longest of them still names the gap
+    ((7.0, 8.0), "plan_queue_wait"),
+])
+def test_a_wait_names_a_gap_only_where_nothing_else_covers_it(gap, want):
+    spans = [("queue_wait", 3.0, 6.5), ("queue_wait", 3.5, 6.9),
+             ("gc_full", 4.4, 4.5),
+             ("gateway_wait", 5.0, 6.0), ("kernel_pack", 5.2, 5.3),
+             ("sched_host", 6.0, 7.0), ("fence_wait", 6.0, 6.9),
+             ("plan_queue_wait", 7.0, 7.9), ("queue_wait", 7.2, 7.9)]
+    assert tr.name_gap(gap, spans) == want
+    assert set(tr.WAITS) == {"queue_wait", "gateway_wait", "fence_wait",
+                             "plan_queue_wait"}
+
+
 def test_longest_gaps_first_and_at_most_n():
     spans = [("sched_host", 1.0, 3.0)]
     gaps = tr.longest_gaps(SMALL, 0.0, 5.0, spans, n=2)
@@ -160,3 +182,14 @@ def test_recorded_trace_gaps_and_busy_make_the_window(recorded):
                             [tuple(s) for s in recorded["spans"]])
     assert len(named) <= 10 and all(sec > 0 for _n, sec in named)
     assert {n for n, _s in named} - {"idle"}      # some gap has a stage
+
+
+def test_recorded_trace_names_no_gap_by_a_wait_a_stage_covers(recorded):
+    spans = [tuple(s) for s in recorded["spans"]]
+    for gap in tr.idle_gaps(recorded["events"], recorded["t0"],
+                            recorded["t1"]):
+        name = tr.name_gap(gap, spans)
+        covering = {s for s, a, b in spans
+                    if min(b, gap[1]) > max(a, gap[0])}
+        if covering - set(tr.WAITS):
+            assert name not in tr.WAITS, (gap, covering)
