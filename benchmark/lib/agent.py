@@ -162,6 +162,72 @@ def _model_node(plain: dict, cfg: dict):
     return node
 
 
+def _load_tiers(replay: _ReplayIndex, cfg: dict, plain: dict) -> None:
+    """The backlog a configuration describes (`resident_tiers`; `plain`
+    is its plain form, fleet.residents) through the store's replay
+    paths, as seed_c2m_allocs loads its one job: upsert_job, then
+    bulk_load_allocs with one shared resource row per tier. Every
+    Allocation carries its `job` — the tier job's own object, with the
+    job_modify_index the store gave it — as upstream's store
+    denormalizes the job into every allocation it takes: without it a
+    resident is never a candidate for eviction, and an eval of its job
+    replaces it as a destructive update."""
+    from nomad_tpu.mock import fixtures as mock
+    from nomad_tpu.models import Allocation
+    from nomad_tpu.models.resources import (AllocatedCpuResources,
+                                            AllocatedMemoryResources,
+                                            AllocatedResources,
+                                            AllocatedSharedResources,
+                                            AllocatedTaskResources)
+    from .fleet import resident_name
+    from .traffic import datacenters_of
+    jobs, shared = {}, []
+    for job_id, pj in plain["jobs"].items():
+        tier = plain["tiers"][pj["tier"]]
+        if tier["alloc"]["mbits"]:
+            raise ValueError(f"tier {tier['name']}: resident network "
+                             f"bandwidth is outside the loader's vocabulary")
+        job = mock.batch_job() if pj["type"] == "batch" else mock.job()
+        job.id = job.name = job_id
+        job.priority = pj["priority"]
+        job.datacenters = datacenters_of(cfg)
+        tg = job.task_groups[0]
+        tg.name = pj["group"]
+        tg.count = pj["count"]
+        tg.networks = []
+        tg.ephemeral_disk.size_mb = tier["alloc"]["disk_mb"]
+        task = tg.tasks[0]
+        task.resources.cpu = tier["alloc"]["cpu"]
+        task.resources.memory_mb = tier["alloc"]["memory_mb"]
+        task.resources.networks = []
+        replay.store.upsert_job(replay.next_index(), job)
+        jobs[job_id] = job
+        if len(shared) <= pj["tier"]:
+            shared.append(AllocatedResources(
+                tasks={task.name: AllocatedTaskResources(
+                    cpu=AllocatedCpuResources(
+                        cpu_shares=tier["alloc"]["cpu"]),
+                    memory=AllocatedMemoryResources(
+                        memory_mb=tier["alloc"]["memory_mb"]))},
+                shared=AllocatedSharedResources(
+                    disk_mb=tier["alloc"]["disk_mb"])))
+    allocs = []
+    for alloc_id, (ti, job_id, node_id) in plain["allocs"].items():
+        job = jobs[job_id]
+        allocs.append(Allocation(
+            id=alloc_id, namespace="default", job_id=job_id, job=job,
+            task_group=job.task_groups[0].name,
+            name=resident_name(alloc_id, job.task_groups[0].name),
+            node_id=node_id, eval_id=f"{job_id}-eval",
+            client_status="running", desired_status="run",
+            allocated_resources=shared[ti]))
+        if len(allocs) >= 250_000:
+            replay.store.bulk_load_allocs(replay.next_index(), allocs)
+            allocs = []
+    if allocs:
+        replay.store.bulk_load_allocs(replay.next_index(), allocs)
+
+
 class Agent:
     """Server + RpcServer + HTTPApiServer as cmd_agent wires them:
     default ServerConfig but for the fields the configuration's file
@@ -197,10 +263,14 @@ class Agent:
         self._timed("boot", t0)
         return f"127.0.0.1:{self.api.port}"
 
-    def load(self, fleet: List[dict]) -> dict:
+    def load(self, fleet: List[dict], residents: Optional[dict] = None
+             ) -> dict:
         """The fleet and its resident backlog through the store's replay
         paths (upsert_node is linear; Server.register_node is not, PR
-        21), then the first resident-table build."""
+        21), then the first resident-table build. `residents`: the
+        plain form of a tiered configuration's backlog
+        (fleet.residents), which the harness's own loader builds;
+        without tiers the program's one-job loader runs."""
         from nomad_tpu.bench.ladder import seed_c2m_allocs
         srv, cfg = self.srv, self.cfg
         replay = _ReplayIndex(srv)
@@ -210,8 +280,11 @@ class Agent:
             srv.store.upsert_node(replay.next_index(), node)
         self._timed("load_nodes", t0)
         t0 = time.perf_counter()
-        n_allocs = len(fleet) * cfg["resident_allocs_per_node"]
-        seed_c2m_allocs(replay, nodes, n_allocs, sched_allocs=0)
+        if residents is not None:
+            _load_tiers(replay, cfg, residents)
+        else:
+            n_allocs = len(fleet) * cfg["resident_allocs_per_node"]
+            seed_c2m_allocs(replay, nodes, n_allocs, sched_allocs=0)
         self._timed("load_backlog", t0)
         t0 = time.perf_counter()
         table = srv.store.snapshot().node_table()
@@ -254,6 +327,22 @@ class Agent:
         its `snapshot_write` span reaches the tap before it closes."""
         if self.srv.persistence is not None:
             self.srv.persistence.wait_idle(timeout_s)
+
+    def quiesce(self, timeout_s: float) -> Tuple[bool, float]:
+        """Wait until the broker holds no eval that is ready, out with a
+        worker or queued behind one of its own job (twice in a row: an
+        eval in a worker's hands may enqueue another as it ends).
+        Whether it went calm before `timeout_s` ran out, and the
+        seconds it took. Blocked evals stay: a follow-up eval of an
+        evicted job blocks on a full fleet by design."""
+        t0 = time.perf_counter()
+        calm = 0
+        while calm < 2 and time.perf_counter() - t0 < timeout_s:
+            st = self.srv.eval_broker.stats
+            busy = st.total_ready + st.total_unacked + st.total_blocked
+            calm = 0 if busy else calm + 1
+            time.sleep(0.05)
+        return calm >= 2, time.perf_counter() - t0
 
     @staticmethod
     def signatures() -> Dict[str, set]:

@@ -30,8 +30,11 @@ def pad_n(n: int) -> int:
 
 def select_floor_bytes(n_nodes: int, dims: int, count: int,
                        spreads: int = 0, affinities: int = 0,
-                       ports: int = 0) -> int:
-    """Least bytes one eval of `count` placements moves on the device."""
+                       ports: int = 0, preempt_candidates: int = 0) -> int:
+    """Least bytes one eval of `count` placements moves on the device.
+    `preempt_candidates`: the resident allocations a victim selection
+    may take (0 where the eval cannot preempt) — each one's resources,
+    priority and node row read once, and a score per node written."""
     n = pad_n(n_nodes)
     table = 2 * n * dims * F32            # capacity and used, read once
     per_eval = n * 1                      # feasibility mask
@@ -41,7 +44,10 @@ def select_floor_bytes(n_nodes: int, dims: int, count: int,
     per_eval += n * I32 if ports else 0   # free dynamic ports per node
     shipped = dims * F32 + I32            # the ask and the count
     out = count * (I32 + F32)             # node row and score, per placement
-    return table + per_eval + shipped + out
+    victims = 0
+    if preempt_candidates:
+        victims = preempt_candidates * (dims * F32 + 2 * I32) + n * F32
+    return table + per_eval + shipped + out + victims
 
 
 def peaks(device_kind: str) -> dict:

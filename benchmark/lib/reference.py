@@ -43,6 +43,28 @@ LIMITS = {
 }
 
 
+# A configuration with `resident_tiers` is held to four more numbers,
+# exact counts too (check_evictions; PERF.md has the controls' readings)
+TIER_LIMITS = {
+    "evicted_wrongly": 0, "evicted_needlessly": 0, "evicted_with_room": 0,
+    "residents_stopped": 0,
+}
+
+# upstream's filterAndGroupPreemptibleAllocs: an allocation may be
+# preempted by a job whose priority is at least this much higher
+PRIORITY_DELTA = 10
+
+
+def preemption_enabled(scheduler_configuration: Optional[dict],
+                       job_type: str) -> bool:
+    """Whether the configuration's `scheduler_configuration` (the wire
+    form of the operator API) lets a job of `job_type` preempt; absent,
+    the defaults hold: system jobs alone."""
+    pc = (scheduler_configuration or {}).get("preemption_config", {})
+    return bool(pc.get(f"{job_type}_scheduler_enabled",
+                       job_type == "system"))
+
+
 def _resolve(node: dict, target: str) -> Tuple[Optional[str], bool]:
     if not target.startswith("${"):
         return target, True
@@ -138,8 +160,10 @@ def check_committed(jobs: List[dict],
 def node_usage(backlog: Dict[str, Dict[str, float]], jobs: List[dict],
                allocs: Dict[str, List[dict]]
                ) -> Dict[str, Dict[str, float]]:
-    """Per-node committed usage: the resident backlog plus every alloc
-    of `jobs`, from the jobs' asks."""
+    """Per-node committed usage: the resident backlog (for a tiered
+    configuration the resident allocations that still `run`:
+    ResidentState.usage) plus every alloc of `jobs`, from the jobs'
+    asks."""
     used = {nid: dict(row) for nid, row in backlog.items()}
     for job in jobs:
         for a in allocs.get(job["id"], []):
@@ -253,7 +277,8 @@ def lane_ids(n_rows: int, lanes: int, rule: dict) -> np.ndarray:
 
 def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
                jobs: List[dict], allocs: Dict[str, List[dict]],
-               lanes: int, decorrelation: Optional[dict] = None
+               lanes: int, decorrelation: Optional[dict] = None,
+               residents: Optional["ResidentState"] = None
                ) -> Tuple[List[str], float, List[str]]:
     """The ranking, judged plan by plan in the order the store
     committed them (the allocs' create_index), each against the fleet
@@ -281,6 +306,12 @@ def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
               bound is the (lanes x k)-th best node with room of the
               fleet. Without a rule every plan is of the second kind.
 
+    With `residents` (a tiered configuration) `backlog` is the fleet as
+    loaded, the resident allocations' evictions and replacements are
+    applied at the index the store committed them under, and a plan
+    that evicted is not ranked: which node among those that need an
+    eviction is the preemption score's choice (PERF.md, Not compared).
+
     Returns (stacked, rank_gap, the widest gaps described)."""
     row = {n["id"]: i for i, n in enumerate(fleet)}
     capacity = np.array([[n["capacity"][d] for d in DIMS] for n in fleet],
@@ -303,10 +334,14 @@ def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
     held: Dict[str, np.ndarray] = {}    # job id -> its allocs per node
     stacked, widest = [], []
     rank_gap = 0.0
+    due = sorted(residents.commits) if residents is not None else []
     for index, job, rows in plans:
+        while due and due[0] < index:
+            residents.apply(due.pop(0), row, used)
         ask = np.array([job["ask"][d] for d in DIMS], dtype=np.float64)
         chosen = np.bincount(rows, minlength=len(fleet))
-        if not job["spreads"] and not job["affinities"]:
+        evicted = residents is not None and index in residents.evicting
+        if not job["spreads"] and not job["affinities"] and not evicted:
             shape = (job["driver"], tuple(job["datacenters"]),
                      tuple(job["constraints"]))
             if shape not in feasible:
@@ -352,6 +387,255 @@ def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
     return stacked, rank_gap, [w for g, w in widest[:5] if g > TIE_EPS]
 
 
+class ResidentState:
+    """The resident allocations of a tiered configuration as they stand
+    after the drain. `plain` is fleet.residents (what the loader made),
+    `now` what GET /v1/job/<id>/allocations answered for every tier
+    job: the loader's allocations, and the replacements a follow-up
+    eval of an evicted job placed. A resident runs on its node from the
+    index it was created under (loaded: before any commit) until the
+    index it was last modified under, if it no longer `run`s.
+
+      commits   {commit index: [(kind, node id, tier, job id)]}: what
+                changed since the load, the one replay that the
+                capacity check (`usage`), the ranking (check_rank) and
+                check_evictions read through `apply` — "placed" a
+                replacement created under that index, "evict" a
+                resident evicted under it, "stop" one that ended any
+                other way
+      stopped   what breaks `residents_stopped`: a loaded allocation
+                that is gone, moved, or neither `run` nor `evict`; a
+                tier job that holds more allocations the loader did
+                not make than it had evicted
+      evicting  the commit indices under which a resident was evicted"""
+
+    def __init__(self, plain: dict, now: Dict[str, List[dict]]):
+        self.tiers = plain["tiers"]
+        self.plain = plain
+        self.vecs = [np.array([t["alloc"][d] for d in DIMS],
+                              dtype=np.float64) for t in self.tiers]
+        self.commits: Dict[int, List[tuple]] = collections.defaultdict(list)
+        self.stopped: List[str] = []
+        self.evicting = set()
+        seen = 0
+        for job_id, pj in plain["jobs"].items():
+            fresh = evicted = 0
+            for stub in now.get(job_id, []):
+                made = plain["allocs"].get(stub["id"])
+                status, node_id = stub["desired_status"], stub["node_id"]
+                if made is None:
+                    fresh += 1
+                    self.commits[int(stub.get("create_index") or 0)].append(
+                        ("placed", node_id, pj["tier"], job_id))
+                else:
+                    seen += 1
+                    if (made[1], made[2]) != (stub["job_id"], node_id):
+                        self.stopped.append(
+                            f"{stub['id']}: loaded on {made[2][:8]} for "
+                            f"{made[1]}, read back on {node_id[:8]} of "
+                            f"{stub['job_id']}")
+                        node_id = made[2]
+                if status == "run":
+                    continue
+                ended = int(stub.get("modify_index") or 0)
+                if status == "evict":
+                    evicted += 1
+                    self.evicting.add(ended)
+                else:
+                    self.stopped.append(f"{stub['id']}: {status}")
+                self.commits[ended].append(
+                    ("evict" if status == "evict" else "stop", node_id,
+                     pj["tier"], job_id))
+            if fresh > evicted:
+                self.stopped.append(
+                    f"{job_id}: {fresh} allocations the loader did not "
+                    f"make, {evicted} evicted")
+        if seen != len(plain["allocs"]):
+            read = {s["id"] for stubs in now.values() for s in stubs}
+            self.stopped += [f"{i}: not read back"
+                             for i in plain["allocs"] if i not in read]
+
+    def apply(self, index: int, row_of: Dict[str, int], used: np.ndarray,
+              count: Optional[np.ndarray] = None) -> None:
+        """What commit `index` did to the residents, onto `used`
+        [rows x DIMS] and, if given, `count` [rows x tiers]."""
+        for kind, node_id, tier, _job_id in self.commits.get(index, ()):
+            at = row_of.get(node_id)
+            if at is None:
+                continue
+            sign = 1 if kind == "placed" else -1
+            used[at] += sign * self.vecs[tier]
+            if count is not None:
+                count[at, tier] += sign
+
+    def usage(self) -> Dict[str, Dict[str, float]]:
+        """Per node, the resident allocations that still `run`: the
+        fleet as loaded with every commit applied."""
+        row_of = {nid: i for i, nid in enumerate(self.plain["usage"])}
+        used = np.array([[row[d] for d in DIMS]
+                         for row in self.plain["usage"].values()],
+                        dtype=np.float64).reshape(len(row_of), len(DIMS))
+        for index in self.commits:
+            self.apply(index, row_of, used)
+        return {nid: dict(zip(DIMS, used[i].tolist()))
+                for nid, i in row_of.items()}
+
+
+def _slots(free: np.ndarray, ask: np.ndarray) -> np.ndarray:
+    """How many `ask`s fit into each row of `free`, every dimension."""
+    dims = ask > 0
+    if not dims.any():
+        return np.full(len(free), np.iinfo(np.int64).max)
+    return np.floor(np.clip(free[:, dims], 0, None) / ask[dims]
+                    ).min(axis=1).astype(np.int64)
+
+
+def check_evictions(fleet: List[dict], residents: ResidentState,
+                    jobs: List[dict], allocs: Dict[str, List[dict]]
+                    ) -> Dict[str, List[str]]:
+    """The evictions, commit by commit in the store's order (the
+    allocations' create_index; a victim's modify_index is its evictor's
+    commit), each against the fleet as it stood before that commit.
+    Placements are the window's jobs' and the replacements that the
+    evicted jobs' follow-up evals made (priority and ask of their tier).
+
+    evicted_wrongly     a victim on a node that took no placement under
+                        that index, or whose tier's priority is within
+                        PRIORITY_DELTA of (or above) the highest
+                        priority placed there
+    evicted_needlessly  per node, victims that can be restored, highest
+                        tier first, with the node's placements still
+                        fitting in every dimension (filterSuperset);
+                        and an eligible victim of a tier while an
+                        eligible allocation of a lower-priority tier
+                        still runs there (tiers are taken lowest
+                        first; sizes within a tier are equal, so both
+                        are exact counts)
+    evicted_with_room   placements that needed an eviction beyond those
+                        that fit nowhere: a placement evicts only once
+                        no feasible node has room for the ask as it is
+    """
+    tiers = residents.tiers
+    prio = [t["priority"] for t in tiers]
+    vecs = residents.vecs
+    row_of = {n["id"]: i for i, n in enumerate(fleet)}
+    capacity = np.array([[n["capacity"][d] for d in DIMS] for n in fleet],
+                        dtype=np.float64)
+    # the fleet as loaded
+    used = np.array([[residents.plain["usage"][n["id"]][d] for d in DIMS]
+                     for n in fleet], dtype=np.float64)
+    loaded = [[0] * len(tiers) for _ in fleet]
+    for tier, _job_id, node_id in residents.plain["allocs"].values():
+        if node_id in row_of:
+            loaded[row_of[node_id]][tier] += 1
+    count = np.array(loaded, dtype=np.int64).reshape(len(fleet), len(tiers))
+
+    # what was committed under each index: parts (who placed what where)
+    # and victims (which residents were evicted there)
+    parts: Dict[int, list] = collections.defaultdict(list)
+    victims: Dict[int, Dict[int, List[int]]] = collections.defaultdict(
+        lambda: collections.defaultdict(list))
+    for job in jobs:
+        by_index: Dict[int, List[int]] = collections.defaultdict(list)
+        for a in allocs.get(job["id"], []):
+            if a["node_id"] in row_of:
+                by_index[int(a.get("create_index") or 0)].append(
+                    row_of[a["node_id"]])
+        ask = np.array([job["ask"][d] for d in DIMS], dtype=np.float64)
+        for index, rows in by_index.items():
+            parts[index].append({"who": job["id"], "job": job, "ask": ask,
+                                 "priority": job["priority"], "rows": rows})
+    born: Dict[tuple, List[int]] = collections.defaultdict(list)
+    for index, events in residents.commits.items():
+        for kind, node_id, tier, job_id in events:
+            if node_id not in row_of:
+                continue
+            if kind == "placed":
+                born[(index, job_id, tier)].append(row_of[node_id])
+            elif kind == "evict":
+                victims[index][row_of[node_id]].append(tier)
+    for (index, job_id, tier), rows in born.items():
+        parts[index].append({"who": job_id, "job": None, "ask": vecs[tier],
+                             "priority": prio[tier], "rows": rows})
+
+    feasible: Dict[tuple, np.ndarray] = {}
+    wrongly, needlessly, with_room = [], [], []
+    for index in sorted(set(parts) | set(residents.commits)):
+        here, gone = parts.get(index, []), victims.get(index, {})
+        placed = collections.defaultdict(list)      # row -> its parts
+        for part in here:
+            for at in set(part["rows"]):
+                placed[at].append(part)
+        if gone:
+            free = capacity - used
+            for part in here:
+                ask, job = part["ask"], part["job"]
+                ok = np.ones(len(fleet), dtype=bool)
+                if job is not None:
+                    shape = (job["driver"], tuple(job["datacenters"]),
+                             tuple(job["constraints"]))
+                    if shape not in feasible:
+                        feasible[shape] = np.array(
+                            [not node_feasible(n, job) for n in fleet])
+                    ok = feasible[shape]
+                slots = _slots(free, ask)
+                on = np.bincount(part["rows"], minlength=len(fleet))
+                forced = sum(max(0, int(on[at] - slots[at]))
+                             for at in gone if on[at])
+                nowhere = max(0, len(part["rows"]) - int(slots[ok].sum()))
+                if forced > nowhere:
+                    with_room.extend(
+                        [f"{part['who']} commit {index}: {forced} of "
+                         f"{len(part['rows'])} placements evicted, "
+                         f"{int(slots[ok].sum())} fitted as the fleet stood"]
+                        * (forced - nowhere))
+                # what this part takes is no longer free for the next
+                free = free - on[:, None] * ask[None, :]
+        for at, took in gone.items():
+            name = fleet[at]["name"]
+            if at not in placed:
+                wrongly.extend([f"{name} commit {index}: {len(took)} evicted, "
+                                f"nothing placed"] * len(took))
+                continue
+            top = max(part["priority"] for part in placed[at])
+            peers = [t for t in took if top - prio[t] < PRIORITY_DELTA]
+            wrongly.extend(
+                [f"{name} commit {index}: {len(peers)} evicted of priority "
+                 f"{sorted({prio[t] for t in peers})} for priority {top}"]
+                * len(peers))
+            after = used[at].copy()
+            for t in took:
+                after -= vecs[t]
+            for part in placed[at]:
+                after += part["rows"].count(at) * part["ask"]
+            spare = 0
+            for t in sorted(took, key=lambda t: -prio[t]):
+                if np.all(after + vecs[t] <= capacity[at]):
+                    after = after + vecs[t]
+                    spare += 1
+            left = count[at].copy()
+            for t in took:
+                left[t] -= 1
+            jumped = sum(
+                1 for t in took if t not in peers and any(
+                    left[u] > 0 and prio[u] < prio[t]
+                    and top - prio[u] >= PRIORITY_DELTA
+                    for u in range(len(tiers))))
+            if spare or jumped:
+                needlessly.extend(
+                    [f"{name} commit {index}: {len(took)} evicted, {spare} "
+                     f"can be restored and the placements still fit, "
+                     f"{jumped} taken while a lower tier still runs there"]
+                    * (spare + jumped))
+        residents.apply(index, row_of, used, count)
+        for part in here:
+            if part["job"] is not None:         # the window's own
+                for at in part["rows"]:
+                    used[at] += part["ask"]
+    return {"evicted_wrongly": wrongly, "evicted_needlessly": needlessly,
+            "evicted_with_room": with_room}
+
+
 def port_sample(fleet: List[dict], jobs: List[dict],
                 allocs: Dict[str, List[dict]], budget: int,
                 rng) -> List[str]:
@@ -381,15 +665,23 @@ def judge(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
           jobs: List[dict], evals: Dict[str, dict],
           allocs: Dict[str, List[dict]], full_allocs: List[dict],
           unread: List[str], port_range, lanes: int,
-          decorrelation: Optional[dict] = None
+          decorrelation: Optional[dict] = None,
+          residents: Optional["ResidentState"] = None
           ) -> Tuple[dict, Dict[str, List[str]]]:
     """Every number compared, beside its limit, and what broke each.
     `lanes` is the configuration's number of concurrent schedulers,
-    `decorrelation` its stated rule for their shares of the fleet."""
+    `decorrelation` its stated rule for their shares of the fleet.
+    `residents` (a tiered configuration alone): the resident
+    allocations as read back after the drain; `backlog` is then the
+    fleet as loaded, the capacity check takes the residents that still
+    run, and TIER_LIMITS' four numbers are compared too."""
     never, unplaced = check_evals(jobs, evals)
     placed_jobs = [j for j in jobs if j["id"] in allocs]
     stacked, rank_gap, widest = check_rank(fleet, backlog, placed_jobs,
-                                           allocs, lanes, decorrelation)
+                                           allocs, lanes, decorrelation,
+                                           residents)
+    if residents is not None:
+        backlog = residents.usage()
     found = {
         "never_completed": never,
         "unplaced_evals": unplaced,
@@ -406,8 +698,13 @@ def judge(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
         "stacked": stacked,
         "rank_gap": widest,
     }
-    compared = {name: {"value": len(found[name]), "limit": LIMITS[name]}
-                for name in LIMITS}
+    limits = dict(LIMITS)
+    if residents is not None:
+        found.update(check_evictions(fleet, residents, placed_jobs, allocs))
+        found["residents_stopped"] = residents.stopped
+        limits.update(TIER_LIMITS)
+    compared = {name: {"value": len(found[name]), "limit": limits[name]}
+                for name in limits}
     compared["rank_gap"]["value"] = rank_gap
     return compared, found
 
@@ -547,7 +844,7 @@ class PlainScorer:
 
 
 CONTROLS = ("capacity", "constraints", "lose", "ports", "firstfit",
-            "norank")
+            "norank", "noevict", "evictpeer", "evictall", "evictearly")
 
 
 class PlainScheduler:
@@ -564,13 +861,44 @@ class PlainScheduler:
                    the table's row order, that is feasible and has room
       norank       ranks nothing but keeps a job's allocs apart: the
                    first `count` such nodes, one alloc each
+
+    With `residents` (a tiered configuration: fleet.residents) and a
+    `scheduler_configuration` that lets the job's type preempt, an
+    instance for which no feasible node has room evicts: the node
+    needing the fewest victims, lowest tier first, exactly as many as
+    the deficit needs. Four more controls break that:
+
+      noevict      places there and evicts nothing
+      evictpeer    takes its victims from the tiers whose priority is
+                   within PRIORITY_DELTA of the job's
+      evictall     takes every eligible allocation of the node
+      evictearly   never looks for room: every instance evicts, on the
+                   first feasible node in row order that is full
     """
 
     def __init__(self, fleet: List[dict],
                  backlog: Dict[str, Dict[str, float]], port_range,
-                 broken: Optional[str] = None):
+                 broken: Optional[str] = None,
+                 residents: Optional[dict] = None,
+                 scheduler_configuration: Optional[dict] = None):
         if broken is not None and broken not in CONTROLS:
             raise ValueError(f"unknown control {broken!r}")
+        self.residents = residents
+        self.scheduler_configuration = scheduler_configuration
+        # node id -> per tier, the ids of its residents that still run
+        self.running: Dict[str, List[List[str]]] = {}
+        self.resident_stubs: Dict[str, dict] = {}
+        if residents is not None:
+            n_tiers = len(residents["tiers"])
+            self.running = {n["id"]: [[] for _ in range(n_tiers)]
+                            for n in fleet}
+            for alloc_id, (tier, job_id, node_id) in \
+                    residents["allocs"].items():
+                self.running[node_id][tier].append(alloc_id)
+                self.resident_stubs[alloc_id] = {
+                    "id": alloc_id, "node_id": node_id, "job_id": job_id,
+                    "desired_status": "run", "create_index": 0,
+                    "modify_index": 0}
         self.fleet = fleet
         self.used = {nid: dict(row) for nid, row in backlog.items()}
         self.broken = broken
@@ -581,19 +909,83 @@ class PlainScheduler:
         self.evals: Dict[str, dict] = {}
         self._n = 0
 
+    def resident_allocs(self) -> Dict[str, List[dict]]:
+        """The residents as GET /v1/job/<id>/allocations would list
+        them, by tier job."""
+        out: Dict[str, List[dict]] = collections.defaultdict(list)
+        for stub in self.resident_stubs.values():
+            out[stub["job_id"]].append(stub)
+        return out
+
+    def _evict_for(self, job: dict, nodes: List[dict],
+                   index: int) -> Optional[dict]:
+        """Make room for one more instance of `job` on one of `nodes`
+        by evicting residents there; the node, or None if none can."""
+        tiers = self.residents["tiers"]
+        pool = [t for t, tier in enumerate(tiers)
+                if (job["priority"] - tier["priority"] >= PRIORITY_DELTA)
+                != (self.broken == "evictpeer")]
+        best = None
+        for node in nodes:
+            short = {d: self.used[node["id"]][d] + job["ask"][d]
+                     - node["capacity"][d] for d in DIMS}
+            if self.broken == "evictearly" and max(short.values()) <= 0:
+                continue
+            take = []
+            for t in pool:
+                size = tiers[t]["alloc"]
+                need = max([math.ceil(short[d] / size[d]) for d in DIMS
+                            if short[d] > 0 and size[d] > 0], default=0)
+                k = min(len(self.running[node["id"]][t]), need)
+                if k:
+                    take.append((t, k))
+                    for d in DIMS:
+                        short[d] -= k * size[d]
+            if max(short.values()) > 0:
+                continue
+            n_victims = sum(k for _t, k in take)
+            if best is None or n_victims < best[0]:
+                best = (n_victims, node, take)
+            if self.broken == "evictearly":
+                break
+        if best is None:
+            return None
+        _n, node, take = best
+        if self.broken == "noevict":
+            take = []
+        elif self.broken == "evictall":
+            take = [(t, len(self.running[node["id"]][t])) for t in pool]
+        for t, k in take:
+            for _ in range(k):
+                stub = self.resident_stubs[
+                    self.running[node["id"]][t].pop(0)]
+                stub["desired_status"] = "evict"
+                stub["modify_index"] = index
+            for d in DIMS:
+                self.used[node["id"]][d] -= k * tiers[t]["alloc"][d]
+        return node
+
     def submit(self, job: dict) -> None:
         scorer = PlainScorer(self.fleet, job, self.used,
                              ignore_constraints=self.broken == "constraints")
+        preempts = self.residents is not None and preemption_enabled(
+            self.scheduler_configuration, job["type"])
         if self.broken in ("firstfit", "norank"):
             nodes = scorer.in_row_order(job["count"],
                                         apart=self.broken == "norank")
+        elif self.broken == "evictearly" and preempts:
+            nodes = [None] * job["count"]
         else:
             nodes = scorer.greedy(job["count"],
                                   check_fit=self.broken != "capacity")
-        stubs = []
+        stubs, placed = [], 0
         for i, node in enumerate(nodes):
+            if node is None and preempts:
+                node = self._evict_for(job, scorer.nodes,
+                                       len(self.evals) + 1)
             if node is None:
                 continue
+            placed += 1
             for d in DIMS:
                 self.used[node["id"]][d] += job["ask"][d]
             self._n += 1
@@ -617,7 +1009,7 @@ class PlainScheduler:
         if self.broken == "lose" and len(self.evals) % 3 == 0 and stubs:
             self.full.pop(stubs.pop()["id"])
         self.allocs[job["id"]] = stubs
-        placed_all = all(n is not None for n in nodes)
+        placed_all = placed == job["count"]
         self.evals[job["id"]] = {
             "status": "complete", "job_id": job["id"],
             "failed_tg_allocs": None if placed_all else {job["group"]: {}},

@@ -56,6 +56,7 @@ def plain_job(mix: dict, job_id: str, count: int,
     return {
         "id": job_id, "type": tpl["type"], "group": tpl["group"],
         "task": tpl["task"], "driver": tpl["driver"], "count": int(count),
+        "priority": int(tpl.get("priority", 50)),
         "ask": dict(tpl["ask"]),
         "dynamic_ports": int(tpl.get("dynamic_ports", 0)),
         "datacenters": list(datacenters),
@@ -79,7 +80,7 @@ def wire_job(job: dict) -> dict:
                        for i in range(job["dynamic_ports"])]}
     return {
         "id": job["id"], "name": job["id"], "namespace": "default",
-        "region": "global", "type": job["type"], "priority": 50,
+        "region": "global", "type": job["type"], "priority": job["priority"],
         "datacenters": job["datacenters"],
         "constraints": [], "affinities": [], "spreads": [],
         "task_groups": [{

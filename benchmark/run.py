@@ -12,16 +12,39 @@ Everything that belongs to one configuration, traffic mix, cell or
 metric is a file the harness finds by the name in BENCHMARK.json:
 
     benchmark/configs/<config>.json    the deployment's sizes
-    benchmark/traffic/<traffic>.json   the mix's parameters
+    benchmark/traffic/<traffic>.json   the mix's parameters (found as
+                                       traffic/<traffic>.json under the
+                                       first of `paths` that has it)
     benchmark/cells/<cell>.json        (optional) a cell's own overrides
     benchmark/metrics/<metric>.json    the metric's reader and arguments
     benchmark/readers/<reader>.py      read(obs, **args) -> number or None
+
+Three optional keys open a deployment that preempts (PR 32); every path
+they switch on is chosen by the key, never by a name, and a
+configuration or mix without it runs as before:
+
+    a mix's job.priority                 the template's priority (else 50)
+    a configuration's resident_tiers     the backlog, tier by tier:
+        [{name, job_type, priority, jobs, allocs_per_node,
+          alloc{cpu, memory_mb, disk_mb, mbits}}] (allocs_per_node is
+        times the node's class scale); the harness's own
+        loader builds it (every resident carries its job), the probe
+        checks one node against it, every tier job's allocations are
+        read back after the drain, and the judge compares four more
+        numbers (evicted_wrongly, evicted_needlessly, evicted_with_room,
+        residents_stopped). Without it: the program's one-job loader
+        and `resident_allocs_per_node` / `resident_alloc`
+    a configuration's scheduler_configuration   the wire form of
+        PUT /v1/operator/scheduler/configuration: sent after boot and
+        before the fleet loads, read back, and refused if it differs
 
 Flags beyond the contract's four are for the builder's own runs:
 --rate (the sweep), --control (the reference in the program's place
 with one guarantee or the ranking broken), --nodes with --rehearse-cpu
 (a toy rehearsal on the CPU, which says platform "cpu" in its line),
---manifest (the tests' own, with a cell for a mix no cell runs yet).
+--manifest (the tests' own, with a cell for a mix no cell runs yet:
+`service-stream`, and the tests' tiered toy on its own mix,
+tests/benchmark/traffic/toy-evict.json).
 """
 
 from __future__ import annotations
@@ -83,7 +106,19 @@ def plan_cell(manifest: dict, name: str) -> dict:
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in mine)]
     return {"cell": cell, "config_file": config["file"],
+            "paths": manifest["paths"],
             "end_to_end": e2e, "per_layer": per_layer}
+
+
+def find_mix(paths: list, name: str) -> str:
+    """A mix's file: `traffic/<name>.json` under the manifest's `paths`,
+    the first in their order that has it (benchmark/ holds the mixes
+    cells run; the tests' toys lie under tests/benchmark/)."""
+    for directory in paths:
+        path = os.path.join(ROOT, directory, "traffic", name + ".json")
+        if os.path.exists(path):
+            return path
+    raise SystemExit(f"no traffic/{name}.json under {paths}")
 
 
 def read_metrics(metrics: list, obs: dict) -> dict:
@@ -112,8 +147,7 @@ class Run:
             overrides = load_json("benchmark", "cells",
                                   self.cell["name"] + ".json")
         self.mix = traffic.load_mix(
-            os.path.join(ROOT, "benchmark", "traffic",
-                         self.cell["traffic"] + ".json"), overrides)
+            find_mix(plan["paths"], self.cell["traffic"]), overrides)
         if args.rate:
             self.mix["rate_per_s"] = args.rate
         self.seconds = float(args.seconds)
@@ -127,7 +161,12 @@ class Run:
             self.mix["rehearse_s"] = min(1.0, self.mix.get("rehearse_s", 0.0))
             traffic.scale_counts(self.mix, min(
                 1.0, 0.1 * len(self.fleet) / max(self.mix["deck"])))
-        self.backlog = fleetlib.backlog_usage(self.cfg, self.fleet)
+        # a backlog the configuration describes tier by tier, as plain
+        # data; without `resident_tiers` the program's one-job loader's
+        self.residents = fleetlib.residents(self.cfg, self.fleet) \
+            if "resident_tiers" in self.cfg else None
+        self.backlog = self.residents["usage"] if self.residents \
+            else fleetlib.backlog_usage(self.cfg, self.fleet)
         self.port_range = tuple(self.cfg["dynamic_port_range"])
         self.problems: list = []
         self.obs: dict = {"seconds": self.seconds, "series": {}}
@@ -157,7 +196,9 @@ class Run:
         compiles = agentlib.CompileCounter()
         agent = self.agent = agentlib.Agent(self.cfg, log)
         addr = agent.boot()
-        loaded = agent.load(self.fleet)
+        if "scheduler_configuration" in self.cfg:
+            self._configure_scheduler(client.Http(addr))
+        loaded = agent.load(self.fleet, self.residents)
         if loaded["nodes"] != len(self.fleet) or \
                 not loaded["rows_in_id_order"]:
             raise RuntimeError(f"fleet did not load as made: {loaded}")
@@ -251,6 +292,20 @@ class Run:
         missing = watch.wait_for(ids, give_up)
         if missing:
             log(f"{len(missing)} evals never completed: {missing[:3]}")
+        if self.residents:
+            # the evicted jobs' follow-up evals are the program's to
+            # finish before the residents are read back
+            calm, idle_s = agent.quiesce(
+                max(1.0, give_up - time.perf_counter()))
+            log(f"broker {'idle' if calm else 'STILL BUSY'} after "
+                f"{idle_s:.2f}s")
+            if not calm:
+                # read back now, the residents are a store still moving:
+                # a late follow-up eval would be judged half done
+                self.problems.append(
+                    f"the broker still held ready or outstanding evals "
+                    f"{idle_s:.1f}s after the drain: the residents were "
+                    f"read back while evals were in flight")
         if taps:
             agent.settle()
         for tap in taps.values():
@@ -281,6 +336,8 @@ class Run:
         log(f"read back {sum(len(a) for a in allocs.values())} allocs of "
             f"{len(allocs)} jobs and {len(full)} in full in "
             f"{time.perf_counter() - t_r:.2f}s")
+        residents_now = self._read_residents(http, unread) \
+            if self.residents else None
         failures = agent.worker_failures()
         op_failures = routing_after["device_op_failures"]
         watch.stop()
@@ -364,10 +421,103 @@ class Run:
                                   in taps["stages"].samples]
             self.obs["gc_pauses"] = [(t - t0, s, gen_) for t, s, gen_
                                      in taps["gc"].pauses]
-            self._reduce_trace(t0, jobs, done)
+            sums: dict = {}
+            for name, end, s in self.obs["stages"]:
+                if 0.0 <= end < seconds:
+                    n, total = sums.get(name, (0, 0.0))
+                    sums[name] = (n + 1, total + s)
+            log("stage reports that ended in the window (count, seconds): "
+                + str({k: (n, round(t, 3))
+                       for k, (n, t) in sorted(sums.items())}))
+            self._reduce_trace(t0, jobs, done, allocs, residents_now)
         return {"jobs": jobs, "evals": watch.evals, "allocs": allocs,
                 "full": full, "unread": unread, "attempted": attempted,
-                "failed": failed, "peak": peak}
+                "failed": failed, "peak": peak,
+                "residents_now": residents_now}
+
+    def _configure_scheduler(self, http) -> None:
+        """The configuration's `scheduler_configuration`, PUT to the
+        operator API as an operator would and read back: a deployment
+        that does not hold what its file states is not run."""
+        want = self.cfg["scheduler_configuration"]
+        path = "/v1/operator/scheduler/configuration"
+        status, body = http.request(
+            "PUT", path, json.dumps({"SchedulerConfig": want}).encode())
+        if status != 200:
+            raise RuntimeError(f"scheduler configuration: HTTP {status} "
+                               f"{body}")
+        status, body = http.request("GET", path)
+        got = (body or {}).get("SchedulerConfig") or {}
+
+        def held(want, got):
+            if isinstance(want, dict):
+                return isinstance(got, dict) and all(
+                    held(v, got.get(k)) for k, v in want.items())
+            return want == got
+        http.close()
+        if status != 200 or not held(want, got):
+            raise RuntimeError(f"scheduler configuration read back as "
+                               f"{got}, the file states {want}")
+        log(f"scheduler configuration PUT and read back: {got}")
+
+    def _probe_tiers(self, http, probe: dict, on_node: list) -> None:
+        """One node's allocations against the tiers: how many of each
+        tier's jobs, their resources, and the jobs' priority."""
+        tiers = self.residents["tiers"]
+        want = [0] * len(tiers)
+        for _alloc, (tier, _job, node_id) in \
+                self.residents["allocs"].items():
+            want[tier] += node_id == probe["id"]
+        got = [0] * len(tiers)
+        first = {}                      # tier -> one of its stubs here
+        for stub in on_node:
+            plain = self.residents["jobs"].get(stub["job_id"])
+            if plain is None:
+                raise RuntimeError(f"backlog on {probe['name']}: "
+                                   f"{stub['job_id']} is no tier's job")
+            got[plain["tier"]] += 1
+            first.setdefault(plain["tier"], stub)
+        if got != want:
+            raise RuntimeError(f"backlog on {probe['name']}: {got} allocs "
+                               f"by tier, the tiers make {want}")
+        for tier, stub in first.items():
+            _s, one = http.request("GET", f"/v1/allocation/{stub['id']}")
+            _s, job = http.request("GET", f"/v1/job/{stub['job_id']}")
+            res = one["allocated_resources"]
+            task = next(iter(res["tasks"].values()))
+            row = {"cpu": task["cpu"]["cpu_shares"],
+                   "memory_mb": task["memory"]["memory_mb"],
+                   "disk_mb": res["shared"]["disk_mb"], "mbits": 0}
+            if row != tiers[tier]["alloc"] \
+                    or job["priority"] != tiers[tier]["priority"] \
+                    or job["type"] != tiers[tier]["job_type"]:
+                raise RuntimeError(
+                    f"backlog on {probe['name']}: {stub['id']} holds {row} "
+                    f"for a {job['type']} job of priority "
+                    f"{job['priority']}, tier {tiers[tier]['name']} states "
+                    f"otherwise")
+        log(f"backlog on {probe['name']}: {got} allocs by tier, as made")
+
+    def _read_residents(self, http, unread: list) -> dict:
+        """Every tier job's allocations as they now stand, over the
+        served path: what was evicted, and what a follow-up eval of an
+        evicted job placed since."""
+        t_r = time.perf_counter()
+        now = {}
+        for job_id in self.residents["jobs"]:
+            status, body = http.request(
+                "GET", f"/v1/job/{job_id}/allocations")
+            if status == 200 and isinstance(body, list):
+                now[job_id] = body
+            else:
+                unread.append(f"{job_id}: HTTP {status}")
+        stubs = [a for body in now.values() for a in body]
+        log(f"read back {len(stubs)} resident allocs of {len(now)} tier "
+            f"jobs in {time.perf_counter() - t_r:.2f}s: "
+            f"{sum(a['desired_status'] == 'evict' for a in stubs)} evicted, "
+            f"{sum(a['id'] not in self.residents['allocs'] for a in stubs)} "
+            f"placed since the load")
+        return now
 
     def _probe_backlog(self, http) -> None:
         """The backlog the capacity check assumes, read back over HTTP
@@ -376,6 +526,8 @@ class Run:
             len(self.fleet))]
         _s, on_node = http.request("GET",
                                    f"/v1/node/{probe['id']}/allocations")
+        if self.residents:
+            return self._probe_tiers(http, probe, on_node)
         _s, one = http.request("GET", f"/v1/allocation/{on_node[0]['id']}")
         task = next(iter(one["allocated_resources"]["tasks"].values()))
         row = {"cpu": task["cpu"]["cpu_shares"],
@@ -412,7 +564,8 @@ class Run:
             f"(+{time.perf_counter() - prof['h1']:.2f}s to write)")
         self.prof = prof
 
-    def _reduce_trace(self, t0: float, jobs: list, done: dict) -> None:
+    def _reduce_trace(self, t0: float, jobs: list, done: dict,
+                      allocs: dict, residents_now) -> None:
         from benchmark.lib import tracered
         prof = self.prof
         path = tracered.find_xplane(self.trace_dir)
@@ -430,16 +583,11 @@ class Run:
                  for name, end, s in self.obs["stages"]]
         red = tracered.reduce(events, h0 - offset, h1 - offset, spans)
         self.obs["trace"] = red
-        spec = self.mix["job"]
         traced = [j for j in jobs if j["id"] in done
                   and h0 <= done[j["id"]] < h1]
         self.obs["traced_evals"] = len(traced)
-        self.obs["traced_floor_bytes"] = sum(
-            kernelcost.select_floor_bytes(
-                len(self.fleet), len(fleetlib.DIMS), j["count"],
-                spreads=len(spec.get("spreads", [])),
-                affinities=len(spec.get("affinities", [])),
-                ports=spec.get("dynamic_ports", 0)) for j in traced)
+        self.obs["traced_floor_bytes"] = self.floor_bytes(
+            traced, allocs, residents_now)
         log(f"trace: {len(events)} device events reduced in "
             f"{time.perf_counter() - t_r:.2f}s; busy {red['busy_s']:.4f}s "
             f"of {red['window_s']:.2f}s, kernels {red['kernel_s']:.4f}s in "
@@ -460,10 +608,46 @@ class Run:
                           f)
         shutil.rmtree(self.trace_dir, ignore_errors=True)
 
+    def floor_bytes(self, traced: list, allocs: dict,
+                    residents_now) -> int:
+        """The roofline's floor for the traced evals: the least bytes
+        any implementation moves for each (kernelcost). The victim
+        selection's candidates are charged to an eval only if a commit
+        of its own evicted (a resident's modify_index is its evictor's
+        create_index): an eval that found room needed none. Their
+        count is the least it was in the run: the resident allocations
+        of the tiers PRIORITY_DELTA or more below the template's
+        priority as loaded, less every one evicted since (replacements,
+        which add to it, left out)."""
+        spec = self.mix["job"]
+        gone = [a for body in (residents_now or {}).values() for a in body
+                if a["desired_status"] == "evict"]
+        evicting = {int(a.get("modify_index") or 0) for a in gone}
+        candidates = 0
+        if evicting:
+            low = {t for t, tier in enumerate(self.residents["tiers"])
+                   if spec.get("priority", 50) - tier["priority"]
+                   >= reference.PRIORITY_DELTA}
+            candidates = sum(1 for tier, _job, _node
+                             in self.residents["allocs"].values()
+                             if tier in low) - len(gone)
+        return sum(
+            kernelcost.select_floor_bytes(
+                len(self.fleet), len(fleetlib.DIMS), j["count"],
+                spreads=len(spec.get("spreads", [])),
+                affinities=len(spec.get("affinities", [])),
+                ports=spec.get("dynamic_ports", 0),
+                preempt_candidates=candidates if any(
+                    int(a.get("create_index") or 0) in evicting
+                    for a in allocs.get(j["id"], [])) else 0)
+            for j in traced)
+
     # -- the control: the plain reference in the program's place -------
     def control(self, broken) -> dict:
-        plain = reference.PlainScheduler(self.fleet, self.backlog,
-                                         self.port_range, broken=broken)
+        plain = reference.PlainScheduler(
+            self.fleet, self.backlog, self.port_range, broken=broken,
+            residents=self.residents,
+            scheduler_configuration=self.cfg.get("scheduler_configuration"))
         warm, timed = self.requests()
         reqs = [r for rnd in warm for r in rnd]
         if self.mix["loop"] == "open":
@@ -487,7 +671,9 @@ class Run:
         return {"jobs": jobs, "evals": plain.evals, "allocs": plain.allocs,
                 "full": [plain.full[i] for i in ids if i in plain.full],
                 "unread": [i for i in ids if i not in plain.full],
-                "attempted": len(jobs), "failed": 0, "peak": 0}
+                "attempted": len(jobs), "failed": 0, "peak": 0,
+                "residents_now": plain.resident_allocs()
+                if self.residents else None}
 
 
 def main(argv=None) -> int:
@@ -556,7 +742,9 @@ def main(argv=None) -> int:
         run.fleet, run.backlog, got["jobs"], got["evals"], got["allocs"],
         got["full"], got["unread"], run.port_range,
         run.cfg["server"]["num_schedulers"],
-        run.cfg["server"].get("decorrelation"))
+        run.cfg["server"].get("decorrelation"),
+        reference.ResidentState(run.residents, got["residents_now"])
+        if run.residents else None)
     compared["harness_problems"] = {"value": len(run.problems), "limit": 0}
     correct = reference.is_correct(compared)
     log(f"reference judged {len(got['jobs'])} jobs in "

@@ -26,18 +26,49 @@ def env():
     return e
 
 
-def stream_manifest(directory):
-    m = json.loads(json.dumps(MANIFEST))
-    m["workloads"].append({"name": STREAM, "config": "prod-10k",
-                           "traffic": "service-stream", "chips": 1,
-                           "why": "toy rehearsal of the open loop"})
+def _add_cell(m, name, config, mix, why):
+    m["workloads"].append({"name": name, "config": config, "traffic": mix,
+                           "chips": 1, "why": why})
     for metric in m["end_to_end"]:
         if "workloads" in metric:
-            metric["workloads"].append(STREAM)
+            metric["workloads"].append(name)
+
+
+def _written(m, directory):
     path = os.path.join(directory, "manifest.json")
     with open(path, "w") as f:
         json.dump(m, f)
     return path
+
+
+def stream_manifest(directory):
+    m = json.loads(json.dumps(MANIFEST))
+    _add_cell(m, STREAM, "prod-10k", "service-stream",
+              "toy rehearsal of the open loop")
+    return _written(m, directory)
+
+
+# PR 32's toy preempting deployment: files that are all new and all
+# the tests' own (the mix tests/benchmark/traffic/toy-evict.json and two
+# tiered configurations beside this file), through the harness as it
+# stands: it finds a mix under the manifest's `paths`
+EVICT = "preempt-toy_toy-evict"
+EVICT_ROOM = "preempt-toy-room_toy-evict"
+
+
+def evict_manifest(directory):
+    """BENCHMARK.json grown by the toy's two configurations and a cell
+    for each on the `toy-evict` mix."""
+    m = json.loads(json.dumps(MANIFEST))
+    for cell in (EVICT, EVICT_ROOM):
+        config = cell.split("_")[0]
+        m["configs"].append({
+            "name": config, "source": "the tests' toy: no deployment",
+            "file": f"tests/benchmark/{config}.json", "reduced": [],
+            "why": "three resident tiers, service preemption on"})
+        _add_cell(m, cell, config, "toy-evict",
+                  "toy rehearsal of a preempting deployment")
+    return _written(m, directory)
 
 
 def rehearse(cell, *extra, patch="", seconds="3", nodes="640"):
@@ -46,6 +77,8 @@ def rehearse(cell, *extra, patch="", seconds="3", nodes="640"):
                 seconds, "--rehearse-cpu", "--nodes", nodes, *extra]
         if cell == STREAM:
             argv += ["--manifest", stream_manifest(tmp)]
+        if cell in (EVICT, EVICT_ROOM):
+            argv += ["--manifest", evict_manifest(tmp)]
         code = (f"import sys; sys.path.insert(0, {ROOT!r})\n"
                 f"import benchmark.run as run\n{patch}\n"
                 f"sys.exit(run.main({argv!r}))\n")

@@ -37,6 +37,12 @@ def grown(tmp_path):
     with open(os.path.join(root, "benchmark", "configs",
                            "prod-10k.json")) as f:
         body = dict(json.load(f), name=CONFIG)
+    # the next deployment preempts (PR 32): its backlog comes in tiers
+    # and it states a scheduler configuration, as the tests' toy does
+    with open(os.path.join(HERE, "preempt-toy.json")) as f:
+        toy = json.load(f)
+    body.update({k: toy[k] for k in ("resident_tiers",
+                                     "scheduler_configuration")})
     with open(os.path.join(root, "benchmark", "configs",
                            CONFIG + ".json"), "w") as f:
         json.dump(body, f)
@@ -112,6 +118,21 @@ def test_a_grown_manifest_passes_every_manifest_level_test(grown, monkeypatch):
     # the grown lists were walked, not the module's own
     assert ran > len(m["per_layer"]) * 2 + len(m["configs"]) \
         + len(m["workloads"]) * 2
+
+
+def test_the_grown_config_is_tiered_and_the_accepted_ones_are_not(grown):
+    _m, root = grown
+    def cfg(name):
+        with open(os.path.join(root, "benchmark", "configs",
+                               name + ".json")) as f:
+            return json.load(f)
+    keys = {"resident_tiers", "scheduler_configuration"}
+    assert keys <= set(cfg(CONFIG))
+    assert not keys & set(cfg("prod-10k")) and not keys & set(cfg("svc-10k"))
+    for mix in ("batch-fill", "service-fill", "service-stream"):
+        with open(os.path.join(root, "benchmark", "traffic",
+                               mix + ".json")) as f:
+            assert "priority" not in json.load(f)["job"]
 
 
 def test_a_new_cell_gets_the_unkeyed_metrics_and_its_own(grown):
