@@ -12,9 +12,15 @@ replicated log can reuse it unchanged.
 
 from __future__ import annotations
 
+import gc
+import logging
 import os
+import signal
 import struct
 import threading
+import time
+import traceback
+import warnings
 from typing import Any, BinaryIO, Dict, List, Optional, Tuple
 
 import msgpack
@@ -152,6 +158,19 @@ def decode_payload(msg_type: str, data: dict) -> dict:
     return out
 
 
+def _copy(src: BinaryIO, dst: BinaryIO, n: Optional[int]) -> int:
+    """Copy `n` bytes (None: to the end of `src`) in 1 MiB reads;
+    returns the bytes copied."""
+    done = 0
+    while n is None or done < n:
+        buf = src.read(1 << 20 if n is None else min(1 << 20, n - done))
+        if not buf:
+            break
+        dst.write(buf)
+        done += len(buf)
+    return done
+
+
 class RaftLog:
     """Append-only WAL of msgpack frames: [u32 length][payload]."""
 
@@ -162,6 +181,7 @@ class RaftLog:
         self._good_offset: Optional[int] = None
         self._dirty = False      # flushed-but-not-fsynced bytes pending
         self._trunc_shift = 0    # bytes dropped by truncate_prefix
+        self._trunc_l = make_lock()     # one truncation; taken before _l
 
     def open(self) -> None:
         os.makedirs(os.path.dirname(self.path), exist_ok=True)
@@ -184,12 +204,11 @@ class RaftLog:
         (ShareMemo's objects, shared). A plan entry reports the
         framing (wire form + packb, not the write) as stage
         wal_encode."""
-        import time as _time
         memo = ShareMemo()
         with (stages.span("wal_encode") if msg_type in PLAN_ENTRIES
               else stages.NULL_SPAN) as sp:
             frame = msgpack.packb(
-                {"i": index, "t": msg_type, "ts": _time.time(),
+                {"i": index, "t": msg_type, "ts": time.time(),
                  "p": encode_payload(msg_type, payload, memo)},
                 use_bin_type=True)
             sp.note(objects=memo.objects, shared=memo.shared,
@@ -228,34 +247,56 @@ class RaftLog:
                 if os.path.exists(self.path) else 0
             return self._trunc_shift + phys
 
-    def truncate_prefix(self, mark: int) -> None:
+    def truncate_prefix(self, mark: int) -> Tuple[int, int]:
         """Drop the log prefix before absolute position `mark` (covered
         by a completed snapshot), KEEPING the tail appended while the
         snapshot was serializing off-thread — a whole-file truncate
         here would lose entries the snapshot does not contain. A mark
         at or below an already-truncated prefix is a no-op, so two
-        racing snapshot writers can never cut at a stale offset."""
-        with self._l:
-            local = mark - self._trunc_shift
-            if local <= 0 or not os.path.exists(self.path):
-                return
-            was_open = self._f is not None
-            if was_open:
-                self._f.close()
-                self._f = None
-            with open(self.path, "rb") as f:
-                f.seek(local)
-                tail = f.read()
+        racing snapshot writers can never cut at a stale offset.
+
+        The tail up to the size read at entry is copied and fsynced
+        WITHOUT the log's lock (appends go on to the old file: at
+        20 MB/s a snapshot's tail is hundreds of MB, and an append
+        parked behind that copy is the writer's stall by another
+        door); the lock is taken only for what was appended meanwhile,
+        the second fsync and the swap. A crash at any point leaves the
+        old file or the complete new one. Returns the bytes copied
+        outside and inside the lock."""
+        with self._trunc_l:
+            with self._l:
+                local = mark - self._trunc_shift
+                if local <= 0 or not os.path.exists(self.path):
+                    return 0, 0
+                # every append flushes under _l: the file holds them all
+                end = os.path.getsize(self.path)
             tmp = self.path + ".tmp"
-            with open(tmp, "wb") as f:
-                f.write(tail)
-                f.flush()
-                os.fsync(f.fileno())
-            os.replace(tmp, self.path)
-            self._trunc_shift += local
-            if was_open:
-                self._f = open(self.path, "ab")
-            self._dirty = False
+            try:
+                with open(self.path, "rb", buffering=0) as src, \
+                        open(tmp, "wb") as dst:
+                    src.seek(local)
+                    outside = _copy(src, dst, end - local)
+                    dst.flush()
+                    os.fsync(dst.fileno())
+                    with self._l:
+                        locked = _copy(src, dst, None)
+                        dst.flush()
+                        os.fsync(dst.fileno())
+                        was_open = self._f is not None
+                        if was_open:
+                            self._f.close()
+                            self._f = None
+                        os.replace(tmp, self.path)
+                        self._trunc_shift += local
+                        if was_open:
+                            self._f = open(self.path, "ab")
+                        self._dirty = False
+            except BaseException:
+                # the old file is whole and still the log
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
+                raise
+            return outside, locked
 
     def replay(self) -> List[Tuple[int, str, dict]]:
         """Read all entries; tolerates a torn final frame (crash)."""
@@ -315,9 +356,10 @@ class Persistence:
         # snapshot format 2 (state/columnar.py struct-of-arrays) vs the
         # legacy per-object dump; restore auto-detects either
         self.columnar = columnar
-        # serialize + write snapshots on a background thread off an
-        # O(1) MVCC store snapshot, so maybe_snapshot never stalls the
-        # commit path
+        # serialize + write snapshots off an O(1) MVCC store snapshot,
+        # in a child forked by a background thread that waits for it,
+        # so maybe_snapshot never stalls the commit path and the dump
+        # never holds the workers' GIL
         self.background = background
         # WAL durability: fsync appends at all (off matches the
         # pre-r12 flush-only behavior), and whether a committed apply
@@ -346,6 +388,12 @@ class Persistence:
             "snapshots": 0, "background_snapshots": 0,
             "snapshot_skipped_inflight": 0, "last_snapshot_s": 0.0,
             "last_snapshot_format": 0, "snapshot_errors": 0,
+            # background snapshots a forked child serialized, and those
+            # the writer thread wrote itself for want of a fork; the
+            # last fork's stall in this process and the last child's
+            # own seconds
+            "snapshot_children": 0, "snapshot_inline": 0,
+            "last_snapshot_fork_s": 0.0, "last_snapshot_child_s": 0.0,
             "restore_s": 0.0, "restore_format": 0,
             # the WAL encoder (encode_payload): dataclass instances
             # walked, and subtrees reused because the payload reached
@@ -400,8 +448,7 @@ class Persistence:
         auto-detects). A leftover ``state.snap.tmp`` from a crash
         mid-snapshot is ignored (os.replace is atomic, so the prior
         snapshot + un-truncated WAL are intact) and cleaned up."""
-        import time as _time
-        t0 = _time.perf_counter()
+        t0 = time.perf_counter()
         highest = 0
         tmp = self.snapshot_path + ".tmp"
         if os.path.exists(tmp):
@@ -422,7 +469,7 @@ class Persistence:
         entries = self.log.replay()
         self.log.open()
         with self._stats_l:
-            self.stats["restore_s"] = _time.perf_counter() - t0
+            self.stats["restore_s"] = time.perf_counter() - t0
         if stages.enabled:
             stages.add("restore", self.stats["restore_s"])
         return highest, entries
@@ -462,9 +509,10 @@ class Persistence:
 
     def trigger_snapshot(self, store) -> Optional[threading.Thread]:
         """Capture (MVCC snapshot, extra, WAL mark) NOW; serialize and
-        write off-thread. Returns the writer thread, or None when the
-        write ran inline (background off) or was skipped because one
-        is already in flight (the next threshold retriggers)."""
+        write in a forked child that a writer thread waits for. Returns
+        that thread, or None when the write ran inline (background off)
+        or was skipped because one is already in flight (the next
+        threshold retriggers)."""
         with self._trigger_l:
             t = self._snap_thread
             if t is not None and t.is_alive():
@@ -479,8 +527,8 @@ class Persistence:
                 self._write_snapshot(snap, extra, mark)
                 return None
             t = threading.Thread(target=self._write_snapshot,
-                                 args=(snap, extra, mark), daemon=True,
-                                 name="snapshot-writer")
+                                 args=(snap, extra, mark, True),
+                                 daemon=True, name="snapshot-writer")
             self._snap_thread = t
             t.start()
             with self._stats_l:
@@ -507,16 +555,19 @@ class Persistence:
             t.join(timeout_s)
 
     def _write_snapshot(self, snap, extra: Optional[dict],
-                        wal_mark: int) -> None:
+                        wal_mark: int, in_child: bool = False) -> None:
         """Serialize + atomically publish one captured snapshot, then
         drop the WAL prefix it covers (entries appended after the
-        capture survive in the tail)."""
-        import time as _time
-        t0 = _time.perf_counter()
+        capture survive in the tail). `in_child` (the background
+        writer's thread): the serialization runs in a forked child and
+        this thread only waits for it, so that a whole-store dump never
+        holds the serving process's GIL."""
+        t0 = time.perf_counter()
+        tmp = self.snapshot_path + ".tmp"
         try:
-            # the writer's own time, the wait for a sibling writer
-            # included: the interval last_snapshot_s reports. `entries`
-            # is the raft index the capture covers
+            # the writer's own time, the wait for a sibling writer and
+            # for the child included: the interval last_snapshot_s
+            # reports. `entries` is the raft index the capture covers
             with stages.span("snapshot_write",
                              entries=snap.latest_index()) as sp, \
                     self._snap_l:
@@ -526,35 +577,129 @@ class Persistence:
                     # with a MORE-truncated WAL and lose the gap
                     sp.cancel()
                     return
-                data = snap.dump_columnar() if self.columnar \
-                    else snap.dump()
-                if extra is not None:
-                    data["extra"] = extra
-                blob = msgpack.packb(data, use_bin_type=True)
-                sp.note(bytes=len(blob))
-                tmp = self.snapshot_path + ".tmp"
-                with open(tmp, "wb") as f:
-                    f.write(blob)
-                    f.flush()
-                    os.fsync(f.fileno())
-                os.replace(tmp, self.snapshot_path)
-                self.log.truncate_prefix(wal_mark)
+                try:
+                    wrote = self._serialize_in_child(snap, extra, tmp) \
+                        if in_child else None
+                    if wrote is None:
+                        wrote = self._serialize(snap, extra, tmp)
+                    os.replace(tmp, self.snapshot_path)
+                except BaseException:
+                    # no half-written tmp outlives its writer
+                    if os.path.exists(tmp):
+                        os.unlink(tmp)
+                    raise
+                tail, locked_tail = self.log.truncate_prefix(wal_mark)
                 self._published_mark = wal_mark
+                sp.note(bytes=wrote["bytes"],
+                        fork_ms=wrote.get("fork_s", 0.0) * 1e3,
+                        child_s=wrote.get("seconds", 0.0),
+                        tail_bytes=tail, locked_tail_bytes=locked_tail)
                 with self._stats_l:
                     self.stats["snapshots"] += 1
                     self.stats["last_snapshot_s"] = \
-                        _time.perf_counter() - t0
-                    self.stats["last_snapshot_format"] = \
-                        int(data.get("format", 1))
+                        time.perf_counter() - t0
+                    self.stats["last_snapshot_format"] = wrote["format"]
+                    if "fork_s" in wrote:
+                        self.stats["snapshot_children"] += 1
+                        self.stats["last_snapshot_fork_s"] = \
+                            wrote["fork_s"]
+                        self.stats["last_snapshot_child_s"] = \
+                            wrote["seconds"]
+                    elif in_child:
+                        self.stats["snapshot_inline"] += 1
                 try:
                     self.save_cost_model()
                 except OSError:     # pragma: no cover — best effort
                     pass
-        except Exception:           # pragma: no cover — a failed
-            # snapshot must not kill the applier or the writer thread;
-            # the WAL keeps everything, the next threshold retries
-            import logging
+        except Exception:
+            # a failed snapshot must not kill the applier or the writer
+            # thread; state.snap and the WAL keep everything, the next
+            # threshold retries
             logging.getLogger("nomad_tpu.persistence").exception(
                 "snapshot write failed")
             with self._stats_l:
                 self.stats["snapshot_errors"] += 1
+
+    def _serialize(self, snap, extra: Optional[dict], tmp: str) -> dict:
+        """Dump the captured root, pack it and write it to `tmp`,
+        fsynced: the one function that makes a snapshot's bytes,
+        whichever process runs it."""
+        data = snap.dump_columnar() if self.columnar else snap.dump()
+        if extra is not None:
+            data["extra"] = extra
+        blob = msgpack.packb(data, use_bin_type=True)
+        with open(tmp, "wb") as f:
+            f.write(blob)
+            f.flush()
+            os.fsync(f.fileno())
+        return {"bytes": len(blob), "format": int(data.get("format", 1))}
+
+    def _serialize_in_child(self, snap, extra: Optional[dict],
+                            tmp: str) -> Optional[dict]:
+        """Run `_serialize` in a forked child — this thread alone, over
+        a copy-on-write image of the captured root — and wait for it
+        with the GIL released. Returns the child's report plus
+        `seconds` (the child's own) and `fork_s` (this process's stall
+        inside os.fork), or None where the platform cannot fork: the
+        caller then serializes inline. A child that raised, exited
+        non-zero or was killed raises here.
+
+        The child takes no lock of the program's, opens no span, logs
+        nothing, touches no JAX object, and leaves by os._exit: no
+        atexit hook, no flush of a buffer it inherited."""
+        if not hasattr(os, "fork"):
+            return None
+        # what the child imports lazily must be loaded already: an
+        # import lock some other thread held at the fork stays held
+        from ..state import columnar  # noqa: F401
+        r, w = os.pipe()
+        t0 = time.perf_counter()
+        try:
+            with warnings.catch_warnings():
+                # expected here, and silenced for this call alone:
+                # CPython 3.12's multi-threaded fork warning and JAX's
+                # from its at-fork hook
+                warnings.filterwarnings(
+                    "ignore", category=DeprecationWarning,
+                    message=r"This process .* is multi-threaded, "
+                            r"use of fork\(\)")
+                warnings.filterwarnings(
+                    "ignore", category=RuntimeWarning,
+                    message=r"os\.fork\(\) was called")
+                pid = os.fork()
+        except OSError:
+            os.close(r)
+            os.close(w)
+            return None
+        if pid == 0:
+            code = 1
+            try:
+                os.close(r)
+                gc.disable()
+                # a signal meant for the server must end this child,
+                # not run the server's handler in it
+                signal.signal(signal.SIGINT, signal.SIG_DFL)
+                signal.signal(signal.SIGTERM, signal.SIG_DFL)
+                c0 = time.perf_counter()
+                report = self._serialize(snap, extra, tmp)
+                report["seconds"] = time.perf_counter() - c0
+                code = 0
+            except BaseException:
+                report = {"error": traceback.format_exc()}
+            try:
+                os.write(w, msgpack.packb(report))
+            finally:
+                os._exit(code)
+        fork_s = time.perf_counter() - t0
+        os.close(w)
+        with open(r, "rb", buffering=0) as pipe:
+            raw = pipe.readall()
+        _, status = os.waitpid(pid, 0)
+        report = msgpack.unpackb(raw, raw=False) if raw else {}
+        if status != 0 or "error" in report or "bytes" not in report:
+            raise RuntimeError(
+                "snapshot child %d ended with status %s: %s" % (
+                    pid, os.waitstatus_to_exitcode(status),
+                    report.get("error", "no report")))
+        report["fork_s"] = fork_s
+        return report

@@ -5,9 +5,9 @@ group-fsync equivalence, and the recovery invariants (warm columnar
 alloc index, primed resident node table)."""
 
 import json
+import multiprocessing
 import os
 import random
-import threading
 import time
 
 import msgpack
@@ -375,8 +375,10 @@ class TestBackgroundSnapshot:
         data_dir = str(tmp_path / "bg")
         srv = Server(ServerConfig(num_schedulers=0, data_dir=data_dir,
                                   snapshot_every=5))
-        gate = threading.Event()
-        entered = threading.Event()
+        # the dump runs in a forked child (PR 33): events it shares
+        fork = multiprocessing.get_context("fork")
+        gate = fork.Event()
+        entered = fork.Event()
         from nomad_tpu.state.store import StateSnapshot
         real_dump = StateSnapshot.dump_columnar
 
