@@ -52,6 +52,8 @@ NEG_INF = -1e30
 TOP_K = 5       # ScoreMetaData entries kept (reference kheap topK)
 CHUNK_J = 256   # max instances placed on one node per chunked step
 KWAY_W = 32     # winners per phase at small tables (floor for _kway_w)
+VICTIMS_SCAN_STEPS = 64   # least scan length of a request that carries
+                          # the victims' columns (SelectRequest.victims)
 KWAY_STEPS = 256  # phases per dispatch: ~56 cover a 10k batch; the out
                   # buffers are [steps, 2w+...] ints copied back on every
                   # dispatch. Not re-measured on a local chip.
@@ -166,6 +168,14 @@ class SelectRequest:
     # the token survives residue instead of forcing a dense re-upload.
     # Only meaningful beside feas_token; cleared with it.
     feas_residue: Optional[Tuple[np.ndarray, np.ndarray]] = None
+    # the victims' program's selection, still on the device
+    # (ops/victims.VictimSelection): its `used_after`, `pre_score` and
+    # `capacity` replace the host's columns in the dispatch, so what
+    # the second select of a preempting eval ranks never crosses the
+    # bus. Such a request runs the scan arm alone and solo (the other
+    # arms re-score on the host from `used` and `pre_score`); `used`
+    # stays the usage BEFORE eviction and `pre_score` None.
+    victims: Optional[object] = None
 
 
 @dataclasses.dataclass
@@ -1656,7 +1666,8 @@ def _cpu_device():
         return None
 
 
-def decorrelation_slice(req, lane: int, total: int, cache):
+def decorrelation_slice(req, lane: int, total: int, cache,
+                        by_room: bool = True):
     """The one shared decorrelation rule (used by both the worker's
     solo-select slicing and the BatchGateway's lane partition): a
     Knuth-mix hash assigns each node to one of `total` lanes; the
@@ -1679,6 +1690,11 @@ def decorrelation_slice(req, lane: int, total: int, cache):
     slice_mask = feas & (lane_ids == (lane % total))
     if int(slice_mask.sum()) < 8:
         return None, cache
+    if not by_room:
+        # an evicting ask: room is what the select is about to make,
+        # so the slice is held to a count of nodes instead
+        return (slice_mask if int(slice_mask.sum()) >= 2 * req.count
+                else None), cache
     free = req.capacity - req.used
     with np.errstate(divide="ignore", invalid="ignore"):
         per = np.where(req.ask[None, :] > 0,
@@ -1777,6 +1793,12 @@ class SelectKernel:
         self._sharded = get_shared_sharded()
         return self._sharded
 
+    def single_device(self) -> bool:
+        """Every dispatch of this kernel runs on the default device: no
+        mesh, no forced host backend. What a request that carries
+        device-resident columns (SelectRequest.victims) needs."""
+        return self._mesh_sharded() is None and self._pick_device() is None
+
     # -- routing -------------------------------------------------------
     def _pick_device(self):
         """The CPU device when NOMAD_TPU_SELECT_BACKEND=cpu forces the
@@ -1844,10 +1866,17 @@ class SelectKernel:
         if dec is None:
             return None
         lane, lanes = dec
-        if req.count < 256:
+        # an ask that carries the victims' columns takes its lane at
+        # ANY count: every worker reads the same columns and the same
+        # ties (thousands of full nodes score alike), so two of them
+        # chase one frontier node, and a one-instance eval was seen to
+        # lose that race 37 times in a row and fail (PERF.md section 6,
+        # PR 34). Which of tied nodes evicts, nothing ranks
+        evicting = req.victims is not None
+        if req.count < 256 and not evicting:
             return None
         slice_mask, cache = decorrelation_slice(
-            req, lane, lanes, self._decor_cache)
+            req, lane, lanes, self._decor_cache, by_room=not evicting)
         self._decor_cache = cache
         if slice_mask is None:
             return None
@@ -1895,7 +1924,7 @@ class SelectKernel:
             return sharded.select(req)      # observes scan@mesh itself
         n = len(req.feasible)
         n_pad = _pad_n(n)
-        if not couples_nodes(req):
+        if not couples_nodes(req) and req.victims is None:
             dev = self._pick_device()
             if req.count > 512 and n_pad > KWAY_W:
                 # big batches: K-way phases place on the top-32 nodes at
@@ -1904,12 +1933,21 @@ class SelectKernel:
             return self._run_chunked(req, n_pad, dev)
         dev = self._pick_device()
         k = _bucket_k(max(req.count, 1))
+        if req.victims is not None:
+            # one scan length for every small preempting ask: a padded
+            # step is a masked pass over the node axis (microseconds),
+            # a bucket of its own another compile in every warm-up
+            k = max(k, VICTIMS_SCAN_STEPS)
         with stages.span("kernel_pack"):
             args, statics = pack_request(req, n_pad)
             args = self._place_args(args, dev)
             resident = self._resident_args(req, n_pad, dev)
             if resident:
                 args.update(resident)
+            if req.victims is not None:
+                v = req.victims
+                args.update(capacity=v.capacity, used0=v.used_after,
+                            pre_score=v.pre_score)
         fresh = _note_trace("scan", n_pad, k_steps=k,
                             cpu=dev is not None, **statics)
         with kernel_span("scan" + ("@cpu" if dev is not None else ""),

@@ -53,6 +53,8 @@ BUILD_STATS: Dict[str, int] = {"full_builds": 0, "delta_refreshes": 0}
 # during a refresh (a handful), not history — verified by the r6 soak
 # instrumentation: post-fix object growth over 2000 evals is ~1
 _MEMO_MAX = 4096
+# one derivation of victims' columns at a time (NodeTable.victim_columns)
+_VICTIMS_L = make_lock()
 _usage_memo: Dict[int, Tuple[object, Tuple[float, float, float, float]]] = {}
 _port_bits_memo: Dict[int, Tuple[object, int]] = {}
 
@@ -175,12 +177,18 @@ class NodeTable:
         # node-class memoization, feasible.go:1026-1118); valid for this
         # table version — node attribute columns are immutable here
         self.mask_cache: Dict[Tuple, List] = {}
-        # cross-eval preemption victim cache keyed on the node's
-        # live-alloc ROW IDENTITY (rows are replaced copy-on-write, so
-        # an unchanged row means unchanged candidates) + the asking
-        # shape; entries pin their row so id() can't be recycled
-        # (scheduler/preemption.py PreemptionRound)
+        # cross-eval victim cache of the per-node preemption path,
+        # keyed on the node's live-alloc ROW IDENTITY (rows are
+        # replaced copy-on-write, so an unchanged row means unchanged
+        # candidates) + the asking shape; entries pin their row so
+        # id() can't be recycled (scheduler/preemption.py)
         self.preempt_cache: Dict[Tuple, tuple] = {}
+        # the victims' columns of this version (ops/victims.py), once
+        # a preemption round has asked for them: victim_columns()
+        self.victims = None
+        # (columns of an earlier version, rows touched since): what
+        # victim_columns() advances from instead of building anew
+        self._victims_base = None
         # device-resident mirror token (ops/device_table.py): set by
         # NodeTableCache on tables it serves; a kernel dispatch uses
         # the mirror's arrays only while the token still matches the
@@ -404,6 +412,8 @@ class NodeTable:
         t.alloc_by_id = self.alloc_by_id
         t.mask_cache = self.mask_cache  # node columns shared => masks too
         t.preempt_cache = self.preempt_cache  # row identity keys the entries
+        t.victims = None
+        t._victims_base = None      # NodeTableCache.get hands it on
         t._attr_codes_cache = self._attr_codes_cache
         t._ready_dc_cache = self._ready_dc_cache  # status cols shared
         t._sealed = True
@@ -534,6 +544,43 @@ class NodeTable:
                 self._net_bits[i] |= bits
                 self._mark_ports_dirty(i)
         return touched
+
+    def inherit_victims(self, parent: "NodeTable", rows) -> None:
+        """(cache lock held) This version came from `parent` by a
+        refresh that touched `rows`: remember the nearest columns to
+        advance from. Nothing is derived here — a cluster whose evals
+        never preempt has no columns, and this is two reads."""
+        pending = parent._victims_base
+        base = parent.victims   # read second: set before the other clears
+        if base is not None:
+            touched = frozenset(rows)
+        elif pending is not None:
+            base, earlier = pending
+            touched = earlier | frozenset(rows)
+        else:
+            return
+        if len(touched) * 4 <= self.n:   # wider: a build is cheaper
+            self._victims_base = (base, touched)
+
+    def victim_columns(self, snapshot, slots_max: int = 1 << 30):
+        """The victims' columns of this version (ops/victims.py),
+        derived on the first call: advanced by the touched rows from
+        the nearest earlier version that had them, else built from
+        `snapshot`, the one this table is current for."""
+        vc = self.victims
+        if vc is None:
+            from .victims import VictimColumns
+            with _VICTIMS_L:
+                vc = self.victims
+                if vc is None:
+                    base = self._victims_base
+                    if base is not None:
+                        vc = base[0].advance(self, snapshot, base[1])
+                    else:
+                        vc = VictimColumns.build(self, snapshot, slots_max)
+                    self.victims = vc
+                    self._victims_base = None
+        return vc
 
     def _mark_ports_dirty(self, i: int) -> None:
         if self._free_ports_dirty is None:
@@ -839,6 +886,7 @@ class NodeTableCache:
                     t = self._table.clone_for_deltas()
                     rows = t.apply_alloc_changes(snapshot, seen)
                     t.finalize()
+                    t.inherit_victims(self._table, rows)
                     BUILD_STATS["delta_refreshes"] += 1
                     self.stats["delta_refreshes"] += 1
                     self._table = self._stamp(

@@ -80,6 +80,7 @@ class GenericScheduler:
         # set by concurrent workers: (lane, lanes) hash-slice
         # decorrelation for big batch selects (SelectKernel.decorrelate)
         self.kernel_decorrelate = None
+        self._job_allocs = None     # (snapshot, the job's allocations)
 
     # -- entry ---------------------------------------------------------
     def process(self, evaluation: Evaluation) -> None:
@@ -492,13 +493,12 @@ class GenericScheduler:
                             self.plan.append_stopped_alloc(
                                 missing.previous_alloc, stop_desc, "", "")
 
-                proposed = ProposedIndex(
-                    self.engine.table, self.job,
-                    self.state.allocs_by_job(self.job.namespace, self.job.id),
-                    self.plan)
+                proposed = self._proposed()
+                # room first (upstream selectNextOption): this select
+                # carries no preemption; only what it finds no node
+                # for goes to a second one that does (_append_general)
                 options_list = self.engine.select_batch(
-                    tg, len(batch), proposed, batch[0][1],
-                    preemption_round=self._preemption_round_for(tg))
+                    tg, len(batch), proposed, batch[0][1])
 
                 if fresh and not batch[0][1].preferred_nodes:
                     # bulk-append the successful fresh placements in one
@@ -546,23 +546,30 @@ class GenericScheduler:
     def _append_general(self, pairs, batch, tg, deployment_id: str,
                         now) -> None:
         """Append (item, option) pairs to the plan one by one: what
-        _append_fresh_bulk left over, and every batch it cannot take."""
+        _append_fresh_bulk left over, and every batch it cannot take.
+        The items no select found a node for are then ranked ONCE more,
+        together, with preemption switched on (_select_evicting)."""
+        unplaced = []
         for (missing, _opts), (option, metrics) in pairs:
             # preferred-node miss falls back to the full node set
             if option is None and batch[0][1].preferred_nodes:
                 fallback = self.engine.select_batch(
-                    tg, 1, ProposedIndex(
-                        self.engine.table, self.job,
-                        self.state.allocs_by_job(
-                            self.job.namespace, self.job.id),
-                        self.plan),
+                    tg, 1, self._proposed(),
                     SelectOptions(
                         penalty_node_ids=batch[0][1].penalty_node_ids))
                 option, metrics = fallback[0] if fallback else (None, metrics)
-            # no fit anywhere: try preemption before failing
-            # (BinPackIterator evict path, rank.go:415-448)
-            if option is None:
-                option = self._try_preemption(tg, metrics)
+            if option is not None:
+                self._append_placement(missing, tg, option,
+                                       deployment_id, now)
+            else:
+                unplaced.append((missing, metrics))
+        if not unplaced:
+            return
+        # no fit anywhere: try preemption before failing
+        # (BinPackIterator evict path, rank.go:415-448)
+        evicting = self._select_evicting(
+            tg, len(unplaced), batch[0][1].penalty_node_ids)
+        for (missing, metrics), (option, _m) in zip(unplaced, evicting):
             if option is not None:
                 self._append_placement(missing, tg, option,
                                        deployment_id, now)
@@ -581,13 +588,29 @@ class GenericScheduler:
             if stop_prev and missing.previous_alloc is not None:
                 self.plan.remove_update(missing.previous_alloc)
 
-    def _preemption_round_for(self, tg):
-        """Per-(eval, task group) PreemptionRound when preemption is
-        enabled for this scheduler type; None otherwise."""
+    def _proposed(self) -> ProposedIndex:
+        """The job's proposed allocations over the plan as it stands.
+        The job's own allocations are listed once a snapshot: a second
+        select, or a batch job of 20,000 allocations, asks again."""
+        hit = self._job_allocs
+        if hit is None or hit[0] is not self.state:
+            hit = self._job_allocs = (self.state, self.state.allocs_by_job(
+                self.job.namespace, self.job.id))
+        return ProposedIndex(self.engine.table, self.job, hit[1],
+                             self.plan)
+
+    def _select_evicting(self, tg, count: int, penalty_node_ids):
+        """The second select, for `count` instances that found no node
+        as the fleet stands: the same ranking over the plan as it is
+        now, with the nodes that would fit after evicting lower-priority
+        allocations (priority delta >= 10) competing. Only here is a
+        PreemptionRound built, one per (eval, task group): an eval that
+        found room pays none. Returns select_batch's pairs, or `count`
+        misses when preemption is off for this scheduler type."""
         from .preemption import PreemptionRound, preemption_enabled
         if not preemption_enabled(self.state.scheduler_config(),
                                   "batch" if self.batch else "service"):
-            return None
+            return [(None, None)] * count
         round_ = self._preemption_rounds.get(tg.name)
         if round_ is None or round_.plan is not self.plan:
             mask, _counts = self.engine.feasibility(tg)
@@ -595,43 +618,10 @@ class GenericScheduler:
                 self.state, self.engine.table, mask,
                 self.engine.group_ask(tg), self.job, self.plan, tg=tg)
             self._preemption_rounds[tg.name] = round_
-        return round_
-
-    def _try_preemption(self, tg, metrics):
-        """When the kernel finds no fit, look for a node where evicting
-        lower-priority allocs (priority delta >= 10) makes room. The
-        PreemptionRound is cached per task group for the whole eval so
-        repeated failures share per-node victim computations."""
-        from ..ops.tables import ProposedIndex as PI
-        from .stack import RankedNode
-        round_ = self._preemption_round_for(tg)
-        if round_ is None:
-            return None
-        proposed = PI(self.engine.table, self.job,
-                      self.state.allocs_by_job(self.job.namespace, self.job.id),
-                      self.plan)
-        found = round_.find_placement(proposed.used())
-        if found is None:
-            return None
-        idx, victims, score = found
-        node = self.engine.table.nodes[idx]
-        # victims free their ports too: rebuild this node's net index
-        # after staging the preemptions
-        for v in victims:
-            self.plan.append_preempted_alloc(v, "")
-        self.engine._net_cache.pop(node.id, None)
-        task_resources, shared, ok = self.engine._assign_resources(
-            node, tg, self.plan)
-        if not ok:
-            for v in victims:
-                lst = self.plan.node_preemptions.get(v.node_id, [])
-                self.plan.node_preemptions[v.node_id] = \
-                    [a for a in lst if a.id != v.id]
-            return None
-        return RankedNode(node=node, final_score=score,
-                          task_resources=task_resources,
-                          alloc_resources=shared, metrics=metrics,
-                          preempted_allocs=victims)
+        return self.engine.select_batch(
+            tg, count, self._proposed(),
+            SelectOptions(penalty_node_ids=penalty_node_ids),
+            preemption_round=round_)
 
     def _append_fresh_bulk(self, batch, options_list, tg,
                            deployment_id: str):

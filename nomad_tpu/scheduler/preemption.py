@@ -20,10 +20,10 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..models import Allocation, ComparableResources
+# the two constants of upstream's rule, shared with the program
+from ..ops.victims import MAX_PARALLEL_PENALTY, PRIORITY_DELTA
 from ..utils import stages
 
-MAX_PARALLEL_PENALTY = 50.0
-PRIORITY_DELTA = 10
 
 # -- batched columnar victim selection (ISSUE 10) ----------------------
 #
@@ -34,9 +34,9 @@ PRIORITY_DELTA = 10
 # like NOMAD_TPU_COLUMNAR_RECONCILE=0 reverts the reconcile engine.
 
 _COLUMNAR = True
-# per-node candidate cap for the dense [nodes, candidates] matrix; a
-# node with more eligible candidates than this takes the per-node
-# reference path (the matrix would pad every other node to its width)
+# per-node cap on the victims' columns' width (ops/victims.py holds its
+# own, lower, ceiling): a node with more job-carrying residents than
+# the columns are wide takes the per-node reference path
 ROWS_MAX = 4096
 # victim-set memo bound (table.preempt_cache); crossing it clears the
 # memo — the governor's preemption.victim_cache_entries watermark
@@ -359,18 +359,36 @@ def preemption_enabled(sched_config, scheduler_type: str) -> bool:
 
 
 class PreemptionRound:
-    """Preemption placement across nodes, amortized over an eval.
+    """Preemption across the whole fleet for one (eval, task group):
+    which full nodes could take an instance of the ask by evicting, at
+    what score, and, for the nodes that win, whom.
 
-    The naive fallback recomputed every node's victim set for every
-    failed instance — O(instances x nodes) Preemptor runs, the dominant
-    cost of preemption-heavy evals. This round object computes each
-    node's (victims, score) entry once and then only re-derives entries
-    whose inputs changed: the plan state touching the node (placements,
-    stops, preemptions) is captured in a per-node signature, plus the
-    global max_parallel preemption counts for the job groups present on
-    the node (the only cross-node coupling in the scoring —
-    scoreForTaskGroup's penalty). Semantics per node are byte-identical
-    to the one-shot path: PreemptionScoringIterator + BinPack fallback
+    What is RESIDENT, and outlives the round: the victims' columns kept
+    with the node table (ops/victims.VictimColumns) — per node row the
+    job-carrying residents' priority, cpu, memory, disk, group code and
+    max_parallel, on the device, one array a table version, advanced by
+    the rows a commit touched. A round walks no `Allocation` list of a
+    node nothing touched.
+
+    What a round RECOMPUTES, every time it is asked (device_columns()):
+    the selection itself — `_select_victims_fn` over every candidate
+    node at once, against this plan's usage, with the slots this plan
+    stops or preempts and the placing job's own taken out. Its columns
+    stay on the device for the select that follows; resolve() brings
+    the winners' victims to the host, victims_for() hands them out.
+
+    The per-node `Preemptor` is the reference the program is held to
+    (tests/test_victims_program.py) and the path for what the columns
+    do not carry: device, reserved-port and bandwidth asks, a row wider
+    than the columns, NOMAD_TPU_COLUMNAR_PREEMPT=0. There each node's
+    (victims, score) entry is computed once and re-derived only when
+    the plan state touching the node changed (`_invalidate_dirty`: the
+    plan's per-node entry counts, and the max_parallel counts of the
+    groups present — scoreForTaskGroup's penalty is the only cross-node
+    coupling), with a cross-eval memo keyed on the row's identity.
+    columns() / find_placement() are that host API; with the program on
+    they run one dispatch and fetch every row (tests, the mesh route).
+    Semantics per node: PreemptionScoringIterator + BinPack fallback
     (rank.go:415-448, 732-745).
     """
 
@@ -430,6 +448,15 @@ class PreemptionRound:
         self._mp_groups: Dict[int, frozenset] = {}
         self._last_counts: Dict[str, Tuple[int, int, int]] = {}
         self._last_mp_counts: Dict[Tuple, int] = {}
+        # the victims' columns this round read (ops/victims.py), its
+        # last dispatch, and the rows whose fit the columns last
+        # handed out came from an eviction
+        self._vc = None
+        self._host: frozenset = frozenset()     # rows the host evaluated
+        self._selection = None
+        self._evicting = np.zeros(n, bool)
+        self._rows_refreshed = 0
+        self._scanned = self._n_victims = 0
 
     # -- plan-state dirty tracking ------------------------------------
     def _preempted_now(self) -> List[Allocation]:
@@ -626,7 +653,7 @@ class PreemptionRound:
         self._freed[i] = freed
         return memo(victims, (binpack + pscore) / 2.0, pscore, freed)
 
-    # -- batched columnar victim selection (the ISSUE 10 tentpole) -----
+    # -- the resident columns and the program over them ----------------
     def _record(self, i: int, victims: Optional[List[Allocation]],
                 score: float) -> None:
         self._known[i] = True
@@ -639,10 +666,49 @@ class PreemptionRound:
             self._freed[i] = 0.0
             self._victims.pop(i, None)
 
+    def _evaluate_pending(self, pending, used,
+                          current: List[Allocation]) -> None:
+        """Resolve every pending node's (victims, score) entry on the
+        host: ONE dispatch of the victims' program with everything
+        fetched (the host API: tests, the mesh route), or the per-node
+        reference Preemptor with its cross-eval memo when the round
+        carries device / port asks or the kill switch is set."""
+        t0 = time.perf_counter()
+        with stages.span("preempt") as sp:
+            sel = self._dispatch(used=used, current=current) \
+                if self._columnar else None
+            if sel is not None:
+                pre, score, freed, slots = sel.fetch_all()
+                rows = self._vc.rows
+                for i in pending.tolist():
+                    if i in self._host:
+                        continue        # _dispatch recorded it
+                    if score[i] < 0:
+                        self._record(i, None, 0.0)
+                        continue
+                    self._logistic[i] = pre[i]
+                    self._freed[i] = freed[i]
+                    self._record(i, [rows[i][s] for s in slots[i]],
+                                 float(score[i]))
+            else:
+                stopped_ids = self._stopped_ids(current)
+                PREEMPT_STATS["fallback_nodes"] += len(pending)
+                for i in pending.tolist():
+                    if self._cache_lookup(i):
+                        continue
+                    PREEMPT_STATS["cache_misses"] += 1
+                    victims, score_i = self._evaluate_node(
+                        i, used[i], current, stopped_ids)
+                    self._record(i, victims, score_i)
+                PREEMPT_STATS["nodes_scanned"] += len(pending)
+            n_victims = sum(len(self._victims.get(i, ()))
+                            for i in pending.tolist())
+            sp.note(nodes_scanned=len(pending), victims=n_victims,
+                    rows_refreshed=self._rows_refreshed)
+        PREEMPT_STATS["select_s"] += time.perf_counter() - t0
+
     def _cache_lookup(self, i: int) -> bool:
-        """The cross-eval victim-memo fast path, hoisted out of
-        _evaluate_node so the batched selector only gathers columns
-        for true misses."""
+        """The per-node path's cross-eval memo."""
         if not self._cacheable(i):
             return False
         row = self.table.live_allocs[i]
@@ -658,364 +724,115 @@ class PreemptionRound:
                      score)
         return True
 
-    def _memoize(self, i: int, victims: Optional[List[Allocation]],
-                 score: float, logistic: float, freed,
-                 cacheable: bool, has_mp: bool) -> None:
-        """Cross-eval memo install, same contract as _evaluate_node's
-        memo closure: only nodes nothing eval-specific touches, and
-        only when no candidate carries max_parallel."""
-        if not cacheable or has_mp:
-            return
-        cache = self.table.preempt_cache
-        if len(cache) > CACHE_MAX:
-            cache.clear()
-            PREEMPT_STATS["cache_clears"] += 1
-        row = self.table.live_allocs[i]
-        cache[(id(row), self._cache_sig)] = (
-            row, list(victims) if victims is not None else None,
-            score, logistic,
-            freed if freed is not None else np.zeros(4, np.float64))
+    def _stopped_ids(self, current: List[Allocation]) -> set:
+        out = {a.id for allocs in self.plan.node_update.values()
+               for a in allocs}
+        out.update(a.id for a in current)
+        return out
 
-    def _evaluate_pending(self, pending, used,
-                          current: List[Allocation]) -> None:
-        """Resolve every pending node's (victims, score) entry: memo
-        hits first, then ONE batched columnar pass over the misses
-        (per-node reference Preemptor when the round carries device/
-        port asks, the kill switch is set, or a node's candidate set
-        overflows the matrix cap)."""
-        t0 = time.perf_counter()
-        stopped_ids = {a.id for allocs in self.plan.node_update.values()
-                       for a in allocs}
-        stopped_ids |= {a.id for a in current}
-        misses: List[int] = []
-        for i in pending:
-            i = int(i)
-            if not self._cache_lookup(i):
-                misses.append(i)
-        PREEMPT_STATS["cache_misses"] += len(misses)
-        if misses:
-            if self._columnar:
-                overflow = self._evaluate_columnar(misses, used, current,
-                                                   stopped_ids)
-            else:
-                overflow = misses
-            PREEMPT_STATS["fallback_nodes"] += len(overflow)
-            for i in overflow:
-                victims, score = self._evaluate_node(
-                    i, used[i], current, stopped_ids)
-                self._record(i, victims, score)
-        n_scanned = len(pending)
-        PREEMPT_STATS["nodes_scanned"] += n_scanned
-        dt = time.perf_counter() - t0
-        PREEMPT_STATS["select_s"] += dt
-        if stages.enabled:
-            n_victims = 0
-            for i in pending:
-                v = self._victims.get(int(i))
-                if v:
-                    n_victims += len(v)
-            stages.add("preempt", dt, {"nodes_scanned": n_scanned,
-                                       "victims": n_victims})
-
-    def _evaluate_columnar(self, idxs: List[int], used,
-                           current: List[Allocation],
-                           stopped_ids: set) -> List[int]:
-        """Victim selection for all of `idxs` at once: one
-        struct-of-arrays gather over the nodes' candidate allocs (per-
-        alloc facts through state/alloc_index's memoized extractors),
-        then the whole reference pipeline — PRIORITY_DELTA filter,
-        greedy closest-distance selection (all nodes step in lockstep:
-        each round is one [nodes, candidates] distance matrix + argmin
-        instead of a Python loop per node), the superset drop via
-        stable two-key argsort + prefix cumulative sums, and the
-        binpack + logistic scoring — as vectorized float64 numpy whose
-        op order mirrors the Preemptor exactly (the 1k-seed parity
-        suite pins bit-identical victims and scores). Returns the node
-        indexes whose candidate sets overflow ROWS_MAX — those take
-        the per-node reference path."""
-        from ..state.alloc_index import alloc_max_parallel, alloc_usage_vec
-
+    def _dispatch(self, used=None, proposed=None,
+                  current: Optional[List[Allocation]] = None):
+        """Run the victims' program over the whole table (asynchronous;
+        ops/victims.py) and return its selection, or None when the plan
+        already preempts from more groups than the program counts. The
+        host's share, the `preempt_gather` span: the columns brought to
+        this table version (the rows commits touched since the nearest
+        version that had them), the slots the plan or the placing job
+        itself takes out, and the rows wider than the columns, which
+        the per-node Preemptor evaluates here. `preempt_kernel`: the
+        dispatch and the wait for its counters; nodes it reports
+        unfinished (more victims needed than it picks) go to the
+        Preemptor too, and the program runs once more over their
+        entries."""
+        from ..ops import victims as vops
         t = self.table
-        plan = self.plan
-        snap = self.snapshot
-        ns, jid = self.job.namespace, self.job.id
-        jp = self.job.priority
+        if current is None:
+            current = self._preempted_now()
+        with stages.span("preempt_gather") as sp:
+            fresh = t.victims is None
+            vc = self._vc = t.victim_columns(self.snapshot, ROWS_MAX)
+            self._rows_refreshed = vc.refreshed if fresh else 0
+            counts: Dict[int, int] = {}
+            for code in vops.group_codes(
+                    (a.namespace, a.job_id, a.task_group)
+                    for a in current):
+                counts[code] = counts.get(code, 0) + 1
+            if len(counts) > vops.GROUP_COUNTS_MAX:
+                sp.cancel()
+                return None
+            id_to_idx = t.id_to_idx
+            dead = []
+            own = [a for a in self.snapshot.allocs_by_job(
+                self.job.namespace, self.job.id)
+                if not a.terminal_status()]
+            for allocs in (own, *self.plan.node_update.values(),
+                           *self.plan.node_preemptions.values()):
+                for a in allocs:
+                    row = id_to_idx.get(a.node_id)
+                    if row is not None:
+                        slot = vc.slot_of(row, a)
+                        if slot >= 0:
+                            dead.append((row, slot))
+            overrides: Dict[int, tuple] = {}
+            if vc.over:
+                self._host_rows(sorted(vc.over), used, proposed, current,
+                                overrides)
+            for i, groups in vc.mp_groups.items():
+                self._mp_groups[i] = groups
+        import jax
 
-        # current preemption counts per group — static for this pass
-        # (set_preemptions is called once per reference evaluation too)
-        cur_counts: Dict[Tuple, int] = {}
-        for a in current:
-            k = (a.namespace, a.job_id, a.task_group)
-            cur_counts[k] = cur_counts.get(k, 0) + 1
+        def run():
+            """One dispatch, and the wait for its counters."""
+            with stages.span("preempt_kernel"):
+                sel = vops.select_victims(
+                    vc, t, self.mask, self.ask_vec, self.job.priority,
+                    dead, counts, overrides, used=used, proposed=proposed)
+                # nomad-lint: allow[host-sync] the program's counters: the wait IS the preempt_kernel span
+                got = jax.device_get(sel.counters)
+            return (sel, *map(int, got))
 
-        P = len(idxs)
-        all_usage = np.zeros((P, 3), np.float64)
-        cand_allocs: List[List[Allocation]] = [[] for _ in range(P)]
-        cand_cols: List[List[Tuple]] = [[] for _ in range(P)]
-        cacheable = [False] * P
-        has_mp = [False] * P
-        overflow: List[int] = []
-        over_p = [False] * P
-        ids = t.ids
-        alloc_of = plan.node_allocation
-        for p, i in enumerate(idxs):
-            node_id = ids[i]
-            proposed = [a for a in snap.allocs_by_node(node_id)
-                        if not a.terminal_status()
-                        and a.id not in stopped_ids]
-            proposed.extend(alloc_of.get(node_id, []))
-            mp_groups = set()
-            al = cand_allocs[p]
-            cl = cand_cols[p]
-            cpu_sum = mem_sum = disk_sum = 0.0
-            for a in proposed:
-                u = alloc_usage_vec(a)
-                cpu_sum += u[0]
-                mem_sum += u[1]
-                disk_sum += u[2]
-                # the placing job's own allocs count against capacity
-                # but are never candidates (set_candidates' contract)
-                if a.job_id == jid and a.namespace == ns:
-                    continue
-                mp = alloc_max_parallel(a)
-                if mp > 0:
-                    mp_groups.add((a.namespace, a.job_id, a.task_group))
-                job = a.job
-                if job is None or jp - job.priority < PRIORITY_DELTA:
-                    continue
-                al.append(a)
-                cl.append((u[0], u[1], u[2], u[3], float(job.priority),
-                           float(mp),
-                           float(cur_counts.get(
-                               (a.namespace, a.job_id, a.task_group), 0))))
-            all_usage[p, 0] = cpu_sum
-            all_usage[p, 1] = mem_sum
-            all_usage[p, 2] = disk_sum
-            self._mp_groups[i] = frozenset(mp_groups)
-            cacheable[p] = self._cacheable(i)
-            has_mp[p] = bool(mp_groups)
-            if len(al) > ROWS_MAX:
-                overflow.append(i)
-                over_p[p] = True
-        PREEMPT_STATS["candidate_rows"] += sum(len(c) for c in cand_cols)
-        PREEMPT_STATS["columnar_nodes"] += P - len(overflow)
+        sel, scanned, n_victims, eligible, unfinished = run()
+        if unfinished:
+            # nodes that need more victims than the program picks
+            # (ops/victims.PICKS_MAX): the Preemptor's, then once more
+            with stages.span("preempt_gather"):
+                # nomad-lint: allow[host-sync] rare: nodes past PICKS_MAX, fetched inside preempt_gather
+                rows = np.nonzero(jax.device_get(sel.unfinished))[0]
+                self._host_rows(rows.tolist(), used, proposed, current,
+                                overrides)
+            sel, scanned, n_victims, eligible, _ = run()
+        self._host = frozenset(vc.over) | frozenset(overrides)
+        n_victims += sum(len(self._victims[i]) for i in overrides)
+        PREEMPT_STATS["nodes_scanned"] += scanned
+        PREEMPT_STATS["candidate_rows"] += eligible
+        PREEMPT_STATS["columnar_nodes"] += scanned - len(overrides)
+        self._scanned, self._n_victims = scanned, n_victims
+        return sel
 
-        idx_arr = np.asarray(idxs, np.int64)
-        # same dtype walk as the reference res_fits check (float32 row
-        # + float32 ask against float32 capacity + 1e-6)
-        res_fits = np.all(used[idx_arr][:, :3]
-                          + np.asarray(self.ask_vec[:3])
-                          <= t.capacity[idx_arr][:, :3] + 1e-6, axis=1)
-        ask3 = np.asarray(self.ask_vec[:3], np.float64)
-        # capacity holds res - reserved exactly (int math at table
-        # build; float32 is exact below 2^24, true for MHz/MB scales)
-        cap3 = t.capacity[idx_arr][:, :3].astype(np.float64)
-        remaining0 = cap3 - all_usage
-
-        rows: List[int] = []           # p-indexes entering the matrix
-        for p, i in enumerate(idxs):
-            if over_p[p]:
+    def _host_rows(self, rows, used, proposed, current, overrides) -> None:
+        """The per-node Preemptor over `rows` (wider than the columns,
+        or in need of more victims than the program picks); those that
+        fit by eviction go into `overrides`, which the program lays
+        over its own result."""
+        used_h = used if used is not None else proposed.used()
+        stopped_ids = self._stopped_ids(current)
+        t = self.table
+        for i in rows:
+            if not self.mask[i] or np.all(
+                    used_h[i] + np.asarray(self.ask_vec)
+                    <= t.capacity[i] + 1e-6):
                 continue
-            if res_fits[p] or not cand_cols[p]:
-                # fits on cpu/mem/disk (victims would be []), or no
-                # eligible candidates: the reference returns
-                # memo(None, 0.0) either way
-                self._memoize(i, None, 0.0, 0.0, None,
-                              cacheable[p], has_mp[p])
-                self._record(i, None, 0.0)
-            else:
-                rows.append(p)
-        if not rows:
-            return overflow
-
-        rows_arr = np.asarray(rows, np.int64)
-        counts = np.asarray([len(cand_cols[p]) for p in rows], np.int64)
-        C = int(counts.max())
-        M = len(rows)
-        flat = [v for p in rows for v in cand_cols[p]]
-        fa = np.asarray(flat, np.float64)               # [total, 7]
-        m_idx = np.repeat(np.arange(M), counts)
-        offs = np.concatenate(([0], np.cumsum(counts)[:-1]))
-        c_idx = np.arange(len(flat)) - np.repeat(offs, counts)
-
-        # ONE dense scatter for every column; the per-dim matrices are
-        # views (slicing numpy per dim would triple the call overhead
-        # the matrix exists to amortize)
-        dense7 = np.zeros((M, C, 7), np.float64)
-        dense7[m_idx, c_idx] = fa
-        validM = np.zeros((M, C), bool)
-        validM[m_idx, c_idx] = True
-        c3 = dense7[:, :, 0:3]          # cpu, mem, disk
-        c4 = dense7[:, :, 0:4]          # + mbits (the freed vector)
-        cprio = dense7[:, :, 4].copy()
-        cprio[~validM] = np.inf
-        cmp_ = dense7[:, :, 5]
-        cnp = dense7[:, :, 6]
-        # scoreForTaskGroup's crowding penalty is static per pass (the
-        # reference reads set_preemptions' counts, never its own picks)
-        penalty = np.where((cmp_ > 0) & (cnp >= cmp_),
-                          (cnp + 1.0 - cmp_) * MAX_PARALLEL_PENALTY, 0.0)
-
-        # -- greedy closest-distance selection, all nodes in lockstep --
-        needed = np.tile(ask3, (M, 1))
-        avail = remaining0[rows_arr].copy()
-        selected = np.zeros((M, C), bool)
-        order = np.full((M, C), C + 1, np.int64)
-        okM = np.zeros(M, bool)
-        alive = np.arange(M)
-        step = 0
-        while alive.size:
-            sub_valid = validM[alive] & ~selected[alive]
-            has = sub_valid.any(axis=1)
-            if not has.all():
-                alive = alive[has]      # exhausted, ask unmet: no fit
-                if not alive.size:
-                    break
-                sub_valid = validM[alive] & ~selected[alive]
-            # band = the lowest priority still unselected; the
-            # reference consumes each ascending group to exhaustion
-            prio_m = np.where(sub_valid, cprio[alive], np.inf)
-            band = prio_m.min(axis=1)
-            in_band = prio_m == band[:, None]
-            # basic_resource_distance with ask = the running `needed`
-            # (sum order mirrors the scalar: mem² + cpu², then disk²)
-            nd3 = needed[alive][:, None, :]             # [k, 1, 3]
-            pos = nd3 > 0.0
-            t3 = np.where(pos, (nd3 - c3[alive]) / np.where(pos, nd3, 1.0),
-                          0.0)
-            t3 = t3 * t3
-            dist = np.sqrt(t3[:, :, 1] + t3[:, :, 0] + t3[:, :, 2]) \
-                + penalty[alive]
-            dist = np.where(in_band, dist, np.inf)
-            # argmin keeps the first minimum — the scalar loop's strict
-            # `dist < best_dist` tie-break over proposed order
-            pick = dist.argmin(axis=1)
-            selected[alive, pick] = True
-            order[alive, pick] = step
-            pv3 = c3[alive, pick]                       # [k, 3]
-            avail[alive] += pv3
-            needed[alive] -= pv3
-            met = (avail[alive] >= ask3).all(axis=1)
-            okM[alive[met]] = True
-            alive = alive[~met]
-            step += 1
-
-        # -- superset drop + scoring for the feasible nodes ------------
-        F = np.nonzero(okM)[0]
-        fail = np.nonzero(~okM)[0]
-        for m in fail:
-            p = rows[int(m)]
-            i = idxs[p]
-            self._memoize(i, None, 0.0, 0.0, None, cacheable[p],
-                          has_mp[p])
-            self._record(i, None, 0.0)
-        if not F.size:
-            return overflow
-
-        # filterSuperset sorts by distance-to-ask DESC, stable over the
-        # selection order (Python's stable sorted + reverse=True):
-        # stable-argsort by selection order first, then stable-argsort
-        # the gathered negated distances
-        cF = c3[F]
-        posF = cF > 0.0
-        f3 = np.where(posF, (cF - ask3) / np.where(posF, cF, 1.0), 0.0)
-        f3 = f3 * f3
-        dfull = np.sqrt(f3[:, :, 1] + f3[:, :, 0] + f3[:, :, 2])
-        selF = selected[F]
-        ordF = np.where(selF, order[F], np.iinfo(np.int64).max)
-        k1 = np.argsort(ordF, axis=1, kind="stable")
-        negd1 = np.take_along_axis(np.where(selF, -dfull, np.inf), k1,
-                                   axis=1)
-        k2 = np.argsort(negd1, axis=1, kind="stable")
-        perm = np.take_along_axis(k1, k2, axis=1)
-        sel_s = np.take_along_axis(selF, perm, axis=1)
-
-        # prefix cumulative sums ARE the reference's sequential
-        # available.add walk (int-valued floats: exact either way)
-        sorted4 = np.where(sel_s[:, :, None],
-                           np.take_along_axis(c4[F], perm[:, :, None],
-                                              axis=1), 0.0)
-        cum4 = np.cumsum(sorted4, axis=1)
-        availF = remaining0[rows_arr][F]
-        met_pref = ((availF[:, None, :] + cum4[:, :, 0:3]
-                     >= ask3).all(axis=2) & sel_s)
-        nvict = selF.sum(axis=1)
-        any_met = met_pref.any(axis=1)
-        keep = np.where(any_met, met_pref.argmax(axis=1) + 1, nvict)
-
-        fr = np.arange(F.size)
-        freed4 = cum4[fr, keep - 1]
-
-        # ScoreFitBinPack over the post-eviction utilization + the ask
-        all3 = all_usage[rows_arr][F]
-        capF = cap3[rows_arr][F]
-        util_cpu = all3[:, 0] - freed4[:, 0] + ask3[0]
-        util_mem = all3[:, 1] - freed4[:, 1] + ask3[1]
-        node_cpu = capF[:, 0]
-        node_mem = capF[:, 1]
-        free_cpu = np.where(node_cpu != 0.0,
-                            1.0 - util_cpu / np.where(node_cpu != 0.0,
-                                                      node_cpu, 1.0), 0.0)
-        free_mem = np.where(node_mem != 0.0,
-                            1.0 - util_mem / np.where(node_mem != 0.0,
-                                                      node_mem, 1.0), 0.0)
-        total = np.power(10.0, free_cpu) + np.power(10.0, free_mem)
-        binpack = np.minimum(18.0, np.maximum(0.0, 20.0 - total)) / 18.0
-
-        # netPriority + the logistic preemption score over the KEPT set
-        pr_s = np.where(sel_s,
-                        np.take_along_axis(cprio[F], perm, axis=1), 0.0)
-        kept = (np.arange(C)[None, :] < keep[:, None]) & sel_s
-        mx = np.max(np.where(kept, pr_s, 0.0), axis=1)
-        tot = np.sum(np.where(kept, pr_s, 0.0), axis=1)
-        netp = np.where(mx != 0.0,
-                        mx + tot / np.where(mx != 0.0, mx, 1.0), 0.0)
-        pscore = 1.0 / (1.0 + np.exp(0.0048 * (netp - 2048.0)))
-        score = (binpack + pscore) / 2.0
-
-        perm_l = perm.tolist()
-        keep_l = keep.tolist()
-        for f, m in enumerate(F.tolist()):
-            p = rows[m]
-            i = idxs[p]
-            al = cand_allocs[p]
-            victims = [al[c] for c in perm_l[f][:keep_l[f]]]
-            lg = float(pscore[f])
-            fr4 = freed4[f]
-            self._logistic[i] = lg
-            self._freed[i] = fr4
-            self._memoize(i, victims, float(score[f]), lg, fr4,
-                          cacheable[p], has_mp[p])
-            self._record(i, victims, float(score[f]))
-        return overflow
+            victims, score = self._evaluate_node(
+                i, used_h[i], current, stopped_ids)
+            self._record(i, victims, score)
+            PREEMPT_STATS["fallback_nodes"] += 1
+            if victims:
+                overrides[i] = (self._logistic[i], score, self._freed[i])
 
     # -- entry ---------------------------------------------------------
-    def find_placement(self, used) -> Optional[Tuple[int, List[Allocation],
-                                                     float]]:
-        """Best (node_idx, victims, score) for one failed instance, or
-        None. `used` is the current proposed usage [N, D]."""
-        current = self._preempted_now()
-        self._invalidate_dirty(current)
-
-        fits = np.all(used + np.asarray(self.ask_vec)[None, :]
-                      <= self.table.capacity + 1e-6, axis=1)
-        candidates = self.mask & ~fits
-        pending = np.nonzero(candidates & ~self._known)[0]
-        if len(pending):
-            self._evaluate_pending(pending, used, current)
-        masked = np.where(candidates & self._known, self._scores, -1.0)
-        best_i = int(np.argmax(masked))
-        if masked[best_i] < 0:
-            return None
-        return best_i, self._victims[best_i], float(masked[best_i])
-
-    def columns(self, used, extra_candidates=None
-                ) -> Tuple["np.ndarray", "np.ndarray"]:
-        """Kernel competition columns (rank.go:415-448): for every
-        masked node that doesn't fit but CAN fit after evictions,
-        (logistic preemption score, freed resources). `used` rows for
-        those nodes should be reduced by `freed` before the kernel so
-        fit and binpack reflect the post-eviction node."""
+    def _candidates(self, used, extra_candidates=None):
+        """(candidate rows bool[N], after the pending ones among them
+        are resolved on the host)."""
         current = self._preempted_now()
         self._invalidate_dirty(current)
         fits = np.all(used + np.asarray(self.ask_vec)[None, :]
@@ -1028,20 +845,77 @@ class PreemptionRound:
         pending = np.nonzero(candidates & ~self._known)[0]
         if len(pending):
             self._evaluate_pending(pending, used, current)
+        return candidates
+
+    def find_placement(self, used) -> Optional[Tuple[int, List[Allocation],
+                                                     float]]:
+        """Best (node_idx, victims, score) for one failed instance, or
+        None. `used` is the current proposed usage [N, D]."""
+        candidates = self._candidates(used)
+        masked = np.where(candidates & self._known, self._scores, -1.0)
+        best_i = int(np.argmax(masked))
+        if masked[best_i] < 0:
+            return None
+        return best_i, self._victims[best_i], float(masked[best_i])
+
+    def columns(self, used, extra_candidates=None
+                ) -> Tuple["np.ndarray", "np.ndarray"]:
+        """Kernel competition columns on the HOST (rank.go:415-448):
+        for every masked node that doesn't fit but CAN fit after
+        evictions, (logistic preemption score, freed resources). `used`
+        rows for those nodes should be reduced by `freed` before the
+        kernel so fit and binpack reflect the post-eviction node. What
+        a select takes when device_columns() cannot serve it."""
+        candidates = self._candidates(used, extra_candidates)
         ok = candidates & self._known & (self._scores >= 0)
+        self._evicting = ok
         d = used.shape[1]
         pre_score = np.where(ok, self._logistic, 0.0).astype(np.float32)
         freed = np.where(ok[:, None], self._freed[:, :d],
                          0.0).astype(np.float32)
         return pre_score, freed
 
+    def device_columns(self, proposed):
+        """The same columns left ON THE DEVICE for the select that
+        follows (ops/victims.VictimSelection: `used_after`, `pre_score`,
+        `capacity`), or None when this round cannot run the program
+        (device / port / bandwidth asks, the kill switch, a plan that
+        already preempts from too many groups): the caller then takes
+        columns(). The winners' victims reach the host by resolve()."""
+        if not self._columnar:
+            return None
+        t0 = time.perf_counter()
+        with stages.span("preempt") as sp:
+            sel = self._dispatch(proposed=proposed)
+            if sel is None:
+                sp.cancel()
+                return None
+            sp.note(nodes_scanned=self._scanned, victims=self._n_victims,
+                    rows_refreshed=self._rows_refreshed)
+        PREEMPT_STATS["select_s"] += time.perf_counter() - t0
+        self._selection = sel
+        self._evicting = np.zeros(len(self.table.nodes), bool)
+        return sel
+
+    def resolve(self, rows) -> None:
+        """After the select that device_columns() fed: fetch the slots
+        of the rows that won and keep their victims for victims_for()."""
+        rows = sorted({int(r) for r in rows if r >= 0})
+        sel = self._selection
+        if sel is None or not rows:
+            return
+        pre, picked = sel.winners(rows)
+        slots = self._vc.rows
+        for k, i in enumerate(rows):
+            if pre[k] <= 0:
+                continue            # the node had room as it was
+            self._evicting[i] = True
+            if i not in self._host:         # else: recorded by _dispatch
+                self._victims[i] = [slots[i][s] for s in picked[k]]
+
     def victims_for(self, idx: int):
+        """The victims whose eviction makes node `idx` fit, or None when
+        the columns last handed out gave it none."""
+        if not self._evicting[idx]:
+            return None
         return self._victims.get(idx)
-
-
-def find_preemption_placement(snapshot, table, mask, used, ask_vec, job,
-                              plan) -> Optional[Tuple[int, List[Allocation], float]]:
-    """One-shot wrapper over PreemptionRound (kept for callers that
-    only need a single placement)."""
-    return PreemptionRound(snapshot, table, mask, ask_vec, job,
-                           plan).find_placement(used)

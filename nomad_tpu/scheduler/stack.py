@@ -570,18 +570,26 @@ class PlacementEngine:
         """Place `count` instances of tg in one kernel dispatch. Returns
         one (RankedNode-or-None, metrics) pair per requested instance.
 
-        With a PreemptionRound, full nodes whose fit comes from evicting
-        lower-priority allocs compete in the same argmax (rank.go
-        :415-448 + PreemptionScoringIterator): their `used` rows are
-        reduced by the victims' resources and they carry the logistic
-        preemption scorer; victims are staged into the plan when such a
-        node wins."""
+        With a PreemptionRound — the caller's SECOND select, for the
+        instances a select without one found no node for (upstream's
+        selectNextOption) — full nodes whose fit comes from evicting
+        lower-priority allocs compete in the argmax (rank.go :415-448 +
+        PreemptionScoringIterator): their `used` rows are reduced by
+        the victims' resources and they carry the logistic preemption
+        scorer; victims are staged into the plan when such a node
+        wins."""
         assert self.table is not None and self.job is not None
         start = time.monotonic_ns()
         with stages.span("select_prep"):
             req, prep = self._select_request(tg, count, proposed, options,
                                              preemption_round)
-        res = self.dispatch(req)
+        if req.victims is not None:
+            # the victims' program left this request's columns on the
+            # device: straight to the kernel, no park for companions
+            res = self.kernel.select(req)
+            preemption_round.resolve(res.node_idx[:count])
+        else:
+            res = self.dispatch(req)
         elapsed = time.monotonic_ns() - start
         with stages.span("select_finish"):
             return self._ranked_nodes(tg, res, proposed, preemption_round,
@@ -731,7 +739,10 @@ class PlacementEngine:
 
         used_arr = proposed.used()
         pre_score = None
-        if preemption_round is not None:
+        victims = None
+        if preemption_round is not None and self.kernel.single_device():
+            victims = preemption_round.device_columns(proposed)
+        if preemption_round is not None and victims is None:
             extra = None
             if dev_slots is not None:
                 extra = dev_slots < 1.0
@@ -763,7 +774,7 @@ class PlacementEngine:
         # rows falls back to dense shipping.
         table_ref = None
         used_rows = used_deltas = None
-        if pre_score is None and proposed.table is t:
+        if pre_score is None and victims is None and proposed.table is t:
             table_ref = t
             used_rows, used_deltas = proposed.used_sparse()
 
@@ -831,18 +842,19 @@ class PlacementEngine:
             used_base_deltas=used_deltas,
             feas_token=feas_token,
             feas_residue=feas_residue,
+            victims=victims,
         )
         if couples_nodes(req):
             self.coupled = True
         return req, (count, count_requested, csi_cap_source,
                      filtered_counts, dev_asks, dyn_ports, reserved_ports,
-                     pre_score)
+                     pre_score is not None or victims is not None)
 
     def _ranked_nodes(self, tg: TaskGroup, res, proposed: ProposedIndex,
                       preemption_round, elapsed: int, count: int,
                       count_requested: int, csi_cap_source: str,
                       filtered_counts: Dict[str, int], dev_asks,
-                      dyn_ports: int, reserved_ports, pre_score,
+                      dyn_ports: int, reserved_ports, preempting: bool,
                       ) -> List[Tuple[Optional[RankedNode], AllocMetric]]:
         """The `select_finish` stage: one (RankedNode-or-None, metrics)
         pair per requested instance from the kernel's result."""
@@ -923,8 +935,7 @@ class PlacementEngine:
             # assignment (they free ports/devices too)
             victims = None
             saved_net = saved_dev = None
-            if pre_score is not None and pre_score[idx] > 0 \
-                    and idx not in staged_victims:
+            if preempting and idx not in staged_victims:
                 victims = preemption_round.victims_for(idx)
                 if victims:
                     staged_victims.add(idx)

@@ -27,7 +27,7 @@ import numpy as np
 
 from ..models import (
     Allocation, Evaluation, Job, Node,
-    EVAL_STATUS_FAILED, EVAL_STATUS_PENDING,
+    EVAL_STATUS_CANCELED, EVAL_STATUS_FAILED, EVAL_STATUS_PENDING,
     JOB_STATUS_PENDING, JOB_STATUS_RUNNING,
     JOB_TYPE_CORE, JOB_TYPE_SERVICE, JOB_TYPE_SYSTEM,
     NODE_STATUS_DOWN, NODE_STATUS_READY,
@@ -422,6 +422,7 @@ class Server:
         self._raft_l = make_rlock()
         self._raft_index = 10
         self.eval_broker = EvalBroker()
+        self.eval_broker.on_superseded = self.cancel_evals
         # backpressure escalation threshold lives on the broker even
         # with the governor off — the HTTP register path reads it
         self.eval_broker.delayed_depth_high = \
@@ -732,6 +733,9 @@ class Server:
                      lambda: broker.stats.total_waiting)
         gov.register("broker.shed", lambda: broker.stats.total_shed,
                      suspect=False)  # monotone counter, not a structure
+        gov.register("broker.redelivered",
+                     lambda: broker.stats.total_redelivered,
+                     suspect=False)  # monotone counter too
         gov.register("blocked_evals.blocked",
                      self.blocked_evals.blocked_count)
         gov.register("plan_queue.depth", self.plan_queue.depth,
@@ -1961,6 +1965,20 @@ class Server:
             self.eval_broker.enqueue(ev)
         elif ev.should_block():
             self.blocked_evals.block(ev)
+
+    def cancel_evals(self, evals: List[Evaluation]) -> None:
+        """Write back as canceled the waiting evals the broker shed
+        when a later eval of their job superseded them
+        (EvalBroker._shed_superseded): one raft entry for the lot."""
+        out = []
+        for ev in evals:
+            gone = ev.copy()
+            gone.status = EVAL_STATUS_CANCELED
+            gone.status_description = (
+                "canceled: a later preemption eval of the job is pending "
+                "and reconciles for this one's evictions too")
+            out.append(gone)
+        self.raft_apply("eval_update", dict(evals=out))
 
     def _unblock_enqueue(self, ev: Evaluation) -> None:
         """Blocked eval woken: back to pending + broker."""

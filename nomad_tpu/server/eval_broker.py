@@ -14,6 +14,7 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 from ..models import Evaluation, JOB_TYPE_CORE
+from ..models.evaluation import TRIGGER_PREEMPTION
 from ..utils.ids import generate_uuid
 from ..utils.locks import make_condition
 
@@ -77,12 +78,17 @@ class BrokerStats:
         self.total_blocked = 0
         self.total_waiting = 0
         self.total_shed = 0     # admission-control deferrals (governor)
+        # deliveries that RAN OUT: the nack timer fired while a worker
+        # still held the eval, which went back to ready (the holder's
+        # next plan then fails its token: plan_applier._check_token)
+        self.total_redelivered = 0
 
     def as_dict(self):
         return {"ready": self.total_ready, "unacked": self.total_unacked,
                 "blocked": self.total_blocked,
                 "waiting": self.total_waiting,
-                "shed": self.total_shed}
+                "shed": self.total_shed,
+                "redelivered": self.total_redelivered}
 
 
 class EvalBroker:
@@ -99,6 +105,11 @@ class EvalBroker:
         self._enabled = False
         self._ready: Dict[str, _PQ] = {}               # queue -> heap
         self._unack: Dict[str, _Unack] = {}            # eval id -> unack
+        # waiting evals a later one of their job superseded
+        # (_shed_superseded), until the server marks them canceled
+        self._cancelable: List[Evaluation] = []
+        # Server.cancel_evals, once a server owns this broker
+        self.on_superseded = None
         self._evals: Dict[str, int] = {}               # eval id -> dequeues
         self._job_evals: Dict[Tuple[str, str], str] = {}   # (ns,job)->eval id
         self._blocked: Dict[Tuple[str, str], _PQ] = {} # per-job pending heaps
@@ -174,6 +185,7 @@ class EvalBroker:
             self._evals.clear()
             self._job_evals.clear()
             self._blocked.clear()
+            self._cancelable = []
             self._requeue.clear()
             self._time_wait.clear()
             self._delayed.clear()
@@ -392,8 +404,10 @@ class EvalBroker:
         ev.broker_wait_s = max(
             ev.queue_wait_s,
             now - (getattr(ev, "_entered_broker_t", None) or now))
+        ev._dequeued_t = now        # the delivery's clock (worker.py)
         token = generate_uuid()
-        timer = threading.Timer(self.nack_timeout_s, self.nack,
+        timer = threading.Timer(self.nack_timeout_s,
+                                self._delivery_ran_out,
                                 args=(ev.id, token))
         timer.daemon = True
         timer.start()
@@ -410,6 +424,14 @@ class EvalBroker:
             return unack.token if unack else None
 
     def ack(self, eval_id: str, token: str) -> None:
+        self._ack(eval_id, token)
+        # outside the lock: the waiting evals this ack superseded go
+        # to the server, which writes them back canceled
+        shed = self.take_cancelable() if self._cancelable else None
+        if shed and self.on_superseded is not None:
+            self.on_superseded(shed)
+
+    def _ack(self, eval_id: str, token: str) -> None:
         with self._l:
             try:
                 unack = self._unack.get(eval_id)
@@ -425,6 +447,7 @@ class EvalBroker:
                 self._job_evals.pop(key, None)
                 blocked = self._blocked.get(key)
                 if blocked is not None and len(blocked):
+                    self._shed_superseded(blocked)
                     ev = blocked.pop()
                     if not len(blocked):
                         del self._blocked[key]
@@ -435,6 +458,49 @@ class EvalBroker:
                     self._process_enqueue(requeued, "")
             finally:
                 self._requeue.pop(token, None)
+
+    def _shed_superseded(self, waiting: "_PQ") -> None:
+        """(lock held, as a job's eval is acked) Of the job's waiting
+        evals that a preemption triggered, only the LATEST has anything
+        left to do: each asks for the same thing — reconcile the job
+        against the state as it stands when a worker takes it — and the
+        latest will be taken after every eviction the earlier ones were
+        made for has committed. The others are taken out of the broker
+        and handed to the server to mark canceled (on_superseded), as
+        upstream's broker sheds all but the latest pending eval of a
+        job (eval_broker.go `cancelable`, reapCancelableEvaluations). A
+        fleet that evicts a thousand allocations of one batch job in a
+        minute otherwise queues a thousand whole reconciles of it."""
+        pre = [t for t in waiting._h
+               if t[3].triggered_by == TRIGGER_PREEMPTION]
+        if len(pre) < 2:
+            return
+        keep = max(pre, key=lambda t: (t[1], t[2]))
+        drop = {t[2] for t in pre if t is not keep}
+        for t in pre:
+            if t[2] in drop:
+                self._evals.pop(t[3].id, None)
+                self._cancelable.append(t[3])
+        self.stats.total_blocked -= len(drop)
+        waiting._h = [t for t in waiting._h if t[2] not in drop]
+        heapq.heapify(waiting._h)
+
+    def take_cancelable(self) -> List[Evaluation]:
+        """The evals _shed_superseded took out since the last call
+        (ack hands them to `on_superseded`)."""
+        with self._l:
+            out, self._cancelable = self._cancelable, []
+        return out
+
+    def _delivery_ran_out(self, eval_id: str, token: str) -> None:
+        """The nack timer's target: the holder neither acked nor nacked
+        inside nack_timeout_s. Counted (stats `redelivered`), then an
+        ordinary nack."""
+        with self._l:
+            unack = self._unack.get(eval_id)
+            if unack is not None and unack.token == token:
+                self.stats.total_redelivered += 1
+        self.nack(eval_id, token)
 
     def nack(self, eval_id: str, token: str,
              delay_s: Optional[float] = None) -> None:

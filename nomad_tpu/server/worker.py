@@ -560,6 +560,9 @@ class EvalLane:
         self.eval = ev
         self.token = token
         self.snapshot_index = 0
+        # monotonic time the last plan's answer came back (the eval's
+        # delivery has to outlast it: process_eval's `delivery_s`)
+        self.last_plan_t: Optional[float] = None
 
     # -- Planner interface --------------------------------------------
     def submit_plan(self, plan: Plan) -> Optional[PlanResult]:
@@ -576,6 +579,7 @@ class EvalLane:
         with stages.span("plan_submit") as sp:
             future = self.server.plan_queue.enqueue(plan)
             result: PlanResult = future.result(timeout=30)
+            self.last_plan_t = time.monotonic()
             if chaos_faults.ACTIVE:
                 # chaos hook (ISSUE 15): the plan IS committed at this
                 # point but the eval is not acked — an armed
@@ -870,8 +874,15 @@ class Worker:
                 if ev.type == JOB_TYPE_CORE:
                     sched.process(ev)
                 else:
-                    with stages.span("sched_host"):
+                    with stages.span("sched_host") as sp:
                         sched.process(ev)
+                        dequeued = getattr(ev, "_dequeued_t", None)
+                        if lane.last_plan_t is not None \
+                                and dequeued is not None:
+                            # dequeue -> the last plan's answer: what
+                            # the broker's nack timer has to outlast
+                            sp.note(delivery_s=round(
+                                lane.last_plan_t - dequeued, 4))
                     self_s = trace.uncovered_s(tr, "sched_host")
                     if self_s is not None:
                         # what no span of this eval's tree names

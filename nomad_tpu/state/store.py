@@ -2290,10 +2290,16 @@ class StateStore(StateSnapshot):
             return None
         if job.stop:
             return JOB_STATUS_DEAD
-        allocs = self.allocs_by_job(namespace, job_id)
-        for a in allocs:
-            if not a.terminal_status():
-                return JOB_STATUS_RUNNING
+        # walked off the index, not off allocs_by_job's list: the first
+        # live allocation answers, and a job of 20,000 allocations is
+        # asked on every plan and every eval update that names it
+        ids = self._root.table("allocs_by_job").get((namespace, job_id))
+        allocs = bool(ids)
+        if ids:
+            table = self._root.table("allocs")
+            for i in ids.keys():
+                if not table[i].terminal_status():
+                    return JOB_STATUS_RUNNING
         evals = self.evals_by_job(namespace, job_id)
         has_eval = False
         for e in evals:

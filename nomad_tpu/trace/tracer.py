@@ -114,7 +114,8 @@ STAGE_PARENTS: Dict[str, Optional[str]] = {
     "snapshot_write": None, "gc_full": None, "gc_whole_walk": None,
     "queue_wait": "eval", "fence_wait": "eval", "table_build": "eval",
     "h2d": "table_build", "sched_host": "eval", "broker_ack": "eval",
-    "reconcile": "sched_host", "preempt": "sched_host",
+    "reconcile": "sched_host", "preempt": "select_prep",
+    "preempt_gather": "preempt", "preempt_kernel": "preempt",
     "table_build_private": "sched_host",
     "select_prep": "sched_host", "feasibility": "select_prep",
     "mask_build": "feasibility", "spread_inputs": "select_prep",
@@ -139,7 +140,8 @@ STAGE_PARENTS: Dict[str, Optional[str]] = {
 # its own thread's context round that call.
 AMBIENT_STAGES = frozenset({
     "restore", "wal_replay", "fence_wait", "sched_host", "reconcile",
-    "preempt", "table_build", "h2d", "table_build_private",
+    "preempt", "preempt_gather", "preempt_kernel",
+    "table_build", "h2d", "table_build_private",
     "select_prep", "feasibility", "mask_build", "spread_inputs",
     "kernel_pack", "kernel", "d2h", "kernel_expand", "select_finish",
     "port_assign", "plan_build", "plan_submit",

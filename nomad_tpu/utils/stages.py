@@ -41,9 +41,6 @@ same tree; a stage's time lies inside its parent's):
       reconcile     alloc-diff host phase: alloc fetch + tainted split
                     + AllocReconciler.compute + result staging (attr
                     columnar)
-      preempt       victim selection across candidate nodes
-                    (scheduler/preemption.py; attrs nodes_scanned,
-                    victims)
       table_build_private  the private full build a snapshot OLDER
                     than the cache pays under the cache lock
       select_prep   select_batch entry -> just before the dispatch:
@@ -57,6 +54,16 @@ same tree; a stage's time lies inside its parent's):
         spread_inputs the affinity column and the spreads' kernel state
                       (value codes, proposed counts, desired counts);
                       only for a group that has an affinity or a spread
+        preempt       victim selection across every candidate node, for
+                      the second select of an eval that found no room
+                      (scheduler/preemption.py; attrs nodes_scanned,
+                      victims, rows_refreshed: the rows of the victims'
+                      columns re-derived to reach this table version)
+          preempt_gather  the host's share: the resident columns brought
+                          up to date, the slots the plan takes out, rows
+                          wider than the columns on the per-node path
+          preempt_kernel  the victims' program (ops/victims.py)
+                          dispatched and its counters fetched
       gateway_wait  the request parked in the micro-batch gateway
                     (attrs trigger, batch, lanes)
       kernel_pack   pack_request / stacking / argument placement: what
@@ -136,7 +143,8 @@ from .locks import make_lock
 STAGES = ("restore", "wal_replay", "job_register", "snapshot_write",
           "gc_full", "gc_whole_walk",
           "queue_wait", "fence_wait", "sched_host", "reconcile",
-          "preempt", "table_build", "h2d", "table_build_private",
+          "preempt", "preempt_gather", "preempt_kernel",
+          "table_build", "h2d", "table_build_private",
           "select_prep", "feasibility", "mask_build", "spread_inputs",
           "gateway_wait", "kernel_pack", "kernel", "d2h",
           "kernel_expand", "select_finish", "port_assign",

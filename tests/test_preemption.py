@@ -180,13 +180,15 @@ def test_system_preemption_enabled_by_default():
     assert placed[0].preempted_allocations
 
 
-def test_mixed_competition_preempting_node_can_win():
-    """rank.go:415-448 semantics: a full node whose post-eviction
-    binpack + logistic preemption score beats an empty node's plain
-    binpack score wins the SAME selection. A low-priority filler on a
-    node leaves it 'full'; the empty node has a weak (nearly empty)
-    binpack score; the preempting node scores (binpack-after-evict +
-    ~1.0 logistic)/2, which is higher."""
+def test_room_first_then_the_preempting_node_wins():
+    """Upstream selectNextOption: a select without preemption first,
+    and eviction only for what found no node. A low-priority filler
+    leaves one node full and the other is empty: the first service
+    instance takes the empty node and evicts nothing, although the full
+    node after an eviction would score higher ((binpack-after-evict
+    ~0.77 + logistic ~1.0) / 2 against a near-zero binpack: until PR 34
+    it won the SAME selection, and the fleet evicted while it had
+    room). The second instance fits nowhere, and lands by evicting."""
     from nomad_tpu import mock
     from nomad_tpu.models import (Evaluation, EVAL_STATUS_PENDING,
                                   TRIGGER_JOB_REGISTER)
@@ -227,33 +229,37 @@ def test_mixed_competition_preempting_node_can_win():
                          for allocs in p.node_allocation.values()
                          for a in allocs][0].node_id
 
-    # also occupy the other node slightly so its binpack score is low
-    # (near-empty binpack score ~ (20-2*10^~1)/18 ~ 0)
-    hi = mock.job()
-    hi.id = "hi"
-    hi.priority = 80
-    tg = hi.task_groups[0]
-    tg.count = 1
-    for t in tg.tasks:
-        t.resources.networks = []
-        t.resources.cpu = 2000
-        t.resources.memory_mb = 4000
-    tg.networks = []
-    h.store.upsert_job(h.next_index(), hi)
-    ev2 = Evaluation(id=generate_uuid(), namespace="default", priority=80,
-                     triggered_by=TRIGGER_JOB_REGISTER, job_id=hi.id,
-                     status=EVAL_STATUS_PENDING, type="service")
-    h.process("service", ev2)
-    plan = h.plans[-1]
-    placed = [a for allocs in plan.node_allocation.values() for a in allocs]
-    assert len(placed) == 1
-    preempted = [a for allocs in plan.node_preemptions.values()
-                 for a in allocs]
-    # the preempting node must win: (binpack-after-evict ~0.77 +
-    # logistic ~1.0)/2 ~ 0.88 beats the empty node's near-zero binpack
-    assert placed[0].node_id == filler_alloc_node
+    def place(job_id):
+        hi = mock.job()
+        hi.id = job_id
+        hi.priority = 80
+        tg = hi.task_groups[0]
+        tg.count = 1
+        for t in tg.tasks:
+            t.resources.networks = []
+            t.resources.cpu = 2000
+            t.resources.memory_mb = 4000
+        tg.networks = []
+        h.store.upsert_job(h.next_index(), hi)
+        ev2 = Evaluation(id=generate_uuid(), namespace="default",
+                         priority=80, triggered_by=TRIGGER_JOB_REGISTER,
+                         job_id=hi.id, status=EVAL_STATUS_PENDING,
+                         type="service")
+        h.process("service", ev2)
+        plan = h.plans[-1]
+        placed = [a for allocs in plan.node_allocation.values()
+                  for a in allocs]
+        assert len(placed) == 1
+        return placed[0], [a for allocs in plan.node_preemptions.values()
+                           for a in allocs]
+
+    first, preempted = place("hi")
+    assert first.node_id != filler_alloc_node and not preempted
+    assert not first.preempted_allocations
+    second, preempted = place("hi2")
+    assert second.node_id == filler_alloc_node
     assert len(preempted) == 1
-    assert placed[0].preempted_allocations == [preempted[0].id]
+    assert second.preempted_allocations == [preempted[0].id]
 
 
 def _dev_holder(node, prio, instance_ids, job_id="holder"):

@@ -920,7 +920,7 @@ def test_recorder_appends_a_bounded_count_of_tuples_per_eval(
     twin below is marked slow.)"""
     from nomad_tpu.trace.tracer import SPAN_EST_BYTES, TRACE_EST_BYTES
 
-    assert SPANS_PER_EVAL_MAX == 29
+    assert SPANS_PER_EVAL_MAX == 31
     for t in served["traces"]:
         names = collections.Counter(s["name"] for s in t["spans"])
         assert set(names) <= {n for n, p in STAGE_PARENTS.items()
@@ -929,8 +929,8 @@ def test_recorder_appends_a_bounded_count_of_tuples_per_eval(
                    for n, c in names.items()), names
         assert 20 <= len(t["spans"]) <= SPANS_PER_EVAL_MAX
         assert "truncated_spans" not in t
-    # what such an eval holds of the ring (4 MiB: 782 of the largest)
-    assert TRACE_EST_BYTES + SPAN_EST_BYTES * SPANS_PER_EVAL_MAX == 5360
+    # what such an eval holds of the ring (4 MiB: 734 of the largest)
+    assert TRACE_EST_BYTES + SPAN_EST_BYTES * SPANS_PER_EVAL_MAX == 5712
 
     tr = _mk_eval_trace("ev-count")
     before, n0 = tracer.stats["spans"], len(tr._raw)
