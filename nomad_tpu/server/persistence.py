@@ -31,7 +31,8 @@ from ..models.alloc import DesiredTransition
 from ..models.deployment import DeploymentStatusUpdate
 from ..models.node import DrainStrategy
 from ..utils import stages
-from ..utils.codec import ShareMemo, from_wire, to_wire
+from ..utils.codec import (ShareMemo, from_wire, rows_from_wire,
+                           rows_to_wire, to_wire)
 from ..utils.locks import make_lock
 
 # payload field -> model type (list-wrapped == repeated)
@@ -78,6 +79,10 @@ SCHEMAS: Dict[str, Dict[str, Any]] = {
 # the entries the plan applier commits (stage wal_encode times their
 # framing, inside plan_commit)
 PLAN_ENTRIES = frozenset({"plan_results", "plan_group_results"})
+# a plan's allocation lists: written as one record of constants, a
+# table of shared objects and rows (utils/codec.py rows_to_wire)
+PLAN_ROW_LISTS = frozenset(k for k, hint in SCHEMAS["plan_results"].items()
+                           if hint == [Allocation])
 
 
 def _register_acl_schemas() -> None:
@@ -123,10 +128,19 @@ def encode_payload(msg_type: str, payload: dict,
     object reached twice is walked once and its subtree reused by
     reference (utils/codec.py ShareMemo) — a plan's 1,000 allocations
     hang off one AllocatedResources and a few AllocMetrics, and the
-    bytes are what a walk of every occurrence gives. The memo never
-    outlives the call; a caller passes its own to read the counts."""
+    bytes are what a walk of every occurrence gives. A plan_results
+    entry (a member of a plan_group_results entry too) writes each of
+    its lists of Allocations as rows_to_wire's record, not as a list of
+    dicts: the plan's job, which the applier hangs on every placement,
+    is packed once. The memo never outlives the call; a caller passes
+    its own to read the counts."""
     if memo is None:
         memo = ShareMemo()
+    if msg_type == "plan_results":
+        return {k: rows_to_wire(v, memo)
+                if k in PLAN_ROW_LISTS and type(v) is list
+                and set(map(type, v)) <= {Allocation}
+                else to_wire(v, memo) for k, v in payload.items()}
     if msg_type == "plan_group_results":
         return {"groups": [encode_payload("plan_results", g, memo)
                            for g in payload.get("groups", [])]}
@@ -139,6 +153,9 @@ def encode_payload(msg_type: str, payload: dict,
 
 
 def decode_payload(msg_type: str, data: dict) -> dict:
+    """The payload a frame's wire form stands for. A repeated field
+    decodes from a list (every entry written before PR 35, and every
+    kind but a plan's since) or from rows_to_wire's record."""
     if msg_type == "plan_group_results":
         return {"groups": [decode_payload("plan_results", g)
                            for g in data.get("groups", [])]}
@@ -152,7 +169,8 @@ def decode_payload(msg_type: str, data: dict) -> dict:
         if hint is None:
             out[k] = v
         elif isinstance(hint, list):
-            out[k] = [from_wire(hint[0], x) for x in (v or [])]
+            out[k] = rows_from_wire(hint[0], v) if isinstance(v, dict) \
+                else [from_wire(hint[0], x) for x in (v or [])]
         else:
             out[k] = from_wire(hint, v) if v is not None else None
     return out
@@ -199,9 +217,9 @@ class RaftLog:
             self._f = None
 
     def append(self, index: int, msg_type: str, payload: dict,
-               sync: bool = False) -> Tuple[int, int]:
+               sync: bool = False) -> Tuple[int, int, int]:
         """Frame and write one entry; returns the encoder's counts
-        (ShareMemo's objects, shared). A plan entry reports the
+        (ShareMemo's objects, shared, rows). A plan entry reports the
         framing (wire form + packb, not the write) as stage
         wal_encode."""
         memo = ShareMemo()
@@ -212,6 +230,7 @@ class RaftLog:
                  "p": encode_payload(msg_type, payload, memo)},
                 use_bin_type=True)
             sp.note(objects=memo.objects, shared=memo.shared,
+                    rows=memo.rows, consts=memo.consts, table=memo.table,
                     bytes=len(frame))
         with self._l:
             self._f.write(struct.pack("<I", len(frame)))
@@ -222,7 +241,7 @@ class RaftLog:
                 self._dirty = False
             else:
                 self._dirty = True
-        return memo.objects, memo.shared
+        return memo.objects, memo.shared, memo.rows
 
     def sync(self) -> None:
         """Group-fsync point: ONE fsync covers every append since the
@@ -397,8 +416,9 @@ class Persistence:
             "restore_s": 0.0, "restore_format": 0,
             # the WAL encoder (encode_payload): dataclass instances
             # walked, and subtrees reused because the payload reached
-            # the same object again
-            "wal_objects": 0, "wal_shared": 0,
+            # the same object again; allocations written as rows of a
+            # plan's record
+            "wal_objects": 0, "wal_shared": 0, "wal_rows": 0,
         }
         # server-level state (e.g. the GC TimeTable) rides along in the
         # snapshot under "extra"; the provider is set by the Server
@@ -475,12 +495,13 @@ class Persistence:
         return highest, entries
 
     def record(self, index: int, msg_type: str, payload: dict) -> None:
-        objects, shared = self.log.append(
+        objects, shared, rows = self.log.append(
             index, msg_type, payload,
             sync=self.wal_fsync and not self.wal_group_fsync)
         with self._stats_l:
             self.stats["wal_objects"] += objects
             self.stats["wal_shared"] += shared
+            self.stats["wal_rows"] += rows
 
     def commit_barrier(self) -> None:
         """Group-fsync boundary: called once per committed apply batch

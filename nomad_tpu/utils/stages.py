@@ -97,7 +97,8 @@ same tree; a stage's time lies inside its parent's):
                       transaction (the write half; attrs group, index)
           wal_encode    framing the plan's WAL record: wire form of
                         the payload + msgpack (server/persistence.py;
-                        attrs objects, shared, bytes)
+                        attrs objects, shared, rows, consts, table,
+                        bytes)
       sched_host_self  the part of sched_host no other span of the
                     eval's trace covers (union, not sum): scheduler
                     set-up, the eval-status write, thread hand-offs —

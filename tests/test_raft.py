@@ -291,3 +291,131 @@ def test_snapshot_reseed_of_fresh_follower():
                 s.shutdown()
             except Exception:
                 pass
+
+
+# -- a plan's rows cross the wire (ISSUE 35) --------------------------------
+# The leader encodes a plan entry's allocation lists as one record of
+# constants, a table and rows (utils/codec.rows_to_wire); whoever is handed
+# the entry, over AppendEntries or Raft.Forward, decodes what it encoded.
+
+_STORE_SET = ("create_index", "modify_index", "alloc_modify_index",
+              "create_time", "modify_time")
+
+
+def _rows_plan(tag):
+    """A node, a job and a plan of five placements that hang off ONE
+    job, ONE resource row and ONE metric, with a stop beside them."""
+    from nomad_tpu.models import Allocation
+    from nomad_tpu.models.alloc import AllocMetric
+    node = mock.node()
+    job = mock.job()
+    job.id = job.name = f"rows-{tag}"
+    res = mock.alloc().allocated_resources
+    metric = AllocMetric(nodes_evaluated=1, nodes_available={"dc1": 1})
+    placed = [Allocation(
+        id=f"rows-{tag}-{i}", eval_id=f"rows-{tag}-eval",
+        name=f"{job.id}.web[{i}]", node_id=node.id, node_name=node.name,
+        job_id=job.id, job=job, task_group="web",
+        allocated_resources=res, metrics=metric) for i in range(5)]
+    stop = Allocation(id=f"rows-{tag}-old", node_id=node.id, job_id=job.id,
+                      task_group="web", desired_status="stop",
+                      desired_description="alloc not needed")
+    return node, job, dict(allocs_stopped=[stop], allocs_placed=placed,
+                           allocs_preempted=[], deployment=None,
+                           deployment_updates=[], evals=[])
+
+
+def _assert_the_plan_landed(store, plan):
+    import dataclasses
+    got = [store.alloc_by_id(a.id) for a in plan["allocs_placed"]]
+    assert all(g is not None for g in got)
+    for g, want in zip(got, plan["allocs_placed"]):
+        for f in dataclasses.fields(want):
+            if f.name not in _STORE_SET:
+                assert getattr(g, f.name) == getattr(want, f.name), f.name
+    # one Job, one resource row, one metric under the five, as sent
+    for field in ("job", "allocated_resources", "metrics"):
+        assert len({id(getattr(g, field)) for g in got}) == 1, field
+    assert store.alloc_by_id(
+        plan["allocs_stopped"][0].id).desired_status == "stop"
+
+
+@pytest.fixture
+def lone_leader():
+    """One server that is its own quorum, on a real RPC port."""
+    s = Server(ServerConfig(num_schedulers=0, heartbeat_ttl_s=30.0))
+    r = RpcServer(s, port=0)
+    s.attach_raft(r, [r.addr])
+    r.start()
+    s.start()
+    assert _wait_for(s.raft.is_leader, timeout=10)
+    yield s, r
+    r.shutdown()
+    s.shutdown()
+
+
+def _follower_of(rpc_addr):
+    from nomad_tpu.server.raft import RaftNode
+    s = Server(ServerConfig(num_schedulers=0, heartbeat_ttl_s=30.0))
+    s.raft = RaftNode(s, "127.0.0.1:2", [rpc_addr, "127.0.0.1:2"])
+    s.raft.leader_addr = rpc_addr
+    return s
+
+
+def test_a_follower_decodes_the_plan_rows_the_leader_appended(lone_leader):
+    from nomad_tpu.rpc.codec import _default_backend
+    from nomad_tpu.server.persistence import decode_payload
+    leader, rpc = lone_leader
+    node, job, plan = _rows_plan("append")
+    leader.raft_apply("node_register", dict(node=node))
+    leader.raft_apply("job_register", dict(job=job, evals=[]))
+    index = leader.raft_apply("plan_results", plan)
+    _assert_the_plan_landed(leader.store, plan)
+
+    entries = [e for e in leader.raft.log if e[0] <= index]
+    enc = entries[-1][3]
+    assert entries[-1][2] == "plan_results"
+    assert enc["allocs_placed"]["rows"] == 5        # the new form
+    assert "job" in enc["allocs_placed"]["consts"]
+    dumps, loads = _default_backend()               # AppendEntries' wire
+    sent = loads(dumps({"entries": entries}))["entries"]
+    back = decode_payload("plan_results", sent[-1][3])
+    assert back == plan
+    assert len({id(a.job) for a in back["allocs_placed"]}) == 1
+
+    follower = _follower_of(rpc.addr)
+    try:
+        res = follower.raft._handle_append_entries({
+            "term": leader.raft.term, "leader": rpc.addr,
+            "prev_index": leader.raft.base_index,
+            "prev_term": leader.raft.base_term,
+            "entries": sent, "leader_commit": index})
+        assert res["success"]
+        for idx, _term, msg_type, payload in list(follower.raft.log):
+            follower.apply_replicated(idx, msg_type, payload)
+        assert follower._raft_index == index
+        _assert_the_plan_landed(follower.store, plan)
+        ours = {a.id: a for a in follower.store.allocs_by_job(
+            "default", job.id)}
+        theirs = {a.id: a for a in leader.store.allocs_by_job(
+            "default", job.id)}
+        assert ours == theirs and len(ours) == 6     # the stop too
+    finally:
+        follower.shutdown()
+
+
+def test_a_forwarded_plan_arrives_as_it_was_sent(lone_leader):
+    leader, rpc = lone_leader
+    node, job, plan = _rows_plan("forward")
+    leader.raft_apply("node_register", dict(node=node))
+    leader.raft_apply("job_register", dict(job=job, evals=[]))
+    follower = _follower_of(rpc.addr)
+    try:
+        index = follower.raft.forward_apply("plan_results", plan)
+        assert _wait_for(lambda: leader._raft_index >= index)
+        _assert_the_plan_landed(leader.store, plan)
+        assert follower.store.alloc_by_id(plan["allocs_placed"][0].id) \
+            is None
+    finally:
+        follower.raft.stop()
+        follower.shutdown()

@@ -484,12 +484,14 @@ def test_stage_is_reported_once_per_occurrence_under_its_parent(
             assert all(sp["attrs"]["refreshed"] in (True, False)
                        for sp in spans)
         if stage == "wal_encode":
-            # two placements off one flyweight: two Allocations and
-            # what they share, walked once
+            # two placements off one flyweight: two rows, and what
+            # they share written once for both (ISSUE 35)
             for sp in spans:
                 assert sp["track"] == "applier"
-                assert sp["attrs"]["objects"] >= 2
-                assert sp["attrs"]["shared"] >= 1
+                assert sp["attrs"]["rows"] >= 2
+                assert sp["attrs"]["consts"] >= 3
+                assert sp["attrs"]["objects"] >= 3
+                assert {"shared", "table"} <= set(sp["attrs"])
                 assert sp["attrs"]["bytes"] > 0
     if stage == "wal_encode":
         assert served["log"].opened_inside("wal_encode", "plan_commit") \

@@ -5,6 +5,14 @@ here to the walk it replaced, which stays below as the oracle: the
 frames of a live server are byte for byte what that walk and the same
 `packb` call give, a restart from them restores the live store, every
 model class encodes as before, and the sharing stays inside one call.
+
+Since ISSUE 35 a plan entry (`plan_results`, and each member of a
+`plan_group_results`) writes its three lists of allocations as one
+record of constants, a table of shared objects and rows
+(`utils/codec.rows_to_wire`). For those two kinds the oracle is what
+the old walk's frame DECODES to, field by field; every other kind
+keeps its frame byte for byte. A WAL of old frames, and one holding
+both forms, restore the live store.
 Counts and bytes only; nothing here reads a clock."""
 
 import dataclasses
@@ -38,7 +46,8 @@ from nomad_tpu.server import persistence
 from nomad_tpu.server.persistence import (PLAN_ENTRIES, decode_payload,
                                           encode_payload)
 from nomad_tpu.utils import stages
-from nomad_tpu.utils.codec import ShareMemo, to_wire
+from nomad_tpu.utils.codec import (ShareMemo, rows_from_wire, rows_to_wire,
+                                   to_wire)
 
 
 # -- the oracle: the walk this PR replaced, word for word ---------------
@@ -102,6 +111,60 @@ def _rows(table):
         else _canon(table)
 
 
+ALLOC_LISTS = ("allocs_stopped", "allocs_placed", "allocs_preempted")
+SHARED_FIELDS = ("job", "allocated_resources", "metrics")
+
+
+def _plans(msg_type, payload):
+    """The plan_results payloads of a plan entry, decoded or live."""
+    return payload["groups"] if msg_type == "plan_group_results" \
+        else [payload]
+
+
+def _distinct(msg_type, payload):
+    """Per member, list and shared field of a plan entry: how many
+    distinct objects (None apart) the list's allocations hold there."""
+    return [{(key, field): len({id(getattr(a, field))
+                                for a in plan.get(key) or []
+                                if getattr(a, field) is not None})
+             for key in ALLOC_LISTS for field in SHARED_FIELDS}
+            for plan in _plans(msg_type, payload)]
+
+
+def _assert_decodes_alike(msg_type, got_tree, want_tree, where):
+    """What two wire trees of one plan entry decode to is equal, field
+    by field of every allocation and value by value of the rest."""
+    got = _plans(msg_type, decode_payload(msg_type, got_tree))
+    want = _plans(msg_type, decode_payload(msg_type, want_tree))
+    assert len(got) == len(want), where
+    for g, w in zip(got, want):
+        assert list(g) == list(w), where
+        for key in w:
+            if key not in ALLOC_LISTS:
+                assert g[key] == w[key], (where, key)
+                continue
+            assert len(g[key]) == len(w[key]), (where, key)
+            for x, y in zip(g[key], w[key]):
+                assert type(x) is type(y) is Allocation
+                for f in dataclasses.fields(Allocation):
+                    assert getattr(x, f.name) == getattr(y, f.name), \
+                        (where, key, y.id, f.name)
+        assert to_wire(g) == to_wire(w), where
+
+
+def _old_frame(index, msg_type, ts, oracle):
+    """The frame the old walk and the same packb call give."""
+    return msgpack.packb({"i": index, "t": msg_type, "ts": ts, "p": oracle},
+                         use_bin_type=True)
+
+
+def _write_log(path, frames):
+    with open(path, "wb") as f:
+        for frame in frames:
+            f.write(struct.pack("<I", len(frame)))
+            f.write(frame)
+
+
 # -- one live server, every kind of entry -------------------------------
 
 N_NODES = 1100
@@ -160,11 +223,14 @@ def wal(tmp_path_factory):
     recording beside every WAL append the oracle's tree of the same
     payload at that moment; then restarts from the directory."""
     data_dir = str(tmp_path_factory.mktemp("walenc"))
-    records = []        # (index, msg_type, oracle tree, (objects, shared))
+    records = []    # (index, msg_type, oracle tree, (objects, shared, rows))
+    aliasing = {}   # index of a plan entry -> _distinct() of its payload
     real_append = persistence.RaftLog.append
 
     def recording_append(self, index, msg_type, payload, sync=False):
         oracle = old_encode_payload(msg_type, payload)
+        if msg_type in PLAN_ENTRIES:
+            aliasing[index] = _distinct(msg_type, payload)
         counts = real_append(self, index, msg_type, payload, sync)
         records.append((index, msg_type, oracle, counts))
         return counts
@@ -328,10 +394,11 @@ def wal(tmp_path_factory):
         again.shutdown()
     return {"records": {i: (t, o, c) for i, t, o, c in records},
             "frames": frames, "labels": labels, "live": live,
-            "replayed": replayed, "stats": stats, "reports": reports}
+            "replayed": replayed, "stats": stats, "reports": reports,
+            "aliasing": aliasing, "cfg": cfg}
 
 
-# -- (a) the frame, byte for byte ---------------------------------------
+# -- (a) the frame: byte for byte, but a plan's, which decodes alike -----
 
 @pytest.mark.parametrize("case", CASES + ["every_entry"])
 def test_frame_equals_the_old_walk_and_packb(wal, case):
@@ -345,10 +412,14 @@ def test_frame_equals_the_old_walk_and_packb(wal, case):
     for index in indexes:
         msg_type, oracle, _counts = wal["records"][index]
         frame = wal["frames"][index]
-        ts = msgpack.unpackb(frame, raw=False)["ts"]
-        assert frame == msgpack.packb(
-            {"i": index, "t": msg_type, "ts": ts, "p": oracle},
-            use_bin_type=True), (case, index, msg_type)
+        entry = msgpack.unpackb(frame, raw=False)
+        if msg_type in PLAN_ENTRIES:
+            assert (entry["i"], entry["t"]) == (index, msg_type)
+            _assert_decodes_alike(msg_type, entry["p"], oracle,
+                                  (case, index))
+        else:
+            assert frame == _old_frame(index, msg_type, entry["ts"],
+                                       oracle), (case, index, msg_type)
 
 
 # -- (b) a restart from those frames restores the live store ------------
@@ -386,7 +457,7 @@ def test_replay_of_the_new_frames_restores_the_live_store(wal, case):
     # what a restart decodes from the new frame is what the oracle's
     # tree decodes to
     entry = msgpack.unpackb(wal["frames"][index], raw=False)
-    assert entry["p"] == oracle
+    assert (entry["p"] == oracle) == (msg_type not in PLAN_ENTRIES)
     assert to_wire(decode_payload(msg_type, entry["p"])) == \
         to_wire(decode_payload(msg_type, oracle))
     ids = _touched(oracle)
@@ -402,6 +473,210 @@ def test_replay_of_the_new_frames_restores_the_live_store(wal, case):
                     "ingest_batch": 6, "job_register": 2,
                     "alloc_client_update": 3,
                     "plan_results_rich": 9}[case]
+
+
+# -- (b') a WAL of old frames, and one of both forms, restore it too -----
+
+@pytest.mark.parametrize("forms", ["old_form", "both_forms"])
+def test_a_wal_written_before_the_rows_restores_the_live_store(
+        wal, tmp_path, forms):
+    """The oracle's frames are what the parent commit wrote (test (a) of
+    ISSUE 26 held them to it byte for byte): a directory of them, or of
+    them and the new ones interleaved, replays into the live store."""
+    frames, old, new = [], 0, 0
+    for index in sorted(wal["frames"]):
+        msg_type, oracle, _counts = wal["records"][index]
+        frame = wal["frames"][index]
+        if msg_type in PLAN_ENTRIES and (forms == "old_form" or old <= new):
+            ts = msgpack.unpackb(frame, raw=False)["ts"]
+            frame = _old_frame(index, msg_type, ts, oracle)
+            assert frame != wal["frames"][index]
+            old += 1
+        elif msg_type in PLAN_ENTRIES:
+            new += 1
+        frames.append(frame)
+    assert old >= 2 and (new >= 1) == (forms == "both_forms")
+    _write_log(str(tmp_path / "raft.log"), frames)
+    again = Server(ServerConfig(num_schedulers=0, **dict(
+        wal["cfg"], data_dir=str(tmp_path))))
+    try:
+        replayed = again.store.dump()
+    finally:
+        again.shutdown()
+    live = wal["live"]
+    assert replayed["indexes"] == live["indexes"]
+    assert sorted(replayed["tables"]) == sorted(live["tables"])
+    for table in live["tables"]:
+        assert _rows(replayed["tables"][table]) == \
+            _rows(live["tables"][table]), table
+
+
+@pytest.mark.parametrize("form", ["new_form", "old_form"])
+def test_a_torn_final_frame_is_dropped(wal, tmp_path, form):
+    index = wal["labels"]["plan_results_1000"]
+    msg_type, oracle, _counts = wal["records"][index]
+    last = wal["frames"][index]
+    if form == "old_form":
+        last = _old_frame(index, msg_type, 0.0, oracle)
+    earlier = [i for i in sorted(wal["frames"]) if i < index]
+    before = [wal["frames"][i] for i in earlier]
+    path = str(tmp_path / "raft.log")
+    _write_log(path, before + [last])
+    whole = persistence.RaftLog(path).replay()
+    assert [e[0] for e in whole] == earlier + [index]
+    assert len(whole[-1][2]["allocs_placed"]) == FILL
+    size = os.path.getsize(path)
+    for cut in (1, len(last) // 2, len(last) + 3):
+        with open(path, "r+b") as f:
+            f.truncate(size - cut)
+        log = persistence.RaftLog(path)
+        assert [e[0] for e in log.replay()] == earlier
+        assert log._good_offset == size - len(last) - 4
+        _write_log(path, before + [last])
+
+
+# -- (b") the decoded plan aliases where the payload did ------------------
+
+@pytest.mark.parametrize("case", ["plan_results_1000", "plan_group_results",
+                                  "plan_results_rich"])
+def test_decoded_allocations_share_what_the_payload_shared(wal, case):
+    index = wal["labels"][case]
+    msg_type, oracle, _counts = wal["records"][index]
+    entry = msgpack.unpackb(wal["frames"][index], raw=False)
+    was = wal["aliasing"][index]
+    assert _distinct(msg_type, decode_payload(msg_type, entry["p"])) == was
+    if case == "plan_results_1000":
+        # ONE Job and ONE AllocatedResources under the 1,000, a few
+        # metrics; the old frame decoded to 1,000 of each
+        assert was[0]["allocs_placed", "job"] == 1
+        assert was[0]["allocs_placed", "allocated_resources"] == 1
+        assert 1 <= was[0]["allocs_placed", "metrics"] < 20
+        old = _distinct(msg_type, decode_payload(msg_type, oracle))
+        assert old[0]["allocs_placed", "job"] == FILL
+    elif case == "plan_group_results":
+        # the second member's first placement holds the first's metric
+        assert [w["allocs_placed", "metrics"] for w in was] == [1, 2]
+    else:
+        # two canaries: one Job, a metric and a resource row each
+        assert was[0]["allocs_placed", "job"] == 1
+        assert was[0]["allocs_placed", "metrics"] == 2
+        assert was[0]["allocs_placed", "allocated_resources"] == 2
+
+
+def _varied(n):
+    """n allocations no two of which agree in any field."""
+    out = []
+    for i in range(n):
+        a = mock.alloc()
+        a.namespace, a.eval_id, a.name = f"ns{i}", f"e{i}", f"j.web[{i}]"
+        a.node_id, a.node_name = f"node-{i}", f"n{i}"
+        a.job_id, a.task_group = f"j{i}", f"g{i}"
+        a.job = _small_job(f"varied-{i}")
+        a.metrics = AllocMetric(nodes_evaluated=i)
+        a.desired_status = ("run", "stop", "evict")[i]
+        a.desired_description = f"d{i}"
+        a.desired_transition = DesiredTransition(migrate=bool(i % 2),
+                                                 reschedule=i == 2)
+        a.client_status = ("pending", "running", "failed")[i]
+        a.client_description = f"c{i}"
+        a.task_states = {f"t{i}": TaskState(state="running")}
+        a.deployment_id = f"dep{i}"
+        a.deployment_status = AllocDeploymentStatus(canary=bool(i % 2),
+                                                    timestamp=float(i))
+        a.reschedule_tracker = RescheduleTracker(events=[
+            RescheduleEvent(reschedule_time=float(i))])
+        a.follow_up_eval_id, a.previous_allocation = f"f{i}", f"p{i}"
+        a.next_allocation, a.preempted_allocations = f"x{i}", [f"v{i}"]
+        a.preempted_by_allocation = f"by{i}"
+        a.create_index, a.modify_index = 10 + i, 20 + i
+        a.alloc_modify_index, a.create_time = 30 + i, 40 + i
+        a.modify_time = 50 + i
+        out.append(a)
+    return out
+
+
+@pytest.mark.parametrize("shape", ["empty", "one", "every_field_varies",
+                                   "atoms_alike_objects_not",
+                                   "none_among_objects"])
+def test_a_list_round_trips_through_the_record(shape):
+    if shape == "empty":
+        allocs = []
+    elif shape == "one":
+        allocs = [mock.alloc()]
+    elif shape == "every_field_varies":
+        allocs = _varied(3)
+    elif shape == "atoms_alike_objects_not":
+        # equal but distinct objects stay distinct; equal atoms of
+        # another type stay apart (1 == True)
+        allocs = [mock.alloc() for _ in range(3)]
+        for a, v in zip(allocs, (1, True, 1)):
+            a.create_index = v
+    else:
+        allocs = [mock.alloc() for _ in range(4)]
+        allocs[0].metrics = allocs[2].metrics = AllocMetric(
+            nodes_evaluated=3)
+        allocs[1].metrics = allocs[3].metrics = None
+        allocs[2].job = None
+    record = msgpack.unpackb(msgpack.packb(rows_to_wire(allocs),
+                                           use_bin_type=True), raw=False)
+    assert record["rows"] == len(allocs)
+    fields = {f.name for f in dataclasses.fields(Allocation)}
+    written = [k for part in ("consts", "cols", "refs")
+               for k in record[part]]
+    assert sorted(written) == (sorted(fields) if allocs else [])
+    back = rows_from_wire(Allocation, record)
+    assert back == allocs
+    assert to_wire(back) == old_to_wire(allocs)
+    # decoded as the list form decodes: by the field's type
+    assert to_wire(back) == to_wire(decode_payload("alloc_client_update", {
+        "allocs": old_to_wire(allocs)})["allocs"])
+    plan = dict(allocs_stopped=[], allocs_placed=allocs,
+                allocs_preempted=[])
+    assert _distinct("plan_results", dict(plan, allocs_placed=back)) == \
+        _distinct("plan_results", plan)
+    if shape == "one":
+        assert not record["cols"] and not record["refs"]
+    elif shape == "every_field_varies":
+        assert not record["consts"]
+    elif shape == "atoms_alike_objects_not":
+        assert [type(v) for v in record["cols"]["create_index"]] == \
+            [int, bool, int]
+        assert len(set(record["refs"]["desired_transition"])) == 3
+    elif shape == "none_among_objects":
+        assert record["refs"]["metrics"][1] is None
+        assert record["refs"]["metrics"][0] == record["refs"]["metrics"][2]
+
+
+def test_a_list_of_anything_else_keeps_the_list_form():
+    """The record is for a plan's lists of Allocations: stubs in one, or
+    the same list under another kind of entry, are walked as before."""
+    allocs = [mock.alloc(), mock.alloc()]
+    enc = encode_payload("plan_results", dict(
+        allocs_placed=[to_wire(allocs[0])], allocs_stopped=allocs))
+    assert isinstance(enc["allocs_placed"], list)
+    assert enc["allocs_stopped"]["rows"] == 2
+    for kind in ("alloc_client_update", "noop"):
+        payload = dict(allocs=allocs, allocs_placed=allocs)
+        assert encode_payload(kind, payload) == \
+            old_encode_payload(kind, payload)
+
+
+def test_the_served_plan_is_rows_and_under_300_bytes_a_placement(wal):
+    index = wal["labels"]["plan_results_1000"]
+    _t, oracle, (_objects, _shared, rows) = wal["records"][index]
+    assert rows == FILL
+    frame = wal["frames"][index]
+    assert len(frame) < 300 * FILL
+    assert len(frame) * 8 < len(_old_frame(index, "plan_results", 0.0,
+                                           oracle))
+    placed = msgpack.unpackb(frame, raw=False)["p"]["allocs_placed"]
+    assert placed["rows"] == FILL
+    # what differs between two placements of one plan, and no more
+    assert {"id", "name", "node_id", "node_name"} <= set(placed["cols"])
+    assert "job" in placed["consts"] and len(placed["consts"]) >= 20
+    report = next(r for r in wal["reports"] if r["rows"] == FILL)
+    assert report["bytes"] == len(frame)
+    assert report["consts"] >= 20 and report["table"] < 50
 
 
 # -- (c) to_wire is what it was, for every model class ------------------
@@ -530,8 +805,14 @@ def _holds_what_it_indexes():
 
 def _within_one_call():
     plan = _shared_plan()
-    enc = encode_payload("plan_results", plan)
-    a, b = enc["allocs_placed"][0], enc["allocs_placed"][1]
+    placed = encode_payload("plan_results", plan)["allocs_placed"]
+    assert placed["rows"] == 4
+    # one AllocatedResources and one AllocMetric under the four: each
+    # written once for the list
+    assert {"allocated_resources", "metrics"} <= set(placed["consts"])
+    ingest = encode_payload("alloc_client_update", dict(
+        allocs=plan["allocs_placed"], evals=[]))
+    a, b = ingest["allocs"][0], ingest["allocs"][1]
     assert a is not b
     assert a["allocated_resources"] is b["allocated_resources"]
     assert a["metrics"] is b["metrics"]
@@ -552,8 +833,9 @@ def _never_across_calls():
 def _across_the_members_of_one_entry():
     plan = _shared_plan()
     enc = encode_payload("plan_group_results", dict(groups=[plan, plan]))
-    assert enc["groups"][0]["allocs_placed"][0] is \
-        enc["groups"][1]["allocs_placed"][0]
+    first, second = (g["allocs_placed"] for g in enc["groups"])
+    assert first is not second and first["consts"] is not second["consts"]
+    assert first["consts"]["metrics"] is second["consts"]["metrics"]
     ingest = encode_payload("ingest_batch", dict(entries=[
         dict(kind="alloc_client_update", evals=[],
              allocs=plan["allocs_placed"][:1]),
@@ -592,31 +874,36 @@ def test_sharing_is_confined_to_one_encode_payload_call(check):
 def test_encoder_counts(wal, case):
     records = wal["records"]
     if case == "plan_results_1000":
-        _t, oracle, (objects, shared) = records[wal["labels"][case]]
-        assert shared > 0
-        assert FILL <= objects < 10 * FILL
+        _t, oracle, (objects, shared, rows) = records[wal["labels"][case]]
+        # the allocations are rows; what is walked is what they hang
+        # off: the job's tree, the resources, the metrics
+        assert rows == FILL and 0 < objects < FILL
         # the walk it replaced reached at least this many
-        assert objects < _count_dataclass_dicts(oracle) == 3 * FILL
+        assert _count_dataclass_dicts(oracle) == 3 * FILL
     elif case == "plan_results_rich":
-        _t, _o, (objects, shared) = records[wal["labels"][case]]
-        assert shared >= 1 and objects > 10      # the one Job, twice
+        _t, _o, (objects, shared, rows) = records[wal["labels"][case]]
+        assert rows == 7 and objects > 10
     elif case in ("eval_update", "node_register"):
         counts = [c for t, _o, c in records.values() if t == case]
-        assert counts and all(shared == 0 for _n, shared in counts)
-        assert all(objects >= 1 for objects, _s in counts)
+        assert counts and all(c[1:] == (0, 0) for c in counts)
+        assert all(c[0] >= 1 for c in counts)
     elif case == "stats_totals":
-        assert wal["stats"]["wal_objects"] == sum(
-            c[0] for _t, _o, c in records.values())
-        assert wal["stats"]["wal_shared"] == sum(
-            c[1] for _t, _o, c in records.values())
+        for k, key in enumerate(("wal_objects", "wal_shared", "wal_rows")):
+            assert wal["stats"][key] == sum(
+                c[k] for _t, _o, c in records.values()), key
+        assert wal["stats"]["wal_rows"] >= FILL + 13
     else:
         plan_entries = sorted(i for i, (t, _o, _c) in records.items()
                               if t in PLAN_ENTRIES)
         assert len(wal["reports"]) == len(plan_entries) >= 3
         for index, attrs in zip(plan_entries, wal["reports"]):
-            _t, _o, (objects, shared) = records[index]
-            assert attrs == {"objects": objects, "shared": shared,
-                             "bytes": len(wal["frames"][index])}
+            _t, _o, (objects, shared, rows) = records[index]
+            assert sorted(attrs) == ["bytes", "consts", "objects", "rows",
+                                     "shared", "table"]
+            assert (attrs["objects"], attrs["shared"], attrs["rows"],
+                    attrs["bytes"]) == (objects, shared, rows,
+                                        len(wal["frames"][index]))
+            assert attrs["consts"] + attrs["table"] > 0
 
 
 def _count_dataclass_dicts(tree):
