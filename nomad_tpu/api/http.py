@@ -31,6 +31,7 @@ from ..jobspec.parse import parse_duration_s
 from ..models import Job, NODE_SCHED_ELIGIBLE, NODE_SCHED_INELIGIBLE
 from ..models.node import DrainSpec, DrainStrategy
 from ..server.eval_broker import AdmissionOverloadError
+from ..telemetry.collector import thread_ended
 from ..utils.codec import from_wire, to_wire
 
 
@@ -79,6 +80,14 @@ class HTTPApiServer:
 
             def log_message(self, fmt, *args):
                 pass
+
+            def finish(self):
+                # the connection's thread ends here: its CPU goes to
+                # the telemetry ledger's http role
+                try:
+                    super().finish()
+                finally:
+                    thread_ended("http")
 
             def _respond(self, code: int, payload, index: Optional[int] = None,
                          headers: Optional[dict] = None):

@@ -1346,8 +1346,10 @@ def cmd_operator_trace(args) -> int:
             for sp in t.get("spans", []):
                 attrs = sp.get("attrs")
                 extra = f"  {json.dumps(attrs)}" if attrs else ""
+                # (attrs' cpu_ms: on a core for that much of the
+                # span's wall; the rest it waited)
                 print(f"      {sp['t0_ms']:9.1f} +{sp['dur_ms']:8.2f}"
-                      f"  {sp['name']:13s} [{sp.get('track', '')}]"
+                      f"  {sp['name']:14s} [{sp.get('track', '')}]"
                       f"{extra}")
     return 0
 
@@ -1411,6 +1413,15 @@ def cmd_operator_top(args) -> int:
     if rss:
         print(f"RSS           = {rss[-1]:.1f} MB "
               f"(start of window {rss[0]:.1f} MB)")
+    if tail_vals(rates, "process.cpu_s"):
+        # the sampler's CPU ledger: 1.00 is one core kept busy, which
+        # is all the threads that hold the GIL can share
+        by_role = ", ".join(
+            f"{role} {rate_now(f'thread_cpu.{role}_s'):.2f}"
+            for role in ("workers", "applier", "http", "other")
+            if tail_vals(rates, f"thread_cpu.{role}_s"))
+        print(f"CPU           = {rate_now('process.cpu_s'):.2f} cores"
+              + (f" ({by_role})" if by_role else ""))
     try:
         flat = c.flatness()
         if flat.get("enabled", flat.get("pass") is not None):
@@ -1480,21 +1491,26 @@ def cmd_operator_top(args) -> int:
               f"window {ilast('window_us'):.0f} us")
 
     # per-stage percentiles over the reservoirs' last 2048 reports
+    # (a span's CPU companion, stage.<stage>_cpu.p50_ms, is a column
+    # of its stage's row: on a core for that much of the p50's wall)
     stage_rows = []
     for name in sorted(series):
-        if name.startswith("stage.") and name.endswith(".p50_ms"):
+        if name.startswith("stage.") and name.endswith(".p50_ms") \
+                and not name.endswith("_cpu.p50_ms"):
             stage = name[len("stage."):-len(".p50_ms")]
             p50 = (tail_vals(series, name) or [0.0])[-1]
             p99 = (tail_vals(series, f"stage.{stage}.p99_ms")
                    or [0.0])[-1]
+            cpu = tail_vals(series, f"stage.{stage}_cpu.p50_ms")
             cnt = (tail_vals(series, f"stage_count.{stage}")
                    or [0.0])[-1]
             stage_rows.append([stage, f"{p50:.2f}", f"{p99:.2f}",
+                               f"{cpu[-1]:.2f}" if cpu else "-",
                                int(cnt)])
     if stage_rows:
         print()
         _print_rows(stage_rows, ["Stage", "p50 ms", "p99 ms",
-                                 "Samples"])
+                                 "cpu p50 ms", "Samples"])
 
     # device economics (the validation campaign's instruments)
     print()

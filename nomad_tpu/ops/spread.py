@@ -46,38 +46,29 @@ STATS: Dict[str, int] = {
     "distinct_folds": 0,       # distinct state folded to plan-time verdict
 }
 
-# accumulated input-build seconds per arm; nothing in the tree reads
-# them (ROADMAP D0): the spread_inputs span times the same build
-TIMINGS: Dict[str, float] = {"vector_s": 0.0, "scalar_s": 0.0}
-
 
 def enabled() -> bool:
     from ..scheduler import feasible_compiler
     return feasible_compiler.residue_enabled()
 
 
-def note_build(dt: float) -> None:
-    """Attribute one eval's spread/distinct input-build wall time to
-    the active arm (called by the stack around both paths)."""
+def note_build() -> None:
+    """Count one eval's spread/distinct input build for the active arm
+    (called by the stack after both paths; the spread_inputs span times
+    the build)."""
     if enabled():
         STATS["vector_builds"] += 1
-        TIMINGS["vector_s"] += dt
     else:
         STATS["scalar_builds"] += 1
-        TIMINGS["scalar_s"] += dt
 
 
-def stats() -> Dict[str, float]:
-    out: Dict[str, float] = dict(STATS)
-    out.update(TIMINGS)
-    return out
+def stats() -> Dict[str, int]:
+    return dict(STATS)
 
 
 def reset_stats() -> None:
     for k in STATS:
         STATS[k] = 0
-    for k in TIMINGS:
-        TIMINGS[k] = 0.0
 
 
 # -- dictionary encoding off the interned columns ----------------------

@@ -716,15 +716,13 @@ class PlacementEngine:
         with stages.span("spread_inputs") as sp:
             if affinities:
                 aff_col, aff_sum = affinity_columns(t.cols, affinities)
-            t_build = time.perf_counter()
             spreads, sum_spread_w = self._spread_inputs(tg, proposed)
             if not affinities and not spreads:
                 sp.cancel()
         distinct_props = self._distinct_prop_inputs(tg, proposed)
         distinct_hosts = self._has_distinct_hosts(tg)
         if spreads or distinct_props:
-            # per-arm build-time attribution (ops/spread.py TIMINGS)
-            spread_ops.note_build(time.perf_counter() - t_build)
+            spread_ops.note_build()     # per-arm build counts
         if count == 1 and (distinct_hosts or distinct_props) \
                 and spread_ops.enabled() \
                 and spread_ops.distinct_uncontended(

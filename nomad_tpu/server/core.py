@@ -38,7 +38,7 @@ from ..models.evaluation import (
     CORE_JOB_JOB_GC, CORE_JOB_NODE_GC, TRIGGER_SCHEDULED,
 )
 from ..state import StateStore
-from ..utils import metrics
+from ..utils import metrics, stages
 from ..utils.timetable import TimeTable
 from .blocked_evals import BlockedEvals
 from .deployment_watcher import (
@@ -49,6 +49,7 @@ from .drainer import NodeDrainer, drain_allocs
 from .eval_broker import EvalBroker, FAILED_QUEUE
 from .event_broker import EventBroker, events_from_apply
 from .periodic import PeriodicDispatch
+from .persistence import PLAN_ENTRIES
 from .plan_applier import PlanApplier
 from .plan_queue import PlanQueue
 from .worker import Worker
@@ -1247,26 +1248,20 @@ class Server:
         from .persistence import decode_payload
         payload = decode_payload(msg_type, enc_payload)
         tl = self._apply_tl
+        called = time.perf_counter()
         with self._raft_l:
             if index <= self._raft_index:
                 return              # duplicate delivery (batch overlap)
             tl.in_fsm_apply = True
             try:
                 self._raft_index = index
-                if self.persistence is not None:
-                    self.persistence.record(index, msg_type, payload)
-                fn = getattr(self, f"_apply_{msg_type}")
-                fn(index, payload)
-                self.time_table.witness(index)
-                if self.persistence is not None:
-                    self.persistence.maybe_snapshot(self.store)
+                # the group-fsync barrier is the FSM loop's, once per
+                # committed batch (raft.py _fsm_loop)
+                self._apply_locked(index, msg_type, payload, called,
+                                   barrier=False)
             finally:
                 tl.in_fsm_apply = False
-            try:
-                self.events.publish(events_from_apply(msg_type, payload,
-                                                      index))
-            except Exception:
-                LOG.exception("event publish for %s", msg_type)
+            self._publish_applied(index, msg_type, payload)
 
     def install_snapshot(self, data: dict,
                          base_index: Optional[int] = None) -> None:
@@ -1472,7 +1467,6 @@ class Server:
         bisection)."""
         import os as _os
 
-        from ..utils import stages
         batch_on = _os.environ.get("NOMAD_TPU_WAL_REPLAY_BATCH", "1") \
             not in ("0", "off")
         t0 = time.perf_counter() if stages.enabled else 0.0
@@ -1637,25 +1631,58 @@ class Server:
         # dev / single-node: inline serialized apply. Change events fan
         # out inside the lock; WAL replay bypasses raft_apply so
         # restores don't replay the event history.
+        called = time.perf_counter()
         with self._raft_l:
             index = self._raft_index + 1
             self._raft_index = index
-            if self.persistence is not None:
-                self.persistence.record(index, msg_type, payload)
-            fn = getattr(self, f"_apply_{msg_type}")
-            fn(index, payload)
-            self.time_table.witness(index)
-            if self.persistence is not None:
-                # dev mode: the entry IS the commit unit, so the
-                # group-fsync barrier sits right here
-                self.persistence.commit_barrier()
-                self.persistence.maybe_snapshot(self.store)
-            try:
-                self.events.publish(events_from_apply(
-                    msg_type, payload, index))
-            except Exception:
-                LOG.exception("event publish for %s", msg_type)
+            # dev mode: the entry IS the commit unit, so the
+            # group-fsync barrier sits right here
+            self._apply_locked(index, msg_type, payload, called,
+                               barrier=True)
+            self._publish_applied(index, msg_type, payload)
         return index, None
+
+    def _apply_locked(self, index: int, msg_type: str, payload: dict,
+                      called: float, barrier: bool) -> None:
+        """What both apply paths (the inline dev apply above,
+        apply_replicated) do to one entry under the raft lock: WAL
+        record, FSM apply, time table, the commit barrier where the
+        entry is the commit unit, the snapshot trigger. For an entry
+        the plan applier commits it names what plan_commit waited for
+        (utils/stages.py): raft_lock_wait from `called` (the caller's
+        perf_counter before it took the lock) to here, wal_encode and
+        wal_write inside record() and round the barrier's fsync,
+        fsm_apply round the store's transaction."""
+        plan = stages.enabled and msg_type in PLAN_ENTRIES
+        if plan:
+            stages.add("raft_lock_wait", time.perf_counter() - called)
+        if self.persistence is not None:
+            self.persistence.record(index, msg_type, payload)
+        with (stages.span("fsm_apply", kind=msg_type) if plan
+              else stages.NULL_SPAN):
+            getattr(self, f"_apply_{msg_type}")(index, payload)
+        self.time_table.witness(index)
+        if self.persistence is not None:
+            if barrier:
+                with (stages.span("wal_write", synced=True)
+                      if plan and self.persistence.group_fsync
+                      else stages.NULL_SPAN):
+                    self.persistence.commit_barrier()
+            self.persistence.maybe_snapshot(self.store)
+
+    def _publish_applied(self, index: int, msg_type: str,
+                         payload: dict) -> None:
+        """The applied entry's change events, built and fanned out (a
+        plan entry's as stage event_publish)."""
+        try:
+            with (stages.span("event_publish")
+                  if stages.enabled and msg_type in PLAN_ENTRIES
+                  else stages.NULL_SPAN) as sp:
+                events = events_from_apply(msg_type, payload, index)
+                sp.note(events=len(events))
+                self.events.publish(events)
+        except Exception:
+            LOG.exception("event publish for %s", msg_type)
 
     def _apply_noop(self, index: int, p: dict) -> None:
         """Leadership no-op (hashicorp/raft LogNoop): commits the new
@@ -2000,7 +2027,6 @@ class Server:
         register is a compare-and-set against the job's current modify
         index (`job run -check-index`; job_endpoint.go:175
         RegisterEnforceIndexErrPrefix): 0 means "must not exist"."""
-        from ..utils import stages
         # call -> job committed and eval enqueued, as the server sees
         # it (the client's clock round the PUT adds HTTP and decode)
         with stages.span("job_register", jobs=1):
@@ -2120,7 +2146,6 @@ class Server:
                 except Exception as e:
                     out.append(e)
             return out
-        from ..utils import stages
         with stages.span("job_register", jobs=len(jobs)):
             return self._register_jobs_coalesced(jobs, triggered_by)
 

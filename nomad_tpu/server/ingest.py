@@ -66,13 +66,6 @@ INGEST_KINDS = ("job_register", "alloc_client_update",
 # immediate and shrinking further just burns reclaim rounds
 SCALE_MIN = 0.125
 
-# process-wide accounting (the GROUP_STATS idiom) across every server
-# of the process. Written only by gateway threads; nothing in the tree
-# reads it (ROADMAP D0): the ingest.* gauges carry the same counts.
-INGEST_STATS: Dict[str, int] = {
-    "batches": 0, "writes": 0, "coalesced": 0, "shed": 0, "max_size": 0,
-}
-
 
 def ingest_batch_enabled() -> bool:
     """The bisection escape hatch: NOMAD_TPU_INGEST_BATCH=0 keeps the
@@ -195,12 +188,6 @@ class IngestGateway:
                 # every request beyond the first shared a raft entry
                 # with a neighbor — the headline coalescing gauge
                 self.stats["coalesced_writes"] += size - 1
-        if size > 1:
-            INGEST_STATS["coalesced"] += size - 1
-        INGEST_STATS["batches"] += 1
-        INGEST_STATS["writes"] += size
-        if size > INGEST_STATS["max_size"]:
-            INGEST_STATS["max_size"] = size
         # counter totals the telemetry ring turns into writes/s rates
         # (`nomad operator top`'s write block)
         metrics.incr_counter("nomad.ingest.writes", size)
@@ -229,7 +216,6 @@ class IngestGateway:
             return
         with self._stats_l:
             self.stats["shed"] += 1
-        INGEST_STATS["shed"] += 1
         metrics.incr_counter("nomad.ingest.shed")
         # back-off scales with overshoot (capped 8x, floor 1s) — the
         # broker valve's Retry-After discipline

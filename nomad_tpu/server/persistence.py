@@ -76,8 +76,9 @@ SCHEMAS: Dict[str, Dict[str, Any]] = {
 }
 
 
-# the entries the plan applier commits (stage wal_encode times their
-# framing, inside plan_commit)
+# the entries the plan applier commits (plan_commit's children —
+# raft_lock_wait, wal_encode, wal_write, fsm_apply, event_publish — are
+# reported for these alone, so the span tree stays true)
 PLAN_ENTRIES = frozenset({"plan_results", "plan_group_results"})
 # a plan's allocation lists: written as one record of constants, a
 # table of shared objects and rows (utils/codec.py rows_to_wire)
@@ -220,10 +221,13 @@ class RaftLog:
                sync: bool = False) -> Tuple[int, int, int]:
         """Frame and write one entry; returns the encoder's counts
         (ShareMemo's objects, shared, rows). A plan entry reports the
-        framing (wire form + packb, not the write) as stage
-        wal_encode."""
+        framing (wire form + packb) as stage wal_encode and, beside
+        it, the write as stage wal_write: the log's lock (a snapshot
+        writer's truncation holds it), write + flush, the fsync when
+        this entry pays its own."""
         memo = ShareMemo()
-        with (stages.span("wal_encode") if msg_type in PLAN_ENTRIES
+        plan = msg_type in PLAN_ENTRIES
+        with (stages.span("wal_encode") if plan
               else stages.NULL_SPAN) as sp:
             frame = msgpack.packb(
                 {"i": index, "t": msg_type, "ts": time.time(),
@@ -232,15 +236,17 @@ class RaftLog:
             sp.note(objects=memo.objects, shared=memo.shared,
                     rows=memo.rows, consts=memo.consts, table=memo.table,
                     bytes=len(frame))
-        with self._l:
-            self._f.write(struct.pack("<I", len(frame)))
-            self._f.write(frame)
-            self._f.flush()
-            if sync:
-                os.fsync(self._f.fileno())
-                self._dirty = False
-            else:
-                self._dirty = True
+        with (stages.span("wal_write", synced=sync) if plan
+              else stages.NULL_SPAN):
+            with self._l:
+                self._f.write(struct.pack("<I", len(frame)))
+                self._f.write(frame)
+                self._f.flush()
+                if sync:
+                    os.fsync(self._f.fileno())
+                    self._dirty = False
+                else:
+                    self._dirty = True
         return memo.objects, memo.shared, memo.rows
 
     def sync(self) -> None:
@@ -419,6 +425,12 @@ class Persistence:
             # the same object again; allocations written as rows of a
             # plan's record
             "wal_objects": 0, "wal_shared": 0, "wal_rows": 0,
+            # the WAL's absolute stream position (bytes ever
+            # appended), and what it has taken since the last snapshot
+            # was triggered: the two quantities the trigger compares
+            # (maybe_snapshot), as of the last applied entry
+            "wal_bytes": 0, "wal_bytes_since_snapshot": 0,
+            "wal_entries_since_snapshot": 0,
         }
         # server-level state (e.g. the GC TimeTable) rides along in the
         # snapshot under "extra"; the provider is set by the Server
@@ -488,6 +500,8 @@ class Persistence:
             highest = store.latest_index()
         entries = self.log.replay()
         self.log.open()
+        with self._l:
+            self._note_wal(self.log.size())
         with self._stats_l:
             self.stats["restore_s"] = time.perf_counter() - t0
         if stages.enabled:
@@ -503,12 +517,18 @@ class Persistence:
             self.stats["wal_shared"] += shared
             self.stats["wal_rows"] += rows
 
+    @property
+    def group_fsync(self) -> bool:
+        """Whether the commit barrier pays an fsync in this
+        configuration (else every entry pays its own, or none does)."""
+        return self.wal_fsync and self.wal_group_fsync
+
     def commit_barrier(self) -> None:
         """Group-fsync boundary: called once per committed apply batch
         (raft.py _fsm_loop; the dev-mode apply calls it per entry —
         there the entry IS the commit unit). One fsync covers every
         record() since the last barrier."""
-        if self.wal_fsync and self.wal_group_fsync:
+        if self.group_fsync:
             self.log.sync()
 
     def maybe_snapshot(self, store) -> None:
@@ -521,12 +541,22 @@ class Persistence:
         with self._l:
             self._since_snapshot += 1
             size = self.log.size()
-            if self._since_snapshot < self.snapshot_every and \
-                    size - self._bytes_at_snapshot < self.SNAPSHOT_WAL_BYTES:
-                return
-            self._since_snapshot = 0
-            self._bytes_at_snapshot = size
-        self.trigger_snapshot(store)
+            due = self._since_snapshot >= self.snapshot_every or \
+                size - self._bytes_at_snapshot >= self.SNAPSHOT_WAL_BYTES
+            if due:
+                self._since_snapshot = 0
+                self._bytes_at_snapshot = size
+            self._note_wal(size)
+        if due:
+            self.trigger_snapshot(store)
+
+    def _note_wal(self, size: int) -> None:
+        """The trigger's two quantities into `stats` (under _l)."""
+        with self._stats_l:
+            self.stats["wal_bytes"] = size
+            self.stats["wal_bytes_since_snapshot"] = \
+                size - self._bytes_at_snapshot
+            self.stats["wal_entries_since_snapshot"] = self._since_snapshot
 
     def trigger_snapshot(self, store) -> Optional[threading.Thread]:
         """Capture (MVCC snapshot, extra, WAL mark) NOW; serialize and

@@ -61,15 +61,6 @@ CONFLICT_WINDOW_S = 10.0
 # consecutive conflict-free groups before a shrunk bound re-widens
 GROUP_RECOVER_CLEAN = 32
 
-# process-wide accounting (the BUILD_STATS idiom) across every server
-# of the process. Written only by applier threads; nothing in the tree
-# reads it (ROADMAP D0): the plan_group.* gauges carry the same counts.
-GROUP_STATS: Dict[str, int] = {
-    "groups": 0, "plans": 0, "conflict_retries": 0,
-    "singleton_fallbacks": 0, "max_size": 0,
-}
-
-
 def fail_futures(pairs, exc: Exception) -> None:
     """Fail every unresolved future in a demux pair list — the shared
     abort tail of the group-commit planes (r9 plan groups, r19 ingest
@@ -124,8 +115,7 @@ class PlanApplier:
         # commit and must keep occupying capacity until applied
         self._failed_pending: set = set()
         self._failed_l = make_lock()
-        # per-applier group accounting (the governor gauges read these;
-        # GROUP_STATS above is the cross-server aggregate)
+        # per-applier group accounting (the governor gauges read these)
         self.stats: Dict[str, int] = {
             "groups": 0, "plans": 0, "conflict_retries": 0,
             "singleton_fallbacks": 0,
@@ -218,16 +208,10 @@ class PlanApplier:
                     singleton: bool = False) -> None:
         self.stats["groups"] += 1
         self.stats["plans"] += size
-        GROUP_STATS["groups"] += 1
-        GROUP_STATS["plans"] += size
-        if size > GROUP_STATS["max_size"]:
-            GROUP_STATS["max_size"] = size
         if singleton:
             self.stats["singleton_fallbacks"] += 1
-            GROUP_STATS["singleton_fallbacks"] += 1
         if conflicts:
             self.stats["conflict_retries"] += conflicts
-            GROUP_STATS["conflict_retries"] += conflicts
             now = _time.monotonic()
             with self._conflict_l:
                 self._conflicts.extend([now] * conflicts)
@@ -432,6 +416,7 @@ class PlanApplier:
                 plan = pending.plan
                 tr = getattr(plan, "_trace", None)
                 _p0 = _time.perf_counter() if stages.enabled else 0.0
+                _c0 = stages.cpu_now() if stages.enabled else None
                 try:
                     self._check_token(plan)
                     result, payload, evals, conflicted = self._verify(
@@ -444,11 +429,15 @@ class PlanApplier:
                 if stages.enabled:
                     # per-plan span with the group anatomy the
                     # aggregate window can't carry: width, intra-group
-                    # conflict, demotion, and how long the plan sat
-                    # queued behind the serialization point
+                    # conflict, demotion, how long the plan sat queued
+                    # behind the serialization point, and how much of
+                    # its share the applier's thread was on a core
+                    cpu = stages.cpu_since(_c0)
                     trace.emit(
                         tr, "plan_verify", _time.perf_counter() - _p0,
                         track="applier", group=len(group),
+                        **({} if cpu is None
+                           else {"cpu_ms": stages.cpu_ms(cpu)}),
                         conflicted=conflicted,
                         demoted=bool(result.refresh_index),
                         queue_ms=round(max(

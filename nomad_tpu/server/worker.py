@@ -31,6 +31,7 @@ from typing import List, Optional
 from ..models import Evaluation, JOB_TYPE_CORE, Plan, PlanResult
 from ..rpc.codec import RpcError, RpcRefused
 from ..scheduler import new_scheduler
+from ..telemetry.collector import thread_ended
 from ..utils.locks import make_condition, make_lock
 
 LOG = logging.getLogger("nomad_tpu.worker")
@@ -1018,13 +1019,16 @@ class Worker:
             it = iter(batch)
 
             def lane_run():
-                while True:
-                    with lock:
-                        ev_tok = next(it, None)
-                    if ev_tok is None:
-                        return
-                    self.process_eval(ev_tok[0], ev_tok[1],
-                                      lat_scale=lanes)
+                try:
+                    while True:
+                        with lock:
+                            ev_tok = next(it, None)
+                        if ev_tok is None:
+                            return
+                        self.process_eval(ev_tok[0], ev_tok[1],
+                                          lat_scale=lanes)
+                finally:
+                    thread_ended("workers")     # the CPU ledger's
 
             threads = [threading.Thread(
                 target=lane_run, daemon=True,
@@ -1047,6 +1051,7 @@ class Worker:
                                   lat_scale=len(batch))
             finally:
                 gateway.lane_finished()
+                thread_ended("workers")         # the CPU ledger's
 
         for ev, token in batch:
             t = threading.Thread(target=lane_run, args=(ev, token),

@@ -14,6 +14,18 @@ float64 columns, one write cursor, wrap-around overwrite — so
 LIVE ring and `nomad operator top` can render rates and trends from
 history instead of a single scrape.
 
+The same sampler keeps the process's CPU ledger (ISSUE 36): each row
+carries `process.cpu_s` (time.process_time) and `thread_cpu.<role>_s`
+for the threads named worker-* (workers), plan-applier /
+plan-committer / raft-fsm (applier), http-api / ingest-gateway / the
+per-connection handlers (http), and the process less the three
+(other), cumulative CPU seconds read from OUTSIDE the threads — nothing
+on any hot path. A thread that lives shorter than a sample adds its own
+CPU to its role as it ends (thread_ended). An operator reads the series
+as cores under `rates`. They are amounts over a sample, no part of any
+span, so they stay off the stages hook (utils/stages.py: a tap draws
+every report there as an interval).
+
 Bounding: `telemetry_ring_slots` slots × MAX_SERIES series × 8 bytes
 (defaults: 512 × 256 = 1 MiB hard ceiling); series past the cap are
 dropped and counted, never grown. The collector only READS — gauge
@@ -31,6 +43,7 @@ from __future__ import annotations
 
 import math
 import os
+import sys
 import threading
 import time
 from statistics import median
@@ -40,7 +53,7 @@ import numpy as np
 
 from ..governor.drift import least_squares_slope
 from ..governor.governor import rss_mb
-from ..utils import metrics
+from ..utils import metrics, stages
 from ..utils.locks import make_lock
 
 # hard series ceiling: a gauge-name churn storm (e.g. per-job counter
@@ -56,6 +69,78 @@ def enabled() -> bool:
     """The NOMAD_TPU_TELEMETRY kill switch (parallel to
     NOMAD_TPU_TRACE): default on."""
     return os.environ.get("NOMAD_TPU_TELEMETRY", "1") not in ("0", "off")
+
+
+# -- the process's CPU by thread role ------------------------------------
+# The ledger is the process's, as time.process_time() and
+# threading.enumerate() are: whichever server's collector samples reads
+# the same clocks. Roles go by the names the threads already have.
+CPU_ROLES = ("workers", "applier", "http")      # and "other": the rest
+_APPLIER_THREADS = frozenset({"plan-applier", "plan-committer",
+                              "raft-fsm"})
+_HTTP_THREADS = frozenset({"http-api", "ingest-gateway"})
+# CPU seconds of threads that ended since the process began, by role
+# (thread_ended); with the live threads' clocks, a role's total
+_ended_l = make_lock()
+_ended_cpu: Dict[str, float] = {role: 0.0 for role in CPU_ROLES}
+
+
+def thread_role(name: str) -> Optional[str]:
+    """The CPU role of a thread by its name; None for `other`."""
+    if name.startswith("worker-"):      # lanes and finishers too
+        return "workers"
+    if name in _APPLIER_THREADS:
+        return "applier"
+    # ThreadingHTTPServer names a connection's thread after its target
+    if name in _HTTP_THREADS or "process_request_thread" in name:
+        return "http"
+    return None
+
+
+def thread_ended(role: str) -> None:
+    """The calling thread is about to end: its CPU goes to its role's
+    tally and the sampler stops reading its clock. For threads that
+    live shorter than a sample (a lane, a connection's handler), from
+    the `finally` of what they run."""
+    with _ended_l:
+        cpu = stages.cpu_now()
+        if cpu is not None:
+            threading.current_thread()._cpu_tallied = True
+            _ended_cpu[role] += cpu
+
+
+def _thread_cpu_s(t: threading.Thread) -> Optional[float]:
+    """Another thread's CPU clock, by its kernel id: the id glibc's
+    pthread_getcpuclockid computes (CPUCLOCK_SCHED | per-thread), asked
+    of the kernel itself — for a thread that is gone that is EINVAL,
+    where pthread_getcpuclockid(t.ident) would read the dead thread's
+    freed descriptor."""
+    tid = t.native_id
+    if tid is None:
+        return None
+    try:
+        return time.clock_gettime((~tid << 3) | 6)
+    except OSError:
+        return None
+
+
+def thread_cpu_by_role() -> Optional[Dict[str, float]]:
+    """Cumulative CPU seconds of the three named roles: the ended
+    threads' tally plus every live thread's clock. None where another
+    thread's clock cannot be read (not Linux)."""
+    if not sys.platform.startswith("linux") or \
+            not hasattr(time, "clock_gettime"):
+        return None
+    with _ended_l:      # a thread cannot tally itself mid-sweep
+        out = dict(_ended_cpu)
+        for t in threading.enumerate():
+            role = thread_role(t.name)
+            if role is None or getattr(t, "_cpu_tallied", False):
+                continue
+            cpu = _thread_cpu_s(t)
+            if cpu is not None:
+                out[role] += cpu
+    return out
 
 
 def default_device_fn() -> Dict[str, float]:
@@ -179,7 +264,9 @@ class TelemetryCollector:
                      "device.packs", "device.mesh_reshard_uploads",
                      "device.mesh_reshard_bytes",
                      "device.mesh_delta_scatters",
-                     "device.mesh_resident_hits")
+                     "device.mesh_resident_hits",
+                     # CPU seconds: the rate is cores
+                     "process.cpu_s", "thread_cpu.")
 
     def __init__(self, interval_s: float = DEFAULT_INTERVAL_S,
                  slots: int = DEFAULT_SLOTS,
@@ -230,8 +317,22 @@ class TelemetryCollector:
                     "telemetry sample failed")
 
     # -- the sampling step ---------------------------------------------
+    @staticmethod
+    def _cpu_row() -> Dict[str, float]:
+        """The CPU ledger's series: the process's clock and, where
+        another thread's can be read, the four roles'."""
+        row = {"process.cpu_s": time.process_time()}
+        roles = thread_cpu_by_role()
+        if roles is not None:
+            for role, total in roles.items():
+                row[f"thread_cpu.{role}_s"] = total
+            row["thread_cpu.other_s"] = max(
+                row["process.cpu_s"] - sum(roles.values()), 0.0)
+        return row
+
     def _collect_row(self) -> Dict[str, float]:
         row: Dict[str, float] = {"process.rss_mb": rss_mb()}
+        row.update(self._cpu_row())
         if self.gauges_fn is not None:
             try:
                 row.update(self.gauges_fn())
@@ -252,6 +353,12 @@ class TelemetryCollector:
         if self.stage_fn is not None:
             try:
                 for stage, pct in self.stage_fn().items():
+                    if stage.endswith(stages.CPU_SUFFIX):
+                        # a span's CPU companion: the median alone,
+                        # a column of its stage's row (`operator top`)
+                        row[f"stage.{stage}.p50_ms"] = \
+                            pct.get("p50_ms", 0.0)
+                        continue
                     row[f"stage.{stage}.p50_ms"] = pct.get("p50_ms", 0.0)
                     row[f"stage.{stage}.p99_ms"] = pct.get("p99_ms", 0.0)
                     row[f"stage_count.{stage}"] = pct.get("count", 0)

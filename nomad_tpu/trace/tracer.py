@@ -125,7 +125,9 @@ STAGE_PARENTS: Dict[str, Optional[str]] = {
     "plan_build": "sched_host",
     "plan_submit": "sched_host", "plan_queue_wait": "plan_submit",
     "plan_verify": "plan_submit", "plan_commit": "plan_submit",
-    "wal_encode": "plan_commit", "sched_host_self": "sched_host",
+    "raft_lock_wait": "plan_commit", "wal_encode": "plan_commit",
+    "wal_write": "plan_commit", "fsm_apply": "plan_commit",
+    "event_publish": "plan_commit", "sched_host_self": "sched_host",
 }
 
 # stages whose report site runs on the eval's OWN thread — or, for the
@@ -134,10 +136,13 @@ STAGE_PARENTS: Dict[str, Optional[str]] = {
 # rest (queue_wait, gateway_wait, plan_queue_wait, plan_verify,
 # plan_commit) are measured across threads and name their traces
 # through span()/report() below; the ambient hook emitting them too
-# would double-count or mis-attribute them. wal_encode is reported
-# deep under the applier's raft append (server/persistence.py), which
-# knows no plan: the applier installs the committing plans' traces as
-# its own thread's context round that call.
+# would double-count or mis-attribute them. plan_commit's children
+# (raft_lock_wait, wal_encode, wal_write, fsm_apply, event_publish) are
+# reported deep under the applier's raft append (server/core.py,
+# server/persistence.py), which knows no plan: the applier installs the
+# committing plans' traces as its own thread's context round that call.
+# A span's CPU companion (<stage>_cpu, utils/stages.py) is in neither
+# map: it feeds the reservoirs and is never a span.
 AMBIENT_STAGES = frozenset({
     "restore", "wal_replay", "fence_wait", "sched_host", "reconcile",
     "preempt", "preempt_gather", "preempt_kernel",
@@ -145,7 +150,8 @@ AMBIENT_STAGES = frozenset({
     "select_prep", "feasibility", "mask_build", "spread_inputs",
     "kernel_pack", "kernel", "d2h", "kernel_expand", "select_finish",
     "port_assign", "plan_build", "plan_submit",
-    "wal_encode", "sched_host_self", "broker_ack",
+    "raft_lock_wait", "wal_encode", "wal_write", "fsm_apply",
+    "event_publish", "sched_host_self", "broker_ack",
 })
 
 
@@ -264,7 +270,7 @@ class EvalTrace:
 class Tracer:
     """The flight recorder: bounded ring + pinned exemplars + stage
     percentile reservoirs. One module-global instance (`tracer`) is
-    shared the way stages/GROUP_STATS are — kernels and gateways have
+    shared the way utils/stages is — kernels and gateways have
     no server handle — and each Server configures it from its
     ServerConfig knobs and wires threshold_fn/gauge_fn to its
     governor."""
@@ -513,8 +519,10 @@ class Tracer:
     def _on_stage(self, stage: str, seconds: float,
                   attrs: Optional[dict] = None) -> None:
         """The stages.add hook: every report feeds its stage's
-        reservoir, an ambient one also lands as a span on the thread
-        context's trace(s)."""
+        reservoir (a span's CPU companion, <stage>_cpu, among them), an
+        ambient one also lands as a span on the thread context's
+        trace(s); where the span read its thread's CPU clock `attrs`
+        say so (cpu_ms: on a core for that much of the wall)."""
         if stage in AMBIENT_STAGES:
             traces, track = getattr(_tls, "ctx", _NO_CTX)
             if traces:
@@ -634,9 +642,9 @@ def to_chrome(traces: List[dict]) -> dict:
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
-# process-wide recorder, same idiom as stages / GROUP_STATS / the
-# sanitizer's trace counter: kernels and gateways have no server
-# handle; Server.configure()s it and wires its governor in
+# process-wide recorder, same idiom as stages / the sanitizer's trace
+# counter: kernels and gateways have no server handle;
+# Server.configure()s it and wires its governor in
 tracer = Tracer()
 stages.set_trace_hook(tracer._on_stage, on=tracer.enabled())
 

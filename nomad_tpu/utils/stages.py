@@ -1,4 +1,4 @@
-"""Per-stage wall-clock accounting for the eval hot path.
+"""Per-stage wall-clock and thread-CPU accounting for the eval hot path.
 
 BENCH_r05 showed a 13x gap between in-kernel placement rate (163.8k/s)
 and end-to-end (12.3k/s) with no way to say WHERE the host time went —
@@ -95,10 +95,20 @@ same tree; a stage's time lies inside its parent's):
                       half; attrs group, demoted, queue_ms)
         plan_commit   raft append/apply + quorum wait + store
                       transaction (the write half; attrs group, index)
+          raft_lock_wait  raft_apply_async / apply_replicated called
+                        -> the server's raft lock held (an
+                        after-the-fact report: a wait, no CPU clock)
           wal_encode    framing the plan's WAL record: wire form of
                         the payload + msgpack (server/persistence.py;
                         attrs objects, shared, rows, consts, table,
                         bytes)
+          wal_write     the framed record's write + flush under the
+                        log's lock, and the commit barrier that covers
+                        it (attr synced: an fsync ran)
+          fsm_apply     the FSM's _apply_<msg_type>: the store's
+                        transaction (attr kind)
+          event_publish the change events of the entry built and
+                        published (attr events)
       sched_host_self  the part of sched_host no other span of the
                     eval's trace covers (union, not sum): scheduler
                     set-up, the eval-status write, thread hand-offs —
@@ -106,8 +116,45 @@ same tree; a stage's time lies inside its parent's):
                     worker's safepoint stops its sibling mid-eval: the
                     pause lands in whatever span is open there)
 
-enable() / snapshot() / disable() give a test the summed seconds and
-the call count of every stage reported in between. Shares of a whole
+Two clocks. A span of CPU_STAGES reads the calling thread's CPU clock
+(time.thread_time, CLOCK_THREAD_CPUTIME_ID) where it reads the wall
+clock. Thread CPU is time ON a core, the GIL held or not: numpy, the
+native codec and XLA's dispatch on the calling thread count too, so it
+is an upper bound on the time the span held the GIL. wall - cpu is
+time OFF the core: waiting for the GIL, a lock, the device, the disk.
+A span's CPU never exceeds its wall by more than the clocks' grain
+(microseconds here; a kernel that samples the thread clock at its tick
+gives multiples of 10 ms, true over a window's sum and coarse for one
+span). The reading rides the span's attrs as `cpu_ms`, so every tap of
+the hook, the flight recorder's span, /v1/operator/trace and the
+Chrome export carry it with no field of their own. add() also takes it
+as `cpu=` and forwards it as ONE companion report under the name
+<stage>_cpu (seconds = the CPU seconds, no attrs), which is how the
+percentile reservoirs, the telemetry ring and a tap that keeps
+(stage, end, seconds) alone hear it; a companion is never a span and
+is in no tree.
+
+Every other report on the hook is an INTERVAL that ends as it is
+reported, and a tap may draw it as (end - seconds, end): the benchmark
+names the device's idle gaps that way. A companion drawn so is the
+tail of its own span (cpu <= wall), so it can stand where its stage
+would have and nowhere else: an amount that is no part of a span (the
+process's CPU over a sample, telemetry/collector.py) does not belong
+on the hook, and sched_host, whose interval wraps every other stage's,
+sends no companion (a tap that lets a wrapper name only what its
+children leave knows it by its name).
+
+Only the stages a reader asks for are in CPU_STAGES: a read of the
+thread clock costs a syscall where the kernel has no vDSO for it
+(5.8 us on the benchmark's machine, PERF.md). A wait measured after
+the fact (queue_wait, gateway_wait, plan_queue_wait, raft_lock_wait)
+and a summed report (port_assign, mask_build) have no interval on a
+thread and no CPU; where the platform has no thread clock none is
+read.
+
+enable() / snapshot() / disable() give a test the summed seconds, the
+summed CPU seconds and the call count of every stage reported in
+between. Shares of a whole
 are not computed here: the tree above is the flight recorder's
 (STAGE_PARENTS), and the benchmark reads its spans.
 
@@ -150,12 +197,40 @@ STAGES = ("restore", "wal_replay", "job_register", "snapshot_write",
           "gateway_wait", "kernel_pack", "kernel", "d2h",
           "kernel_expand", "select_finish", "port_assign",
           "plan_build", "plan_submit", "plan_queue_wait", "plan_verify",
-          "plan_commit", "wal_encode", "sched_host_self", "broker_ack")
+          "plan_commit", "raft_lock_wait", "wal_encode", "wal_write",
+          "fsm_apply", "event_publish", "sched_host_self", "broker_ack")
+
+# the spans that read their thread's CPU clock beside the wall clock:
+# the stages whose CPU a reader asks for, and not sched_host (docstring)
+CPU_STAGES = frozenset({
+    "job_register", "table_build", "select_prep", "kernel_pack",
+    "kernel", "plan_build", "plan_verify", "plan_commit", "fsm_apply"})
+# the companion report of a span's CPU seconds: <stage>_cpu
+CPU_SUFFIX = "_cpu"
+# the calling thread's CPU clock; None where the platform has none
+thread_time: Optional[Callable[[], float]] = getattr(
+    time, "thread_time", None)
+
+
+def cpu_now() -> Optional[float]:
+    """The calling thread's CPU clock, or None without one."""
+    return thread_time() if thread_time is not None else None
+
+
+def cpu_since(c0: Optional[float]) -> Optional[float]:
+    """CPU seconds of the calling thread since its cpu_now() gave c0."""
+    return None if c0 is None else thread_time() - c0
+
+
+def cpu_ms(cpu: float) -> float:
+    """CPU seconds as the `cpu_ms` a span's attrs carry."""
+    return round(max(cpu, 0.0) * 1000.0, 3)
+
 
 enabled = False
 
 _l = make_lock()
-_acc: Dict[str, list] = {s: [0.0, 0] for s in STAGES}
+_acc: Dict[str, list] = {s: [0.0, 0, 0.0] for s in STAGES}
 
 # the flight recorder's tap (nomad_tpu/trace/ installs it at import):
 # called as hook(stage, seconds, attrs) AFTER the accumulator update
@@ -182,6 +257,7 @@ def enable(reset: bool = True) -> None:
             for v in _acc.values():
                 v[0] = 0.0
                 v[1] = 0
+                v[2] = 0.0
         _collecting = True
         enabled = True
 
@@ -193,22 +269,30 @@ def disable() -> None:
 
 
 def add(stage: str, seconds: float,
-        attrs: Optional[dict] = None) -> None:
+        attrs: Optional[dict] = None,
+        cpu: Optional[float] = None) -> None:
     """Report `seconds` of wall clock spent in `stage`. Callers guard
     with `if stages.enabled:` so the disabled cost is one bool read.
     `attrs` ride through to the flight recorder's span (never into the
-    aggregate sums)."""
+    aggregate sums). `cpu`: the reporting thread's CPU seconds over the
+    same interval, where the site read them (Span does, for
+    CPU_STAGES); it goes out through the hook as one more report,
+    <stage>_cpu, after the stage's own."""
     if _collecting:
         with _l:
             ent = _acc.get(stage)
             if ent is None:             # unknown stage: count it anyway
-                ent = _acc.setdefault(stage, [0.0, 0])
+                ent = _acc.setdefault(stage, [0.0, 0, 0.0])
             ent[0] += seconds
             ent[1] += 1
+            if cpu is not None:
+                ent[2] += cpu
     hook = _trace_hook
     if _trace_on and hook is not None:
         try:
             hook(stage, seconds, attrs)
+            if cpu is not None:
+                hook(stage + CPU_SUFFIX, cpu, None)
         except Exception:       # pragma: no cover — defensive
             pass
 
@@ -216,12 +300,17 @@ def add(stage: str, seconds: float,
 class Span:
     """One stage's interval as a context manager: the clock is read on
     entry and exit and the report (add) is made as the interval ends —
-    on an exception too. While a profiler session is live a
-    jax.profiler.TraceAnnotation "nomad/<stage>" is held open for the
-    interval, so the stage sits in the same xplane, on the profiler's
-    clock, above the device ops it waited for."""
+    on an exception too. A stage of CPU_STAGES reads its thread's CPU
+    clock inside the wall clock's interval, so `cpu` <= `seconds` but
+    for the clocks' grain, and notes it as attr `cpu_ms`; `cpu` is None
+    for any other stage and where there is no thread clock. While a
+    profiler session is live a jax.profiler.TraceAnnotation
+    "nomad/<stage>" is held open for the interval, so the stage sits in
+    the same xplane, on the profiler's clock, above the device ops it
+    waited for."""
 
-    __slots__ = ("stage", "attrs", "seconds", "_t0", "_ann", "_live")
+    __slots__ = ("stage", "attrs", "seconds", "cpu", "_t0", "_c0",
+                 "_ann", "_live")
 
     def __init__(self, stage: str, attrs: dict):
         self.stage = stage
@@ -245,14 +334,18 @@ class Span:
         else:
             self._ann = None
         self._t0 = time.perf_counter()
+        self._c0 = cpu_now() if self.stage in CPU_STAGES else None
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
+        self.cpu = cpu_since(self._c0)
         self.seconds = time.perf_counter() - self._t0
         if self._ann is not None:
             self._ann.__exit__(et, ev, tb)
         if self._live:
-            add(self.stage, self.seconds, self.attrs or None)
+            if self.cpu is not None:
+                self.attrs["cpu_ms"] = cpu_ms(self.cpu)
+            add(self.stage, self.seconds, self.attrs or None, self.cpu)
         return False
 
 
@@ -261,6 +354,7 @@ class _NullSpan:
 
     __slots__ = ()
     seconds = 0.0
+    cpu = None
 
     def note(self, **attrs) -> None:
         pass
@@ -318,8 +412,11 @@ def span(stage: str, **attrs):
 
 
 def snapshot() -> Dict[str, dict]:
-    """{stage: {seconds, calls}} over every stage of STAGES, and any
-    other name reported, since enable()."""
+    """{stage: {seconds, calls, cpu_seconds}} over every stage of
+    STAGES, and any other name reported, since enable(). cpu_seconds
+    sums the reports that came with a CPU reading (spans), so it is 0
+    for a stage that only waits."""
     with _l:
-        return {s: {"seconds": round(v[0], 4), "calls": v[1]}
+        return {s: {"seconds": round(v[0], 4), "calls": v[1],
+                    "cpu_seconds": round(v[2], 4)}
                 for s, v in _acc.items() if v[1] > 0 or s in STAGES}

@@ -407,6 +407,11 @@ def test_a_failed_child_publishes_nothing_and_the_next_one_does(
         p.trigger_snapshot(s)
         assert entered.wait(CHILD_TIMEOUT_S), "the child never dumped"
         if fate == "killed":
+            # the child can say it entered before the writer thread,
+            # back from fork(), has noted its pid
+            deadline = time.monotonic() + CHILD_TIMEOUT_S
+            while not forks and time.monotonic() < deadline:
+                time.sleep(0.001)
             os.kill(forks[-1], signal.SIGKILL)
         _idle(p)
     assert p.stats["snapshot_errors"] == 1

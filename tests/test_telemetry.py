@@ -467,9 +467,80 @@ def test_telemetry_history_route_and_operator_top(monkeypatch):
         assert "Placements/s" in text
         assert "Device economics" in text
         assert "Flatness" in text
+        # the CPU ledger (ISSUE 36) reads as cores: the process's line,
+        # the roles beside it where a thread's clock can be read
+        assert "process.cpu_s" in tel["rates"]
+        cpu_line = next(line for line in text.splitlines()
+                        if line.startswith("CPU "))
+        assert "cores" in cpu_line
+        if "thread_cpu.workers_s" in tel["rates"]:
+            assert all(role in cpu_line for role in
+                       ("workers", "applier", "http", "other"))
     finally:
         api.shutdown()
         server.shutdown()
+
+
+def test_operator_top_puts_a_spans_cpu_beside_its_wall(monkeypatch):
+    """`operator top`'s stage table: a span's CPU companion is a column
+    of its stage's row, never a row of its own; `operator trace` prints
+    a span's `cpu_ms` among its attrs where the span read the clock."""
+    import contextlib
+    import io
+    from nomad_tpu import trace
+    from nomad_tpu.cli.main import main as cli_main
+    from nomad_tpu.utils import stages
+    server = Server(ServerConfig(num_schedulers=0,
+                                 telemetry_sample_interval_s=3600.0))
+    api = HTTPApiServer(server, port=0)
+    api.start()
+    try:
+        trace.tracer.force_threshold_ms = 0.0   # every trace an exemplar
+
+        class Ev:
+            id, job_id, namespace, type = "ev-top", "job-top", "default", \
+                "service"
+            queue_wait_s = 0.0
+        tr = trace.begin(Ev(), track="worker-0")
+        with trace.use(tr):
+            with stages.span("plan_build", placements=1):
+                sum(range(20000))
+            stages.add("sched_host_self", 0.001)
+        trace.finish(tr)
+        server.telemetry.sample_once(now=3_000_000.0)
+        def cli(*sub):
+            out = io.StringIO()
+            with contextlib.redirect_stdout(out):
+                assert cli_main(["-address",
+                                 f"http://127.0.0.1:{api.port}",
+                                 "operator", *sub]) == 0
+            return out.getvalue().splitlines()
+
+        lines = cli("top", "-n", "4")
+        head = next(l for l in lines if l.startswith("Stage")
+                    and "cpu p50 ms" in l)
+        assert head.split()[-1] == "Samples"
+        row = next(l for l in lines if l.startswith("plan_build ")
+                   and len(l.split()) == 5)
+        assert float(row.split()[3]) <= float(row.split()[1]) + 0.01
+        wait = next(l for l in lines if l.startswith("sched_host_self ")
+                    and len(l.split()) == 5)
+        assert wait.split()[3] == "-"
+        assert not any(l.startswith("plan_build_cpu") and
+                       len(l.split()) == 5 for l in lines)
+        # `operator trace`: the reservoirs' table lists the companion
+        # as what it is, a reservoir; the exemplar's tree says cpu_ms
+        lines = cli("trace")
+        assert any(l.startswith("plan_build_cpu ") for l in lines)
+        span = next(l for l in lines if " plan_build " in l and "[" in l)
+        assert '"placements": 1' in span and '"cpu_ms": ' in span
+        bare = next(l for l in lines if " sched_host_self " in l
+                    and "[" in l)
+        assert "cpu_ms" not in bare
+    finally:
+        api.shutdown()
+        server.shutdown()
+        trace.tracer.reset()
 
 
 def test_prometheus_route_reflects_registry():
@@ -543,10 +614,15 @@ def test_sample_once_reads_each_source_once_and_allocates_nothing_new():
                             extra_fn=extra)
     assert tc.sample_once(now=1000.0) == 1
     counters = {n for n in tc._series if n.startswith("counter.")}
+    # the CPU ledger (ISSUE 36): the process's clock and, where another
+    # thread's clock can be read, the four roles'
+    cpu = {"process.cpu_s"} | {n for n in tc._series
+                               if n.startswith("thread_cpu.")}
+    assert len(cpu) in (1, 5)
     want = {"process.rss_mb", "g.a", "g.b", "latency.p50_ms",
             "latency.p99_ms", "stage.kernel.p50_ms",
             "stage.kernel.p99_ms", "stage_count.kernel", "device.packs",
-            "cluster.nodes_total"} | counters
+            "cluster.nodes_total"} | counters | cpu
     assert set(tc._series) == want
     arrays = {n: id(a) for n, a in tc._series.items()}
     t_id, ring_bytes = id(tc._t), tc.status()["ring_bytes"]
