@@ -287,24 +287,31 @@ def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
     the job's own anti-affinity; no spread, no affinity) are ranked;
     the others only add their usage.
 
-    stacked   allocs of one job that share a node although the
-              reference's greedy would stack none: a node that already
+    stacked   plans that put an alloc on a node which then holds the
+              job twice or more (by this plan or an earlier one of the
+              job) although the reference's greedy, over the share that
+              ranked the plan, would stack none: a node that already
               holds the job scores at most (1 - 2/count)/2, and at
-              least `count` other nodes with room score above that.
+              least as many other nodes with room OF THAT SHARE as the
+              plan asked for score above that.
     rank_gap  the widest gap by which a plan's worst chosen node scores
               below the bound of the share that ranked it, k being the
-              plan's distinct nodes. `lanes` concurrent schedulers rank
-              large asks over disjoint shares of the fleet
-              (`decorrelation`, the configuration's stated rule: that
-              is how they avoid each other's winners). A plan of an ask
-              of `min_count` instances or more whose rows all lie in
-              one lane was ranked over that lane: its bound is the k-th
-              best node with room OF THAT LANE, whatever the other
-              lane still holds (a share can run out of a machine class
-              before the fleet does). Any other plan ranked the whole
-              fleet, beside up to `lanes` - 1 others that did: its
-              bound is the (lanes x k)-th best node with room of the
-              fleet. Without a rule every plan is of the second kind.
+              plan's distinct nodes.
+
+    The share. `lanes` concurrent schedulers rank large asks over
+    disjoint shares of the fleet (`decorrelation`, the configuration's
+    stated rule: that is how they avoid each other's winners). A plan
+    of an ask of `min_count` instances or more whose rows all lie in
+    one lane was ranked over that lane: both numbers hold it to THAT
+    LANE, whatever the other lane still holds (a share can run out of a
+    machine class before the fleet does), and its rank bound is the
+    k-th best node with room of the lane. Any other plan ranked the
+    whole fleet, beside up to `lanes` - 1 others that did: both numbers
+    hold it to the fleet, and its rank bound is the (lanes x k)-th best
+    node with room of the fleet. Without a rule every plan is of the
+    second kind. The rule's headroom test is not judged: the program
+    takes it on a snapshot that may trail this replay by the other
+    scheduler's plan in flight.
 
     With `residents` (a tiered configuration) `backlog` is the fleet as
     loaded, the resident allocations' evictions and replacements are
@@ -355,22 +362,29 @@ def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
                 score = np.where(before > 0, (score - (before + 1.0)
                                               / job["count"]) / 2.0, score)
                 asked -= int(before.sum())
-            if before is None and job["count"] > 1:
-                ceiling = (1.0 - 2.0 / job["count"]) / 2.0
-                mine = [row[a["node_id"]] for a in allocs[job["id"]]
-                        if a["node_id"] in row]
-                if int((room & (score > ceiling)).sum()) >= job["count"] \
-                        and len(mine) > len(set(mine)):
-                    stacked.append(f"{job['id']}: {len(mine)} allocs on "
-                                   f"{len(set(mine))} nodes")
             k = int((chosen > 0).sum())
-            pool, nth, share = room, lanes * k, f"the {lanes}x{k}-th best"
+            lane = None         # the lane that ranked this plan, if one did
             if lane_of is not None and k \
-                    and asked >= decorrelation["min_count"]:
-                lane = lane_of[rows[0]]
-                if bool((lane_of[rows] == lane).all()):
-                    pool, nth = room & (lane_of == lane), k
-                    share = f"lane {int(lane)}'s {k}-th best"
+                    and asked >= decorrelation["min_count"] \
+                    and bool((lane_of[rows] == lane_of[rows[0]]).all()):
+                lane = int(lane_of[rows[0]])
+            if lane is None:
+                pool, where = room, "fleet"
+                nth, share = lanes * k, f"the {lanes}x{k}-th best"
+            else:
+                pool, where = room & (lane_of == lane), f"lane {lane}"
+                nth, share = k, f"lane {lane}'s {k}-th best"
+            if job["count"] > 1:
+                ceiling = (1.0 - 2.0 / job["count"]) / 2.0
+                now = chosen if before is None else chosen + before
+                doubled = int(((chosen > 0) & (now > 1)).sum())
+                above = int((pool & (score > ceiling)).sum())
+                if doubled and above >= asked:
+                    stacked.append(
+                        f"{job['id']} plan {index}: {where}: {len(rows)} "
+                        f"allocs on {k} nodes, {doubled} holding the job "
+                        f"twice or more, {above:,} with room above the "
+                        f"ceiling")
             best = np.sort(score[pool])[::-1]
             if k and len(best):
                 ref = float(best[min(nth, len(best)) - 1])

@@ -174,21 +174,10 @@ def test_a_broker_still_busy_at_the_read_back_is_a_harness_problem():
     assert broke == ["harness_problems"], line["compared"]
 
 
-# REVIEW 32: not strict. The fault is the program's, and the PR that
-# mends it may not edit this file: the test then passes (an XPASS that
-# fails nothing) and the next `benchmark` PR takes the mark away. The
-# counts read are in the reason and in PERF.md section 7.
-@pytest.mark.xfail(strict=False, reason=(
-    "PROGRAM FAULT (PERF.md section 7, first): the stack scores nodes that "
-    "need an eviction in the same pass as nodes with room "
-    "(scheduler/generic.py select_batch(preemption_round=...), "
-    "stack.py:734-768) and evicts while the fleet has room: "
-    "evicted_with_room read 82 of 82 and 76 of 76 placements (seeds "
-    "2147483999, 11; 4 victims each on a 1x node) at 640 nodes with 320 "
-    "free slots, and 58 of 58 / 49 of 49 on the per-node path "
-    "(NOMAD_TPU_COLUMNAR_PREEMPT=0); upstream's selectNextOption tries "
-    "preemption only after a select without it found no node"))
 def test_the_program_fills_room_before_it_evicts():
+    """Upstream's selectNextOption tries preemption only after a select
+    without it found no node: on a toy whose fleet still has room, no
+    placement evicts."""
     line, err = rehearse(EVICT_ROOM, "--trace", "0")
     _read, evicted, _fresh = read_back(err)
     assert line["compared"]["evicted_with_room"]["value"] == 0, \

@@ -457,8 +457,6 @@ def exhausted(cfg, rule):
     """5,120 nodes of every class; lane 0 has no 1x node with room left
     (its share's generations are spent), lane 1's are untouched. One
     job of 600 ranked by the program's K-way arm as scheduler 0."""
-    import numpy as np
-    import nomad_tpu.ops.select as sel
     fleet = fleetlib.build_fleet(cfg, 11, 5120)
     backlog = fleetlib.backlog_usage(cfg, fleet)
     lane_of = ref.lane_ids(len(fleet), LANES, rule)
@@ -467,6 +465,14 @@ def exhausted(cfg, rule):
             backlog[n["id"]] = dict(backlog[n["id"]],
                                     cpu=n["capacity"]["cpu"])
     job, = jobs_of("batch-fill", [600])
+    return fleet, backlog, job, ranked_by_lane_0(fleet, backlog, job), lane_of
+
+
+def ranked_by_lane_0(fleet, backlog, job):
+    """The job's one plan as the program's K-way arm ranks it for
+    scheduler 0 of two."""
+    import numpy as np
+    import nomad_tpu.ops.select as sel
     dims = fleetlib.DIMS
     req = sel.SelectRequest(
         ask=np.array([job["ask"][d] for d in dims], np.float32),
@@ -485,12 +491,26 @@ def exhausted(cfg, rule):
     assert res.placed == job["count"]
     assert sel.device_stats_snapshot()["dispatches"]["kway"] == before + 1
     rows = [int(r) for r in res.node_idx[:res.placed]]
-    allocs = {job["id"]: [
+    return {job["id"]: [
         {"id": f"a{i}", "name": f"{job['id']}.{job['group']}[{i}]",
          "node_id": fleet[r]["id"], "job_id": job["id"],
          "desired_status": "run", "create_index": 7}
         for i, r in enumerate(rows)]}
-    return fleet, backlog, job, allocs, lane_of
+
+
+@pytest.fixture(scope="module")
+def overfull(exhausted):
+    """A job of 1,200 on the exhausted fleet, ranked by the program:
+    more than lane 0 has nodes with room (796 2x, 254 4x), so it
+    stacks there, while lane 1 still has 1,562 untouched 1x nodes."""
+    fleet, backlog, _job, _allocs, lane_of = exhausted
+    job, = jobs_of("batch-fill", [1200])
+    allocs = ranked_by_lane_0(fleet, backlog, job)
+    row = {n["id"]: i for i, n in enumerate(fleet)}
+    rows = [row[a["node_id"]] for a in allocs[job["id"]]]
+    assert {int(lane_of[r]) for r in rows} == {0}
+    assert len(set(rows)) == 1050
+    return job, allocs
 
 
 def test_a_share_that_ran_out_of_a_class_is_judged_within_the_share(
@@ -534,6 +554,71 @@ def test_a_plan_that_skips_its_lane_s_best_by_a_class_fails(exhausted, rule):
     across[job["id"]][5]["node_id"] = small["id"]
     gap = ref.check_rank(fleet, backlog, [job], across, LANES, rule)[1]
     assert gap == pytest.approx(scorer.score(small) - scorer.score(mid))
+
+
+def _onto(allocs, job, i, node_id):
+    moved = copy.deepcopy(allocs)
+    moved[job["id"]][i]["node_id"] = node_id
+    return moved
+
+
+@pytest.mark.parametrize("case,pool", [
+    ("lane_ran_out", None),
+    ("stacked_in_its_lane", "plan 7: lane 0"),
+    ("straddles_the_lanes", "plan 7: fleet"),
+    ("under_min_count", "plan 7: fleet"),
+    ("retry_stacks_in_the_fleet", "plan 8: fleet")])
+def test_stacked_is_judged_in_the_share_that_ranked_the_plan(
+        cfg, rule, exhausted, overfull, case, pool):
+    """`stacked` holds a plan to the share `rank_gap` holds it to.
+    lane_ran_out: the program's 1,200 on lane 0, whose nodes above the
+    ceiling are all gone (its 1x class is full): the greedy confined to
+    the lane stacks too, though lane 1 holds 1,562 such nodes.
+    stacked_in_its_lane: the 600-plan with one alloc moved onto a node
+    it holds, on the fleet as loaded, where lane 0 still has its 1x
+    nodes. straddles_the_lanes: the 1,200-plan with one alloc moved
+    onto a lane 1 node. under_min_count: 200 stacked two a node on lane
+    0's 2x nodes, an ask the rule never slices. retry_stacks_in_the_fleet:
+    the 1,200-plan's last ten committed apart, as a retry of ten (under
+    min_count, so ranked over the fleet), two on each of five lane 1 1x
+    nodes: the sound first plan reads nothing, the retry reads stacked.
+    Without the rule every plan is judged over the fleet and reads
+    stacked."""
+    fleet, backlog, job, allocs, lane_of = exhausted
+    if case == "lane_ran_out":
+        job, allocs = overfull
+    elif case == "stacked_in_its_lane":
+        backlog = fleetlib.backlog_usage(cfg, fleet)
+        allocs = _onto(allocs, job, 1, allocs[job["id"]][0]["node_id"])
+    elif case == "straddles_the_lanes":
+        job, allocs = overfull
+        held = {a["node_id"] for a in allocs[job["id"]]}
+        other = next(n for i, n in enumerate(fleet) if lane_of[i] == 1
+                     and n["class"] == "c1x" and n["id"] not in held)
+        allocs = _onto(allocs, job, 0, other["id"])
+    elif case == "retry_stacks_in_the_fleet":
+        job, allocs = overfull
+        allocs = copy.deepcopy(allocs)
+        small = [n for i, n in enumerate(fleet) if lane_of[i] == 1
+                 and n["class"] == "c1x"][:5]
+        for i, a in enumerate(allocs[job["id"]][-10:]):
+            a["node_id"], a["create_index"] = small[i // 2]["id"], 8
+    else:
+        job, = jobs_of("batch-fill", [200])
+        mid = [n for i, n in enumerate(fleet) if n["class"] == "c2x"
+               and lane_of[i] == 0][:100]
+        allocs = {job["id"]: [
+            {"id": f"a{i}", "node_id": mid[i // 2]["id"],
+             "name": f"{job['id']}.{job['group']}[{i}]", "create_index": 7}
+            for i in range(200)]}
+    stacked = ref.check_rank(fleet, backlog, [job], allocs, LANES, rule)[0]
+    whole = ref.check_rank(fleet, backlog, [job], allocs, LANES)[0]
+    assert whole and all(": fleet: " in w for w in whole), whole
+    if pool is None:
+        assert stacked == []
+    else:
+        assert len(stacked) == 1 and f" {pool}: " in stacked[0], stacked
+        assert "with room above the ceiling" in stacked[0]
 
 
 def test_small_asks_and_retries_below_the_rule_s_size_rank_the_fleet(

@@ -132,6 +132,23 @@ def test_closed_loop_has_jobs_for_the_fastest_program_allowed():
     assert len(reqs) * m["bulk"] >= 45.0 * m["max_jobs_per_s"]
 
 
+def test_batch_request_ceiling_grew_and_kept_the_list_s_head():
+    """Four times what the program completes, so that a faster program
+    does not run out of requests before the close; the deck is one size
+    and the ids count up, so a list built at 12 jobs a second is the
+    head of it."""
+    m = mix("batch-fill")
+    assert m["max_jobs_per_s"] == 32.0 and "not a rate" in m["max_jobs_note"]
+    new = traffic.closed_loop(m, 7, 51.0, DCS)
+    old = traffic.closed_loop(dict(m, max_jobs_per_s=12.0), 7, 51.0, DCS)
+
+    def jobs(reqs):
+        return [j for r in reqs for j in r.jobs]
+    assert len(jobs(old)) == 752 and len(jobs(new)) >= 1900
+    assert jobs(new)[:752] == jobs(old)
+    assert [r.body for r in new[:len(old)]] == [r.body for r in old]
+
+
 @pytest.mark.parametrize("name,largest", [("service-stream", 50),
                                           ("batch-fill", 1000)])
 def test_warmup_covers_every_bucket_of_the_deck(name, largest):
@@ -168,7 +185,7 @@ def test_toy_rehearsal_shrinks_every_job_with_the_fleet():
     m = mix("batch-fill")
     traffic.scale_counts(m, 640 / 10000)
     assert m["deck"] == [64] and m["warmup"]["solo"] == [64, 45, 19, 3]
-    assert m["max_jobs_per_s"] == 12.0 / 0.064
+    assert m["max_jobs_per_s"] == 32.0 / 0.064
     assert all(c >= 1 for b in m["warmup"]["bursts"] for c in b)
     m = mix("service-stream")
     traffic.scale_counts(m, 0.01)
