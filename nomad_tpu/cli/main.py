@@ -1422,6 +1422,10 @@ def cmd_operator_top(args) -> int:
             if tail_vals(rates, f"thread_cpu.{role}_s"))
         print(f"CPU           = {rate_now('process.cpu_s'):.2f} cores"
               + (f" ({by_role})" if by_role else ""))
+    cl = tail_vals(series, "state.changelog")
+    if cl:
+        trims = (tail_vals(series, "state.changelog_trims") or [0.0])[-1]
+        print(f"Change log    = {cl[-1]:.0f} entries, {trims:.0f} trims")
     try:
         flat = c.flatness()
         if flat.get("enabled", flat.get("pass") is not None):

@@ -788,7 +788,13 @@ class Server:
                      WatermarkPolicy(cfg.governor_version_debt_high),
                      reclaim=lambda: self.store.compact(min_tip=1024,
                                                         force=True))
-        gov.register("state.changelog", self.store.changelog_len)
+        gov.register("state.changelog",
+                     lambda: self.store.changelog_stats()["len"])
+        # the log's trims, one a publish past CHANGELOG_MAX: monotone,
+        # never a drift suspect (one series: the ring is near MAX_SERIES)
+        gov.register("state.changelog_trims",
+                     lambda: self.store.changelog_stats()["trims"],
+                     suspect=False)
         gov.register("state.allocs",
                      lambda: len(self.store._root.table("allocs")))
         gov.register("state.evals",

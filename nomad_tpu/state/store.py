@@ -460,6 +460,9 @@ class StateStore(StateSnapshot):
         self._changes: List[Tuple[int, str, str]] = []
         self._change_indexes: List[int] = []
         self._change_floor = 0
+        # publishes that trimmed the log, and the entries they dropped
+        self._changelog_trims = 0
+        self._changelog_dropped = 0
         from ..ops.tables import NodeTableCache
         self.table_cache = NodeTableCache()
         # columnar per-job alloc index (state/alloc_index.py): the
@@ -478,13 +481,25 @@ class StateStore(StateSnapshot):
 
     # -- changelog -----------------------------------------------------
     def _log_change(self, index: int, kind: str, key: str) -> None:
+        # append only: _publish trims the log once per transaction (a
+        # per-change trim of a full log shifts all CHANGELOG_MAX entries
+        # for every allocation a plan places)
         self._changes.append((index, kind, key))
         self._change_indexes.append(index)
-        if len(self._changes) > self.CHANGELOG_MAX:
-            drop = len(self._changes) - self.CHANGELOG_MAX
-            self._change_floor = self._changes[drop - 1][0]
-            del self._changes[:drop]
-            del self._change_indexes[:drop]
+
+    def _trim_changes(self) -> None:
+        """Drop what lies beyond CHANGELOG_MAX, oldest first; the floor
+        becomes the index of the last entry dropped. Called under the
+        store lock, so no changes_since reader sees the log between a
+        transaction's appends and this trim."""
+        drop = len(self._changes) - self.CHANGELOG_MAX
+        if drop <= 0:
+            return
+        self._change_floor = self._changes[drop - 1][0]
+        del self._changes[:drop]
+        del self._change_indexes[:drop]
+        self._changelog_trims += 1
+        self._changelog_dropped += drop
 
     def changes_since(self, from_idx: int,
                       to_idx: int) -> Optional[List[Tuple[str, str]]]:
@@ -522,9 +537,13 @@ class StateStore(StateSnapshot):
                 debt += ov()
         return debt
 
-    def changelog_len(self) -> int:
+    def changelog_stats(self) -> Dict[str, int]:
+        """The change log's length and floor, and the publishes that
+        trimmed it with the entries they dropped."""
         with self._lock:
-            return len(self._changes)
+            return {"len": len(self._changes), "floor": self._change_floor,
+                    "trims": self._changelog_trims,
+                    "dropped": self._changelog_dropped}
 
     def compact(self, min_tip: int = 1024, force: bool = False) -> dict:
         """Fold every table whose overlay warrants it into its base,
@@ -599,6 +618,7 @@ class StateStore(StateSnapshot):
             return True
 
     def _publish(self, root: _Root) -> None:
+        self._trim_changes()
         # seal any open edit context: published roots are immutable
         self._root = root.frozen()
         with self._watch:

@@ -404,6 +404,7 @@ class TestGovernorServerWiring:
         for expected in ("broker.ready", "plan_queue.depth",
                          "service.p99_ms", "event_broker.events",
                          "event_broker.bytes", "state.version_debt",
+                         "state.changelog", "state.changelog_trims",
                          "kernel_cache.entries"):
             assert expected in names, expected
         assert server.eval_broker.pressure_fn is not None

@@ -131,6 +131,48 @@ def test_changelog_truncation_forces_rebuild():
     _assert_tables_equal(t, NodeTable.build_all(snap))
 
 
+def test_plan_crossing_the_changelog_cap_keeps_delta_table_exact():
+    """A plan of many allocations that carries the log past its cap in
+    the middle of its transaction: the publish trims once, the table
+    refreshed from changes_since equals a full build, both while the
+    floor stays under the table's index (deltas) and once it passes it
+    (rebuild)."""
+    s, nodes = _store_with_nodes(4)
+    s.CHANGELOG_MAX = 250
+    t0 = s.snapshot().node_table()
+    t0.mask_cache[("probe",)] = [("r", np.ones(4, bool))]
+
+    def plan(index, n):
+        placed = []
+        for k in range(n):
+            a = mock.alloc()
+            a.node_id = nodes[k % len(nodes)].id
+            placed.append(a)
+        s.upsert_plan_results(index, allocs_stopped=[],
+                              allocs_placed=placed, allocs_preempted=[])
+
+    plan(100, 120)
+    s.snapshot().node_table()           # the table moves to index 100
+    trims = s.changelog_stats()["trims"]
+    plan(101, 200)                      # 324 entries -> one trim to 250
+    stats = s.changelog_stats()
+    assert stats["trims"] == trims + 1
+    assert stats["len"] == 250
+    assert stats["floor"] == 100
+    snap = s.snapshot()
+    t1 = snap.node_table()
+    # refreshed by deltas (an alloc-only refresh keeps the mask cache)
+    assert ("probe",) in t1.mask_cache
+    _assert_tables_equal(t1, NodeTable.build_all(snap))
+
+    plan(102, 300)                      # the floor passes index 101
+    assert s.changelog_stats()["floor"] == 102
+    snap = s.snapshot()
+    t2 = snap.node_table()
+    assert ("probe",) not in t2.mask_cache     # rebuilt
+    _assert_tables_equal(t2, NodeTable.build_all(snap))
+
+
 def test_hamt_update_transient_preserves_old_versions():
     h = Hamt()
     for i in range(100):

@@ -1,5 +1,7 @@
 """State store tests (reference patterns: nomad/state/state_store_test.go)."""
 
+import bisect
+import dataclasses
 import threading
 import time
 
@@ -13,6 +15,7 @@ from nomad_tpu.models import (
 )
 from nomad_tpu.models.node import DrainStrategy
 from nomad_tpu.state import StateStore
+from nomad_tpu.utils.ids import generate_uuid
 
 
 def test_upsert_node_and_snapshot_isolation():
@@ -225,3 +228,129 @@ def test_deployment_lifecycle():
     s.update_deployment_status(3, DeploymentStatusUpdate(
         deployment_id=d.id, status="successful", status_description="done"))
     assert s.deployment_by_id(d.id).status == "successful"
+
+
+class _PerChangeLog:
+    """The change log as it was trimmed before the trim moved to the
+    publish: every logged change trims on its own. The oracle the
+    store's log is held to after every publish."""
+
+    def __init__(self, cap):
+        self.cap = cap
+        self.changes = []
+        self.indexes = []
+        self.floor = 0
+
+    def log(self, index, kind, key):
+        self.changes.append((index, kind, key))
+        self.indexes.append(index)
+        if len(self.changes) > self.cap:
+            drop = len(self.changes) - self.cap
+            self.floor = self.changes[drop - 1][0]
+            del self.changes[:drop]
+            del self.indexes[:drop]
+
+    def since(self, from_idx, to_idx):
+        if from_idx < self.floor:
+            return None
+        lo = bisect.bisect_right(self.indexes, from_idx)
+        hi = bisect.bisect_right(self.indexes, to_idx)
+        return [(k, key) for (_i, k, key) in self.changes[lo:hi]]
+
+
+@pytest.mark.parametrize("cap", [25, 600, 1500])
+def test_changelog_trimmed_per_publish_matches_per_change(cap):
+    """The log trims once a published transaction, not once a logged
+    change: after every publish its entries, floor and every
+    changes_since answer equal the per-change oracle's, and a
+    transaction that leaves the log past the cap trims exactly once."""
+    s = StateStore()
+    s.CHANGELOG_MAX = cap   # instance override, as the resident tests do
+    oracle = _PerChangeLog(cap)
+    trims = []
+
+    log = s._log_change
+
+    def logged(index, kind, key):
+        log(index, kind, key)
+        oracle.log(index, kind, key)
+
+    publish = s._publish
+
+    def published(root):
+        n = len(s._changes)
+        before = s.changelog_stats()
+        publish(root)
+        after = s.changelog_stats()
+        over = n > cap
+        assert after["trims"] - before["trims"] == int(over)
+        assert after["dropped"] - before["dropped"] == max(0, n - cap)
+        if over:
+            trims.append(n - cap)
+        assert s._changes == oracle.changes
+        assert s._change_indexes == oracle.indexes
+        assert s._change_floor == oracle.floor
+        assert after["len"] == len(oracle.changes)
+        assert after["floor"] == oracle.floor
+        latest = s.latest_index()
+        for i in range(latest + 1):
+            assert s.changes_since(i, latest) == oracle.since(i, latest), i
+
+    s._log_change = logged
+    s._publish = published
+
+    idx = 0
+
+    def nxt():
+        nonlocal idx
+        idx += 1
+        return idx
+
+    nodes = []
+    for i in range(6):
+        n = mock.node()
+        n.name = f"n{i}"
+        nodes.append(n)
+        s.upsert_node(nxt(), n)
+    job = mock.job()
+    s.upsert_job(nxt(), job)
+    base = mock.alloc()
+    base.job_id = job.id
+    base.job = job
+
+    def fresh(k, node):
+        return dataclasses.replace(base, id=generate_uuid(),
+                                   node_id=node.id,
+                                   name=f"{job.id}.web[{k}]")
+
+    singles = []
+
+    def single(k):
+        a = fresh(k, nodes[k % len(nodes)])
+        singles.append(a)
+        s.upsert_allocs(nxt(), [a])
+
+    # one entry an index: at the smallest cap the trims fall between
+    # transactions, where the floor is the last dropped entry's index
+    for k in range(30):
+        single(k)
+    for r in range(3):
+        for k in range(6):
+            single(k)
+        stops = []
+        for a in singles[-3:]:
+            stop = a.copy()
+            stop.desired_status = ALLOC_DESIRED_STOP
+            stops.append(stop)
+        placed = [fresh(k, nodes[k % len(nodes)]) for k in range(1000)]
+        s.upsert_plan_results(nxt(), allocs_stopped=stops,
+                              allocs_placed=placed, allocs_preempted=[])
+        s.update_node_status(nxt(), nodes[r].id, NODE_STATUS_DOWN)
+        s.upsert_node(nxt(), nodes[r].copy())
+    assert trims, "no publish crossed the cap"
+    # a 1,000-allocation plan past the cap dropped many entries in one
+    # trim where the per-change log trimmed once an entry
+    assert max(trims) > 1
+    assert s.changelog_stats()["trims"] == len(trims)
+    assert s.changelog_stats()["dropped"] == sum(trims)
+    assert len(s._changes) == cap

@@ -481,6 +481,42 @@ def test_telemetry_history_route_and_operator_top(monkeypatch):
         server.shutdown()
 
 
+def test_operator_top_reads_the_change_logs_trims():
+    """The store's change-log trims ride the governor's gauge into the
+    ring, and `operator top` prints them: one trim a publish past the
+    cap."""
+    import contextlib
+    import io
+    from nomad_tpu import mock
+    from nomad_tpu.cli.main import main as cli_main
+    server = Server(ServerConfig(num_schedulers=0,
+                                 telemetry_sample_interval_s=3600.0))
+    api = HTTPApiServer(server, port=0)
+    api.start()
+    try:
+        store = server.store
+        store.CHANGELOG_MAX = 3
+        for i in range(5):
+            store.upsert_node(10_000 + i, mock.node())
+        assert store.changelog_stats()["trims"] == 2
+        server.governor.sample_once()
+        server.telemetry.sample_once()
+        tel = ApiClient(f"http://127.0.0.1:{api.port}").telemetry(last=4)
+        assert tel["series"]["state.changelog_trims"][-1] == 2
+        assert store.changelog_stats()["dropped"] == 2
+        out = io.StringIO()
+        with contextlib.redirect_stdout(out):
+            rc = cli_main(["-address", f"http://127.0.0.1:{api.port}",
+                           "operator", "top", "-n", "16"])
+        assert rc == 0
+        line = next(ln for ln in out.getvalue().splitlines()
+                    if ln.startswith("Change log"))
+        assert "3 entries, 2 trims" in line
+    finally:
+        api.shutdown()
+        server.shutdown()
+
+
 def test_operator_top_puts_a_spans_cpu_beside_its_wall(monkeypatch):
     """`operator top`'s stage table: a span's CPU companion is a column
     of its stage's row, never a row of its own; `operator trace` prints
