@@ -106,18 +106,28 @@ class GcWatch:
 class StageTap:
     """Every utils/stages report (stage, seconds, attrs), stamped with
     the host time it ended at, forwarded to the flight recorder that
-    owned the hook before."""
+    owned the hook before. A companion `<stage>_cpu` is the CPU clock
+    read over its span's own interval and sent right after the span by
+    the same thread: it is stamped with the span's end, not with its
+    own arrival, which trails by whatever held the interpreter lock
+    while the flight recorder took the span."""
 
     def __init__(self):
         from nomad_tpu.utils import stages
         self._stages = stages
         self._prev = stages._trace_hook
         self._prev_on = stages._trace_on
+        self._last = threading.local()      # this thread's last report
         self.samples: List[Tuple[str, float, float]] = []  # stage, end, s
         stages.set_trace_hook(self._on, on=True)
 
     def _on(self, stage: str, seconds: float, attrs=None) -> None:
-        self.samples.append((stage, time.perf_counter(), seconds))
+        end = time.perf_counter()
+        last = getattr(self._last, "report", None)
+        if last is not None and stage == last[0] + self._stages.CPU_SUFFIX:
+            end = last[1]
+        self._last.report = (stage, end)
+        self.samples.append((stage, end, seconds))
         if self._prev is not None and self._prev_on:
             self._prev(stage, seconds, attrs)
 
@@ -158,6 +168,16 @@ def _model_node(plain: dict, cfg: dict):
     node.reserved_resources.cpu_shares = rsv["cpu"]
     node.reserved_resources.memory_mb = rsv["memory_mb"]
     node.reserved_resources.disk_mb = rsv["disk_mb"]
+    if plain.get("devices"):
+        from nomad_tpu.models import NodeDevice, NodeDeviceResource
+        # the attributes as a node registered over RPC holds them: the
+        # fingerprint's values, which the program types as it compares
+        # them (plugins/psstructs)
+        node.node_resources.devices = [NodeDeviceResource(
+            vendor=g["vendor"], type=g["type"], name=g["model"],
+            attributes=dict(g["attributes"]),
+            instances=[NodeDevice(id=i, healthy=True) for i in g["ids"]])
+            for g in plain["devices"]]
     node.compute_class()
     return node
 

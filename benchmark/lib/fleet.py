@@ -30,8 +30,18 @@ def class_of(cfg: dict, i: int) -> dict:
 
 
 def build_fleet(cfg: dict, seed: int, n_nodes: int = 0) -> List[dict]:
-    """Plain node dicts sorted by id — the server's table row order."""
+    """Plain node dicts sorted by id — the server's table row order.
+
+    A machine class may carry `devices`, a list of groups {vendor, type,
+    model, count, attributes} as a device plugin fingerprints them
+    (attributes: numbers, strings, or numbers with a unit such as
+    "16 GiB"); each node of the class then holds them as
+    {vendor, type, model, attributes, ids}, `count` healthy instances
+    whose ids are UUIDs drawn from a stream of their own, so that the
+    node ids, and with them the row order, are the same with or without
+    devices."""
     rng = random.Random(seed)
+    dev_rng = random.Random(f"{seed}/devices")
     n_nodes = n_nodes or cfg["nodes"]
     n_dcs, n_racks = cfg["datacenters"], cfg["racks"]
     res, rsv = cfg["node"]["resources"], cfg["node"]["reserved"]
@@ -51,6 +61,14 @@ def build_fleet(cfg: dict, seed: int, n_nodes: int = 0) -> List[dict]:
             "drivers": list(cfg["node"]["drivers"]),
             "capacity": {d: res[d] * cls["scale"] - rsv[d] for d in DIMS},
         })
+        if cls.get("devices"):
+            fleet[-1]["devices"] = [{
+                "vendor": g["vendor"], "type": g["type"],
+                "model": g["model"], "attributes": dict(g["attributes"]),
+                "ids": [str(uuid.UUID(int=dev_rng.getrandbits(128),
+                                      version=4))
+                        for _ in range(g["count"])]}
+                for g in cls["devices"]]
     fleet.sort(key=lambda n: n["id"])
     return fleet
 

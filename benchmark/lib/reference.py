@@ -17,8 +17,10 @@ from __future__ import annotations
 
 import collections
 import heapq
+import json
 import math
 import re
+from fractions import Fraction
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -49,6 +51,10 @@ TIER_LIMITS = {
     "evicted_wrongly": 0, "evicted_needlessly": 0, "evicted_with_room": 0,
     "residents_stopped": 0,
 }
+
+# A configuration whose machine classes carry device groups is held to
+# one more, an exact count too (check_devices)
+DEVICE_LIMITS = {"device_conflicts": 0}
 
 # upstream's filterAndGroupPreemptibleAllocs: an allocation may be
 # preempted by a job whose priority is at least this much higher
@@ -96,8 +102,132 @@ def constraint_ok(node: dict, constraint) -> bool:
                      f"vocabulary")
 
 
-def node_feasible(node: dict, job: dict) -> List[str]:
-    """Why `node` may NOT run `job` ([] when it may)."""
+# ---------------------------------------------------------------------
+# Devices: upstream's device stanza (a name, a count, constraints and
+# affinities on ${device.*}), DeviceChecker and AssignDevice. A node's
+# group is the plain dict fleet.build_fleet makes: vendor, type, model,
+# attributes (the fingerprint's values) and the ids of its instances,
+# all healthy.
+# ---------------------------------------------------------------------
+
+# A unit: its dimension and how many of the dimension's base unit it is
+# (the NVIDIA plugin fingerprints memory in MiB, clocks in MHz, power in
+# W). Two values compare only within one dimension, or both bare.
+UNITS = {
+    "B": ("byte", 1), "KiB": ("byte", 2 ** 10), "MiB": ("byte", 2 ** 20),
+    "GiB": ("byte", 2 ** 30), "TiB": ("byte", 2 ** 40),
+    "kB": ("byte", 10 ** 3), "KB": ("byte", 10 ** 3), "MB": ("byte", 10 ** 6),
+    "GB": ("byte", 10 ** 9), "TB": ("byte", 10 ** 12),
+    "MHz": ("hertz", 10 ** 6), "GHz": ("hertz", 10 ** 9),
+    "mW": ("watt", Fraction(1, 1000)), "W": ("watt", 1), "kW": ("watt", 10 ** 3),
+}
+_UNITS_LONGEST_FIRST = sorted(UNITS, key=len, reverse=True)
+
+DEVICE_OPERANDS = ("=", "!=", "<", "<=", ">", ">=")
+
+
+def device_value(value) -> tuple:
+    """A fingerprint's value as ("number", amount in the base unit, its
+    dimension or None) or ("string", text): a number, or a string that
+    is a number and one of UNITS ("16 GiB", "1530 MHz", "300 W"), is a
+    number; anything else is a string."""
+    text = str(value).lower() if isinstance(value, bool) else str(value)
+    unit = next((u for u in _UNITS_LONGEST_FIRST
+                 if text[-1:].isalpha() and text.endswith(u)), None)
+    digits = text[:-len(unit)].strip() if unit else text
+    try:
+        amount = Fraction(digits)
+    except (ValueError, ZeroDivisionError):
+        return ("string", text)
+    if unit is None:
+        return ("number", amount, None)
+    dimension, scale = UNITS[unit]
+    return ("number", amount * scale, dimension)
+
+
+def compare_device_values(left, right) -> Optional[int]:
+    """-1, 0 or 1, or None where the two cannot be compared: a number
+    and a string, or numbers in two dimensions (a bare number against
+    one with a unit among them)."""
+    a, b = device_value(left), device_value(right)
+    if a[0] != b[0] or a[2:] != b[2:]:
+        return None
+    return (a[1] > b[1]) - (a[1] < b[1])
+
+
+def _resolve_device(group: dict, target: str) -> Tuple[Optional[object], bool]:
+    """${device.vendor|type|model} and ${device.attr.<key>} of a group;
+    a target that is no interpolation is a literal."""
+    if not target.startswith("${"):
+        return target, True
+    key = target[2:-1]
+    if key in ("device.vendor", "device.type", "device.model"):
+        return group[key[7:]], True
+    if key.startswith("device.attr."):
+        val = group["attributes"].get(key[12:])
+        return val, val is not None
+    raise ValueError(f"device target {target!r} is outside the reference's "
+                     f"vocabulary")
+
+
+def device_constraint_ok(group: dict, constraint) -> bool:
+    """One device constraint or affinity's test against one group
+    (feasible.go checkAttributeConstraint): `!=` holds where one side is
+    missing, every other operand needs both; values that cannot be
+    compared satisfy nothing."""
+    ltarget, operand, rtarget = constraint[:3]
+    if operand not in DEVICE_OPERANDS:
+        raise ValueError(f"device operand {operand!r} is outside the "
+                         f"reference's vocabulary")
+    lval, lfound = _resolve_device(group, ltarget)
+    rval, rfound = _resolve_device(group, rtarget)
+    if operand == "!=" and lfound != rfound:
+        return True
+    if not (lfound and rfound):
+        return False
+    c = compare_device_values(lval, rval)
+    if c is None:
+        return False
+    return {"=": c == 0, "!=": c != 0, "<": c < 0, "<=": c <= 0,
+            ">": c > 0, ">=": c >= 0}[operand]
+
+
+def device_name_matches(group: dict, name: str) -> bool:
+    """An ask's name in upstream's three forms: `type`,
+    `vendor/type` or `vendor/type/model`."""
+    parts = name.split("/")
+    vendor, kind, model = (["", name, ""] if len(parts) == 1
+                           else [parts[0], parts[1], "/".join(parts[2:])])
+    return all(not want or want == group[key] for want, key in
+               ((vendor, "vendor"), (kind, "type"), (model, "model")))
+
+
+def group_satisfies(group: dict, ask: dict) -> bool:
+    """The ask's name and every one of its constraints."""
+    return device_name_matches(group, ask["name"]) and all(
+        device_constraint_ok(group, c) for c in ask["constraints"])
+
+
+def devices_ok(node: dict, job: dict) -> bool:
+    """DeviceChecker: every ask of `job` has a group of `node` that
+    satisfies it with at least `count` healthy instances."""
+    return all(any(group_satisfies(g, ask) and len(g["ids"]) >= ask["count"]
+                   for g in node.get("devices") or ())
+               for ask in job.get("devices") or ())
+
+
+def _shape(job: dict) -> tuple:
+    """What node_feasible reads of a job, as a key."""
+    key = (job["driver"], tuple(job["datacenters"]),
+           tuple(job["constraints"]))
+    if job.get("devices"):
+        key += (json.dumps(job["devices"], sort_keys=True),)
+    return key
+
+
+def node_feasible(node: dict, job: dict, devices: bool = True) -> List[str]:
+    """Why `node` may NOT run `job` ([] when it may); `devices`: whether
+    its device asks count."""
     why = []
     if node["datacenter"] not in job["datacenters"]:
         why.append(f"datacenter {node['datacenter']}")
@@ -106,7 +236,87 @@ def node_feasible(node: dict, job: dict) -> List[str]:
     for c in job["constraints"]:
         if not constraint_ok(node, c):
             why.append(f"constraint {c}")
+    if devices and not devices_ok(node, job):
+        why.append(f"devices {[a['name'] for a in job['devices']]}")
     return why
+
+
+class DeviceFleet:
+    """The fleet's device groups as arrays (row = the fleet's order,
+    column = a group's place on its node), and what one allocation of a
+    job takes of them where a node says so alone."""
+
+    def __init__(self, fleet: List[dict]):
+        self.fleet = fleet
+        groups = [n.get("devices") or [] for n in fleet]
+        self.instances = np.zeros((len(fleet), max(map(len, groups),
+                                                   default=0)), np.int64)
+        for i, gs in enumerate(groups):
+            for j, g in enumerate(gs):
+                self.instances[i, j] = len(g["ids"])
+        self._takes: Dict[str, Tuple[np.ndarray, np.ndarray]] = {}
+
+    def takes(self, job: dict) -> Tuple[np.ndarray, np.ndarray]:
+        """(per row and group, the instances one allocation of `job`
+        takes there; per row, whether that is known): known where every
+        ask is satisfied by at most one group of the node (by none: a
+        placement there is infeasible and takes nothing); where two or
+        more satisfy one ask, which one the program took only the
+        allocation's own grant says."""
+        key = json.dumps(job.get("devices") or [], sort_keys=True)
+        hit = self._takes.get(key)
+        if hit is not None:
+            return hit
+        need = np.zeros_like(self.instances)
+        known = np.ones(len(self.fleet), dtype=bool)
+        for i, node in enumerate(self.fleet):
+            groups = node.get("devices") or []
+            for ask in job.get("devices") or ():
+                fit = [j for j, g in enumerate(groups)
+                       if group_satisfies(g, ask)]
+                if len(fit) > 1:
+                    known[i] = False
+                elif fit:
+                    need[i, fit[0]] += ask["count"]
+        self._takes[key] = (need, known)
+        return need, known
+
+    def room(self, job: dict, granted: np.ndarray) -> np.ndarray:
+        """Per row, how many more allocations of `job` its free matching
+        instances hold (`granted`: instances taken, per row and group);
+        unbounded for a job without asks."""
+        if not job.get("devices"):
+            return np.full(len(self.fleet), np.iinfo(np.int64).max)
+        need, _known = self.takes(job)
+        free = self.instances - granted
+        per = np.where(need > 0, free // np.maximum(need, 1),
+                       np.iinfo(np.int64).max)
+        out = per.min(axis=1) if per.shape[1] else np.zeros(len(self.fleet),
+                                                            np.int64)
+        # a node none of whose groups an ask can use holds none
+        return np.where(need.sum(axis=1) > 0, out, 0)
+
+    def granted(self, jobs: List[dict], allocs: Dict[str, List[dict]]
+                ) -> Tuple[np.ndarray, set]:
+        """Instances granted to live allocations of `jobs`, per row and
+        group, from the stubs; and the rows where that is not known (a
+        node with two groups that satisfy one ask)."""
+        row = {n["id"]: i for i, n in enumerate(self.fleet)}
+        out = np.zeros_like(self.instances)
+        unknown = set()
+        for job in jobs:
+            if not job.get("devices"):
+                continue
+            need, known = self.takes(job)
+            for a in allocs.get(job["id"], []):
+                at = row.get(a["node_id"])
+                if at is None or a.get("desired_status", "run") != "run":
+                    continue
+                if known[at]:
+                    out[at] += need[at]
+                else:
+                    unknown.add(at)
+        return out, unknown
 
 
 # ---------------------------------------------------------------------
@@ -187,6 +397,107 @@ def check_capacity(fleet: List[dict],
     return bad
 
 
+def _granted(alloc: dict) -> List[dict]:
+    """The device grants of a full allocation, every task's, in order."""
+    tasks = (alloc.get("allocated_resources") or {}).get("tasks") or {}
+    return [d for task in tasks.values() for d in task.get("devices") or []]
+
+
+def _group_of(node: dict, grant: dict) -> Optional[dict]:
+    """The node's group a grant names (vendor, type, model)."""
+    return next((g for g in node.get("devices") or ()
+                 if (g["vendor"], g["type"], g["model"])
+                 == (grant.get("vendor"), grant.get("type"),
+                     grant.get("name"))), None)
+
+
+def check_device_capacity(fleet: List[dict], devices: DeviceFleet,
+                          jobs: List[dict], allocs: Dict[str, List[dict]],
+                          device_full: List[dict]) -> List[str]:
+    """The device dimension of over_capacity: per node and group, the
+    instances granted to live allocations are at most the group's. From
+    the stubs (asks x allocations) on every node that says alone which
+    group an ask takes; on a node with two groups that satisfy one ask,
+    from the allocations read there in full (`device_full`: the device
+    check's sample)."""
+    granted, unknown = devices.granted(jobs, allocs)
+    bad = []
+
+    def over(node, group, n):
+        bad.append(f"{node['name']}: {n} instances of {group['vendor']}/"
+                   f"{group['type']}/{group['model']} granted, it has "
+                   f"{len(group['ids'])}")
+
+    for at in np.flatnonzero((granted > devices.instances).any(axis=1)):
+        for j, group in enumerate(fleet[at]["devices"]):
+            if granted[at, j] > devices.instances[at, j]:
+                over(fleet[at], group, int(granted[at, j]))
+    row = {n["id"]: i for i, n in enumerate(fleet)}
+    read: Dict[Tuple[int, int], int] = collections.Counter()
+    for a in device_full:
+        at = row.get(a["node_id"])
+        if at not in unknown or a.get("desired_status") != "run":
+            continue
+        for grant in _granted(a):
+            group = _group_of(fleet[at], grant)
+            if group is not None:
+                read[(at, id(group))] += len(grant.get("device_ids") or [])
+    for at in sorted(unknown):
+        for group in fleet[at]["devices"]:
+            if read[(at, id(group))] > len(group["ids"]):
+                over(fleet[at], group, read[(at, id(group))])
+    return bad
+
+
+def check_devices(fleet: List[dict], jobs: List[dict],
+                  device_full: List[dict]) -> List[str]:
+    """The device grants of the allocations read in full on the device
+    check's sample of nodes (device_conflicts): each live allocation
+    holds one grant an ask, in the asks' order, each of `count` distinct
+    ids of one group of its node that satisfies the ask; and no id is
+    granted twice, to two live allocations or in two grants of one."""
+    by_id = {n["id"]: n for n in fleet}
+    job_of = {j["id"]: j for j in jobs}
+    holder: Dict[Tuple[str, str], str] = {}
+    bad = []
+    for a in device_full:
+        job, node = job_of.get(a["job_id"]), by_id.get(a["node_id"])
+        if job is None or node is None or a.get("desired_status") != "run":
+            continue        # check_committed / check_feasible report these
+        asks, grants = job.get("devices") or [], _granted(a)
+        if len(grants) != len(asks):
+            bad.append(f"{a['name']} on {node['name']}: {len(grants)} "
+                       f"device grants for {len(asks)} asks")
+        for ask, grant in zip(asks, grants):
+            ids = grant.get("device_ids") or []
+            group = _group_of(node, grant)
+            if group is None or not group_satisfies(group, ask):
+                bad.append(f"{a['name']} on {node['name']}: granted "
+                           f"{grant.get('vendor')}/{grant.get('type')}/"
+                           f"{grant.get('name')}, no group there that "
+                           f"satisfies {ask['name']}")
+            elif not set(ids) <= set(group["ids"]):
+                bad.append(f"{a['name']} on {node['name']}: ids "
+                           f"{sorted(set(ids) - set(group['ids']))[:2]} are "
+                           f"no instances of its group")
+            if len(set(ids)) != len(ids) or len(ids) != ask["count"]:
+                bad.append(f"{a['name']} on {node['name']}: {len(ids)} ids "
+                           f"({len(set(ids))} distinct) for a count of "
+                           f"{ask['count']}")
+        held = collections.Counter(dev for grant in grants
+                                   for dev in set(grant.get("device_ids")
+                                                  or []))
+        for dev, n in held.items():
+            if n > 1:
+                bad.append(f"{a['name']} on {node['name']}: instance "
+                           f"{dev[:8]} granted in {n} of its grants")
+            other = holder.setdefault((node["id"], dev), a["id"])
+            if other != a["id"]:
+                bad.append(f"{a['name']} on {node['name']}: instance "
+                           f"{dev[:8]} granted to {other} too")
+    return bad
+
+
 def check_feasible(fleet: List[dict], jobs: List[dict],
                    allocs: Dict[str, List[dict]]) -> List[str]:
     """Every placement sits on a node whose attributes satisfy the job."""
@@ -194,8 +505,7 @@ def check_feasible(fleet: List[dict], jobs: List[dict],
     bad = []
     verdicts: Dict[tuple, List[str]] = {}
     for job in jobs:
-        shape = (job["driver"], tuple(job["datacenters"]),
-                 tuple(job["constraints"]))
+        shape = _shape(job)
         for a in allocs.get(job["id"], []):
             node = by_id.get(a["node_id"])
             if node is None:
@@ -275,6 +585,13 @@ def lane_ids(n_rows: int, lanes: int, rule: dict) -> np.ndarray:
             ).astype(np.int64)
 
 
+def _name_index(name: str) -> int:
+    """The index of an allocation's name, `<job>.<group>[<index>]`; -1
+    for a name of another form (check_committed reports it)."""
+    m = re.search(r"\[(\d+)\]$", name)
+    return int(m.group(1)) if m else -1
+
+
 def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
                jobs: List[dict], allocs: Dict[str, List[dict]],
                lanes: int, decorrelation: Optional[dict] = None,
@@ -284,8 +601,14 @@ def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
     committed them (the allocs' create_index), each against the fleet
     as it stood before that plan: the resident backlog plus every plan
     committed earlier. Jobs whose score is node-local (bin-pack and
-    the job's own anti-affinity; no spread, no affinity) are ranked;
-    the others only add their usage.
+    the job's own anti-affinity; no spread, no affinity, no affinity of
+    a device ask) are ranked; the others only add their usage. A node
+    has room for a job that asks for devices where one more allocation
+    fits by bin-pack AND its free matching instances hold one more
+    grant of every ask (the instances granted replayed plan by plan from
+    the stubs); where some node has two groups that satisfy one ask,
+    which one an allocation took only its own grant says, and no device
+    job is ranked.
 
     stacked   plans that put an alloc on a node which then holds the
               job twice or more (by this plan or an earlier one of the
@@ -296,7 +619,16 @@ def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
               plan asked for score above that.
     rank_gap  the widest gap by which a plan's worst chosen node scores
               below the bound of the share that ranked it, k being the
-              plan's distinct nodes.
+              plan's distinct nodes, and for a plan the applier committed
+              in part k plus the placements it lost: the names below its
+              last that neither it nor an earlier plan of the job holds
+              (a later plan of the job places them; the scheduler names
+              a plan's placements from the lowest free name on).
+
+    Plans the store committed in one entry (one create_index) are
+    replayed one at a time in the order of `jobs`; a node in which a
+    plan of that entry replayed later leaves no room for this plan's
+    job has no room for it either: the applier gave it to the other.
 
     The share. `lanes` concurrent schedulers rank large asks over
     disjoint shares of the fleet (`decorrelation`, the configuration's
@@ -327,34 +659,69 @@ def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
                     dtype=np.float64)
     lane_of = lane_ids(len(fleet), lanes, decorrelation) \
         if decorrelation and lanes > 1 else None
-    plans = []          # (create_index, job, rows of its placements)
+    asking = [j for j in jobs if j.get("devices")]
+    devices = DeviceFleet(fleet) if asking else None
+    granted = devices.instances * 0 if devices else None
+    devices_ranked = devices is not None and all(
+        devices.takes(j)[1].all() for j in asking)
+    plans = []      # (create_index, job, rows and name indexes placed)
     for job in jobs:
-        by_index: Dict[int, List[int]] = collections.defaultdict(list)
+        by_index: Dict[int, Tuple[List[int], List[int]]] = \
+            collections.defaultdict(lambda: ([], []))
         for a in allocs.get(job["id"], []):
             if a["node_id"] in row:
-                by_index[int(a.get("create_index") or 0)].append(
-                    row[a["node_id"]])
-        plans.extend((index, job, rows) for index, rows in by_index.items())
+                rows, names = by_index[int(a.get("create_index") or 0)]
+                rows.append(row[a["node_id"]])
+                names.append(_name_index(a["name"]))
+        plans.extend((index, job, *placed)
+                     for index, placed in by_index.items())
     plans.sort(key=lambda p: p[0])
+    named: Dict[str, set] = collections.defaultdict(set)
 
     feasible: Dict[tuple, np.ndarray] = {}
     held: Dict[str, np.ndarray] = {}    # job id -> its allocs per node
     stacked, widest = [], []
     rank_gap = 0.0
     due = sorted(residents.commits) if residents is not None else []
-    for index, job, rows in plans:
+    # what the plans of this plan's entry replayed after it take
+    rest_used = np.zeros_like(used)
+    rest_granted = granted * 0 if devices else None
+    for at, (index, job, rows, names) in enumerate(plans):
         while due and due[0] < index:
             residents.apply(due.pop(0), row, used)
         ask = np.array([job["ask"][d] for d in DIMS], dtype=np.float64)
         chosen = np.bincount(rows, minlength=len(fleet))
+        if at == 0 or plans[at - 1][0] != index:
+            rest_used[:] = 0
+            if devices:
+                rest_granted[:] = 0
+            nxt = at + 1
+            while nxt < len(plans) and plans[nxt][0] == index:
+                _i, other, other_rows, _n = plans[nxt]
+                took = np.bincount(other_rows, minlength=len(fleet))
+                rest_used += took[:, None] * np.array(
+                    [other["ask"][d] for d in DIMS])[None, :]
+                if other.get("devices"):
+                    rest_granted += took[:, None] * devices.takes(other)[0]
+                nxt += 1
+        else:
+            rest_used -= chosen[:, None] * ask[None, :]
+            if job.get("devices"):
+                rest_granted -= chosen[:, None] * devices.takes(job)[0]
         evicted = residents is not None and index in residents.evicting
-        if not job["spreads"] and not job["affinities"] and not evicted:
-            shape = (job["driver"], tuple(job["datacenters"]),
-                     tuple(job["constraints"]))
+        asks = job.get("devices") or []
+        ranked = not asks or devices_ranked and not any(
+            a["affinities"] for a in asks)
+        if not job["spreads"] and not job["affinities"] and not evicted \
+                and ranked:
+            shape = _shape(job)
             if shape not in feasible:
                 feasible[shape] = np.array(
                     [not node_feasible(n, job) for n in fleet])
-            room = feasible[shape] & np.all(used + ask <= capacity, axis=1)
+            room = feasible[shape] & np.all(used + rest_used + ask
+                                            <= capacity, axis=1)
+            if asks:
+                room &= devices.room(job, granted + rest_granted) >= 1
             score = binpack_scores(capacity, used, ask)
             before = held.get(job["id"])
             asked = job["count"]        # what this plan's select asked for
@@ -363,6 +730,12 @@ def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
                                               / job["count"]) / 2.0, score)
                 asked -= int(before.sum())
             k = int((chosen > 0).sum())
+            # a plan the applier committed in part ranked the placements
+            # it lost too: the names below its last that neither it nor
+            # an earlier plan of the job holds
+            lost = len(set(range(max(names, default=0))) - set(names)
+                       - named[job["id"]])
+            depth = k + lost
             lane = None         # the lane that ranked this plan, if one did
             if lane_of is not None and k \
                     and asked >= decorrelation["min_count"] \
@@ -370,10 +743,10 @@ def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
                 lane = int(lane_of[rows[0]])
             if lane is None:
                 pool, where = room, "fleet"
-                nth, share = lanes * k, f"the {lanes}x{k}-th best"
+                nth, share = lanes * depth, f"the {lanes}x{depth}-th best"
             else:
                 pool, where = room & (lane_of == lane), f"lane {lane}"
-                nth, share = k, f"lane {lane}'s {k}-th best"
+                nth, share = depth, f"lane {lane}'s {depth}-th best"
             if job["count"] > 1:
                 ceiling = (1.0 - 2.0 / job["count"]) / 2.0
                 now = chosen if before is None else chosen + before
@@ -396,7 +769,10 @@ def check_rank(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
                     f"{float(best[0]):.6f}")))
                 rank_gap = max(rank_gap, gap)
         held[job["id"]] = held.get(job["id"], 0) + chosen
+        named[job["id"]].update(names)
         used += chosen[:, None] * ask[None, :]
+        if asks:
+            granted += chosen[:, None] * devices.takes(job)[0]
     widest.sort(key=lambda g: -g[0])
     return stacked, rank_gap, [w for g, w in widest[:5] if g > TIE_EPS]
 
@@ -586,8 +962,7 @@ def check_evictions(fleet: List[dict], residents: ResidentState,
                 ask, job = part["ask"], part["job"]
                 ok = np.ones(len(fleet), dtype=bool)
                 if job is not None:
-                    shape = (job["driver"], tuple(job["datacenters"]),
-                             tuple(job["constraints"]))
+                    shape = _shape(job)
                     if shape not in feasible:
                         feasible[shape] = np.array(
                             [not node_feasible(n, job) for n in fleet])
@@ -675,12 +1050,35 @@ def port_sample(fleet: List[dict], jobs: List[dict],
     return out
 
 
+def device_sample(fleet: List[dict], jobs: List[dict],
+                  allocs: Dict[str, List[dict]], n_nodes: int,
+                  rng) -> List[str]:
+    """Which allocs to read in full for the device check: every
+    allocation of `jobs` on `n_nodes` nodes that hold device groups and
+    allocations of the jobs, the fullest first (an eighth of them: where
+    a conflict is likeliest), then nodes drawn from the seed."""
+    if n_nodes <= 0:
+        return []
+    has = {n["id"] for n in fleet if n.get("devices")}
+    by_node: Dict[str, List[str]] = collections.defaultdict(list)
+    for job in jobs:
+        for a in allocs.get(job["id"], []):
+            if a["node_id"] in has:
+                by_node[a["node_id"]].append(a["id"])
+    nodes = sorted(by_node, key=lambda n: (-len(by_node[n]), n))
+    head = nodes[:max(1, n_nodes // 8)]
+    tail = nodes[len(head):]
+    rng.shuffle(tail)
+    return [a for nid in (head + tail)[:n_nodes] for a in by_node[nid]]
+
+
 def judge(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
           jobs: List[dict], evals: Dict[str, dict],
           allocs: Dict[str, List[dict]], full_allocs: List[dict],
           unread: List[str], port_range, lanes: int,
           decorrelation: Optional[dict] = None,
-          residents: Optional["ResidentState"] = None
+          residents: Optional["ResidentState"] = None,
+          device_full: Optional[List[dict]] = None
           ) -> Tuple[dict, Dict[str, List[str]]]:
     """Every number compared, beside its limit, and what broke each.
     `lanes` is the configuration's number of concurrent schedulers,
@@ -688,7 +1086,10 @@ def judge(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
     `residents` (a tiered configuration alone): the resident
     allocations as read back after the drain; `backlog` is then the
     fleet as loaded, the capacity check takes the residents that still
-    run, and TIER_LIMITS' four numbers are compared too."""
+    run, and TIER_LIMITS' four numbers are compared too. A fleet with
+    device groups: over_capacity takes their instances too, and
+    `device_full`, the allocations read in full on the device check's
+    sample of nodes (device_sample), is judged as DEVICE_LIMITS says."""
     never, unplaced = check_evals(jobs, evals)
     placed_jobs = [j for j in jobs if j["id"] in allocs]
     stacked, rank_gap, widest = check_rank(fleet, backlog, placed_jobs,
@@ -717,6 +1118,12 @@ def judge(fleet: List[dict], backlog: Dict[str, Dict[str, float]],
         found.update(check_evictions(fleet, residents, placed_jobs, allocs))
         found["residents_stopped"] = residents.stopped
         limits.update(TIER_LIMITS)
+    if any(n.get("devices") for n in fleet):
+        found["over_capacity"] += check_device_capacity(
+            fleet, DeviceFleet(fleet), placed_jobs, allocs, device_full or [])
+        found["device_conflicts"] = check_devices(fleet, placed_jobs,
+                                                  device_full or [])
+        limits.update(DEVICE_LIMITS)
     compared = {name: {"value": len(found[name]), "limit": limits[name]}
                 for name in limits}
     compared["rank_gap"]["value"] = rank_gap
@@ -732,6 +1139,33 @@ def is_correct(compared: dict) -> bool:
 # program's place
 # ---------------------------------------------------------------------
 
+def plain_grant(node: dict, job: dict, free: List[List[str]]
+                ) -> Optional[List[Tuple[dict, List[str]]]]:
+    """AssignDevice, plainly: per ask in order, the first group of the
+    node that satisfies it with `count` free instances, and its first
+    free ids, taken out of `free` (per group, the ids not granted, in
+    the group's order); None, and `free` untouched, where an ask finds
+    none."""
+    trial = [list(ids) for ids in free]
+    out = []
+    for ask in job["devices"]:
+        for j, group in enumerate(node.get("devices") or []):
+            if len(trial[j]) >= ask["count"] and group_satisfies(group, ask):
+                out.append((group, trial[j][:ask["count"]]))
+                del trial[j][:ask["count"]]
+                break
+        else:
+            return None
+    free[:] = trial
+    return out
+
+
+def _first_groups(node: dict, asks: List[dict]) -> List[Optional[dict]]:
+    """Per ask, the node's first group that satisfies it."""
+    return [next((g for g in node.get("devices") or ()
+                  if group_satisfies(g, ask)), None) for ask in asks]
+
+
 class PlainScorer:
     """The reference scheduler's ranking in float64, one node at a time
     (rank.go BinPack / JobAntiAffinity / NodeAffinity, spread.go,
@@ -740,11 +1174,35 @@ class PlainScorer:
 
     def __init__(self, fleet: List[dict], job: dict,
                  used: Dict[str, Dict[str, float]],
-                 ignore_constraints: bool = False):
+                 ignore_constraints: bool = False,
+                 free: Optional[Dict[str, List[List[str]]]] = None,
+                 ignore_devices: bool = False):
         self.job = job
         self.ask = job["ask"]
-        self.nodes = [n for n in fleet
-                      if ignore_constraints or not node_feasible(n, job)]
+        self.nodes = [n for n in fleet if ignore_constraints
+                      or not node_feasible(n, job, not ignore_devices)]
+        # a device job: how many more of its allocations each node's free
+        # instances grant (`free`: the scheduler's, per node and group),
+        # and the `devices` scorer (rank.go: the matched weights of the
+        # groups granted over every device affinity's weight)
+        asks = job.get("devices") or []
+        self.dev_room: Dict[str, float] = {}
+        self.dev_score: Optional[Dict[str, float]] = None
+        for n in self.nodes if asks else ():
+            if ignore_devices and not devices_ok(n, job):
+                self.dev_room[n["id"]] = math.inf
+                continue
+            trial = [list(ids) for ids in (free or {}).get(n["id"], [])]
+            room = 0
+            while plain_grant(n, job, trial) is not None:
+                room += 1
+            self.dev_room[n["id"]] = room
+        sum_dw = sum(abs(w) for a in asks for *_c, w in a["affinities"])
+        if sum_dw:
+            self.dev_score = {n["id"]: sum(
+                w for a, g in zip(asks, _first_groups(n, asks)) if g
+                for *c, w in a["affinities"] if device_constraint_ok(g, c)
+            ) / sum_dw for n in self.nodes}
         self.used = {n["id"]: dict(used[n["id"]]) for n in self.nodes}
         self.coll: Dict[str, int] = collections.Counter()
         aff = job["affinities"]
@@ -767,7 +1225,8 @@ class PlainScorer:
 
     def fits(self, node: dict) -> bool:
         u = self.used[node["id"]]
-        return all(u[d] + self.ask[d] <= node["capacity"][d] for d in DIMS)
+        return all(u[d] + self.ask[d] <= node["capacity"][d] for d in DIMS) \
+            and self.dev_room.get(node["id"], 1) >= 1
 
     def score(self, node: dict) -> float:
         u = self.used[node["id"]]
@@ -782,6 +1241,8 @@ class PlainScorer:
             parts.append(-(coll + 1.0) / max(self.job["count"], 1.0))
         if self.affinity[node["id"]] != 0.0:
             parts.append(self.affinity[node["id"]])
+        if self.dev_score is not None:
+            parts.append(self.dev_score[node["id"]])
         spread = 0.0
         for sp in self.spreads:
             value = sp["value"][node["id"]]
@@ -796,6 +1257,8 @@ class PlainScorer:
         return sum(parts) / len(parts)
 
     def place(self, node: dict) -> None:
+        if node["id"] in self.dev_room:
+            self.dev_room[node["id"]] -= 1
         u = self.used[node["id"]]
         for d in DIMS:
             u[d] += self.ask[d]
@@ -858,7 +1321,8 @@ class PlainScorer:
 
 
 CONTROLS = ("capacity", "constraints", "lose", "ports", "firstfit",
-            "norank", "noevict", "evictpeer", "evictall", "evictearly")
+            "norank", "noevict", "evictpeer", "evictall", "evictearly",
+            "devblind", "devtwice")
 
 
 class PlainScheduler:
@@ -888,6 +1352,17 @@ class PlainScheduler:
       evictall     takes every eligible allocation of the node
       evictearly   never looks for room: every instance evicts, on the
                    first feasible node in row order that is full
+
+    A job that asks for devices is granted instance ids on its node:
+    per ask, the first free ones of the first group that satisfies it
+    (plain_grant), and a node has room for it only where they are
+    there. Two more controls break that:
+
+      devblind     picks nodes as if no group had to satisfy the asks (a
+                   node whose groups can grant still has to have them
+                   free); where nothing satisfies an ask it grants none
+      devtwice     hands the first id a node granted to its next
+                   allocation's first grant again
     """
 
     def __init__(self, fleet: List[dict],
@@ -918,6 +1393,11 @@ class PlainScheduler:
         self.broken = broken
         self.port_lo = port_range[0]
         self.next_port: Dict[str, int] = collections.Counter()
+        # node id -> per device group, the ids not granted yet; and the
+        # first id each node granted (devtwice's)
+        self.free = {n["id"]: [list(g["ids"]) for g in n["devices"]]
+                     for n in fleet if n.get("devices")}
+        self.first_granted: Dict[str, str] = {}
         self.allocs: Dict[str, List[dict]] = {}
         self.full: Dict[str, dict] = {}
         self.evals: Dict[str, dict] = {}
@@ -979,9 +1459,24 @@ class PlainScheduler:
                 self.used[node["id"]][d] -= k * tiers[t]["alloc"][d]
         return node
 
+    def _grant(self, node: dict, job: dict) -> List[dict]:
+        """The device grants of one allocation on `node`, as a full
+        allocation lists them; none where the node cannot grant."""
+        grants = plain_grant(node, job, self.free.get(node["id"], [])) or []
+        out = [{"vendor": g["vendor"], "type": g["type"], "name": g["model"],
+                "device_ids": list(ids)} for g, ids in grants]
+        if out:
+            first = self.first_granted.setdefault(node["id"],
+                                                  out[0]["device_ids"][0])
+            if self.broken == "devtwice":
+                out[0]["device_ids"][0] = first
+        return out
+
     def submit(self, job: dict) -> None:
         scorer = PlainScorer(self.fleet, job, self.used,
-                             ignore_constraints=self.broken == "constraints")
+                             ignore_constraints=self.broken == "constraints",
+                             free=self.free,
+                             ignore_devices=self.broken == "devblind")
         preempts = self.residents is not None and preemption_enabled(
             self.scheduler_configuration, job["type"])
         if self.broken in ("firstfit", "norank"):
@@ -1017,9 +1512,11 @@ class PlainScheduler:
                 if self.broken == "ports" and k == 1:
                     k = 0       # the node's first port, handed out twice
                 ports.append({"label": f"p{p}", "value": self.port_lo + k})
+            task = {"networks": [{"dynamic_ports": ports}]}
+            if job.get("devices"):
+                task["devices"] = self._grant(node, job)
             self.full[alloc_id] = dict(
-                stub, allocated_resources={"tasks": {job["task"]: {
-                    "networks": [{"dynamic_ports": ports}]}}})
+                stub, allocated_resources={"tasks": {job["task"]: task}})
         if self.broken == "lose" and len(self.evals) % 3 == 0 and stubs:
             self.full.pop(stubs.pop()["id"])
         self.allocs[job["id"]] = stubs

@@ -13,6 +13,7 @@ do, not as two traffics.
 
 from __future__ import annotations
 
+import itertools
 import json
 import math
 import random
@@ -50,10 +51,33 @@ def arrivals(rng: random.Random, n: int, start_s: float,
     return out
 
 
+def device_asks(asks: List[dict]) -> List[dict]:
+    """Device asks (upstream's device stanza) in the plain form: each a
+    name (`type`, `vendor/type` or `vendor/type/model`), a count of at
+    least one, constraints [ltarget, operand, rtarget] and affinities
+    [ltarget, operand, rtarget, weight] on ${device.*}."""
+    out = []
+    for a in asks:
+        if int(a["count"]) < 1:
+            raise ValueError(f"device ask {a['name']!r}: count "
+                             f"{a['count']} is under one")
+        out.append({"name": a["name"], "count": int(a["count"]),
+                    "constraints": [tuple(c) for c in a.get("constraints",
+                                                            [])],
+                    "affinities": [tuple(x) for x in a.get("affinities",
+                                                           [])]})
+    return out
+
+
 def plain_job(mix: dict, job_id: str, count: int,
-              datacenters: List[str]) -> dict:
+              datacenters: List[str],
+              devices: Optional[List[dict]] = None) -> dict:
+    """One job of the mix's template. `devices`: the device asks the job
+    was dealt from the mix's `device_deck` (_dealt); a job that asks for
+    none has no `devices` key."""
     tpl = mix["job"]
-    return {
+    asks = device_asks(devices or [])
+    job = {
         "id": job_id, "type": tpl["type"], "group": tpl["group"],
         "task": tpl["task"], "driver": tpl["driver"], "count": int(count),
         "priority": int(tpl.get("priority", 50)),
@@ -65,6 +89,22 @@ def plain_job(mix: dict, job_id: str, count: int,
         "spreads": [(s[0], s[1], [tuple(t) for t in s[2]])
                     for s in tpl.get("spreads", [])],
     }
+    if asks:
+        job["devices"] = asks
+    return job
+
+
+def _dealt(mix: dict, counts: List[int]) -> List[tuple]:
+    """The job sizes, each with the device asks it is dealt: a mix's
+    `device_deck` (a list of ask lists) deals card d to every size of
+    the d-th deck of `counts` (deck_counts' order), so that over as many
+    decks as it has cards every size meets every card, and the same
+    pairs come on every seed once shuffled; without one, none."""
+    cards = mix.get("device_deck")
+    if not cards:
+        return [(c, None) for c in counts]
+    per = len(mix["deck"])
+    return [(c, cards[(i // per) % len(cards)]) for i, c in enumerate(counts)]
 
 
 def wire_job(job: dict) -> dict:
@@ -78,7 +118,7 @@ def wire_job(job: dict) -> dict:
                        {"label": f"p{i}", "value": 0, "to": 0,
                         "host_network": "default"}
                        for i in range(job["dynamic_ports"])]}
-    return {
+    wire = {
         "id": job["id"], "name": job["id"], "namespace": "default",
         "region": "global", "type": job["type"], "priority": job["priority"],
         "datacenters": job["datacenters"],
@@ -106,6 +146,16 @@ def wire_job(job: dict) -> dict:
             "ephemeral_disk": {"size_mb": job["ask"]["disk_mb"]},
         }],
     }
+    if job.get("devices"):
+        wire["task_groups"][0]["tasks"][0]["resources"]["devices"] = [
+            {"name": a["name"], "count": a["count"],
+             "constraints": [{"ltarget": l, "operand": op, "rtarget": r}
+                             for l, op, r in a["constraints"]],
+             "affinities": [{"ltarget": l, "operand": op, "rtarget": r,
+                             "weight": w}
+                            for l, op, r, w in a["affinities"]]}
+            for a in job["devices"]]
+    return wire
 
 
 def payload(jobs: List[dict]) -> bytes:
@@ -140,14 +190,18 @@ def warmup_requests(mix: dict, seed: int,
     (one job alone: the solo arms' buckets) and bursts (several jobs
     in one bulk PUT: the batched lanes)."""
     ids = _ids(mix, seed, "w")
+    cards = mix.get("device_deck") or [None]
+    k = itertools.count()
+
+    def job(count):
+        return plain_job(mix, next(ids), count, datacenters,
+                         cards[next(k) % len(cards)])
     rounds = []
     warm = mix.get("warmup", {})
     for count in warm.get("solo", []):
-        rounds.append([Request([plain_job(mix, next(ids), count,
-                                          datacenters)])])
+        rounds.append([Request([job(count)])])
     for burst in warm.get("bursts", []):
-        rounds.append([Request([plain_job(mix, next(ids), c, datacenters)
-                                for c in burst])])
+        rounds.append([Request([job(c) for c in burst])])
     return rounds
 
 
@@ -160,19 +214,19 @@ def open_loop(mix: dict, seed: int, seconds: float,
     out = []
     rehearse_s = float(mix.get("rehearse_s", 0.0))
     n_re = int(round(rate * rehearse_s))
-    counts = deck_counts(mix["deck"], n_re)
-    rng.shuffle(counts)
-    for due, count in zip(arrivals(rng, n_re, -rehearse_s, rehearse_s),
-                          counts):
-        out.append(Request([plain_job(mix, next(ids), count, datacenters)],
-                           due))
+    cards = _dealt(mix, deck_counts(mix["deck"], n_re))
+    rng.shuffle(cards)
+    for due, (count, asks) in zip(
+            arrivals(rng, n_re, -rehearse_s, rehearse_s), cards):
+        out.append(Request([plain_job(mix, next(ids), count, datacenters,
+                                      asks)], due))
     ids = _ids(mix, seed, "j")
     n = int(round(rate * seconds))
-    counts = deck_counts(mix["deck"], n)
-    rng.shuffle(counts)
-    for due, count in zip(arrivals(rng, n, 0.0, seconds), counts):
-        out.append(Request([plain_job(mix, next(ids), count, datacenters)],
-                           due))
+    cards = _dealt(mix, deck_counts(mix["deck"], n))
+    rng.shuffle(cards)
+    for due, (count, asks) in zip(arrivals(rng, n, 0.0, seconds), cards):
+        out.append(Request([plain_job(mix, next(ids), count, datacenters,
+                                      asks)], due))
     return out
 
 
@@ -185,10 +239,10 @@ def closed_loop(mix: dict, seed: int, seconds: float,
     bulk = int(mix["bulk"])
     span = seconds + float(mix.get("rehearse_s", 0.0)) + 5.0
     n_req = int(math.ceil(span * mix["max_jobs_per_s"] / bulk)) + 4
-    counts = deck_counts(mix["deck"], n_req * bulk)
-    rng.shuffle(counts)
-    return [Request([plain_job(mix, next(ids), counts[r * bulk + b],
-                               datacenters) for b in range(bulk)])
+    cards = _dealt(mix, deck_counts(mix["deck"], n_req * bulk))
+    rng.shuffle(cards)
+    return [Request([plain_job(mix, next(ids), count, datacenters, asks)
+                     for count, asks in cards[r * bulk:(r + 1) * bulk]])
             for r in range(n_req)]
 
 

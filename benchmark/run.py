@@ -38,6 +38,18 @@ configuration or mix without it runs as before:
         PUT /v1/operator/scheduler/configuration: sent after boot and
         before the fleet loads, read back, and refused if it differs
 
+A fourth opens a fleet with devices, chosen the same way:
+
+    a machine class's devices            device groups {vendor, type,
+        model, count, attributes} on every node of the class, each
+        instance a UUID from a stream of its own (fleet.build_fleet);
+        the probe reads one device node back and refuses the run if
+        its groups differ; the judge compares one more number,
+        device_conflicts, over the allocations read in full on the
+        mix's `device_check_nodes` device nodes, and over_capacity
+        counts instances. A mix's `device_deck`, lists of device asks
+        dealt over its deck, is sent as the task's resources.devices
+
 Flags beyond the contract's four are for the builder's own runs:
 --rate (the sweep), --control (the reference in the program's place
 with one guarantee or the ranking broken), --nodes with --rehearse-cpu
@@ -168,6 +180,7 @@ class Run:
         self.backlog = self.residents["usage"] if self.residents \
             else fleetlib.backlog_usage(self.cfg, self.fleet)
         self.port_range = tuple(self.cfg["dynamic_port_range"])
+        self.has_devices = any(n.get("devices") for n in self.fleet)
         self.problems: list = []
         self.obs: dict = {"seconds": self.seconds, "series": {}}
         self.trace_dir = None
@@ -204,6 +217,8 @@ class Run:
             raise RuntimeError(f"fleet did not load as made: {loaded}")
         http = client.Http(addr)
         self._probe_backlog(http)
+        if self.has_devices:
+            self._probe_devices(http)
 
         warm, timed = self.requests()
         loop = None
@@ -336,6 +351,8 @@ class Run:
         log(f"read back {sum(len(a) for a in allocs.values())} allocs of "
             f"{len(allocs)} jobs and {len(full)} in full in "
             f"{time.perf_counter() - t_r:.2f}s")
+        device_full = self._read_device_sample(http, jobs, allocs, unread) \
+            if self.has_devices else None
         residents_now = self._read_residents(http, unread) \
             if self.residents else None
         failures = agent.worker_failures()
@@ -433,7 +450,7 @@ class Run:
         return {"jobs": jobs, "evals": watch.evals, "allocs": allocs,
                 "full": full, "unread": unread, "attempted": attempted,
                 "failed": failed, "peak": peak,
-                "residents_now": residents_now}
+                "residents_now": residents_now, "device_full": device_full}
 
     def _configure_scheduler(self, http) -> None:
         """The configuration's `scheduler_configuration`, PUT to the
@@ -518,6 +535,47 @@ class Run:
             f"{sum(a['id'] not in self.residents['allocs'] for a in stubs)} "
             f"placed since the load")
         return now
+
+    def device_sample(self, jobs: list, allocs: dict) -> list:
+        """The allocations the device check reads in full: on the mix's
+        `device_check_nodes` device nodes, drawn from the seed on a
+        stream of their own (the port check's stays as it was)."""
+        return reference.device_sample(
+            self.fleet, jobs, allocs, int(self.mix["device_check_nodes"]),
+            random.Random(f"{self.seed}/device-check"))
+
+    def _read_device_sample(self, http, jobs: list, allocs: dict,
+                            unread: list) -> list:
+        t_r = time.perf_counter()
+        ids = self.device_sample(jobs, allocs)
+        full = []
+        for alloc_id in ids:
+            status, body = http.request("GET", f"/v1/allocation/{alloc_id}")
+            if status == 200:
+                full.append(body)
+            else:
+                unread.append(f"alloc {alloc_id}: HTTP {status}")
+        log(f"device check: read {len(full)} allocs in full on "
+            f"{len({a['node_id'] for a in full})} device nodes in "
+            f"{time.perf_counter() - t_r:.2f}s")
+        return full
+
+    def _probe_devices(self, http) -> None:
+        """One device node drawn from the seed, read back over HTTP: its
+        groups, their instances' ids and their attributes as made."""
+        probe = random.Random(f"{self.seed}/device-probe").choice(
+            [n for n in self.fleet if n.get("devices")])
+        _s, node = http.request("GET", f"/v1/node/{probe['id']}")
+        got = [{"vendor": g["vendor"], "type": g["type"], "model": g["name"],
+                "attributes": g["attributes"],
+                "ids": [i["id"] for i in g["instances"] if i["healthy"]]}
+               for g in ((node or {}).get("node_resources") or {}).get(
+                   "devices") or []]
+        if got != probe["devices"]:
+            raise RuntimeError(f"devices on {probe['name']}: read back "
+                               f"{got}, the fleet made {probe['devices']}")
+        log(f"devices on {probe['name']}: "
+            f"{[(g['model'], len(g['ids'])) for g in got]}, as made")
 
     def _probe_backlog(self, http) -> None:
         """The backlog the capacity check assumes, read back over HTTP
@@ -667,13 +725,18 @@ class Run:
         rng = random.Random(self.seed)
         ids = reference.port_sample(self.fleet, jobs, plain.allocs,
                                     int(self.mix["port_check_allocs"]), rng)
+        dev_ids = self.device_sample(jobs, plain.allocs) \
+            if self.has_devices else []
         self.obs.update(setup_s=time.perf_counter() - T_START)
         return {"jobs": jobs, "evals": plain.evals, "allocs": plain.allocs,
                 "full": [plain.full[i] for i in ids if i in plain.full],
-                "unread": [i for i in ids if i not in plain.full],
+                "unread": [i for i in ids + dev_ids if i not in plain.full],
                 "attempted": len(jobs), "failed": 0, "peak": 0,
                 "residents_now": plain.resident_allocs()
-                if self.residents else None}
+                if self.residents else None,
+                "device_full": [plain.full[i] for i in dev_ids
+                                if i in plain.full]
+                if self.has_devices else None}
 
 
 def main(argv=None) -> int:
@@ -744,7 +807,7 @@ def main(argv=None) -> int:
         run.cfg["server"]["num_schedulers"],
         run.cfg["server"].get("decorrelation"),
         reference.ResidentState(run.residents, got["residents_now"])
-        if run.residents else None)
+        if run.residents else None, got["device_full"])
     compared["harness_problems"] = {"value": len(run.problems), "limit": 0}
     correct = reference.is_correct(compared)
     log(f"reference judged {len(got['jobs'])} jobs in "

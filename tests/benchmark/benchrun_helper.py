@@ -71,6 +71,24 @@ def evict_manifest(directory):
     return _written(m, directory)
 
 
+# the toy GPU fleet: a configuration and a mix that are the tests' own
+# (tests/benchmark/gpu-toy.json, tests/benchmark/traffic/toy-gpu.json):
+# device groups on two machine classes, jobs that ask for devices
+GPU = "gpu-toy_toy-gpu"
+
+
+def gpu_manifest(directory):
+    """BENCHMARK.json grown by the toy GPU fleet and a cell on it."""
+    m = json.loads(json.dumps(MANIFEST))
+    m["configs"].append({
+        "name": "gpu-toy", "source": "the tests' toy: no deployment",
+        "file": "tests/benchmark/gpu-toy.json", "reduced": [],
+        "why": "device groups on two machine classes"})
+    _add_cell(m, GPU, "gpu-toy", "toy-gpu",
+              "toy rehearsal of a fleet with devices")
+    return _written(m, directory)
+
+
 def rehearse(cell, *extra, patch="", seconds="3", nodes="640"):
     with tempfile.TemporaryDirectory() as tmp:
         argv = ["--workload", cell, "--seed", "2147483999", "--seconds",
@@ -79,6 +97,8 @@ def rehearse(cell, *extra, patch="", seconds="3", nodes="640"):
             argv += ["--manifest", stream_manifest(tmp)]
         if cell in (EVICT, EVICT_ROOM):
             argv += ["--manifest", evict_manifest(tmp)]
+        if cell == GPU:
+            argv += ["--manifest", gpu_manifest(tmp)]
         code = (f"import sys; sys.path.insert(0, {ROOT!r})\n"
                 f"import benchmark.run as run\n{patch}\n"
                 f"sys.exit(run.main({argv!r}))\n")

@@ -43,11 +43,22 @@ def grown(tmp_path):
         toy = json.load(f)
     body.update({k: toy[k] for k in ("resident_tiers",
                                      "scheduler_configuration")})
+    # and its machines carry devices, its jobs ask for them, as the
+    # tests' GPU toy's do
+    with open(os.path.join(HERE, "gpu-toy.json")) as f:
+        body["machine_classes"] = json.load(f)["machine_classes"]
     with open(os.path.join(root, "benchmark", "configs",
                            CONFIG + ".json"), "w") as f:
         json.dump(body, f)
-    shutil.copy(os.path.join(root, "benchmark", "traffic", "batch-fill.json"),
-                os.path.join(root, "benchmark", "traffic", "next-fill.json"))
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "batch-fill.json")) as f:
+        mix = json.load(f)
+    with open(os.path.join(HERE, "traffic", "toy-gpu.json")) as f:
+        gpu = json.load(f)
+    mix.update({k: gpu[k] for k in ("device_deck", "device_check_nodes")})
+    with open(os.path.join(root, "benchmark", "traffic", "next-fill.json"),
+              "w") as f:
+        json.dump(mix, f)
     m["configs"].append({
         "name": CONFIG, "source": "a public source of its own",
         "file": f"benchmark/configs/{CONFIG}.json", "reduced": [],
@@ -133,6 +144,19 @@ def test_the_grown_config_is_tiered_and_the_accepted_ones_are_not(grown):
         with open(os.path.join(root, "benchmark", "traffic",
                                mix + ".json")) as f:
             assert "priority" not in json.load(f)["job"]
+    # devices: the grown configuration and mix have them, no accepted one
+    assert any("devices" in c for c in cfg(CONFIG)["machine_classes"])
+    for name in ("prod-10k", "svc-10k", "preempt-10k"):
+        assert not any("devices" in c for c in cfg(name)["machine_classes"])
+    for mix in ("batch-fill", "service-fill", "service-stream",
+                "service-evict", "next-fill"):
+        with open(os.path.join(root, "benchmark", "traffic",
+                               mix + ".json")) as f:
+            body = json.load(f)
+        grown = mix == "next-fill"
+        assert "devices" not in body["job"]
+        assert ("device_deck" in body) is grown
+        assert ("device_check_nodes" in body) is grown
 
 
 def test_a_new_cell_gets_the_unkeyed_metrics_and_its_own(grown):

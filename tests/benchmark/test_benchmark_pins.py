@@ -58,9 +58,76 @@ def digest_of_every_body(mix_name, seed=7, seconds=51.0):
      "6d05de98d88825f19a7087a4bc9fdbc08731781d03c71717345991e460a3d17b"),
     ("service-stream", 346,
      "026ecc179cf4c81b07ed06305a67603b36cd0eca728e10307f0f82e2c63c6ee7"),
+    ("service-evict", 1874,
+     "67385d0df5865d215660d9ced80841d9715494b38d078245083d3af5b2b96eac"),
 ])
 def test_request_bodies_of_seed_7_are_pinned(mix, n, sha):
     assert digest_of_every_body(mix) == (n, sha)
+
+
+def sha_of(obj):
+    return hashlib.sha256(json.dumps(obj, sort_keys=True).encode()
+                          ).hexdigest()
+
+
+# The device keys (a machine class's `devices`, a mix's `device_deck`)
+# change nothing where they are absent: the accepted
+# configurations' fleets at seed 7, and what the judge says of the plain
+# scheduler's answers to six jobs of each cell's mix on 320 of their
+# nodes, whole and with two controls, hash as on the parent commit
+# (97624d2) before the keys went in.
+@pytest.mark.parametrize("config", ["prod-10k", "svc-10k", "preempt-10k"])
+def test_fleet_of_seed_7_is_pinned(config):
+    fleet = fleetlib.build_fleet(load("benchmark", "configs",
+                                      config + ".json"), 7)
+    assert len(fleet) == 10000 and not any("devices" in n for n in fleet)
+    assert sha_of(fleet) == ("44daa35bfbc37ca73d791e035d628404"
+                             "e7658de97142426c975aece51efbee24")
+
+
+@pytest.mark.parametrize("config,mix,broken,n,sha", [
+    ("prod-10k", "batch-fill", None, 10,
+     "50b75d4e2d993c3880d0f923a435bfd8b2aea4700c3e4b750073e589aae34533"),
+    ("prod-10k", "batch-fill", "capacity", 10,
+     "50b75d4e2d993c3880d0f923a435bfd8b2aea4700c3e4b750073e589aae34533"),
+    ("prod-10k", "batch-fill", "firstfit", 10,
+     "2c86237df89a703864784e94ac05cac7d09d9672675618e3701aee6b0f36c7f7"),
+    ("svc-10k", "service-fill", None, 10,
+     "7fa9ceb9e552a5952aedd6e5676075dddfe8279790ad940077c302a97295114e"),
+    ("svc-10k", "service-fill", "capacity", 10,
+     "7fa9ceb9e552a5952aedd6e5676075dddfe8279790ad940077c302a97295114e"),
+    ("svc-10k", "service-fill", "firstfit", 10,
+     "1bd8a3f667fbf6bacaab12ea5f8ab5fa8c2a3f674e7a9899224bf0d8b8234fc7"),
+    ("preempt-10k", "service-evict", None, 14,
+     "45b2bec6746df0493bd63b80fdb74ab5488176ef122feff4c71c2f0584aa9a0a"),
+    ("preempt-10k", "service-evict", "capacity", 14,
+     "7c3909c957b919addd3dc42a605c3e72aac460d6bf744d8b3d5a3ef278914d52"),
+    ("preempt-10k", "service-evict", "firstfit", 14,
+     "45b2bec6746df0493bd63b80fdb74ab5488176ef122feff4c71c2f0584aa9a0a"),
+])
+def test_a_fixed_verdict_of_seed_7_is_pinned(config, mix, broken, n, sha):
+    cfg = load("benchmark", "configs", config + ".json")
+    fleet = fleetlib.build_fleet(cfg, 7, 320)
+    tiers = fleetlib.residents(cfg, fleet) if "resident_tiers" in cfg \
+        else None
+    backlog = tiers["usage"] if tiers else fleetlib.backlog_usage(cfg, fleet)
+    tpl = traffic.load_mix(os.path.join(ROOT, "benchmark", "traffic",
+                                        mix + ".json"))
+    jobs = [traffic.plain_job(tpl, f"pin-{i}", c, DCS)
+            for i, c in enumerate([3, 1, 5, 2, 8, 4])]
+    plain = ref.PlainScheduler(
+        fleet, backlog, PORTS, broken=broken, residents=tiers,
+        scheduler_configuration=cfg.get("scheduler_configuration"))
+    for job in jobs:
+        plain.submit(job)
+    full = [plain.full[a["id"]] for stubs in plain.allocs.values()
+            for a in stubs if a["id"] in plain.full]
+    compared, found = ref.judge(
+        fleet, backlog, jobs, plain.evals, plain.allocs, full, [], PORTS,
+        cfg["server"]["num_schedulers"], cfg["server"].get("decorrelation"),
+        ref.ResidentState(tiers, plain.resident_allocs()) if tiers else None)
+    assert "device_conflicts" not in compared and len(compared) == n
+    assert sha_of([compared, found, plain.allocs, full]) == sha
 
 
 @pytest.mark.parametrize("config", ["prod-10k", "svc-10k"])
